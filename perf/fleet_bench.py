@@ -25,7 +25,7 @@ match ROUTER.json's in-process 0.75 (the radix tree and digest
 protocol are the production classes in the children; only the model
 is stubbed, and hit rate is a pure control-plane quantity).
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/FLEET.json``.
 

@@ -25,7 +25,7 @@ The acceptance bar is the single-engine KV_TIER.json ample-tier
 baseline (prefill_work_avoided_frac 0.6154): the fleet number with hot
 sets sharded across 2 replicas must hold ≥ it.
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/KV_FABRIC.json``.
 

@@ -20,7 +20,7 @@ in ``perf/KV_QUANT.json``:
   (the factor by which the radix prefix cache's retention and the
   continuous engine's admissible slots grow at fixed HBM).
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/KV_QUANT.json``.
 

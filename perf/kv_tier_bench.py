@@ -27,7 +27,7 @@ snapshots"):
    from the durable snapshots vs regenerated — gated on every output
    matching the stub's pure-function golden bit-exactly.
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/KV_TIER.json``.
 

@@ -11,8 +11,7 @@ numbers).
    gap-free (``validate_cp_ring``), and the recorded
    ``hidden_fraction`` gated > 0 — the exchange for block i+1
    measurably flew UNDER block i's attention (the T3/A2A discipline,
-   host-stamped the same way perf/OVERLAP_RESULTS.md measures GEMM
-   overlap).
+   host-stamped).
 2. **sharded_decode** — a slot whose KV exceeds ``rank_page_budget``
    decodes as a sharded slot (resident paged window + tier-demoted
    cold pages, lse_combine partial merge) vs a big-pool reference:

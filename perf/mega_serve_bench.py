@@ -551,7 +551,7 @@ def main() -> int:
             "mega_fallback_steps/decode_steps ledgers",
             "caveat": "CPU wall-clock is interpret-mode-taxed and "
             "advisory; the platform-independent levers are dispatches/"
-            "token (the ~2 ms/dispatch relay tax amortized NS×) and "
+            "token (per-dispatch host cost amortized NS×) and "
             "bytes/token (unchanged by fusion)",
         },
     }

@@ -18,7 +18,7 @@ quantities are the migration primitive's costs and wins
   re-prefills the prompt). Exactness is asserted, not assumed: the
   migrated continuation must be bit-identical to the un-migrated run.
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/MIGRATION.json``.
 

@@ -38,7 +38,7 @@ def main(argv=None) -> int:
     p.add_argument("--steps", type=int, default=16)
     p.add_argument("--ns", type=int, default=8)
     p.add_argument("--layers", type=int, default=4,
-                   help="reduced depth (relay-gentle; geometry stays "
+                   help="reduced depth (a quick run; geometry stays "
                         "true 0.6B so tiles/DMAs are production-shaped)")
     p.add_argument("--cpu", action="store_true")
     args = p.parse_args(argv)

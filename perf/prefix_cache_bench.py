@@ -12,7 +12,7 @@ TTFT is measured exactly: each request is served with ``gen_len=1``, so
 ``run()`` returns right after admission emits the first token — prefill
 plus one sampling step, the part the prefix cache shortens.
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/PREFIX_CACHE.json``.
 

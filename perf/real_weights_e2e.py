@@ -15,8 +15,8 @@ upstream transformers is separately pinned by
 bit-identical at fp32), so a coherent run here certifies the
 checkpoint path, not the weights' knowledge.
 
-Relay safety (skill notes: heavy first contact can wedge the relay):
-defaults to a reduced depth; pass --full for true Qwen3-0.6B dims.
+Defaults to a reduced depth for a quick run; pass --full for true
+Qwen3-0.6B dims.
 
 Usage: python perf/real_weights_e2e.py [--full] [--mode mega_multi]
 """
@@ -159,7 +159,7 @@ def main(argv=None) -> int:
     prompt = np.arange(1, 33, dtype=np.int32)[None]
 
     # First serve is the WARM-UP (prefill + decode compiles, tens of
-    # seconds through the relay); the timed number comes from the
+    # seconds); the timed number comes from the
     # second, already-compiled call. The pair doubles as the greedy
     # determinism check.
     t0 = time.perf_counter()

@@ -16,7 +16,7 @@ Arrivals are served one at a time (``router.run`` per arrival, gen_len=1
 — the TTFT shape), so routing decisions see a current prefix mirror and
 both arms execute identical workloads deterministically.
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/ROUTER.json``.
 

@@ -8,7 +8,7 @@ drives it over the SOCKET protocol — ping, two generate requests (the
 repeat doubles as the greedy-determinism check), shutdown — and emits
 the wire-measured latency + tok/s.
 
-Defaults are relay-gentle (depth-8 0.6B geometry); --full for the true
+Defaults are a quick run (depth-8 0.6B geometry); --full for the true
 0.6B and --model for the headline presets.
 
 Usage: python perf/serve_demo.py [--mode mega] [--gen-len 32]

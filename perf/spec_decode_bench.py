@@ -13,7 +13,7 @@ to cost ~one decode step, which holds when decode is
 memory-bandwidth-bound); ``step_reduction`` is the platform-independent
 lever.
 
-Output follows perf/MEASURED.json conventions: one JSON object with a
+Output follows the perf/ convention: one JSON object with a
 ``provenance`` block, printed to stdout and written to
 ``perf/SPEC_DECODE.json`` (same shape as ``perf/PREFIX_CACHE.json`` so
 the bench-trajectory tooling picks both up).

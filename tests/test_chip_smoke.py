@@ -1,0 +1,143 @@
+"""CPU rehearsal of ``chip_smoke.py`` and the compile-cache rule.
+
+The smoke exists to fail when the chip is not there, so the first thing
+shown is that it does: unsteered, on this CPU, it exits non-zero before
+serving anything. The platform check is then steered FROM THE TEST (the
+script has no option for it) to show that the phases themselves pass at
+``tiny`` size through the same entry points, and that a phase that
+raises ends the run with no result line.
+"""
+
+import importlib.util
+import json
+import os
+
+import jax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py")
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture
+def steered(smoke, monkeypatch):
+    """The smoke with its platform check replaced, and no compile cache
+    left behind in the checkout (the cache rule has its own tests)."""
+    from triton_distributed_tpu.runtime import compile_cache
+
+    monkeypatch.setattr(smoke, "check_on_chip", lambda ctx=None: None)
+    monkeypatch.setattr(compile_cache, "enable_compile_cache",
+                        lambda: "off for the rehearsal")
+    return smoke
+
+
+@pytest.fixture
+def cache_config():
+    """Put JAX's compile-cache configuration back after a test that
+    moved it, so later tests in this worker compile as before."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
+def test_fails_without_a_tpu_before_serving(smoke, monkeypatch, capsys):
+    def never(*a, **kw):
+        raise AssertionError("a phase ran without a TPU")
+
+    monkeypatch.setattr(smoke, "serve_phase", never)
+    monkeypatch.setattr(smoke, "logit_checks", never)
+    with pytest.raises(SystemExit) as exit_:
+        smoke.main(["--model", "tiny"])
+    assert exit_.value.code not in (0, None)
+    assert "needs a TPU" in str(exit_.value.code)
+    assert capsys.readouterr().out == ""  # no result, no phase line
+
+
+def test_context_check_refuses_interpreted_kernels(smoke, monkeypatch):
+    """The second half of the device check: a context that interprets
+    its Pallas kernels is refused even when JAX reports a TPU."""
+    from triton_distributed_tpu.runtime import mesh
+
+    monkeypatch.setattr(
+        smoke, "device_facts",
+        lambda: {"platform": "tpu", "kind": "steered", "count": 1},
+    )
+    ctx = mesh.initialize_distributed(tp=1, devices=jax.devices()[:1])
+    try:
+        with pytest.raises(RuntimeError, match="not on the TPU"):
+            smoke.check_on_chip(ctx)
+    finally:
+        mesh.finalize_distributed()
+
+
+def test_phases_pass_at_tiny_size_when_steered(steered, capsys):
+    # Two served phases (full-width and int8 pools) through
+    # run_server.main and the socket, then the kernel and logit checks.
+    assert steered.main(["--model", "tiny", "--modes", "xla,int8"]) == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    last = lines[-1]
+    d = jax.devices()
+    assert last == {"ok": True, "device": {
+        "platform": d[0].platform, "kind": d[0].device_kind,
+        "count": len(d)}}
+    served = [ln for ln in lines if ln.get("phase") == "serve"]
+    assert [ln["mode"] for ln in served] == ["xla", "int8"]
+    assert all(ln["prefix_hit_tokens_warm"] > 0 for ln in served)
+    logits = next(ln for ln in lines if ln.get("phase") == "logits")
+    assert logits["rel_err"]["full"] <= logits["tolerance"]
+    # The int8 pool is the lower-precision path the full-width bound
+    # has to be able to tell apart.
+    assert logits["int8_exceeds_full_width_tolerance"]
+
+
+def test_a_phase_that_raises_fails_the_run(steered, monkeypatch, capsys):
+    def broken(model, mode):
+        raise RuntimeError(f"phase {mode} broke")
+
+    monkeypatch.setattr(steered, "serve_phase", broken)
+    with pytest.raises(RuntimeError, match="phase xla broke"):
+        steered.main(["--model", "tiny"])
+    out = capsys.readouterr().out
+    assert '"ok"' not in out
+
+
+def test_unknown_mode_is_refused(smoke):
+    with pytest.raises(SystemExit) as exit_:
+        smoke.main(["--modes", "xla,turbo"])
+    assert exit_.value.code == 2
+
+
+def test_cache_rule_env_set_sets_no_directory_in_code(
+        monkeypatch, tmp_path, cache_config):
+    from triton_distributed_tpu.runtime import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a, **kw: updates.append(a))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert updates == []
+
+
+def test_cache_rule_unset_uses_the_checkout(monkeypatch, cache_config):
+    from triton_distributed_tpu.runtime import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(ROOT, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -28,11 +28,6 @@ distributed compiler for overlapping kernels, reference layout documented in
 
 __version__ = "0.1.0"
 
-# Install hasattr-guarded aliases for JAX names this package uses that
-# older releases spell differently (no-op on current JAX). Must run
-# before any submodule touches jax.lax / pallas.
-from triton_distributed_tpu.runtime import jax_compat as _jax_compat  # noqa: F401
-
 from triton_distributed_tpu.runtime import (  # noqa: F401
     DistContext,
     current_context,

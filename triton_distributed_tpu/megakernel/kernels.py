@@ -32,27 +32,15 @@ from triton_distributed_tpu.megakernel.task import TR_MID, TaskType
 
 # -- device task tracer (docs/observability.md "Device task tracer") ---------
 #
-# Candidate cycle-counter primitives, probed in order: jaxlib 0.4.x
-# exposes none publicly, so the tracer's default clock is a LOGICAL
-# one — an SMEM counter bumped once per read. The Pallas grid is
-# sequential on a TPU core, so the logical clock is monotonic and
-# race-free by construction; under interpret it is fully deterministic.
-# On a jaxlib that grows a cycle counter the same records carry real
-# cycle timestamps with no decoder change (the decoder treats clock
-# values as opaque monotonic ticks either way).
-_CYCLE_PRIMS = ("get_cycle_count", "cycle_count", "get_timestamp")
+# The installed Pallas (jax 0.9.0 ``pallas.tpu``) exposes no cycle
+# counter, so the tracer's clock is a LOGICAL one — an SMEM counter
+# bumped once per read. The Pallas grid is sequential on a TPU core, so
+# the clock is monotonic and race-free by construction, and its values
+# order events; they are not durations.
 
 
 def trace_tick(kctx):
-    """One monotonic device-clock read for a trace-ring record: the
-    TPU cycle counter when the installed Pallas exposes one (Mosaic
-    builds only — interpret always uses the logical clock so tests are
-    deterministic), else the SMEM logical clock."""
-    if not kctx.interpret:
-        for name in _CYCLE_PRIMS:
-            prim = getattr(pltpu, name, None)
-            if prim is not None:
-                return prim().astype(jnp.int32)
+    """One monotonic logical-clock read for a trace-ring record."""
     c = kctx.clk[0] + 1
     kctx.clk[0] = c
     return c
@@ -390,15 +378,8 @@ def _stream_rows(kctx, x_ref, w_hbm, out_ref, n: int, tk: int,
 
 
 def _barrier(kctx):
-    """Cross-rank barrier, skipped under the interpret path: discharge-
-    based interpret executes every remote DMA synchronously at its
-    program point, so the barrier's temporal ordering is vacuous there
-    (and 0.4.x interpret has no barrier-semaphore support). Mosaic
-    builds — including TPU-targeted AOT lowering traced on a CPU host —
-    keep every barrier (``kctx.interpret`` comes from the build ctx,
-    not the process backend)."""
-    if not kctx.interpret:
-        dl.barrier_all(kctx.axis)
+    """Cross-rank barrier over the kernel's mesh axis."""
+    dl.barrier_all(kctx.axis)
 
 
 def _ar_put_dmas(kctx):

@@ -34,8 +34,8 @@ def _attn_kernel(
     q_ref,    # [1, block_q, d] VMEM
     k_ref,    # [1, block_k, d] VMEM — full-width, or int8 codes
     v_ref,    # [1, block_k, d] VMEM
-    ks_ref,   # [1, 1] VMEM f32 or None — this kv block's K dequant scale
-    vs_ref,   # [1, 1] VMEM f32 or None — this kv block's V dequant scale
+    ks_ref,   # [B*Hkv*num_k] f32 SMEM (scalar prefetch) or None — K scales
+    vs_ref,   # [B*Hkv*num_k] f32 SMEM (scalar prefetch) or None — V scales
     b_ref,    # [block_q, block_k] VMEM f32 or None — additive score bias
     o_ref,    # [1, block_q, d] VMEM
     lse_ref,  # [1, 1, sq] VMEM or None — full row; slice qi written at
@@ -50,10 +50,13 @@ def _attn_kernel(
     kv_offset: int,
     block_q: int,
     block_k: int,
+    group: int,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     num_k = pl.num_programs(2)
+    # This (batch·kv head, kv block)'s slot in the flattened scales.
+    si = (pl.program_id(0) // group) * num_k + ki
     kv_offset = kv_offset if off_ref is None else off_ref[0]
 
     @pl.when(ki == 0)
@@ -73,7 +76,7 @@ def _attn_kernel(
         # In-register dequant (int8 KV): the per-block symmetric scale
         # is a scalar, so it folds into the softmax multiplier after
         # QK^T — full-width K never materializes.
-        mult = sm_scale if ks_ref is None else sm_scale * ks_ref[0, 0]
+        mult = sm_scale if ks_ref is None else sm_scale * ks_ref[si]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * mult  # [block_q, block_k]
@@ -104,7 +107,7 @@ def _attn_kernel(
             pv = jnp.dot(
                 p, v_ref[0].astype(jnp.float32),
                 preferred_element_type=jnp.float32,
-            ) * vs_ref[0, 0]
+            ) * vs_ref[si]
         acc[:] = acc[:] * alpha + pv
         m_i[:] = m_new
 
@@ -213,12 +216,12 @@ def flash_attention(
 
     out_shape = [jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype)]
     out_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+        pl.BlockSpec((1, block_q, d), lambda bh, qi, ki, *_: (bh, qi, 0)),
     ]
     if return_lse:
         out_shape.append(jax.ShapeDtypeStruct((b * hq, 1, sq), jnp.float32))
         out_specs.append(
-            pl.BlockSpec((1, 1, sq), lambda bh, qi, ki: (bh, 0, 0))
+            pl.BlockSpec((1, 1, sq), lambda bh, qi, ki, *_: (bh, 0, 0))
         )
 
     kernel = functools.partial(
@@ -228,80 +231,57 @@ def flash_attention(
         kv_offset=0 if dynamic_off else kv_offset,
         block_q=block_q,
         block_k=block_k,
+        group=group,
     )
     kernel = functools.partial(
         _adapt_refs, kernel, dynamic_off, quant, bias is not None,
         return_lse,
     )
+    # Index maps take the scalar-prefetch refs as trailing args.
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+        pl.BlockSpec((1, block_q, d), lambda bh, qi, ki, *_: (bh, qi, 0)),
         pl.BlockSpec(
-            (1, block_k, d), lambda bh, qi, ki, g=group: (bh // g, ki, 0)
+            (1, block_k, d), lambda bh, qi, ki, *_: (bh // group, ki, 0)
         ),
         pl.BlockSpec(
-            (1, block_k, d), lambda bh, qi, ki, g=group: (bh // g, ki, 0)
+            (1, block_k, d), lambda bh, qi, ki, *_: (bh // group, ki, 0)
         ),
     ]
     operands = [qf, kf, vf]
-    if quant:
-        in_specs += [
-            pl.BlockSpec(
-                (1, 1), lambda bh, qi, ki, g=group: (bh // g, ki)
-            ),
-            pl.BlockSpec(
-                (1, 1), lambda bh, qi, ki, g=group: (bh // g, ki)
-            ),
-        ]
-        operands += [
-            k_scale.reshape(b * hkv, -1), v_scale.reshape(b * hkv, -1)
-        ]
     if bias is not None:
         in_specs.append(
-            pl.BlockSpec((block_q, block_k), lambda bh, qi, ki: (qi, ki))
+            pl.BlockSpec((block_q, block_k), lambda bh, qi, ki, *_: (qi, ki))
         )
         operands.append(bias.astype(jnp.float32))
-    scratch_shapes = [
-        pltpu.VMEM((block_q, d), jnp.float32),
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, 1), jnp.float32),
-    ]
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-    )
+    # Scalar-prefetch (SMEM) operands: a traced kv offset, so one
+    # compiled kernel serves every chunk offset, and the int8 scales —
+    # one f32 per (kv head, kv block), read as scalars. A (1, 1) VMEM
+    # block of the scale array is below Mosaic's (8, 128) tile and is
+    # refused by the TPU compiler.
+    scalars = []
     if dynamic_off:
-        # Dynamic offset rides as scalar prefetch; index maps gain the
-        # scalar ref as a trailing arg (flash_decode's paged idiom).
-        off = jnp.asarray(kv_offset, jnp.int32).reshape(1)
-        res = pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=grid,
-                in_specs=[
-                    pl.BlockSpec(s.block_shape, _drop_scalar_arg(s.index_map))
-                    for s in in_specs
-                ],
-                out_specs=[
-                    pl.BlockSpec(s.block_shape, _drop_scalar_arg(s.index_map))
-                    for s in out_specs
-                ],
-                scratch_shapes=scratch_shapes,
-            ),
-            out_shape=out_shape,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(off, *operands)
-    else:
-        res = pl.pallas_call(
-            kernel,
+        scalars.append(jnp.asarray(kv_offset, jnp.int32).reshape(1))
+    if quant:
+        scalars += [k_scale.reshape(-1), v_scale.reshape(-1)]
+    res = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=scratch_shapes,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(*operands)
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(*scalars, *operands)
 
     o = res[0].reshape(b, hq, sq, d)
     if return_lse:
@@ -309,26 +289,19 @@ def flash_attention(
     return o
 
 
-def _drop_scalar_arg(index_map):
-    """Index map adapted for PrefetchScalarGridSpec (which appends the
-    scalar-prefetch ref as a trailing arg the plain map doesn't take)."""
-    return lambda bh, qi, ki, _off: index_map(bh, qi, ki)
-
-
 def _adapt_refs(kernel, has_off: bool, has_scales: bool, has_bias: bool,
                 has_lse: bool, *refs):
     """Route pallas_call's positional refs into ``_attn_kernel``'s
-    keyword-stable signature: optional scalar-prefetch offset first,
-    optional int8 dequant scales after v, optional score bias, optional
-    lse output, then the three scratch refs."""
+    keyword-stable signature: the scalar-prefetch refs first (optional
+    kv offset, optional int8 dequant scales), then q/k/v, optional
+    score bias, optional lse output, then the three scratch refs."""
     refs = list(refs)
     off_ref = refs.pop(0) if has_off else None
-    q_ref, k_ref, v_ref = refs[:3]
-    nxt = 3
     ks_ref = vs_ref = None
     if has_scales:
-        ks_ref, vs_ref = refs[3:5]
-        nxt = 5
+        ks_ref, vs_ref = refs.pop(0), refs.pop(0)
+    q_ref, k_ref, v_ref = refs[:3]
+    nxt = 3
     b_ref = None
     if has_bias:
         b_ref = refs[nxt]

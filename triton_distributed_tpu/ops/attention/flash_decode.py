@@ -36,8 +36,8 @@ def _decode_body(
     q_ref,       # [1, 1, group, d] VMEM
     k_ref,       # [1, 1, chunk, d] VMEM — full-width, or int8 codes
     v_ref,       # [1, 1, chunk, d] VMEM
-    ks_ref,      # [1, 1, 1] VMEM f32 or None — this chunk's K dequant scale
-    vs_ref,      # [1, 1, 1] VMEM f32 or None — this chunk's V dequant scale
+    ks_ref,      # [B*Hkv*C] f32 SMEM (scalar prefetch) or None — K scales
+    vs_ref,      # [B*Hkv*C] f32 SMEM (scalar prefetch) or None — V scales
     o_ref,       # [1, 1, 1, group, d] VMEM f32 — partial output, chunk ci
     lse_ref,     # [1, 1, C, group] VMEM f32 — full chunk column, row ci
                  # written per step (Mosaic needs the block's trailing two
@@ -49,6 +49,8 @@ def _decode_body(
     b = pl.program_id(0)
     ci = pl.program_id(2)
     start = ci * chunk_k
+    # This (sequence, kv head, chunk)'s slot in the flattened scales.
+    si = (b * pl.num_programs(1) + pl.program_id(1)) * pl.num_programs(2) + ci
     valid = kv_len_ref[b] - start  # may be <=0 (fully masked chunk)
 
     @pl.when(valid > 0)
@@ -60,7 +62,7 @@ def _decode_body(
         # scalar, so it folds into the softmax multiplier AFTER QK^T —
         # the MXU sees the raw int8-widened codes and full-width K
         # never exists anywhere (not even in VMEM).
-        mult = sm_scale if ks_ref is None else sm_scale * ks_ref[0, 0, 0]
+        mult = sm_scale if ks_ref is None else sm_scale * ks_ref[si]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * mult  # [group, chunk]
@@ -79,7 +81,7 @@ def _decode_body(
             o = jnp.dot(
                 p, v_ref[0, 0].astype(jnp.float32),
                 preferred_element_type=jnp.float32,
-            ) * vs_ref[0, 0, 0]
+            ) * vs_ref[si]
         o_ref[0, 0, 0] = o / l
         lse_ref[0, 0, ci] = (m + jnp.log(l))[:, 0]
 
@@ -96,7 +98,7 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, **kw):
 
 
 def _decode_kernel_q(
-    kv_len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, lse_ref, **kw
+    kv_len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, **kw
 ):
     _decode_body(
         kv_len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, lse_ref, **kw
@@ -185,22 +187,18 @@ def flash_decode(
 
     qg = q.reshape(b, hkv, group, d)
     grid = (b, hkv, num_chunks)
+    # Index maps receive the scalar-prefetch refs as trailing args.
     in_specs = [
-        pl.BlockSpec((1, 1, group, d), lambda b, h, ci, _: (b, h, 0, 0)),
-        pl.BlockSpec(
-            (1, 1, chunk_k, d), lambda b, h, ci, _: (b, h, ci, 0)
-        ),
-        pl.BlockSpec(
-            (1, 1, chunk_k, d), lambda b, h, ci, _: (b, h, ci, 0)
-        ),
+        pl.BlockSpec((1, 1, group, d), lambda b, h, ci, *_: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, chunk_k, d), lambda b, h, ci, *_: (b, h, ci, 0)),
+        pl.BlockSpec((1, 1, chunk_k, d), lambda b, h, ci, *_: (b, h, ci, 0)),
     ]
-    operands = [qg, k_cache, v_cache]
+    scalars = [kv_len]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, 1), lambda b, h, ci, _: (b, h, ci)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, ci, _: (b, h, ci)),
-        ]
-        operands += [k_scale, v_scale]
+        # One f32 per (sequence, head, chunk): read as scalars from
+        # SMEM. A (1, 1, 1) VMEM block of the scale array is below
+        # Mosaic's (8, 128) tile and is refused by the TPU compiler.
+        scalars += [k_scale.reshape(-1), v_scale.reshape(-1)]
     kernel = functools.partial(
         _decode_kernel_q if quant else _decode_kernel,
         sm_scale=sm_scale, chunk_k=chunk_k,
@@ -208,16 +206,15 @@ def flash_decode(
     o_parts, lse_parts = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=grid,
-            # index maps receive the scalar-prefetch ref as a trailing arg
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec(
-                    (1, 1, 1, group, d), lambda b, h, ci, _: (b, h, ci, 0, 0)
+                    (1, 1, 1, group, d), lambda b, h, ci, *_: (b, h, ci, 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, 1, num_chunks, group), lambda b, h, ci, _: (b, h, 0, 0)
+                    (1, 1, num_chunks, group), lambda b, h, ci, *_: (b, h, 0, 0)
                 ),
             ],
         ),
@@ -229,7 +226,7 @@ def flash_decode(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=resolved,
-    )(kv_len, *operands)
+    )(*scalars, qg, k_cache, v_cache)
 
     o, lse = lse_combine(o_parts, lse_parts, part_axis=2)  # [B, Hkv, group, d]
     o = o.reshape(b, hq, d).astype(q.dtype)
@@ -302,34 +299,28 @@ def paged_flash_decode(
     qg = q.reshape(b, hkv, group, d)
     grid = (b, hkv, pps)
     in_specs = [
-        pl.BlockSpec(
-            (1, 1, group, d), lambda b, h, ci, _, __: (b, h, 0, 0)
-        ),
+        pl.BlockSpec((1, 1, group, d), lambda b, h, ci, *_: (b, h, 0, 0)),
         # The paged part: block ci of row b is pool page
         # table[b, ci].
         pl.BlockSpec(
             (1, 1, page, d),
-            lambda b, h, ci, _, tab: (tab[b, ci], h, 0, 0),
+            lambda b, h, ci, _, tab, *__: (tab[b, ci], h, 0, 0),
         ),
         pl.BlockSpec(
             (1, 1, page, d),
-            lambda b, h, ci, _, tab: (tab[b, ci], h, 0, 0),
+            lambda b, h, ci, _, tab, *__: (tab[b, ci], h, 0, 0),
         ),
     ]
-    operands = [qg, k_pages, v_pages]
+    scalars = [kv_len, page_table]
     if quant:
-        # Scales ride the same table indirection as their pages
-        # (trailing singleton so the kernel reads a uniform [1,1,1]
-        # block in both the dense and paged layouts).
-        in_specs += [
-            pl.BlockSpec(
-                (1, 1, 1), lambda b, h, ci, _, tab: (tab[b, ci], h, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, 1), lambda b, h, ci, _, tab: (tab[b, ci], h, 0)
-            ),
+        # Scales follow their pages through the table HERE, in XLA (a
+        # [B, pps, Hkv] gather of f32), and reach the kernel as
+        # flattened SMEM scalars in the dense kernel's
+        # (sequence, head, chunk) order — see flash_decode.
+        scalars += [
+            jnp.swapaxes(jnp.take(sc, page_table, axis=0), 1, 2).reshape(-1)
+            for sc in (k_scale, v_scale)
         ]
-        operands += [k_scale[..., None], v_scale[..., None]]
     kernel = functools.partial(
         _paged_decode_kernel_q if quant else _paged_decode_kernel,
         sm_scale=sm_scale, chunk_k=page,
@@ -337,15 +328,15 @@ def paged_flash_decode(
     o_parts, lse_parts = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # kv_len, page_table
+            num_scalar_prefetch=len(scalars),
             grid=grid,
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec(
-                    (1, 1, 1, group, d), lambda b, h, ci, _, __: (b, h, ci, 0, 0)
+                    (1, 1, 1, group, d), lambda b, h, ci, *_: (b, h, ci, 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, 1, pps, group), lambda b, h, ci, _, __: (b, h, 0, 0)
+                    (1, 1, pps, group), lambda b, h, ci, *_: (b, h, 0, 0)
                 ),
             ],
         ),
@@ -357,7 +348,7 @@ def paged_flash_decode(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=resolved,
-    )(kv_len, page_table, *operands)
+    )(*scalars, qg, k_pages, v_pages)
 
     o, lse = lse_combine(o_parts, lse_parts, part_axis=2)
     o = o.reshape(b, hq, d).astype(q.dtype)

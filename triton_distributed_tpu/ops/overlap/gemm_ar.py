@@ -251,11 +251,21 @@ def gemm_ar(
 
     out_bytes = m * n_out * a.dtype.itemsize
     if method == GemmARMethod.AUTO:
+        # Both kernels slice the [M, N] workspace by rows, and Mosaic
+        # refuses a slice that does not cover whole sublane tiles
+        # ("Slice shape ... must be aligned to tiling"): the one-shot
+        # kernel takes all M rows — whole 8-row tiles, or one
+        # power-of-two tile no smaller than a packed 32-bit row — and
+        # the two-shot ring halves each rank's M/n-row chunk. Any other
+        # M (an odd --max-batch, a 96-token chunk at tp=4) goes to XLA.
+        rows_ok = m % 8 == 0 or (
+            m in (1, 2, 4) and m * a.dtype.itemsize >= 4
+        )
         if not device_initiable(axis, ctx):
             method = GemmARMethod.XLA
-        elif out_bytes <= _ONE_SHOT_MAX_BYTES:
+        elif out_bytes <= _ONE_SHOT_MAX_BYTES and rows_ok:
             method = GemmARMethod.ONE_SHOT
-        elif m % n == 0 and out_bytes <= VMEM_COMM_MAX_BYTES:
+        elif m % (16 * n) == 0 and out_bytes <= VMEM_COMM_MAX_BYTES:
             # The trailing ring all-gather holds the full [M, N] in VMEM.
             method = GemmARMethod.TWO_SHOT
         else:

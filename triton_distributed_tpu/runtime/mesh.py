@@ -36,16 +36,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at top level (check_vma kwarg)
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax uses check_rep
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
+shard_map = jax.shard_map
 
 # Canonical axis names, outermost (least communication) to innermost
 # (most communication → contiguous ICI). Mirrors the scaling-book recipe:
@@ -70,6 +61,8 @@ class MeshTopology:
     devices_per_process: int
     torus_shape: tuple[int, ...] | None = None  # physical ICI grid dims
     has_wraparound: bool | None = None  # any torus dim with wrap links
+    # Which rung of ``_tpu_device_grid`` arranged the mesh (TPU only).
+    mesh_rung: str | None = None
 
     @property
     def on_tpu(self) -> bool:
@@ -268,31 +261,43 @@ def snake_ring_order(coords: np.ndarray) -> np.ndarray:
 
 def _tpu_device_grid(
     devices: list[jax.Device], shape: tuple[int, ...]
-) -> np.ndarray:
+) -> tuple[np.ndarray, str]:
     """Arrange TPU devices so the innermost mesh axis rides contiguous ICI.
 
+    Returns the grid and the name of the rung that built it.
     ``jax.experimental.mesh_utils.create_device_mesh`` does the real
     assignment from physical coords; it requires the full device set of
-    the slice. For subsets (or when it declines), fall back to the snake
-    ring over coords so consecutive innermost-axis entries are still
-    one-hop neighbors; last resort is enumeration order.
+    the slice. For subsets (or when it declines) the snake ring over
+    coords keeps consecutive innermost-axis entries one-hop neighbours.
+    There is no enumeration-order rung: on a 2x2 without wraparound it
+    can put "ring neighbours" two hops apart, so when neither
+    topology-aware rung can build the grid this raises with both
+    reasons.
     """
+    declined = []
     if len(devices) == len(jax.devices()):
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            return mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
-            pass
+        try:
+            grid = mesh_utils.create_device_mesh(shape, devices=devices)
+            return grid, "create_device_mesh"
+        except (ValueError, NotImplementedError, AssertionError) as e:
+            declined.append(f"create_device_mesh: {e}")
     try:
         coords = np.asarray([d.coords for d in devices])
         order = snake_ring_order(coords)
-        return np.asarray(devices)[order].reshape(shape)
-    except Exception:
-        return np.asarray(devices).reshape(shape)
+        return np.asarray(devices)[order].reshape(shape), "snake_ring"
+    except (AttributeError, ValueError) as e:
+        declined.append(f"snake_ring: {e}")
+    raise RuntimeError(
+        f"no topology-aware arrangement of {len(devices)} TPU devices "
+        f"into {shape}: " + "; ".join(declined)
+    )
 
 
-def _detect_topology(devices: Sequence[jax.Device]) -> MeshTopology:
+def _detect_topology(
+    devices: Sequence[jax.Device], mesh_rung: str | None = None
+) -> MeshTopology:
     platform = devices[0].platform
     num_processes = jax.process_count()
     torus_shape = None
@@ -309,8 +314,8 @@ def _detect_topology(devices: Sequence[jax.Device]) -> MeshTopology:
             elif "lite" in kind or "v5e" in kind or "v6e" in kind:
                 has_wrap = False  # 2D-mesh generations: no wrap links
             # else: unknown generation — leave None
-        except Exception:
-            pass
+        except AttributeError:
+            pass  # a TPU device object without coords: facts stay None
     return MeshTopology(
         num_devices=len(devices),
         num_processes=num_processes,
@@ -319,6 +324,7 @@ def _detect_topology(devices: Sequence[jax.Device]) -> MeshTopology:
         devices_per_process=max(1, len(devices) // num_processes),
         torus_shape=torus_shape,
         has_wraparound=has_wrap,
+        mesh_rung=mesh_rung,
     )
 
 
@@ -385,12 +391,13 @@ def initialize_distributed(
     if not ordered:
         ordered, shape = ["dp"], (n,)
 
+    mesh_rung = None
     if devices[0].platform == "tpu":
-        dev_array = _tpu_device_grid(devices, shape)
+        dev_array, mesh_rung = _tpu_device_grid(devices, shape)
     else:
         dev_array = np.asarray(devices).reshape(shape)
     mesh = Mesh(dev_array, tuple(ordered))
-    ctx = DistContext(mesh, _detect_topology(devices))
+    ctx = DistContext(mesh, _detect_topology(devices, mesh_rung))
     if set_as_current:
         set_context(ctx)
     return ctx

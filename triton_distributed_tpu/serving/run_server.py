@@ -29,6 +29,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import itertools
 import os
 import sys
 
@@ -519,6 +520,7 @@ def main(argv=None) -> int:
                     max_batch=args.stub_max_batch,
                 )
         else:
+            chip_ids = itertools.count()
             child = [
                 sys.executable, "-m",
                 "triton_distributed_tpu.serving.run_server",
@@ -559,6 +561,21 @@ def main(argv=None) -> int:
 
             def make_spec(name: str, role: str = "mixed") -> ReplicaSpec:
                 argv_i = list(child)
+                # One chip per child. A TPU chip belongs to one process
+                # at a time, so children that all see every chip
+                # contend for chip 0 and all but one fail; this process
+                # never touches JAX, and each tp=1 child is shown its
+                # own chip (libtpu reads these; they mean nothing on
+                # other platforms). A child past the host's chip count
+                # fails at its own start-up with libtpu's reason.
+                # tp > 1 children get no placement yet (ROADMAP R2).
+                env_i = None
+                if args.tp == 1:
+                    env_i = {
+                        "TPU_VISIBLE_CHIPS": str(next(chip_ids)),
+                        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                        "TPU_PROCESS_BOUNDS": "1,1,1",
+                    }
                 if args.tier_dir:
                     # Default: per-child tier dirs — one disk tier per
                     # engine (digest-keyed entries would be content-
@@ -576,7 +593,7 @@ def main(argv=None) -> int:
                         (args.tier_dir if args.tier_shared
                          else os.path.join(args.tier_dir, name)),
                     ]
-                return ReplicaSpec(name, argv_i, role=role)
+                return ReplicaSpec(name, argv_i, env=env_i, role=role)
 
         specs = [make_spec(name, role) for name, role in members]
         launcher = None
@@ -680,10 +697,46 @@ def main(argv=None) -> int:
 
     from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.engine import Engine
+    from triton_distributed_tpu.runtime.compile_cache import (
+        enable_compile_cache,
+    )
     from triton_distributed_tpu.runtime.mesh import initialize_distributed
 
-    ctx = initialize_distributed(tp=args.tp, devices=jax.devices()[: args.tp])
-    model = AutoLLM.from_pretrained(model_name, ctx=ctx, **overrides)
+    cache_dir = enable_compile_cache()
+    devices = jax.devices()
+    # On TPU each replica gets a device slice and a model of its own;
+    # stacking replicas on one chip buys nothing and hides the others,
+    # so more replicas than slices is refused. Off TPU (tests, CPU
+    # demos) the replicas share one model on the first slice: the CPU
+    # backend's devices are one host, and a second copy buys nothing.
+    on_tpu = devices[0].platform == "tpu"
+    n_slices = len(devices) // args.tp
+    if on_tpu and args.replicas > n_slices:
+        p.error(
+            f"--replicas {args.replicas} with --tp {args.tp} needs "
+            f"{args.replicas * args.tp} chips and this host has "
+            f"{len(devices)}: replicas would share chips. Use at most "
+            f"--replicas {n_slices}."
+        )
+    models = []
+    for j in range(max(args.replicas, 1) if on_tpu else 1):
+        # The LAST context built stays the process-global one; every
+        # model carries its own, and all of them agree on the platform.
+        ctx = initialize_distributed(
+            tp=args.tp, devices=devices[j * args.tp: (j + 1) * args.tp]
+        )
+        models.append(
+            AutoLLM.from_pretrained(model_name, ctx=ctx, **overrides)
+        )
+    model = models[0]
+    # Say what the kernels run on: an interpreted run on a host that
+    # lost its chip must not read like a chip run.
+    pallas = ("compiled by Mosaic" if ctx.pallas_interpret() is False
+              else "INTERPRETED")
+    rung = f", mesh by {ctx.topology.mesh_rung}" if ctx.on_tpu else ""
+    print(f"device: {devices[0].platform} {devices[0].device_kind!r} "
+          f"x{len(devices)} (tp={args.tp}{rung}); Pallas kernels "
+          f"{pallas}; compile cache {cache_dir}")
     # --trace: device-side kernel tracing rides the mega engines only
     # (the xla/pallas paths have no device ring); host profiling wraps
     # the run regardless of mode.
@@ -707,7 +760,8 @@ def main(argv=None) -> int:
             )
         engines = [
             ContinuousEngine(
-                model, max_batch=args.max_batch, mode=args.mode,
+                models[i % len(models)], max_batch=args.max_batch,
+                mode=args.mode,
                 temperature=args.temperature, prefix_cache=True,
                 kv_dtype=args.kv_dtype, speculative=args.speculative,
                 kernel_trace=kernel_trace,
