@@ -324,123 +324,45 @@ def test_trace_span_numeric_args_survive_in_ring():
     assert clash[0].fields["dur_s"] >= 0.0
 
 
-def test_trace_span_float_probe_cached(monkeypatch):
-    """Regression: a profiler that rejects float metadata pays ONE
-    failed TraceAnnotation construction ever — the rejection is
-    remembered (``_FLOAT_META_OK``) and later float spans go straight
-    to the stringified form instead of raising/catching per span."""
+@pytest.mark.parametrize("where", ["construct", "enter", "exit"])
+def test_trace_span_profiler_failure_never_sinks_body(monkeypatch, where):
+    """A profiler API mismatch costs the span, never the body: one
+    conversion, one ``try`` (the type ladder went with PR 26). The
+    ring mirror keeps the span and its native numerics all the same."""
     from triton_distributed_tpu.runtime import profiling
 
-    attempts = []
+    seen = []
 
-    class RejectsFloats:
+    class Broken:
         def __init__(self, name, **kwargs):
-            attempts.append(kwargs)
-            if any(isinstance(v, float) for v in kwargs.values()):
-                raise TypeError("no float metadata")
+            seen.append(kwargs)
+            if where == "construct":
+                raise RuntimeError("profiler API mismatch")
 
         def __enter__(self):
+            if where == "enter":
+                raise RuntimeError("profiler API mismatch")
             return self
 
         def __exit__(self, *exc):
+            if where == "exit":
+                raise RuntimeError("profiler API mismatch")
             return False
 
-    monkeypatch.setattr(
-        profiling.jax.profiler, "TraceAnnotation", RejectsFloats
-    )
-    monkeypatch.setattr(profiling, "_FLOAT_META_OK", None)
-    monkeypatch.setattr(profiling, "_STR_META_ONLY", False)
-    with profiling.trace_span("t:probe1", rate=0.5):
-        pass
-    # First float span: failed float probe + stringified retry.
-    assert len(attempts) == 2
-    assert profiling._FLOAT_META_OK is False
-    with profiling.trace_span("t:probe2", rate=0.25):
-        pass
-    # Cached: exactly one (stringified) construction, no re-probe.
-    assert len(attempts) == 3
-    assert isinstance(attempts[-1]["rate"], str)
-    # The ring mirror still keeps the float native either way.
-    evts, _ = obs_events.default_ring().tail(0)
-    p2 = [e for e in evts if e.kind == "span"
-          and e.fields.get("name") == "t:probe2"]
-    assert len(p2) == 1 and p2[0].fields["rate"] == 0.25
-
-    # A WHOLLY broken profiler (every construction raises) settles the
-    # FLOAT probe (later float spans skip the native-float rung) but
-    # NOT the stringify ladder position — a total failure may be
-    # transient and must not downgrade future spans' metadata.
-    class AlwaysRaises:
-        def __init__(self, name, **kwargs):
-            attempts.append(kwargs)
-            raise RuntimeError("profiler API mismatch")
-
-    monkeypatch.setattr(
-        profiling.jax.profiler, "TraceAnnotation", AlwaysRaises
-    )
-    monkeypatch.setattr(profiling, "_FLOAT_META_OK", None)
-    monkeypatch.setattr(profiling, "_STR_META_ONLY", False)
-    n0 = len(attempts)
-    with profiling.trace_span("t:broken1", rate=0.5):
-        pass
-    # Unsettled ladder: float probe + int retry + uniform stringify.
-    assert len(attempts) == n0 + 3
-    assert profiling._FLOAT_META_OK is False
-    assert profiling._STR_META_ONLY is False
-    with profiling.trace_span("t:broken2", rate=0.5):
-        pass
-    # Float probe settled: the float rung is skipped, the rest of the
-    # ladder still runs (the failure could have been transient).
-    assert len(attempts) == n0 + 5
-
-
-def test_trace_span_uniform_stringify_fallback(monkeypatch):
-    """Regression (ISSUE 8): a profiler that rejects a NON-float arg
-    type too (here: any non-str metadata) used to lose the span — and
-    its args — on the retry path. The uniform stringify rung must keep
-    the span alive with all-string args, remember the ladder position,
-    and leave the ring mirror's numerics native."""
-    from triton_distributed_tpu.runtime import profiling
-
-    entered = []
-
-    class StrOnly:
-        def __init__(self, name, **kwargs):
-            if any(not isinstance(v, str) for v in kwargs.values()):
-                raise TypeError("string metadata only")
-            self.kwargs = kwargs
-
-        def __enter__(self):
-            entered.append(self.kwargs)
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-    monkeypatch.setattr(
-        profiling.jax.profiler, "TraceAnnotation", StrOnly
-    )
-    monkeypatch.setattr(profiling, "_FLOAT_META_OK", None)
-    monkeypatch.setattr(profiling, "_STR_META_ONLY", False)
-    # Mixed arg types INCLUDING a non-float the old retry path lost:
-    # floats stringified on rung 2 still left the int native, so rung
-    # 2 failed too and the span vanished.
-    with profiling.trace_span("t:mixed", rate=0.5, slot=3, tag="x"):
-        pass
-    assert len(entered) == 1  # the span survived
-    assert entered[0] == {"rate": "0.5", "slot": "3", "tag": "x"}
-    assert profiling._STR_META_ONLY is True
-    # Settled: the next span goes straight to the stringify rung.
-    with profiling.trace_span("t:mixed2", slot=4):
-        pass
-    assert len(entered) == 2
-    assert entered[1] == {"slot": "4"}
-    # Ring mirror keeps numerics native regardless of profiler mode.
+    monkeypatch.setattr(profiling.jax.profiler, "TraceAnnotation", Broken)
+    ran = []
+    with profiling.trace_span("t:broken", rate=0.5, slot=3, tag=[1]):
+        ran.append(1)
+    assert ran == [1]
+    # One construction, typed primitives kept, the rest stringified.
+    assert seen == [{"rate": 0.5, "slot": 3, "tag": "[1]"}]
     evts, _ = obs_events.default_ring().tail(0)
     mine = [e for e in evts if e.kind == "span"
-            and e.fields.get("name") == "t:mixed"]
-    assert len(mine) == 1
-    assert mine[0].fields["rate"] == 0.5 and mine[0].fields["slot"] == 3
+            and e.fields.get("name") == "t:broken"]
+    assert len(mine) == 1 and mine[0].fields["rate"] == 0.5
+    with pytest.raises(KeyError):
+        with profiling.trace_span("t:raises"):
+            raise KeyError("body exceptions propagate")
 
 
 # -- timelines ---------------------------------------------------------------

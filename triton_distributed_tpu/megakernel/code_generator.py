@@ -874,6 +874,7 @@ def build_mega_call(
 
     call = pl.pallas_call(
         kernel,
+        name="tdt_megakernel",
         grid_spec=grid_spec,
         cost_estimate=cost,
         # The kernel reads the KV cache but does not write it: appending

@@ -346,14 +346,14 @@ class MegaQwen3:
         )
         V = m.cfg.vocab_size
 
-        def f(params, tokens, cache):
+        def tdt_mega_step(params, tokens, cache):
             outs = g(params, tokens, cache)
             # Drop vocab-pad logits (zero-weight columns score 0 and
             # could beat real logits under greedy sampling).
             return (outs[0][:, :V], *outs[1:])
 
-        step = jax.jit(f, donate_argnums=(2,))
-        return compiled, step, f
+        step = jax.jit(tdt_mega_step, donate_argnums=(2,))
+        return compiled, step, tdt_mega_step
 
     def _q8_specs(self) -> Q8Params:
         ax = self.model.axis
@@ -387,7 +387,11 @@ class MegaQwen3:
                 in_specs=(m.param_specs,),
                 out_specs=self._q8_specs(),
             )
-            self._q8 = jax.jit(f)(m.params)
+
+            def tdt_mega_quantize(params):
+                return f(params)
+
+            self._q8 = jax.jit(tdt_mega_quantize)(m.params)
             jax.block_until_ready(self._q8)
         return self._q8
 
@@ -417,7 +421,7 @@ class MegaQwen3:
         v_pad = pad_vocab(c.vocab_size, n)
         dt = c.dtype
 
-        def build(k):
+        def tdt_mega_quantized_init(k):
             ks = iter(jax.random.split(k, 7))
 
             def w8(*shape):
@@ -447,7 +451,9 @@ class MegaQwen3:
             lambda s: m.ctx.sharding(*s), self._q8_specs(),
             is_leaf=lambda x: isinstance(x, P),
         )
-        self._q8 = jax.jit(build, out_shardings=shardings)(key)
+        self._q8 = jax.jit(
+            tdt_mega_quantized_init, out_shardings=shardings
+        )(key)
         jax.block_until_ready(self._q8)
         return self._q8
 
@@ -560,7 +566,11 @@ class MegaQwen3:
                 in_specs=(m.param_specs,),
                 out_specs=self._moe_specs(),
             )
-            self._moe_p = jax.jit(f)(m.params)
+
+            def tdt_mega_moe_reshard(params):
+                return f(params)
+
+            self._moe_p = jax.jit(tdt_mega_moe_reshard)(m.params)
             jax.block_until_ready(self._moe_p)
         return self._moe_p
 
@@ -789,7 +799,7 @@ class MegaQwen3:
             out_specs=out_specs,
         )
 
-        def f(params, tokens, cache, *extra):
+        def tdt_mega_round(params, tokens, cache, *extra):
             toks, logits, *rest = g(params, tokens, cache, *extra)
             # toks [nsteps, B]; logits are the LAST step's (pad cols
             # dropped as in the single-step path). Trace builds append
@@ -799,7 +809,7 @@ class MegaQwen3:
         # Donated cache: the nsteps-row dynamic_update_slice aliases in
         # place instead of copying the whole KV cache per launch (same
         # reasoning as the single-step build).
-        return jax.jit(f, donate_argnums=(2,))
+        return jax.jit(tdt_mega_round, donate_argnums=(2,))
 
     def decode_multi_fn(
         self, batch: int, s_max: int, nsteps: int, sampled: bool = False,
@@ -905,11 +915,11 @@ class MegaQwen3:
         )
         V = m.cfg.vocab_size
 
-        def f(params, tokens, true_len, cache):
+        def tdt_mega_prompt(params, tokens, true_len, cache):
             logits, cache = g(params, tokens, true_len, cache)
             return logits[:V], cache  # drop vocab-pad logits
 
-        return jax.jit(f)
+        return jax.jit(tdt_mega_prompt)
 
     def prefill(self, tokens: jax.Array, cache: KVCache, *, true_len=None):
         """Prefill one prompt (``tokens [S]``) through the megakernel;

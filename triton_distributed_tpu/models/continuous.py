@@ -189,11 +189,9 @@ _NonFiniteLogits = sampling.NonFiniteLogitsError
 
 # Finite mask + greedy argmax of a decode step's logits in ONE device
 # program: the NaN guard rides the token fetch the loop already pays.
-_finite_greedy = jax.jit(
-    lambda logits: (
-        jnp.isfinite(logits).all(axis=-1), sampling.greedy(logits)
-    )
-)
+@jax.jit
+def tdt_finite_greedy(logits):
+    return jnp.isfinite(logits).all(axis=-1), sampling.greedy(logits)
 
 # Serving counters mirrored live into the process metrics registry
 # (docs/observability.md): same numbers as ``last_stats``, but
@@ -455,11 +453,11 @@ class ContinuousEngine(MegaDispatch):
         self._pend = None  # in-flight resident launch (depth-1 pipeline)
         # Device task tracer (docs/observability.md "Device task
         # tracer"): mega launches carry an in-kernel trace ring; every
-        # launch's ring is folded into tdt_mega_task_seconds /
-        # tdt_mega_overlap_exposure and kept (bounded) for the
-        # server's {"cmd": "kernel_trace"} verb and the merged chrome
-        # timeline (plumbing shared with Engine via MegaDispatch). Off
-        # by default: the untraced build is bit-identical to PR 7's.
+        # launch's ring is folded into tdt_mega_task_seconds and kept
+        # (bounded) for the server's {"cmd": "kernel_trace"} verb and
+        # the merged chrome timeline (plumbing shared with Engine via
+        # MegaDispatch). Off by default: the untraced build is
+        # bit-identical to PR 7's.
         self._init_kernel_trace(kernel_trace, mode)
         self.temperature = temperature
         self.top_p = top_p
@@ -1587,10 +1585,25 @@ class ContinuousEngine(MegaDispatch):
         active = np.asarray([r is not None for r in self._slots], np.int32)
         if not active.any():
             return False
+        n_active = int(active.sum())
+        with self._round_span(n_active):
+            return self._decode_round(active, n_active)
+
+    def _round_span(self, active: int):
+        """The span of one scheduling round of the in-flight batch,
+        whatever kind: single step, speculative verify, mega launch."""
+        return trace_span("engine:decode_round", active=active,
+                          step=self.stats["decode_steps"], _ring=False)
+
+    def _decode_round(self, active: np.ndarray, n_active: int) -> bool:
+        """The round itself, in the three phases its spans name: the
+        step's dispatch, the blocking fetch, and the host's sampling
+        and token frames."""
         fault_point("engine.decode", step=self.stats["decode_steps"])
-        logits, self.cache = self._decode_step(
-            jnp.asarray(self._tok), self.cache
-        )
+        with trace_span("engine:dispatch", _ring=False):
+            logits, self.cache = self._decode_step(
+                jnp.asarray(self._tok), self.cache
+            )
         logits = mutate_point(
             "engine.logits", logits, step=self.stats["decode_steps"]
         )
@@ -1600,8 +1613,7 @@ class ContinuousEngine(MegaDispatch):
         self._kv_len = self._kv_len + active
         self._bump("decode_steps")
         if self._moe_k:
-            self._bump("moe_routed_tokens",
-                       int(active.sum()) * self._moe_k)
+            self._bump("moe_routed_tokens", n_active * self._moe_k)
         # Sharded long-context slots were invisible to the batched step
         # (device table/kv_len masked to the trash page): run their
         # per-slot partial-merge decode now and splice the real logits
@@ -1613,10 +1625,16 @@ class ContinuousEngine(MegaDispatch):
         # One device program computes the finite mask AND the greedy
         # base tokens, so the NaN guard adds no extra host-sync round
         # trip to the hot decode loop.
-        finite, greedy_base = _finite_greedy(logits)
-        failed = self._guard_logits(np.asarray(finite))
-        nxt = self._sample_slots(logits, np.array(greedy_base))
-        changed = self._process(lambda slot: [nxt[slot]])
+        with trace_span("engine:fetch", _ring=False):
+            finite, greedy_base = tdt_finite_greedy(logits)
+            finite = np.asarray(finite)
+            greedy_base = np.array(greedy_base)
+        # One token per active slot, less the slots the guard fails.
+        with trace_span("engine:sample_emit", emitted=n_active,
+                        _ring=False):
+            failed = self._guard_logits(finite)
+            nxt = self._sample_slots(logits, greedy_base)
+            changed = self._process(lambda slot: [nxt[slot]])
         return changed or bool(failed) or lc_changed
 
     def _guard_logits(self, finite: np.ndarray) -> list[int]:
@@ -2483,7 +2501,18 @@ class ContinuousEngine(MegaDispatch):
                     break  # head-of-line waits for pages
                 req = queue.popleft()
                 try:
-                    first = self._admit(req, slot, m)
+                    # One request's whole admission, on every path:
+                    # pages, prefill chunks (with the running batch's
+                    # rounds between them), first token.
+                    with trace_span(
+                        "engine:admit", trace_id=req.trace_id, slot=slot,
+                        prompt=len(req.prompt),
+                        queue_wait_ms=int(
+                            (time.monotonic() - req.timeline.enqueue_t)
+                            * 1e3
+                        ),
+                    ):
+                        first = self._admit(req, slot, m)
                 except Exception as e:  # noqa: BLE001 — isolation
                     self._admit_failure(req, m, e)
                     progress = True
@@ -2551,7 +2580,8 @@ class ContinuousEngine(MegaDispatch):
             n_active = int(active.sum())
             changed = False
             if drafted:
-                changed = self._spec_round(drafted)
+                with self._round_span(len(drafted)):
+                    changed = self._spec_round(drafted)
             if not ok or len(drafted) < n_active:
                 changed = self._decode_once() or changed
             return changed
@@ -2566,7 +2596,8 @@ class ContinuousEngine(MegaDispatch):
         # max_length, filtered slots at tp > 1) fall back to single
         # steps.
         if self.mode == "mega":
-            changed = self._mega_round(active, kv_high)
+            with self._round_span(int(active.sum())):
+                changed = self._mega_round(active, kv_high)
             if changed is not None:
                 return changed
             self._bump("mega_fallback_steps")
@@ -2957,8 +2988,8 @@ class ContinuousEngine(MegaDispatch):
             n = self.model.ctx.axis_size(self.model.axis)
             v_pad = mega._dims(Bk, self.max_length).v_loc * n
 
-            def wrapped(params, tok, cache, n_valid, extra, key, temps,
-                        sampcfg):
+            def tdt_mega_round(params, tok, cache, n_valid, extra, key,
+                               temps, sampcfg):
                 keys = jax.random.split(key, NS)
                 noise = jax.vmap(
                     lambda k: jax.random.gumbel(
@@ -2968,12 +2999,12 @@ class ContinuousEngine(MegaDispatch):
                 tail = (noise, sampcfg) if filtered else (noise,)
                 return base(params, tok, cache, n_valid, *extra, *tail)
 
-            fn = jax.jit(wrapped, donate_argnums=(2,))
+            fn = jax.jit(tdt_mega_round, donate_argnums=(2,))
         elif eos or ring:
-            def wrapped_g(params, tok, cache, n_valid, extra):
+            def tdt_mega_round(params, tok, cache, n_valid, extra):
                 return base(params, tok, cache, n_valid, *extra)
 
-            fn = jax.jit(wrapped_g, donate_argnums=(2,))
+            fn = jax.jit(tdt_mega_round, donate_argnums=(2,))
         else:
             fn = base
         self._multi_fns[key] = fn
@@ -3188,7 +3219,8 @@ class ContinuousEngine(MegaDispatch):
                 if len(self._cancelled) > 4096:
                     self._cancelled.clear()
 
-        self.audit(raise_on_violation=True)
+        with trace_span("engine:audit", _ring=False):
+            self.audit(raise_on_violation=True)
         if results:
             return [r.result() for r in reqs]
         failures = [(i, r) for i, r in enumerate(reqs) if r.status != "ok"]

@@ -93,7 +93,7 @@ def prefill_suffix_chunks(
         # suffix never gathers the full max_length KV view.
         kv_pages = gather_bucket(off + c, page, pps)
         with trace_span("prefix_cache:chunk", slot=slot, offset=off,
-                        take=take):
+                        take=take, _ring=False):
             logits, cache = model.prefill_paged_chunk(
                 buf, slot, off, off + take, take - 1, cache, mode,
                 kv_pages=kv_pages,
@@ -323,7 +323,7 @@ class Engine(MegaDispatch):
         # Device task tracer (docs/observability.md "Device task
         # tracer"): multi-step mega launches in serve() carry the
         # in-kernel trace ring; decoded launches feed
-        # tdt_mega_task_seconds/_overlap_exposure and are kept
+        # tdt_mega_task_seconds and are kept
         # (bounded) for kernel_trace_summary / the merged timeline.
         self._init_kernel_trace(kernel_trace, mode)
         self._prefix_state: _PrefixState | None = None
@@ -609,14 +609,15 @@ class Engine(MegaDispatch):
                     wkey = (b, s_max, NS, self.paged, quant, filtered)
                     fn = self._sampled_multi.get(wkey)
                     if fn is None:
-                        def fn(params, tok, cache, key, temp, cfg):
+                        def tdt_mega_round(params, tok, cache, key, temp,
+                                           cfg):
                             noise = temp * jax.random.gumbel(
                                 key, (NS, b, v_pad), jnp.float32
                             )
                             tail = (noise, cfg) if filtered else (noise,)
                             return base_fn(params, tok, cache, *tail)
 
-                        fn = jax.jit(fn, donate_argnums=(2,))
+                        fn = jax.jit(tdt_mega_round, donate_argnums=(2,))
                         self._sampled_multi[wkey] = fn
                 else:
                     fn = base_fn

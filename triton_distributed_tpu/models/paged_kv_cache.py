@@ -16,6 +16,7 @@ table as a scalar-prefetch operand (future paged flash-decode).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -377,7 +378,7 @@ def rollback_kv(cache: PagedKVCache, slot, new_len) -> PagedKVCache:
     a later write at page offset 0 resets it)."""
     return dataclasses.replace(
         cache,
-        kv_len=_set_len_jit(
+        kv_len=tdt_kv_set_len(
             cache.kv_len,
             jnp.asarray(slot, jnp.int32),
             jnp.asarray(new_len, jnp.int32),
@@ -388,9 +389,9 @@ def rollback_kv(cache: PagedKVCache, slot, new_len) -> PagedKVCache:
 # Donated: rollback runs once per rejected verify chunk — an eager
 # .at[].set would copy the (small) kv_len array but break the cache
 # threading discipline every other cache op follows.
-_set_len_jit = jax.jit(
-    lambda kv_len, slot, n: kv_len.at[slot].set(n), donate_argnums=(0,)
-)
+@functools.partial(jax.jit, donate_argnums=(0,))
+def tdt_kv_set_len(kv_len, slot, n):
+    return kv_len.at[slot].set(n)
 
 
 def move_kv_rows(
@@ -436,14 +437,17 @@ def move_kv_rows(
     src_a = jnp.asarray([p[0] for p in pairs], jnp.int32)
     dst_a = jnp.asarray([p[1] for p in pairs], jnp.int32)
     page = int(cache.k_pages.shape[3])
-    k_pages, v_pages = _move_rows_jit(
+    k_pages, v_pages = tdt_kv_move_rows(
         cache.k_pages, cache.v_pages, cache.page_table[slot],
         src_a, dst_a, page,
     )
     return dataclasses.replace(cache, k_pages=k_pages, v_pages=v_pages)
 
 
-def _move_rows(kp, vp, table_row, src, dst, page: int):
+# Donated like the other pool writers (an eager scatter would copy the
+# pool to move a handful of rows); one program per move-count bucket.
+@functools.partial(jax.jit, static_argnums=(5,), donate_argnums=(0, 1))
+def tdt_kv_move_rows(kp, vp, table_row, src, dst, page: int):
     ps, so = jnp.take(table_row, src // page), src % page
     pd, do = jnp.take(table_row, dst // page), dst % page
     # Two advanced indices split by slices → advanced axes lead: the
@@ -454,13 +458,6 @@ def _move_rows(kp, vp, table_row, src, dst, page: int):
     kp = kp.at[:, pd, :, do, :].set(rows_k)
     vp = vp.at[:, pd, :, do, :].set(rows_v)
     return kp, vp
-
-
-# Donated like the other pool writers (an eager scatter would copy the
-# pool to move a handful of rows); one program per move-count bucket.
-_move_rows_jit = jax.jit(
-    _move_rows, static_argnums=(5,), donate_argnums=(0, 1)
-)
 
 
 def truncate_pages(
@@ -535,10 +532,10 @@ def write_prefill(
 
         tl = jnp.asarray(true_len, jnp.int32)
         with trace_span("kv:quant", op="write_prefill", pages=2 * npages):
-            k_pages, k_scale = _scatter_q_jit(
+            k_pages, k_scale = tdt_kv_scatter_q(
                 cache.k_pages, cache.k_scale, k_dense, row, tl, npages, page
             )
-            v_pages, v_scale = _scatter_q_jit(
+            v_pages, v_scale = tdt_kv_scatter_q(
                 cache.v_pages, cache.v_scale, v_dense, row, tl, npages, page
             )
         return PagedKVCache(
@@ -546,14 +543,19 @@ def write_prefill(
             kv_len=kv_len, k_scale=k_scale, v_scale=v_scale,
         )
     return PagedKVCache(
-        k_pages=_scatter_jit(cache.k_pages, k_dense, row, npages, page),
-        v_pages=_scatter_jit(cache.v_pages, v_dense, row, npages, page),
+        k_pages=tdt_kv_scatter(cache.k_pages, k_dense, row, npages, page),
+        v_pages=tdt_kv_scatter(cache.v_pages, v_dense, row, npages, page),
         page_table=cache.page_table,
         kv_len=kv_len,
     )
 
 
-def _scatter(pages, dense, table_row, npages: int, page: int):
+# Donated + jitted (as is the quantized scatter below): the
+# page-by-page scatter updates the pool in place; eager
+# dynamic_update_slices would copy the whole (GB-scale) pool once per
+# page.
+@functools.partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0,))
+def tdt_kv_scatter(pages, dense, table_row, npages: int, page: int):
     for j in range(npages):
         pid = table_row[j]
         chunk = jax.lax.dynamic_slice_in_dim(
@@ -565,9 +567,10 @@ def _scatter(pages, dense, table_row, npages: int, page: int):
     return pages
 
 
-def _scatter_q(pages, scales, dense, table_row, true_len, npages: int,
-               page: int):
-    """Quantized :func:`_scatter`: every written page is a FRESH full
+@functools.partial(jax.jit, static_argnums=(5, 6), donate_argnums=(0, 1))
+def tdt_kv_scatter_q(pages, scales, dense, table_row, true_len,
+                     npages: int, page: int):
+    """Quantized :func:`tdt_kv_scatter`: every written page is a FRESH full
     write, so its scale is set absolutely from the page's amax (never
     grown from a previous tenant's stale scale). Dense rows at
     positions ≥ ``true_len`` are ZEROED before quantization: the dense
@@ -595,15 +598,6 @@ def _scatter_q(pages, scales, dense, table_row, true_len, npages: int,
     return pages, scales
 
 
-# Donated + jitted: the page-by-page scatter updates the pool in place;
-# eager dynamic_update_slices would copy the whole (GB-scale) pool once
-# per page.
-_scatter_jit = jax.jit(_scatter, static_argnums=(3, 4), donate_argnums=(0,))
-_scatter_q_jit = jax.jit(
-    _scatter_q, static_argnums=(5, 6), donate_argnums=(0, 1)
-)
-
-
 def copy_page(cache: PagedKVCache, src: int, dst: int) -> PagedKVCache:
     """Copy one pool page (both K and V, all layers) — the prefix
     cache's copy-on-write: a partially matched shared page is cloned
@@ -613,8 +607,8 @@ def copy_page(cache: PagedKVCache, src: int, dst: int) -> PagedKVCache:
     s = jnp.asarray(src, jnp.int32)
     d = jnp.asarray(dst, jnp.int32)
     return PagedKVCache(
-        k_pages=_copy_page_jit(cache.k_pages, s, d),
-        v_pages=_copy_page_jit(cache.v_pages, s, d),
+        k_pages=tdt_kv_copy_page(cache.k_pages, s, d),
+        v_pages=tdt_kv_copy_page(cache.v_pages, s, d),
         page_table=cache.page_table,
         kv_len=cache.kv_len,
         # COW on a quantized pool clones the scale WITH the codes — the
@@ -622,25 +616,24 @@ def copy_page(cache: PagedKVCache, src: int, dst: int) -> PagedKVCache:
         # would dequantize the copy under the wrong amax.
         k_scale=(
             None if cache.k_scale is None
-            else _copy_page_jit(cache.k_scale, s, d)
+            else tdt_kv_copy_page(cache.k_scale, s, d)
         ),
         v_scale=(
             None if cache.v_scale is None
-            else _copy_page_jit(cache.v_scale, s, d)
+            else tdt_kv_copy_page(cache.v_scale, s, d)
         ),
     )
 
 
-# Donated for the same reason as _scatter_jit: an eager update would
+# Donated for the same reason as tdt_kv_scatter: an eager update would
 # copy the whole pool to move one page. Shape-polymorphic over the
 # trailing dims, so the same program body serves pools AND their
 # [L, P, H] scale arrays (jit re-specializes per shape).
-_copy_page_jit = jax.jit(
-    lambda pages, s, d: jax.lax.dynamic_update_slice_in_dim(
+@functools.partial(jax.jit, donate_argnums=(0,))
+def tdt_kv_copy_page(pages, s, d):
+    return jax.lax.dynamic_update_slice_in_dim(
         pages, jax.lax.dynamic_slice_in_dim(pages, s, 1, axis=1), d, axis=1
-    ),
-    donate_argnums=(0,),
-)
+    )
 
 
 def append(
@@ -756,11 +749,11 @@ def gather_pages(cache: PagedKVCache, page_ids: list[int]):
     ``[L, n, Hkv, page, hd]`` pools and ``[L, n, Hkv]`` scales (scales
     are None on an unquantized pool)."""
     ids = jnp.asarray([int(p) for p in page_ids], jnp.int32)
-    k = np.asarray(_gather_pages_jit(cache.k_pages, ids))
-    v = np.asarray(_gather_pages_jit(cache.v_pages, ids))
+    k = np.asarray(tdt_kv_gather_pages(cache.k_pages, ids))
+    v = np.asarray(tdt_kv_gather_pages(cache.v_pages, ids))
     if cache.quantized:
-        ks = np.asarray(_gather_pages_jit(cache.k_scale, ids))
-        vs = np.asarray(_gather_pages_jit(cache.v_scale, ids))
+        ks = np.asarray(tdt_kv_gather_pages(cache.k_scale, ids))
+        vs = np.asarray(tdt_kv_gather_pages(cache.v_scale, ids))
     else:
         ks = vs = None
     return k, v, ks, vs
@@ -770,7 +763,9 @@ def gather_pages(cache: PagedKVCache, page_ids: list[int]):
 # the pool stays live for the decode loop that owns it. jit
 # re-specializes per (pool shape, id count); migration exports reuse a
 # handful of shapes per engine.
-_gather_pages_jit = jax.jit(lambda pages, ids: jnp.take(pages, ids, axis=1))
+@jax.jit
+def tdt_kv_gather_pages(pages, ids):
+    return jnp.take(pages, ids, axis=1)
 
 
 def write_page(cache: PagedKVCache, pid: int, k_page, v_page,
@@ -789,28 +784,27 @@ def write_page(cache: PagedKVCache, pid: int, k_page, v_page,
             f"{'present' if k_scale is not None else 'absent'})"
         )
     p = jnp.asarray(pid, jnp.int32)
-    kp = _write_page_jit(cache.k_pages, p,
+    kp = tdt_kv_write_page(cache.k_pages, p,
                          jnp.asarray(k_page, cache.k_pages.dtype))
-    vp = _write_page_jit(cache.v_pages, p,
+    vp = tdt_kv_write_page(cache.v_pages, p,
                          jnp.asarray(v_page, cache.v_pages.dtype))
     ks, vs = cache.k_scale, cache.v_scale
     if cache.quantized:
-        ks = _write_page_jit(ks, p, jnp.asarray(k_scale, jnp.float32))
-        vs = _write_page_jit(vs, p, jnp.asarray(v_scale, jnp.float32))
+        ks = tdt_kv_write_page(ks, p, jnp.asarray(k_scale, jnp.float32))
+        vs = tdt_kv_write_page(vs, p, jnp.asarray(v_scale, jnp.float32))
     return dataclasses.replace(
         cache, k_pages=kp, v_pages=vp, k_scale=ks, v_scale=vs
     )
 
 
-# Donated like _scatter_jit (an eager update would copy the pool to
+# Donated like tdt_kv_scatter (an eager update would copy the pool to
 # move one page); shape-polymorphic over trailing dims so the same body
 # serves pools and their [L, P, H] scale arrays.
-_write_page_jit = jax.jit(
-    lambda pages, pid, data: jax.lax.dynamic_update_slice_in_dim(
+@functools.partial(jax.jit, donate_argnums=(0,))
+def tdt_kv_write_page(pages, pid, data):
+    return jax.lax.dynamic_update_slice_in_dim(
         pages, data[:, None], pid, axis=1
-    ),
-    donate_argnums=(0,),
-)
+    )
 
 
 def as_dense(cache: PagedKVCache, layer=None):

@@ -203,9 +203,11 @@ class Qwen3:
         program, executed device-side: one compile (one compile-cache
         entry) instead of ~50 eager dispatches, and the weights never
         transit the host."""
+        def tdt_set_params(k):
+            return self._pad_lm_head(build(k))
+
         self.params = jax.jit(
-            lambda k: self._pad_lm_head(build(k)),
-            out_shardings=self.param_shardings,
+            tdt_set_params, out_shardings=self.param_shardings
         )(key)
         return self.params
 
@@ -499,10 +501,11 @@ class Qwen3:
                 ),
                 out_specs=(P(), paged_cache_specs(self.axis, quant)),
             )
+            def tdt_prefill_chunk(p, t, c, s, o, n, li, *tr):
+                return f(p, t, c, s, o, n, li, *tr)
+
             self._prefill_jit[key] = jax.jit(
-                lambda p, t, c, s, o, n, li, *tr: f(p, t, c, s, o, n, li,
-                                                    *tr),
-                donate_argnums=(2,),
+                tdt_prefill_chunk, donate_argnums=(2,)
             )
         tree_args = ()
         if tree:
@@ -610,11 +613,12 @@ class Qwen3:
                 ),
                 out_specs=(P(), paged_cache_specs(self.axis, quant)),
             )
+            def tdt_longctx_chunk(p, t, c, kc, vc, ksc, vsc, tr, sc, o, e,
+                                  li):
+                return f(p, t, c, kc, vc, ksc, vsc, tr, sc, o, e, li)
+
             self._prefill_jit[key] = jax.jit(
-                lambda p, t, c, kc, vc, ksc, vsc, tr, sc, o, e, li: f(
-                    p, t, c, kc, vc, ksc, vsc, tr, sc, o, e, li
-                ),
-                donate_argnums=(2,),
+                tdt_longctx_chunk, donate_argnums=(2,)
             )
         return self._prefill_jit[key](
             self.params, jnp.asarray(tokens, jnp.int32), cache,
@@ -700,11 +704,11 @@ class Qwen3:
                 ),
                 out_specs=(P(), paged_cache_specs(self.axis, quant)),
             )
+            def tdt_longctx_step(p, t, c, kc, vc, ksc, vsc, tr, kl, sc):
+                return f(p, t, c, kc, vc, ksc, vsc, tr, kl, sc)
+
             self._decode_jit[key] = jax.jit(
-                lambda p, t, c, kc, vc, ksc, vsc, tr, kl, sc: f(
-                    p, t, c, kc, vc, ksc, vsc, tr, kl, sc
-                ),
-                donate_argnums=(2,),
+                tdt_longctx_step, donate_argnums=(2,)
             )
         return self._decode_jit[key](
             self.params, jnp.asarray(token, jnp.int32), cache,
@@ -756,8 +760,13 @@ class Qwen3:
                 self.decode_fn_paged(mode, quantized=quant) if paged
                 else self.decode_fn(mode)
             )
+            # The one program whose name holds "decode": the
+            # benchmark's readers select the step by that word.
+            def tdt_decode_step(p, t, c):
+                return f(p, t, c)
+
             self._decode_jit[key] = jax.jit(
-                lambda p, t, c: f(p, t, c), donate_argnums=(2,)
+                tdt_decode_step, donate_argnums=(2,)
             )
         return self._decode_jit[key](self.params, tokens, cache)
 
@@ -788,9 +797,10 @@ class Qwen3:
             # No cache donation here: callers may alias slices of a
             # larger cache — donating would delete their buffer. The
             # per-token donation win lives in decode_step.
-            self._prefill_jit[key] = jax.jit(
-                lambda p, t, c, tl: f(p, t[None], c, tl[None])
-            )
+            def tdt_prefill_batched(p, t, c, tl):
+                return f(p, t[None], c, tl[None])
+
+            self._prefill_jit[key] = jax.jit(tdt_prefill_batched)
         logits, cache = self._prefill_jit[key](
             self.params, tokens, cache, jnp.asarray(true_len, jnp.int32)
         )
@@ -819,8 +829,11 @@ class Qwen3:
                 ),
                 out_specs=(P(), cache_specs(self.axis)),
             )
+            def tdt_prefill_batched(p, t, c, tl):
+                return f(p, t, c, tl)
+
             self._prefill_jit[key] = jax.jit(
-                lambda p, t, c, tl: f(p, t, c, tl), donate_argnums=(2,)
+                tdt_prefill_batched, donate_argnums=(2,)
             )
         return self._prefill_jit[key](
             self.params, tokens, cache, jnp.asarray(true_lens, jnp.int32)

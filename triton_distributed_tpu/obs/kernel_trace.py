@@ -29,8 +29,7 @@ the host half:
   trace ids so one request can be followed server → router → replica →
   engine → individual device tasks.
 - :func:`observe_launch` — feeds ``tdt_mega_task_seconds{opcode}``
-  histograms and the ``tdt_mega_overlap_exposure`` gauge in the PR 5
-  registry from one launch's ring.
+  histograms in the PR 5 registry from one launch's ring.
 
 Clock semantics (docs/profiling.md "Device task tracer"): on hardware
 whose Pallas exposes a cycle counter the ticks are cycles; everywhere
@@ -520,11 +519,9 @@ class KernelTraceLaunch:
 def observe_launch(launch: KernelTraceLaunch, registry=None) -> dict:
     """Fold one traced launch into the PR 5 metrics registry:
     ``tdt_mega_task_seconds{opcode}`` histograms (rank 0's records,
-    ticks apportioned over the launch's measured wall time) and the
-    ``tdt_mega_overlap_exposure`` gauge — measured wall seconds of AR
-    comm window that coincided with compute work in this launch (the
-    ring-derived replacement for the analytic estimate). Returns the
-    overlap report.
+    ticks apportioned over the launch's measured wall time). Returns
+    the launch's overlap report: counts of ticks, which the
+    ``kernel_trace`` verb shows and no gauge scales into seconds.
 
     This runs INLINE per traced launch on the serving decode path:
     with a raw ``ring`` attached it is fully vectorized (gap check,
@@ -582,17 +579,6 @@ def observe_launch(launch: KernelTraceLaunch, registry=None) -> dict:
             dur * sec_per_tick, n,
             opcode=_OP_NAMES.get(op, f"OP{op}"),
         )
-    reg.gauge(
-        "tdt_mega_overlap_exposure",
-        "Measured wall seconds of AR comm window coinciding with "
-        "compute in the last traced launch (device ring; hidden comm).",
-    ).set(rep["hidden_ticks"] * sec_per_tick)
-    reg.gauge(
-        "tdt_mega_overlap_hidden_fraction",
-        "Measured fraction of AR comm window hidden under compute in "
-        "the last traced launch (device ring).",
-    ).set(rep["hidden_fraction"] if rep["hidden_fraction"] is not None
-          else 1.0)
     return rep
 
 

@@ -7,6 +7,8 @@ transitions the engines drive:
 =================  ====================================================
 ``enqueue``        the request entered the system (server payload
                    decode, or ``run()`` entry for direct callers)
+``batch_start``    a replica worker took it off its queue into the
+                   batch it runs next (replica-served requests only)
 ``admit``          a decode slot + pages were assigned
 ``first_chunk``    its first prefill chunk program was dispatched
 ``first_token``    its first token was sampled (admission prefill)
@@ -15,6 +17,8 @@ transitions the engines drive:
 =================  ====================================================
 
 Derived durations: ``queue_wait_s`` (enqueue→admit),
+``batch_wait_s`` (enqueue→batch start: the wait for the running batch
+to end; queue wait less batch wait is the wait for a slot),
 ``prefill_dispatch_s`` (admit→first chunk: how long an admitted
 request waited for the chunked-prefill scheduler to first touch it),
 ``ttft_s`` (enqueue→first token), ``e2e_s`` (enqueue→finish), and
@@ -64,12 +68,13 @@ class Timeline:
     not when the engine latched them. Engine-side timelines leave it
     empty and keep the PR 5 first-token/finish arithmetic."""
 
-    __slots__ = ("enqueue_t", "admit_t", "first_chunk_t", "first_token_t",
-                 "finish_t", "tokens_in", "tokens_out", "status",
-                 "reroutes", "token_ts")
+    __slots__ = ("enqueue_t", "batch_start_t", "admit_t", "first_chunk_t",
+                 "first_token_t", "finish_t", "tokens_in", "tokens_out",
+                 "status", "reroutes", "token_ts")
 
     def __init__(self):
         self.enqueue_t: float | None = None
+        self.batch_start_t: float | None = None
         self.admit_t: float | None = None
         self.first_chunk_t: float | None = None
         self.first_token_t: float | None = None
@@ -91,6 +96,9 @@ class Timeline:
 
     def stamp_enqueue(self) -> None:
         self._stamp("enqueue_t")
+
+    def stamp_batch_start(self) -> None:
+        self._stamp("batch_start_t")
 
     def stamp_admit(self) -> None:
         self._stamp("admit_t")
@@ -131,6 +139,10 @@ class Timeline:
     @property
     def queue_wait_s(self) -> float | None:
         return self._delta(self.enqueue_t, self.admit_t)
+
+    @property
+    def batch_wait_s(self) -> float | None:
+        return self._delta(self.enqueue_t, self.batch_start_t)
 
     @property
     def prefill_dispatch_s(self) -> float | None:
@@ -200,6 +212,10 @@ def _handles(reg) -> dict:
                 "tdt_request_queue_wait_seconds",
                 "Enqueue-to-admission wait.",
             ),
+            "batch_wait": reg.histogram(
+                "tdt_request_batch_wait_seconds",
+                "Enqueue-to-batch-start wait behind a replica worker.",
+            ),
             "prefill_dispatch": reg.histogram(
                 "tdt_request_prefill_dispatch_seconds",
                 "Admission-to-first-prefill-chunk wait.",
@@ -245,6 +261,9 @@ def observe_request(tl: Timeline, registry=None) -> None:
     qw = tl.queue_wait_s
     if qw is not None:
         h["queue_wait"].observe(qw)
+    bw = tl.batch_wait_s
+    if bw is not None:
+        h["batch_wait"].observe(bw)
     pd = tl.prefill_dispatch_s
     if pd is not None:
         h["prefill_dispatch"].observe(pd)

@@ -265,6 +265,7 @@ def flash_attention(
         scalars += [k_scale.reshape(-1), v_scale.reshape(-1)]
     res = pl.pallas_call(
         kernel,
+        name="tdt_flash_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=grid,

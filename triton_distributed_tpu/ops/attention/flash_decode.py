@@ -205,6 +205,7 @@ def flash_decode(
     )
     o_parts, lse_parts = pl.pallas_call(
         kernel,
+        name="tdt_flash_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=grid,
@@ -327,6 +328,7 @@ def paged_flash_decode(
     )
     o_parts, lse_parts = pl.pallas_call(
         kernel,
+        name="tdt_flash_decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=grid,

@@ -179,6 +179,7 @@ def sp_ag_attention(
     kv = jnp.stack([k, v])  # [2, hkv, s_loc, hd]
 
     out, lse, _ws = comm_pallas_call(
+        "tdt_sp_ag_attention",
         functools.partial(
             _sp_ag_attn_kernel,
             axis=axis, group=hq // hkv, sm_scale=sm_scale, bq=bq,

@@ -312,6 +312,7 @@ def all_gather(
         raise ValueError(f"unknown method {method}")
 
     return comm_pallas_call(
+        "tdt_all_gather_" + method.value.removeprefix("pallas_"),
         kernel,
         out_shape,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
@@ -424,6 +425,7 @@ def all_gather_torus_2d(
     m_per = x.shape[0]
     out_shape = jax.ShapeDtypeStruct((nx * ny * m_per, *x.shape[1:]), x.dtype)
     return comm_pallas_call(
+        "tdt_all_gather_torus_2d",
         functools.partial(_torus_2d_kernel, ax=ax, ay=ay),
         out_shape,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],

@@ -164,6 +164,7 @@ def _straggle_entry(x, axis, straggler_rank, straggler_nanos, ctx):
         cp.wait()
 
     return comm_pallas_call(
+        "tdt_straggle_entry",
         kern,
         jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
@@ -204,6 +205,7 @@ def all_reduce(
         if x.ndim < 2:
             raise ValueError("pallas all_reduce needs >=2D input")
         return comm_pallas_call(
+            "tdt_all_reduce_one_shot",
             functools.partial(
                 _one_shot_kernel, axis=axis,
                 straggler_rank=straggler_rank,
@@ -228,6 +230,7 @@ def all_reduce(
             raise ValueError(f"DOUBLING needs power-of-two axis, got {n}")
         lg = max(n.bit_length() - 1, 1)
         return comm_pallas_call(
+            "tdt_all_reduce_doubling",
             functools.partial(
                 _doubling_kernel, axis=axis,
                 straggler_rank=straggler_rank,

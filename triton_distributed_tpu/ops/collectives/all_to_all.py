@@ -86,6 +86,7 @@ def all_to_all(
     if x.shape[0] % n:
         raise ValueError(f"rows {x.shape[0]} not divisible by axis size {n}")
     return comm_pallas_call(
+        "tdt_all_to_all",
         functools.partial(_a2a_kernel, axis=axis),
         jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],

@@ -92,6 +92,7 @@ def broadcast(
     if x.ndim < 2:
         raise ValueError("pallas broadcast needs >=2D input")
     return comm_pallas_call(
+        "tdt_broadcast",
         functools.partial(_one_shot_bcast_kernel, axis=axis, root=root),
         jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],

@@ -163,6 +163,7 @@ def ll_all_gather(
         barrier_free = _on_tpu(ctx)
 
     out, ws_new = comm_pallas_call(
+        "tdt_ll_all_gather",
         functools.partial(_ll_ag_kernel, axis=axis, barrier_free=barrier_free),
         (out_shape, jax.ShapeDtypeStruct(ws.shape, ws.dtype)),
         in_specs=[

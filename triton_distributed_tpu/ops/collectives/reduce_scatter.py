@@ -355,6 +355,7 @@ def reduce_scatter(
         if n == 1:
             return x
         return comm_pallas_call(
+            "tdt_reduce_scatter_one_shot",
             functools.partial(_one_shot_rs_kernel, axis=axis),
             out_shape,
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -380,6 +381,7 @@ def reduce_scatter(
         num_t = m_per // tile_r
         rest = x.shape[1:]
         out, _bufs = comm_pallas_call(
+            "tdt_reduce_scatter_ring_hbm",
             functools.partial(_ring_rs_hbm_kernel, axis=axis),
             (
                 out_shape,
@@ -416,6 +418,7 @@ def reduce_scatter(
     if method == ReduceScatterMethod.PALLAS_BIDIR_RING:
         half = m_per // 2
         return comm_pallas_call(
+            "tdt_reduce_scatter_bidir_ring",
             functools.partial(_bidir_ring_rs_kernel, axis=axis),
             out_shape,
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -431,6 +434,7 @@ def reduce_scatter(
         )(x)
 
     return comm_pallas_call(
+        "tdt_reduce_scatter_ring",
         functools.partial(_ring_rs_kernel, axis=axis),
         out_shape,
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],

@@ -132,6 +132,7 @@ def interpret_mode(ctx: DistContext | None = None):
 
 
 def comm_pallas_call(
+    name: str,
     kernel,
     out_shape: Any,
     *,
@@ -148,6 +149,8 @@ def comm_pallas_call(
 ):
     """Build a pallas_call configured for communication kernels.
 
+    ``name`` is the kernel's name in a profiler trace (``tdt_<op>``):
+    required, so that no comm kernel reads ``closed_call.N`` there.
     Applies: side-effect marking (DMA-only kernels must not be DCE'd),
     collective id (barrier semaphore scoping), and interpret-mode
     selection for the CPU simulator.
@@ -177,6 +180,7 @@ def comm_pallas_call(
         scratch_shapes=list(scratch_shapes),
         compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret_mode(ctx),
+        name=name,
         **kwargs,
     )
 

@@ -212,6 +212,7 @@ def ep_exchange(
     rows = rows.reshape(n, cp // block, block, r)
 
     out = comm_pallas_call(
+        "tdt_ep_exchange",
         functools.partial(
             _ep_exchange_kernel,
             axis=axis,

@@ -333,6 +333,7 @@ def ag_gemm(
 
     grid = (n, num_i, num_j)
     out, _ws, order = comm_pallas_call(
+        "tdt_ag_gemm",
         functools.partial(
             _ag_gemm_kernel, axis=axis, acc_dtype=config.acc_dtype,
             adaptive=adaptive,

@@ -295,6 +295,7 @@ def gemm_ar(
     num_j = n_out // tile_n
 
     outs = comm_pallas_call(
+        "tdt_gemm_ar",
         functools.partial(
             _gemm_ar_one_shot_kernel, axis=axis,
             acc_dtype=config.acc_dtype, trace=trace,

@@ -433,6 +433,7 @@ def gemm_rs(
     ]
 
     out, _ws, _acc = comm_pallas_call(
+        "tdt_gemm_rs",
         kernel,
         (
             jax.ShapeDtypeStruct((m_per, n_out), a.dtype),

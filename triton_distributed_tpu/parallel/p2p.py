@@ -91,6 +91,7 @@ def pp_shift(
             perm = [(i, i + 1) for i in range(n - 1)]
         return jax.lax.ppermute(x, axis, perm)
     return comm_pallas_call(
+        "tdt_p2p_shift",
         functools.partial(_shift_kernel, axis=axis, wrap=wrap),
         jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],

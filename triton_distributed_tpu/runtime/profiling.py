@@ -29,101 +29,37 @@ from triton_distributed_tpu.obs import events as obs_events
 # ranks (the reference remaps pids the same way, ``utils.py:430-470``).
 _PID_STRIDE = 10_000_000
 
-# Whether the installed profiler accepts float metadata values. Settled
-# by the first float-carrying span (None = not yet probed): a profiler
-# that rejects floats costs ONE failed TraceAnnotation construction
-# ever, not exception-driven control flow on every spec:rollback span
-# in the serving loop. Unsynchronized on purpose — a race just repeats
-# the probe.
-_FLOAT_META_OK: bool | None = None
-
-# Whether the profiler demands ALL-string metadata: set only when a
-# span SUCCEEDED on the uniform-stringify rung after a lower rung was
-# rejected — a proven, deterministic type restriction. A span on which
-# every rung failed settles nothing beyond the float probe: that
-# failure may be transient (capture teardown race), and one transient
-# error must not downgrade every future span's metadata to strings.
-_STR_META_ONLY: bool = False
-
 @contextlib.contextmanager
 def trace_span(name: str, **args):
     """Named host-side span on the jax.profiler timeline AND the
-    telemetry event ring.
+    telemetry event ring: the one way this program makes a span.
 
-    The serving engines wrap control-plane phases (prefix-cache
-    admission, chunk prefills, evictions, speculative verify/rollback)
-    so they land on the same merged trace as the device programs they
-    interleave with. For the profiler, arg values outside its metadata
-    types are stringified rather than risking the whole span — floats
-    (e.g. spec accept rates) are tried natively first and the span is
-    RETRIED with them stringified if the installed profiler rejects
-    them, so a float-metadata mismatch costs precision, never the
-    span — and the rejection is remembered process-wide
-    (``_FLOAT_META_OK``), so later float spans go straight to the
-    stringified form. The final rung stringifies EVERY arg uniformly,
-    so a profiler that rejects some other type too (an out-of-range
-    int, say) still gets the span with all-string args instead of
-    losing it. Outside an active capture the annotation is free; a profiler
-    API mismatch must never sink serving, so entry failures degrade to
-    a plain yield (body exceptions still propagate).
+    The span is a ``jax.profiler.TraceAnnotation``, so inside any
+    profiler session it lands in the host plane of the same xplane
+    file as the device lines (one clock, nothing to align); with no
+    session open it costs its construction and nothing is kept. Ints,
+    floats and strings ride as typed event stats; anything else is
+    stringified. A profiler API mismatch must never sink serving, so
+    an entry failure degrades to a plain yield (body exceptions still
+    propagate).
 
     On exit the span also lands in the event ring (kind ``span``, with
-    the span's wall duration and its args — numerics kept native), so
+    the span's wall duration and its args, numerics kept native), so
     host spans are visible through ``{"cmd": "events"}`` without an
-    active profiler capture (docs/observability.md). A span whose site
-    already emits a dedicated, richer ring event (e.g. ``spec_verify``)
-    passes ``_ring=False`` to skip the duplicate ``span`` entry —
-    bounded ring space shouldn't hold the same moment twice."""
-    global _FLOAT_META_OK, _STR_META_ONLY
+    active profiler capture (docs/observability.md). ``_ring=False``
+    skips that entry: for a site whose moment already has a dedicated,
+    richer ring event (``spec_verify``), and for per-step and
+    per-chunk spans, which at tens a second would wash the bounded
+    ring clean of the rare events it is for."""
     ring_emit = args.pop("_ring", True)
-    span = None
-    has_float = any(
-        isinstance(v, float) and not isinstance(v, bool)
-        for v in args.values()
-    )
-    # Fallback ladder: floats native → ints native → EVERYTHING
-    # stringified. The last rung is the uniform stringify fallback: a
-    # profiler that also rejects some non-float type (an int out of
-    # its range, say) used to lose the span entirely on the retry
-    # path — now such a span survives with all-string args, which is
-    # the documented degradation (precision, never the span). Both
-    # ladder positions are remembered (_FLOAT_META_OK /
-    # _STR_META_ONLY), so a persistently strict profiler costs one
-    # construction per span, not the ladder.
-    if _STR_META_ONLY:
-        variants = ((str,),)
-    elif has_float and _FLOAT_META_OK is not False:
-        variants = ((int, str, float), (int, str), (str,))
-    else:
-        variants = ((int, str), (str,))
-    for num_ok in variants:
-        try:
-            prof_args = {
-                k: (v if isinstance(v, num_ok) else str(v))
-                for k, v in args.items()
-            }
-            span = jax.profiler.TraceAnnotation(name, **prof_args)
-            span.__enter__()
-            if has_float:
-                # Probe settled: either floats passed natively, or a
-                # stringified retry succeeded where the float attempt
-                # failed (so the floats were the rejection's cause —
-                # a wholly broken profiler never reaches here).
-                _FLOAT_META_OK = float in num_ok
-            if num_ok == (str,) and len(variants) > 1:
-                # A lower rung rejected native numerics beyond floats:
-                # later spans skip straight to uniform stringify.
-                _STR_META_ONLY = True
-            break
-        except Exception:
-            span = None
-    if span is None and has_float and _FLOAT_META_OK is None:
-        # Every rung failed (profiler wholly broken, not a float
-        # rejection): settle the float probe so later float spans
-        # skip the native-float rung. _STR_META_ONLY is NOT set here
-        # — a wholly-failed span proves nothing about accepted types,
-        # and the failure may be transient.
-        _FLOAT_META_OK = False
+    try:
+        span = jax.profiler.TraceAnnotation(name, **{
+            k: v if isinstance(v, (int, float, str)) else str(v)
+            for k, v in args.items()
+        })
+        span.__enter__()
+    except Exception:  # noqa: BLE001 - telemetry never sinks the body
+        span = None
     # Honor the disabled-mode contract (attribute check + return):
     # skip the clock reads and the kwargs coercion entirely when the
     # ring won't record the event anyway.

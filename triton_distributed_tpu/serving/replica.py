@@ -43,6 +43,7 @@ from triton_distributed_tpu.models.continuous import Request, RequestResult
 from triton_distributed_tpu.obs import events as obs_events
 from triton_distributed_tpu.obs.timeline import Timeline
 from triton_distributed_tpu.runtime.faults import fault_point
+from triton_distributed_tpu.runtime.profiling import trace_span
 
 HEALTHY = "healthy"
 DRAINING = "draining"
@@ -188,6 +189,10 @@ class Ticket:
         tl = Timeline()
         tl.enqueue_t = self.enqueue_t
         tl.stamp_enqueue()  # no-op when enqueue_t already set (latched)
+        # A worker turns tickets into requests only as it starts their
+        # batch: enqueue to here is the wait for the running batch to
+        # end (``tdt_request_batch_wait_seconds``).
+        tl.stamp_batch_start()
         tl.reroutes = self.reroutes
         return Request(
             self.prompt, self.gen_len, temperature=self.temperature,
@@ -525,7 +530,12 @@ class EngineReplica:
             handoff_orphans: list[Ticket] = []
             with self._cond:
                 while not self._queue and self._state == HEALTHY:
-                    self._cond.wait(0.1)
+                    # Names the idle device's largest gaps: the worker
+                    # has nothing to run (ten spans an idle second, so
+                    # none goes to the event ring).
+                    with trace_span("scheduler:wait_for_work",
+                                    _ring=False):
+                        self._cond.wait(0.1)
                 if self._state == DRAINING and self._handoff:
                     # Lossless drain: NOTHING queued runs here — the
                     # queue hands back for re-dispatch (the in-flight
@@ -610,7 +620,12 @@ class EngineReplica:
             # the engine runs, so a killed batch re-routes wholesale
             # with nothing half-admitted.
             fault_point("replica.run", replica=self.name, batch=len(reqs))
-            results = self.engine.run(reqs, results=True)
+            oldest = min(r.timeline.enqueue_t for r in reqs)
+            with trace_span(
+                "scheduler:batch", n=len(reqs),
+                oldest_wait_ms=int((time.monotonic() - oldest) * 1e3),
+            ):
+                results = self.engine.run(reqs, results=True)
         except Exception as e:  # noqa: BLE001 — replica isolation boundary
             self._die(f"{type(e).__name__}: {e}")
             return

@@ -146,6 +146,7 @@ from triton_distributed_tpu.obs import slo as obs_slo
 from triton_distributed_tpu.obs.metrics import prometheus_text
 from triton_distributed_tpu.obs.timeline import Timeline
 from triton_distributed_tpu.runtime.faults import fault_point, mutate_point
+from triton_distributed_tpu.runtime.profiling import trace_span
 
 
 # The probe verbs _dispatch_inner answers. ONE tuple: the metrics
@@ -877,43 +878,55 @@ class ModelServer:
                 shed_depth = self._pending
             else:
                 self._pending += 1
-        if shed_depth is not None:
-            self._count("shed")
-            # Front-door sheds are MISSES: the user got nothing, and
-            # a server that sheds its way past the engine must not
-            # read as 100% goodput (the invariant
-            # docs/observability.md states; engine-level sheds are
-            # judged through their results the same way). Outside the
-            # pending lock: the ledger fold must not serialize the
-            # admission gate during exactly the storm that sheds.
-            self._observe_shed(req)
-            # Load-proportional backoff hint: clients that honor
-            # ``retry_after_s`` (see :func:`request`) spread their
-            # retries with the depth of the queue they bounced off,
-            # instead of hammering a shedding server in lockstep.
-            return self._error(
-                "overloaded",
-                f"{shed_depth} generation payloads already "
-                f"pending (bound {self.max_pending}); retry with "
-                "backoff",
-                retry_after_s=round(
-                    min(max(0.1 * shed_depth, 0.05), 2.0), 3
-                ),
-            )
-        # Enqueue stamp BEFORE the engine lock: a request's queue-wait
-        # must include the time its payload spent waiting on other
-        # generations, not just the engine's admission queue.
-        enqueue_t = time.monotonic()
-        try:
-            if self._concurrent:
-                self._count("requests")
-                return self._generate(req, enqueue_t, stream_f)
-            with self._engine_lock:
-                self._count("requests")
-                return self._generate(req, enqueue_t, stream_f)
-        finally:
-            with self._pending_lock:
-                self._pending -= 1
+        rows = req.get("requests", req.get("input_ids"))
+        with trace_span(
+            "entry:payload",
+            requests=len(rows) if isinstance(rows, list) else 1,
+            shed=int(shed_depth is not None), _ring=False,
+        ):
+            if shed_depth is not None:
+                return self._shed(req, shed_depth)
+            # Enqueue stamp BEFORE the engine lock: a request's
+            # queue-wait must include the time its payload spent
+            # waiting on other generations, not just the engine's
+            # admission queue.
+            enqueue_t = time.monotonic()
+            try:
+                if self._concurrent:
+                    self._count("requests")
+                    return self._generate(req, enqueue_t, stream_f)
+                with self._engine_lock:
+                    self._count("requests")
+                    return self._generate(req, enqueue_t, stream_f)
+            finally:
+                with self._pending_lock:
+                    self._pending -= 1
+
+    def _shed(self, req: dict, shed_depth: int) -> dict:
+        """The front door's ``overloaded`` reply to a payload that
+        found ``shed_depth`` others already pending."""
+        self._count("shed")
+        # Front-door sheds are MISSES: the user got nothing, and
+        # a server that sheds its way past the engine must not
+        # read as 100% goodput (the invariant
+        # docs/observability.md states; engine-level sheds are
+        # judged through their results the same way). Outside the
+        # pending lock: the ledger fold must not serialize the
+        # admission gate during exactly the storm that sheds.
+        self._observe_shed(req)
+        # Load-proportional backoff hint: clients that honor
+        # ``retry_after_s`` (see :func:`request`) spread their
+        # retries with the depth of the queue they bounced off,
+        # instead of hammering a shedding server in lockstep.
+        return self._error(
+            "overloaded",
+            f"{shed_depth} generation payloads already "
+            f"pending (bound {self.max_pending}); retry with "
+            "backoff",
+            retry_after_s=round(
+                min(max(0.1 * shed_depth, 0.05), 2.0), 3
+            ),
+        )
 
     def _observe_synthetic(self, n: int, slo_class, enqueue_t,
                            status: str, tokens_out: int = 0) -> None:

@@ -70,6 +70,7 @@ def main():
 
     def shard_fn(xi):
         return comm_pallas_call(
+            "tutorial_signal_wait",
             kernel,
             jax.ShapeDtypeStruct(xi.shape, xi.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
