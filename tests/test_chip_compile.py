@@ -86,28 +86,127 @@ def compile_for_chip(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def pool(ctx, dtype):
-    return sds(ctx, (NUM_PAGES, HKV, PAGE, D), dtype)
-
-
+@pytest.mark.parametrize("pool_form", ["one_layer", "layer_of_pool"])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_flash_decode(chip, kv):
+def test_paged_flash_decode(chip, kv, pool_form):
+    """``layer_of_pool``: the served form — the whole 36-layer pool and
+    a traced layer index, which rides into the kernel inside the page
+    table (``table + layer * P`` over the ``[L * P, ...]`` view)."""
     from triton_distributed_tpu.ops.attention.flash_decode import (
         paged_flash_decode,
     )
 
-    args = [sds(chip, (BATCH, HQ, D), BF16),
-            pool(chip, BF16 if kv == "bf16" else jnp.int8),
-            pool(chip, BF16 if kv == "bf16" else jnp.int8),
+    lead = (36,) if pool_form == "layer_of_pool" else ()
+    kv_dtype = BF16 if kv == "bf16" else jnp.int8
+    pages = sds(chip, (*lead, NUM_PAGES, HKV, PAGE, D), kv_dtype)
+    args = [sds(chip, (BATCH, HQ, D), BF16), pages, pages,
             sds(chip, (BATCH, PPS), jnp.int32),
             sds(chip, (BATCH,), jnp.int32)]
-    fn = paged_flash_decode
+    if lead:
+        args.append(sds(chip, (), jnp.int32))
     if kv == "int8":
-        scale = sds(chip, (NUM_PAGES, HKV), jnp.float32)
+        scale = sds(chip, (*lead, NUM_PAGES, HKV), jnp.float32)
         args += [scale, scale]
-        fn = lambda q, k, v, t, n, ks, vs: paged_flash_decode(  # noqa: E731
-            q, k, v, t, n, k_scale=ks, v_scale=vs)
-    assert "tpu_custom_call" in compile_for_chip(fn, *args)
+
+    def fn(q, k, v, t, n, *rest):
+        layer = rest[0] if lead else None
+        ks, vs = rest[-2:] if kv == "int8" else (None, None)
+        return paged_flash_decode(
+            q, k, v, t, n, layer=layer, k_scale=ks, v_scale=vs)
+
+    text = compile_for_chip(fn, *args)
+    assert "tpu_custom_call" in text
+    # The kernel reads pages of the pool it was given: nothing
+    # pool-shaped is sliced or copied for it.
+    assert not _pool_shaped_moves(text, (*lead, NUM_PAGES, HKV, PAGE, D), kv)
+
+
+def _pool_shaped_moves(hlo_text, pool_shape, kv="bf16"):
+    """prof/described.py's check (the builder's tool prints the same
+    list): instructions that copy or slice pool-sized data."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).parent.parent / "prof" / "described.py"
+    spec = importlib.util.spec_from_file_location("prof_described", path)
+    described = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(described)
+    return described.pool_shaped_moves(
+        hlo_text, pool_shape, "bf16" if kv == "bf16" else "s8")
+
+
+@pytest.fixture
+def qwen3_4b(chip):
+    """Qwen3-4B on the described chip with parameter SHAPES (a
+    described device holds no arrays)."""
+    from triton_distributed_tpu.models.config import get_config
+    from triton_distributed_tpu.models.qwen import Qwen3
+
+    model = Qwen3(get_config("Qwen/Qwen3-4B"), ctx=chip)
+    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
+    model.params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, model.param_shardings,
+    )
+    return model
+
+
+@pytest.mark.parametrize("program,slots,kv", [
+    ("decode", 4, "bf16"),   # the benchmark's cells
+    ("decode", 8, "bf16"),   # refused until PR 27: 17.31 GB of 15.75
+    ("chunk", 4, "bf16"),    # one width of tdt_prefill_chunk
+    ("chunk", 8, "bf16"),
+    ("decode", 4, "int8"),
+    ("chunk", 4, "int8"),
+])
+def test_served_step_addresses_pool_in_place(chip, qwen3_4b, program, slots,
+                                             kv):
+    """The served decode step and chunk prefill at Qwen3-4B with a
+    donated cache: the pool rides the layer scan's carry and is written
+    and read in place at (layer, page). A scan that takes it as
+    ``xs``/``ys`` slices every layer's pool out, stacks it back and
+    copies the stack onto the donated buffer: 2.3 GiB of temporaries and
+    half the decode step at 4 slots, and no fit at 8."""
+    from triton_distributed_tpu.models.paged_kv_cache import (
+        PagedKVCache,
+        paged_cache_specs,
+    )
+
+    model, quant = qwen3_4b, kv == "int8"
+    cfg = model.cfg
+    pool_shape = (cfg.num_layers, slots * PPS + 1, HKV, PAGE, D)
+    pages = sds(chip, pool_shape, jnp.int8 if quant else BF16)
+    scale = sds(chip, pool_shape[:3], jnp.float32) if quant else None
+    cache = PagedKVCache(
+        k_pages=pages, v_pages=pages,
+        page_table=sds(chip, (slots, PPS), jnp.int32),
+        kv_len=sds(chip, (slots,), jnp.int32),
+        k_scale=scale, v_scale=scale,
+    )
+    i32 = sds(chip, (), jnp.int32)
+    if program == "decode":
+        fn = model.decode_fn_paged("xla", quantized=quant)
+        args = (model.params, sds(chip, (slots,), jnp.int32), cache)
+    else:
+        specs = paged_cache_specs("tp", quant)
+        fn = chip.shard_map(
+            functools.partial(model._prefill_chunk_shard, mode="xla",
+                              kv_pages=2),
+            in_specs=(model.param_specs, P(), specs, P(), P(), P(), P()),
+            out_specs=(P(), specs),
+        )
+        args = (model.params, sds(chip, (256,), jnp.int32), cache,
+                i32, i32, i32, i32)
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    # The donated pool is the output: no second pool among the
+    # temporaries, and the program fits the v5e's HBM.
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert mem.alias_size_in_bytes >= 2 * pages.size * pages.dtype.itemsize
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _pool_shaped_moves(text, pool_shape, kv)
 
 
 @pytest.mark.parametrize(
@@ -141,22 +240,14 @@ def test_flash_attention(chip, variant):
     assert "tpu_custom_call" in compile_for_chip(fn, *args)
 
 
-def test_megakernel_decode_launch(chip):
+def test_megakernel_decode_launch(chip, qwen3_4b):
     """The serving megakernel program at Qwen3-4B, all 36 layers and
     the 151,936-row LM head: one 8-step launch over the paged pool."""
     from triton_distributed_tpu.megakernel import MegaQwen3
     from triton_distributed_tpu.megakernel.code_generator import MegaConfig
-    from triton_distributed_tpu.models.config import get_config
     from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
-    from triton_distributed_tpu.models.qwen import Qwen3
 
-    cfg = get_config("Qwen/Qwen3-4B")
-    model = Qwen3(cfg, ctx=chip)
-    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
-    model.params = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        shapes, model.param_shardings,
-    )
+    model, cfg = qwen3_4b, qwen3_4b.cfg
     # The serving default (models/engine.py MegaDispatch._mega_model).
     mega = MegaQwen3(model, cfg=MegaConfig(
         fuse_norms=True, cross_prefetch=True, overlap_ar=True))

@@ -113,6 +113,95 @@ def test_paged_flash_decode_int8_parity(rng):
     )
 
 
+@pytest.mark.parametrize("layer", [1, 2], ids=["middle", "last"])
+def test_paged_flash_decode_int8_layer_addressed(rng, layer):
+    """Layer-addressed int8 decode over the whole pool (codes AND
+    ``[L, P, Hkv]`` scales) == the 4-D call on that layer's slices, bit
+    for bit."""
+    n_layers, b, hq, hkv, page, pps, p, d = 3, 2, 8, 2, 16, 4, 9, 32
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.float32)
+    pools = [_random_pool(rng, p, hkv, page, d) for _ in range(n_layers)]
+    k_q, k_sc = quantize_pages(jnp.stack([k for k, _ in pools]))
+    v_q, v_sc = quantize_pages(jnp.stack([v for _, v in pools]))
+    table = jnp.asarray(
+        rng.permutation(p - 1)[: b * pps].reshape(b, pps), jnp.int32)
+    lens = jnp.asarray([page * pps, 21], jnp.int32)
+    # Both sides jitted: the kernel's partials are the same bits, and
+    # the LSE merge around them is then the same XLA fusion too.
+    want = jax.jit(
+        lambda: paged_flash_decode(
+            q, k_q[layer], v_q[layer], table, lens,
+            k_scale=k_sc[layer], v_scale=v_sc[layer])
+    )()
+    got = jax.jit(
+        lambda lyr: paged_flash_decode(
+            q, k_q, v_q, table, lens, layer=lyr, k_scale=k_sc, v_scale=v_sc)
+    )(jnp.asarray(layer, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="per-page layout"):
+        paged_flash_decode(
+            q, k_q, v_q, table, lens, layer=layer,
+            k_scale=k_sc[layer], v_scale=v_sc[layer],
+        )
+
+
+@pytest.mark.parametrize("writer", ["append", "chunk"])
+def test_in_place_writers_int8_match_one_layer_scatter(rng, writer):
+    """The serving programs' in-place writers on the WHOLE int8 pool
+    (``layers/tp_attn.py``: the decode append's single-row updates, the
+    chunk's page read-merge-write) leave the addressed layer's codes and
+    scales bit-identical to ``quantized_row_scatter`` on that layer's
+    slice — the one-layer form every earlier test pins — and every other
+    layer untouched."""
+    from triton_distributed_tpu.layers.tp_attn import (
+        _append_rows,
+        _write_chunk,
+    )
+
+    n_layers, p, h, page, d, layer = 3, 7, 2, 8, 16, 1
+    pages = jnp.asarray(
+        rng.integers(-127, 128, (n_layers, p, h, page, d)), jnp.int8)
+    scales = jnp.asarray(
+        rng.uniform(0.01, 0.05, (n_layers, p, h)), jnp.float32)
+    lyr = jnp.asarray(layer, jnp.int32)
+    if writer == "append":
+        # Three sequences mid-page, one at offset 0 (scale reset), one
+        # inactive slot on the trash page.
+        rows = jnp.asarray(rng.standard_normal((5, h, d)) * 3, jnp.float32)
+        pids = jnp.asarray([2, 5, 3, 6, 0], jnp.int32)
+        offs = jnp.asarray([3, 7, 1, 0, 0], jnp.int32)
+        got_p, got_s = jax.jit(_append_rows)(
+            pages, scales, rows, lyr, pids, offs)
+    else:
+        # A 13-row chunk starting mid-page, 10 real rows: it crosses
+        # into a second and third page; the pad rows touch nothing but
+        # the trash page.
+        table_row = jnp.asarray([4, 2, 6, 1], jnp.int32)
+        start, n_real = 5, 10
+        rows = jnp.asarray(rng.standard_normal((13, h, d)) * 3, jnp.float32)
+        pos = start + np.arange(13)
+        real = np.arange(13) < n_real
+        pids = jnp.asarray(
+            np.where(real, np.asarray(table_row)[pos // page], 0), jnp.int32)
+        offs = jnp.asarray(np.where(real, pos % page, 0), jnp.int32)
+        got_p, got_s = jax.jit(_write_chunk)(
+            pages, scales, rows, lyr, table_row,
+            jnp.asarray(start, jnp.int32), jnp.asarray(n_real, jnp.int32))
+    want_p, want_s = quantized_row_scatter(
+        pages[layer], scales[layer], rows, pids, offs)
+    # Page 0 is the trash page: several rows land on one offset there
+    # and which of them survives is nobody's contract.
+    np.testing.assert_array_equal(
+        np.asarray(got_p)[layer, 1:], np.asarray(want_p)[1:])
+    np.testing.assert_array_equal(
+        np.asarray(got_s)[layer, 1:], np.asarray(want_s)[1:])
+    for other in (0, 2):
+        np.testing.assert_array_equal(
+            np.asarray(got_p)[other], np.asarray(pages)[other])
+        np.testing.assert_array_equal(
+            np.asarray(got_s)[other], np.asarray(scales)[other])
+
+
 def test_flash_decode_dense_int8_parity(rng):
     """Dense split-KV kernel with per-chunk scales (the layout the
     distributed 1/2-level variants pass through)."""

@@ -261,6 +261,118 @@ class TestPagedKVCache:
             np.asarray(out), np.asarray(gold), atol=2e-5, rtol=2e-5
         )
 
+    @pytest.mark.parametrize("page", [16, 64])
+    def test_paged_step_and_chunk_match_dense_cache(self, ctx4, rng, page):
+        """The served programs over the paged pool (the pool in the
+        layer scan's carry, rows written and pages read in place at
+        (layer, page)) against the dense-cache programs, three layers
+        deep: every layer's rows land where the dense cache has them and
+        nothing else in the pool changes. Where the two attentions split
+        the keys into the same blocks the match is BIT FOR BIT, logits
+        included: the chunk prefill at 16-token pages, the decode step
+        at one 64-token page a sequence; the other pairing differs by
+        float rounding of the block merge only."""
+        import dataclasses
+
+        from triton_distributed_tpu.models.paged_kv_cache import (
+            as_dense,
+            gather_bucket,
+            init_paged_cache,
+            write_prefill,
+        )
+
+        cfg = get_config("tiny", num_layers=3, max_length=64)
+        model = Qwen3(cfg, ctx=ctx4)
+        model.init_params(jax.random.key(0))
+        b, s, pps = 2, 16, 64 // page
+        toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, s)), jnp.int32)
+        logits_d, dense = model.prefill_batched(
+            toks, model.new_cache(b, 64), "xla")
+        # Shuffled page ids; page 0 is the trash page, in no table.
+        table = 1 + rng.permutation(b * pps).reshape(b, pps).astype(np.int32)
+
+        def empty():
+            cache, _ = init_paged_cache(
+                cfg, b, ctx4, max_length=64, page_size=page,
+                num_pages=b * pps + 1, assign_pages=False)
+            return dataclasses.replace(
+                cache, page_table=ctx4.replicate(jnp.asarray(table)))
+
+        def check(got, want, exact):
+            got, want = np.asarray(got), np.asarray(want)
+            if exact:
+                np.testing.assert_array_equal(got, want)
+            else:
+                np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+        # Chunk prefill, slot by slot, against the dense prefill.
+        cache, rows = empty(), []
+        for i in range(b):
+            row, cache = model.prefill_paged_chunk(
+                toks[i], i, 0, s, s - 1, cache, "xla",
+                kv_pages=gather_bucket(s, page, pps))
+            rows.append(row)
+        for got, want in zip(as_dense(cache), (dense.k, dense.v)):
+            assert got.shape[0] == cfg.num_layers
+            check(got[:, :, :, :s], want[:, :, :, :s], exact=page == 16)
+        check(jnp.stack(rows), logits_d, exact=page == 16)
+        # The trash page and the pages past the prompt were never written.
+        used = table[:, : -(-s // page)].ravel()
+        idle = np.setdiff1d(np.arange(b * pps + 1), used)
+        assert not np.asarray(cache.k_pages)[:, idle].any()
+
+        # One decode step from the SAME cached rows on both sides.
+        cache = empty()
+        for i in range(b):
+            cache = write_prefill(
+                cache, i, dense.k[:, i:i + 1], dense.v[:, i:i + 1], s)
+        before = np.asarray(cache.k_pages).copy()
+        nxt = jnp.asarray(rng.integers(0, cfg.vocab_size, (b,)), jnp.int32)
+        logits_d, dense = model.decode_step(nxt, dense, "xla")
+        logits_p, cache = model.decode_step(nxt, cache, "xla")
+        for got, want in zip(as_dense(cache), (dense.k, dense.v)):
+            check(got[:, :, :, : s + 1], want[:, :, :, : s + 1],
+                  exact=page == 64)
+        check(logits_p, logits_d, exact=page == 64)
+        # A step writes one row a sequence a layer, and nothing else.
+        changed = (np.asarray(cache.k_pages) != before).reshape(
+            cfg.num_layers, -1).sum(axis=1)
+        assert (changed <= b * cfg.num_kv_heads * cfg.head_dim).all()
+        assert (changed > 0).all()
+        np.testing.assert_array_equal(np.asarray(cache.kv_len), s + 1)
+
+    @pytest.mark.parametrize("start,rows", [
+        (0, 16),    # page-aligned, whole pages
+        (5, 13),    # starts mid-page, crosses two boundaries
+        (21, 3),    # a short speculative chunk inside one page
+        (27, 9),    # runs off the table: the overflow goes to page 0
+    ])
+    def test_write_chunk_in_place(self, rng, start, rows):
+        """``layers/tp_attn._write_chunk`` (page read-merge-write on the
+        whole pool) == the row scatter ``.at[layer, pids, :, offs, :]``
+        it replaces, on every page a sequence owns; the layers it does
+        not address are untouched."""
+        from triton_distributed_tpu.layers.tp_attn import _write_chunk
+
+        n_layers, p, h, page, d, layer = 3, 6, 2, 8, 16, 1
+        pool = jnp.asarray(
+            rng.standard_normal((n_layers, p, h, page, d)), jnp.bfloat16)
+        new = jnp.asarray(rng.standard_normal((rows, h, d)), jnp.float32)
+        table_row = jnp.asarray([4, 2, 5, 1], jnp.int32)
+        got, _ = jax.jit(_write_chunk)(
+            pool, None, new, jnp.asarray(layer, jnp.int32), table_row,
+            jnp.asarray(start, jnp.int32))
+        pos = start + np.arange(rows)
+        on = pos < table_row.shape[0] * page
+        pids = np.where(on, np.asarray(table_row)[np.minimum(pos // page, 3)], 0)
+        offs = np.where(on, pos % page, 0)
+        want = pool.at[layer, pids, :, offs, :].set(new.astype(jnp.bfloat16))
+        np.testing.assert_array_equal(
+            np.asarray(got)[:, 1:], np.asarray(want)[:, 1:])
+        # Nothing but the trash page differs from the pool elsewhere.
+        np.testing.assert_array_equal(
+            np.asarray(got)[[0, 2]], np.asarray(pool)[[0, 2]])
+
     def test_engine_serve_paged(self, ctx4):
         """Paged serving end-to-end matches dense serving token-for-token
         (parity: reference paged megakernel serving)."""

@@ -128,11 +128,105 @@ def tp_attn_prefill(
     return out, k, v
 
 
+# -- in-place writers of the paged pool -----------------------------------
+#
+# The pool ``[L, P, h, page, hd]`` rides a layer scan's carry whole
+# (``Qwen3._scan_layers_paged``) and is only ever addressed by (layer,
+# page): a scan that takes it as ``xs`` slices each layer's pool out,
+# stacks it back and copies the stack onto the donated buffer, every
+# step. Both writers are ``dynamic_update_slice``s, which XLA performs in
+# place on a loop-carried buffer in its own layout. A ``scatter`` of rows
+# ``.at[layer, pids, :, offs, :]`` into the carried pool is NOT: on the
+# TPU it asks for a pool laid out ``[L, P, page, h, hd]`` and XLA
+# re-lays the whole pool out before the loop, after it, and again for
+# the kernel in every layer.
+
+
+def _append_rows(pages, scales, rows, layer, pids, offs):
+    """Decode append: ``rows [B, h, hd]``, one per sequence, land at
+    ``(layer, pids[i], :, offs[i], :)`` — ``B`` single-row updates (an
+    int8 pool's rows first go through the one scale protocol). Inactive
+    slots all point at the trash page, where the last write wins.
+    Returns ``(pages, scales)``."""
+    if scales is not None:
+        from triton_distributed_tpu.models.paged_kv_cache import quantize_rows
+
+        pages, scales, rows = quantize_rows(
+            pages, scales, rows, pids, offs, layer
+        )
+    for i in range(rows.shape[0]):
+        pages = jax.lax.dynamic_update_slice(
+            pages, rows[i][None, None, :, None, :].astype(pages.dtype),
+            (layer, pids[i], 0, offs[i], 0),
+        )
+    return pages, scales
+
+
+def _write_chunk(pages, scales, rows, layer, table_row, start, n_real=None):
+    """Chunk write: ``rows [C, h, hd]`` are the CONTIGUOUS sequence
+    positions ``start + i`` of the sequence whose pages ``table_row``
+    lists, so they fill at most ``(C - 1) // page + 2`` consecutive
+    table entries: each of those pages is read, merged with its rows
+    and written back whole. Rows that fall off the table (final-chunk
+    right-padding past capacity) go to the trash page (id 0) instead of
+    letting a clamped index corrupt the last real page. On an int8 pool
+    the rows past ``n_real`` (the chunk's right-padding) are kept out of
+    the sequence's pages and their scales altogether: on a full-width
+    pool pad KV is inert (overwritten/masked), but a quantized pad row
+    would grow — or, at page offset 0, seed — its page's scale with
+    garbage amax, permanently requantizing accepted history against rows
+    that are not part of the sequence. Returns ``(pages, scales)``."""
+    c, h, hd = rows.shape
+    page = pages.shape[3]
+    pps = table_row.shape[0]
+    idx = jnp.arange(c, dtype=jnp.int32)
+    n_write = c
+    if scales is not None:
+        from triton_distributed_tpu.models.paged_kv_cache import quantize_rows
+
+        pos = start + idx
+        real = (pos >= 0) & (pos < pps * page)
+        if n_real is not None:
+            n_write = n_real
+            real &= idx < n_real
+        pids = jnp.where(
+            real, jnp.take(table_row, jnp.clip(pos // page, 0, pps - 1)), 0
+        )
+        pages, scales, rows = quantize_rows(
+            pages, scales, rows, pids, jnp.where(real, pos % page, 0), layer
+        )
+    # Row r of table entry ``first + j`` is chunk row ``j*page + r -
+    # start % page``: a page-long window of the chunk padded by a page
+    # either side, masked to the rows the chunk really holds there.
+    padded = jnp.pad(
+        rows.astype(pages.dtype).swapaxes(0, 1),
+        ((0, 0), (page, page), (0, 0)),
+    )  # [h, page + C + page, hd]
+    first = start // page
+    shift = page - start % page
+    r = jnp.arange(page, dtype=jnp.int32)
+    for j in range((c + page - 2) // page + 1):
+        entry = first + j
+        src = j * page + r - (start % page)  # chunk row held by page row r
+        mine = (src >= 0) & (src < n_write)
+        on_table = (entry >= 0) & (entry < pps)
+        pid = jnp.where(
+            on_table, jnp.take(table_row, jnp.clip(entry, 0, pps - 1)), 0
+        )
+        at = (layer, pid, 0, 0, 0)
+        old = jax.lax.dynamic_slice(pages, at, (1, 1, h, page, hd))
+        new = jax.lax.dynamic_slice_in_dim(padded, j * page + shift, page, 1)
+        merged = jnp.where(mine[:, None], new, old[0, 0])  # [h, page, hd]
+        pages = jax.lax.dynamic_update_slice(pages, merged[None, None], at)
+    return pages, scales
+
+
 def tp_attn_prefill_paged_chunk(
     params: TPAttnParams,
     x: jax.Array,           # [C, d] replicated — one chunk of ONE sequence
-    k_pages: jax.Array,     # [P, hkv_loc, page, hd] — this layer's pool shard
+    k_pages: jax.Array,     # [L, P, hkv_loc, page, hd] — the WHOLE pool shard
     v_pages: jax.Array,
+    layer: jax.Array,       # scalar int32 — the layer addressed in the pool
     table_row: jax.Array,   # [pages_per_seq] int32 — the sequence's pages
     q_offset: jax.Array,    # scalar int32 — tokens already cached
     dims: TPAttnDims,
@@ -141,7 +235,7 @@ def tp_attn_prefill_paged_chunk(
     axis: str = "tp",
     mode: Mode = "xla_ar",
     ctx: DistContext | None = None,
-    k_scale: jax.Array | None = None,  # [P, hkv_loc] f32 — int8 pool scales
+    k_scale: jax.Array | None = None,  # [L, P, hkv_loc] f32 — int8 scales
     v_scale: jax.Array | None = None,
     q_end: jax.Array | None = None,    # scalar int32 — end of REAL rows
     rope_pos: jax.Array | None = None,  # [C] int32 — rope positions (tree)
@@ -153,7 +247,9 @@ def tp_attn_prefill_paged_chunk(
     flash attention of the chunk's queries against the WHOLE cached
     context (prefix pages + the chunk itself) via the dynamic
     ``kv_offset``. This is the prefix-cache suffix prefill: matched
-    prefix pages are read, never recomputed.
+    prefix pages are read, never recomputed. The pool is the whole
+    ``[L, ...]`` array off the layer scan's carry, written and gathered
+    in place at ``(layer, page)`` (:func:`_write_chunk`).
 
     With ``k_scale``/``v_scale`` (int8 pool) the scatter quantizes the
     chunk's rows (growing/resetting the touched pages' scales) and the
@@ -183,8 +279,7 @@ def tp_attn_prefill_paged_chunk(
     ``(out [C, d], k_pages, v_pages, k_scale, v_scale)``.
     """
     c = x.shape[0]
-    page = k_pages.shape[2]
-    pps = table_row.shape[0]
+    page = k_pages.shape[3]
     quant = k_scale is not None
     qkv = jnp.dot(x, params.wqkv, preferred_element_type=jnp.float32).astype(
         x.dtype
@@ -198,36 +293,14 @@ def tp_attn_prefill_paged_chunk(
     k = apply_rope(k.swapaxes(0, 1), rpos, dims.rope_theta)
     v = v.swapaxes(0, 1)
 
-    # Scatter the chunk's KV through the table. Final-chunk right-padding
-    # may run past the table's capacity; those rows are routed to the
-    # trash page (id 0) instead of letting a clamped gather corrupt the
-    # last real page.
-    valid = pos < pps * page
-    pids = jnp.where(
-        valid, jnp.take(table_row, jnp.clip(pos // page, 0, pps - 1)), 0
+    # Write the chunk's KV through the table, in place.
+    n_real = None if q_end is None else q_end - q_offset
+    k_pages, k_scale = _write_chunk(
+        k_pages, k_scale, k.swapaxes(0, 1), layer, table_row, q_offset, n_real
     )
-    offs = jnp.where(valid, pos % page, 0)
-    if quant:
-        from triton_distributed_tpu.models.paged_kv_cache import (
-            quantized_row_scatter,
-        )
-
-        real = valid if q_end is None else valid & (pos < q_end)
-        pids_q = jnp.where(real, pids, 0)
-        offs_q = jnp.where(real, offs, 0)
-        k_pages, k_scale = quantized_row_scatter(
-            k_pages, k_scale, k.swapaxes(0, 1), pids_q, offs_q
-        )
-        v_pages, v_scale = quantized_row_scatter(
-            v_pages, v_scale, v.swapaxes(0, 1), pids_q, offs_q
-        )
-    else:
-        k_pages = k_pages.at[pids, :, offs, :].set(
-            k.swapaxes(0, 1).astype(k_pages.dtype)
-        )
-        v_pages = v_pages.at[pids, :, offs, :].set(
-            v.swapaxes(0, 1).astype(v_pages.dtype)
-        )
+    v_pages, v_scale = _write_chunk(
+        v_pages, v_scale, v.swapaxes(0, 1), layer, table_row, q_offset, n_real
+    )
 
     # Attend over the sequence's dense view (prefix + chunk). The
     # gather is bounded to ``kv_pages`` table entries — the caller's
@@ -242,15 +315,15 @@ def tp_attn_prefill_paged_chunk(
     )
 
     gather_row = table_row if kv_pages is None else table_row[:kv_pages]
-    k_dense = pages_to_dense(k_pages, gather_row[None])  # [1, h, S_kv, hd]
-    v_dense = pages_to_dense(v_pages, gather_row[None])
+    k_dense = pages_to_dense(k_pages, gather_row[None], layer)  # [1,h,S_kv,hd]
+    v_dense = pages_to_dense(v_pages, gather_row[None], layer)
     s_max = gather_row.shape[0] * page
     if quant:
         # The gathered view keeps int8 codes; per-page scales gather
         # through the same bucket and dequantize inside the kernel
         # (block_k = page so pages and kv blocks coincide).
-        ks_dense = jnp.take(k_scale, gather_row, axis=0).T[None]  # [1,h,pps]
-        vs_dense = jnp.take(v_scale, gather_row, axis=0).T[None]
+        ks_dense = k_scale[layer, gather_row].T[None]  # [1, h, pps]
+        vs_dense = v_scale[layer, gather_row].T[None]
         o = flash_attention(
             q[None], k_dense, v_dense, causal=True, kv_offset=q_offset,
             block_k=page, k_scale=ks_dense, v_scale=vs_dense,
@@ -275,8 +348,9 @@ def tp_attn_prefill_paged_chunk(
 def tp_attn_prefill_paged_chunk_cold(
     params: TPAttnParams,
     x: jax.Array,           # [C, d] replicated — one chunk of ONE sequence
-    k_pages: jax.Array,     # [P, hkv_loc, page, hd] — this layer's pool shard
+    k_pages: jax.Array,     # [L, P, hkv_loc, page, hd] — the WHOLE pool shard
     v_pages: jax.Array,
+    layer: jax.Array,       # scalar int32 — the layer addressed in the pool
     table_row: jax.Array,   # [budget_pages] int32 — the slot's RESIDENT row
     k_cold: jax.Array,      # [hkv_loc, S_bucket, hd] — demoted-page window
     v_cold: jax.Array,
@@ -287,7 +361,7 @@ def tp_attn_prefill_paged_chunk_cold(
     axis: str = "tp",
     mode: Mode = "xla_ar",
     ctx: DistContext | None = None,
-    k_scale: jax.Array | None = None,   # [P, hkv_loc] f32 — int8 pool scales
+    k_scale: jax.Array | None = None,   # [L, P, hkv_loc] f32 — int8 scales
     v_scale: jax.Array | None = None,
     ks_cold: jax.Array | None = None,   # [hkv_loc, S_bucket/page] f32
     vs_cold: jax.Array | None = None,
@@ -315,8 +389,7 @@ def tp_attn_prefill_paged_chunk_cold(
     Returns ``(out [C, d], k_pages, v_pages, k_scale, v_scale)``.
     """
     c = x.shape[0]
-    page = k_pages.shape[2]
-    n_res = table_row.shape[0]
+    page = k_pages.shape[3]
     s_bucket = k_cold.shape[1]
     quant = k_scale is not None
     qkv = jnp.dot(x, params.wqkv, preferred_element_type=jnp.float32).astype(
@@ -330,37 +403,19 @@ def tp_attn_prefill_paged_chunk_cold(
     k = apply_rope(k.swapaxes(0, 1), pos, dims.rope_theta)
     v = v.swapaxes(0, 1)
 
-    # Scatter at LOCAL resident positions. Final-chunk right-padding may
-    # run past the resident capacity; route those rows (and any row that
-    # would land before the resident window — impossible by the engine's
-    # demote contract, but cheap to guard) to the trash page.
+    # Write at LOCAL resident positions, in place. Final-chunk
+    # right-padding may run past the resident capacity; those rows (and
+    # any row that would land before the resident window — impossible by
+    # the engine's demote contract, but cheap to guard) go to the trash
+    # page.
     lpos = pos - s_cold
-    valid = (lpos >= 0) & (lpos < n_res * page)
-    pids = jnp.where(
-        valid, jnp.take(table_row, jnp.clip(lpos // page, 0, n_res - 1)), 0
+    n_real = None if q_end is None else q_end - q_offset
+    k_pages, k_scale = _write_chunk(
+        k_pages, k_scale, k.swapaxes(0, 1), layer, table_row, lpos[0], n_real
     )
-    offs = jnp.where(valid, lpos % page, 0)
-    if quant:
-        from triton_distributed_tpu.models.paged_kv_cache import (
-            quantized_row_scatter,
-        )
-
-        real = valid if q_end is None else valid & (pos < q_end)
-        pids_q = jnp.where(real, pids, 0)
-        offs_q = jnp.where(real, offs, 0)
-        k_pages, k_scale = quantized_row_scatter(
-            k_pages, k_scale, k.swapaxes(0, 1), pids_q, offs_q
-        )
-        v_pages, v_scale = quantized_row_scatter(
-            v_pages, v_scale, v.swapaxes(0, 1), pids_q, offs_q
-        )
-    else:
-        k_pages = k_pages.at[pids, :, offs, :].set(
-            k.swapaxes(0, 1).astype(k_pages.dtype)
-        )
-        v_pages = v_pages.at[pids, :, offs, :].set(
-            v.swapaxes(0, 1).astype(v_pages.dtype)
-        )
+    v_pages, v_scale = _write_chunk(
+        v_pages, v_scale, v.swapaxes(0, 1), layer, table_row, lpos[0], n_real
+    )
 
     from triton_distributed_tpu.ops.attention.flash_decode import (
         lse_combine,
@@ -369,11 +424,11 @@ def tp_attn_prefill_paged_chunk_cold(
 
     # Resident partial: causal at the LOCAL offset (rows live at local
     # positions lpos), over the resident dense view.
-    k_dense = pages_to_dense(k_pages, table_row[None])  # [1, h, S_res, hd]
-    v_dense = pages_to_dense(v_pages, table_row[None])
+    k_dense = pages_to_dense(k_pages, table_row[None], layer)  # [1,h,S_res,hd]
+    v_dense = pages_to_dense(v_pages, table_row[None], layer)
     if quant:
-        ks_dense = jnp.take(k_scale, table_row, axis=0).T[None]
-        vs_dense = jnp.take(v_scale, table_row, axis=0).T[None]
+        ks_dense = k_scale[layer, table_row].T[None]
+        vs_dense = v_scale[layer, table_row].T[None]
         o_res, lse_res = flash_attention(
             q[None], k_dense, v_dense, causal=True, kv_offset=lpos[0],
             block_k=page, k_scale=ks_dense, v_scale=vs_dense,
@@ -419,8 +474,9 @@ def tp_attn_prefill_paged_chunk_cold(
 def tp_attn_decode_sharded(
     params: TPAttnParams,
     x: jax.Array,           # [1, d] replicated — the slot's new token
-    k_pages: jax.Array,     # [P, hkv_loc, page, hd] — this layer's pool shard
+    k_pages: jax.Array,     # [L, P, hkv_loc, page, hd] — the WHOLE pool shard
     v_pages: jax.Array,
+    layer: jax.Array,       # scalar int32 — the layer addressed in the pool
     table_row: jax.Array,   # [budget_pages] int32 — the slot's RESIDENT row
     kv_len_loc: jax.Array,  # [1] int32 — tokens in the resident region
     k_cold: jax.Array,      # [hkv_loc, S_bucket, hd] — demoted-page window
@@ -431,7 +487,7 @@ def tp_attn_decode_sharded(
     axis: str = "tp",
     mode: Mode = "xla_ar",
     ctx: DistContext | None = None,
-    k_scale: jax.Array | None = None,   # [P, hkv_loc] f32 — int8 pool scales
+    k_scale: jax.Array | None = None,   # [L, P, hkv_loc] f32 — int8 scales
     v_scale: jax.Array | None = None,
     ks_cold: jax.Array | None = None,   # [hkv_loc, S_bucket/page] f32
     vs_cold: jax.Array | None = None,
@@ -453,8 +509,7 @@ def tp_attn_decode_sharded(
         lse_combine,
     )
 
-    page = k_pages.shape[2]
-    quant = k_scale is not None
+    page = k_pages.shape[3]
     qkv = jnp.dot(x, params.wqkv, preferred_element_type=jnp.float32).astype(
         x.dtype
     )
@@ -465,31 +520,16 @@ def tp_attn_decode_sharded(
     q = apply_rope(q, pos_abs[:, None], dims.rope_theta)
     k = apply_rope(k, pos_abs[:, None], dims.rope_theta)
 
-    if quant:
-        from triton_distributed_tpu.models.paged_kv_cache import (
-            quantized_row_scatter,
-        )
-
-        pids = jnp.take(table_row, kv_len_loc // page)
-        k_pages, k_scale = quantized_row_scatter(
-            k_pages, k_scale, k, pids, kv_len_loc % page
-        )
-        v_pages, v_scale = quantized_row_scatter(
-            v_pages, v_scale, v, pids, kv_len_loc % page
-        )
-    else:
-        pid = jnp.take(table_row, kv_len_loc[0] // page)
-        k_pages = jax.lax.dynamic_update_slice(
-            k_pages, k[0][:, None, :].astype(k_pages.dtype)[None],
-            (pid, 0, kv_len_loc[0] % page, 0),
-        )
-        v_pages = jax.lax.dynamic_update_slice(
-            v_pages, v[0][:, None, :].astype(v_pages.dtype)[None],
-            (pid, 0, kv_len_loc[0] % page, 0),
-        )
+    pids = jnp.take(table_row, kv_len_loc // page)
+    k_pages, k_scale = _append_rows(
+        k_pages, k_scale, k, layer, pids, kv_len_loc % page
+    )
+    v_pages, v_scale = _append_rows(
+        v_pages, v_scale, v, layer, pids, kv_len_loc % page
+    )
 
     o_res, lse_res = paged_flash_decode(
-        q, k_pages, v_pages, table_row[None], kv_len_loc + 1,
+        q, k_pages, v_pages, table_row[None], kv_len_loc + 1, layer=layer,
         return_lse=True, k_scale=k_scale, v_scale=v_scale,
     )
     o_cold, lse_cold = dense_flash_decode(
@@ -568,8 +608,9 @@ def tp_attn_decode(
 def tp_attn_decode_paged(
     params: TPAttnParams,
     x: jax.Array,          # [B, d] replicated — one new token per sequence
-    k_pages: jax.Array,    # [P, hkv_loc, page, hd] — this layer's pool shard
+    k_pages: jax.Array,    # [L, P, hkv_loc, page, hd] — the WHOLE pool shard
     v_pages: jax.Array,
+    layer: jax.Array,      # scalar int32 — the layer addressed in the pool
     page_table: jax.Array,  # [B, pages_per_seq] int32
     kv_len: jax.Array,      # [B] int32
     dims: TPAttnDims,
@@ -577,7 +618,7 @@ def tp_attn_decode_paged(
     axis: str = "tp",
     mode: Mode = "pallas_ar",
     ctx: DistContext | None = None,
-    k_scale: jax.Array | None = None,  # [P, hkv_loc] f32 — int8 pool scales
+    k_scale: jax.Array | None = None,  # [L, P, hkv_loc] f32 — int8 scales
     v_scale: jax.Array | None = None,
 ):
     """Per-shard decode step over a paged KV pool (inside ``shard_map``).
@@ -588,6 +629,12 @@ def tp_attn_decode_paged(
     gather). Parity: the reference megakernel's paged decode
     (``mega_triton_kernel/models/paged_kv_cache.py``).
 
+    The pool is the WHOLE ``[L, ...]`` array off the layer scan's carry:
+    the step's ``B`` rows land in place at ``(layer, page_table[i, pos //
+    page], :, pos % page, :)`` and the kernel reads pages at ``(layer,
+    page)``, so a step moves the rows it writes and the pages it
+    attends, never a layer's pool.
+
     With ``k_scale``/``v_scale`` (int8 pool) the append quantizes each
     new row into its page (growing the page scale, requantizing when it
     moves) and the attention streams int8 codes, dequantized inside the
@@ -597,8 +644,7 @@ def tp_attn_decode_paged(
     from triton_distributed_tpu.ops.attention import paged_flash_decode
 
     b = x.shape[0]
-    page = k_pages.shape[2]
-    quant = k_scale is not None
+    page = k_pages.shape[3]
     qkv = jnp.dot(x, params.wqkv, preferred_element_type=jnp.float32).astype(
         x.dtype
     )
@@ -608,38 +654,18 @@ def tp_attn_decode_paged(
     q = apply_rope(q, kv_len[:, None], dims.rope_theta)
     k = apply_rope(k, kv_len[:, None], dims.rope_theta)
 
-    def upd(pages, new):  # pages [P, h, page, hd], new [B, h, hd]
-        for i in range(b):
-            pos = kv_len[i]
-            pid = page_table[i, pos // page]
-            pages = jax.lax.dynamic_update_slice(
-                pages, new[i][None, :, None, :].astype(pages.dtype),
-                (pid, 0, pos % page, 0),
-            )
-        return pages
-
-    def upd_q(pages, scales, new):
-        from triton_distributed_tpu.models.paged_kv_cache import (
-            quantized_row_scatter,
-        )
-
-        # One batched scatter for all B sequences (active rows never
-        # share a page; inactive rows fan into the trash page, where
-        # the scatter's duplicate-pid contract holds).
-        pids = page_table[jnp.arange(b), kv_len // page]
-        return quantized_row_scatter(
-            pages, scales, new, pids, kv_len % page
-        )
-
-    if quant:
-        k_pages, k_scale = upd_q(k_pages, k_scale, k)
-        v_pages, v_scale = upd_q(v_pages, v_scale, v)
-    else:
-        k_pages = upd(k_pages, k)
-        v_pages = upd(v_pages, v)
+    # Active rows never share a page; inactive rows fan into the trash
+    # page, where the scale protocol's duplicate-pid contract holds.
+    pids = page_table[jnp.arange(b), kv_len // page]
+    k_pages, k_scale = _append_rows(
+        k_pages, k_scale, k, layer, pids, kv_len % page
+    )
+    v_pages, v_scale = _append_rows(
+        v_pages, v_scale, v, layer, pids, kv_len % page
+    )
 
     o = paged_flash_decode(
-        q, k_pages, v_pages, page_table, kv_len + 1,
+        q, k_pages, v_pages, page_table, kv_len + 1, layer=layer,
         k_scale=k_scale, v_scale=v_scale,
     )
     o_flat = o.reshape(b, dims.hq_loc * dims.head_dim).astype(x.dtype)

@@ -97,42 +97,45 @@ def quantize_pages(pages: jax.Array) -> tuple[jax.Array, jax.Array]:
     return quantize_page(pages, scale), scale
 
 
-def quantized_row_scatter(pages, scales, rows, pids, offs):
-    """Scatter ``rows [C, H, hd]`` into a ONE-LAYER int8 pool
-    ``pages [P, H, page, hd]`` at ``(pids[c], offs[c])``: grow each
-    touched page's ``scales [P, H]`` to cover its new rows (reset, not
-    grown, when a row lands at page offset 0 — a fresh page has no
-    valid prior rows, and a stale tenant's scale must not survive page
-    recycling), re-quantize the touched pages' existing codes under the
-    grown scale (value-exact when it did not move), then write the rows
-    as int8.
+def quantize_rows(pages, scales, rows, pids, offs, layer=None):
+    """The int8 pool's scale protocol for ``rows [C, H, hd]`` bound for
+    ``(pids[c], offs[c])``: grow each touched page's scale to cover its
+    new rows (reset, not grown, when a row lands at page offset 0 — a
+    fresh page has no valid prior rows, and a stale tenant's scale must
+    not survive page recycling), re-quantize the touched pages' existing
+    codes under the grown scale (value-exact when it did not move), and
+    quantize the rows under it. Returns ``(pages, scales, int8 rows)``;
+    writing the rows is the caller's (:func:`quantized_row_scatter`, or
+    ``layers/tp_attn.py``'s in-place writers).
 
-    THE one implementation of the scale protocol: the chunk-prefill
-    scatter and the decode append (``layers/tp_attn.py``) call it
-    directly (C = chunk width / batch), and :func:`append_n` vmaps it
-    over the layer axis — any change to the reset/grow/requant rule
-    lands in every write path at once.
+    ``pages [P, H, page, hd]`` / ``scales [P, H]`` are ONE layer's, or
+    with ``layer`` (traced: a layer scan's index) the WHOLE pool's
+    ``[L, P, H, page, hd]`` / ``[L, P, H]``, addressed in place at
+    (layer, page) so no layer is sliced out of the pool or stacked back.
 
+    THE one implementation of the reset/grow/requant rule: every write
+    path goes through it, so a change lands in all of them at once.
     Duplicate ``pids`` (several rows in one page, trash-page fan-in)
     are safe: scatter-min/max are associative and duplicate requant
     writes are identical."""
+    at = (lambda ix: ix) if layer is None else (lambda ix: (layer, ix))
     rows = rows.astype(jnp.float32)
     row_sc = jnp.max(jnp.abs(rows), axis=-1) / _Q_MAX  # [C, H]
     clear = jnp.broadcast_to(
         jnp.where(offs == 0, 0.0, jnp.inf)[:, None], row_sc.shape
     )
-    new_scales = scales.at[pids].min(clear)
-    new_scales = new_scales.at[pids].max(row_sc)
-    old_sc = jnp.take(scales, pids, axis=0)      # [C, H]
-    new_sc = jnp.take(new_scales, pids, axis=0)
+    new_scales = scales.at[at(pids)].min(clear)
+    new_scales = new_scales.at[at(pids)].max(row_sc)
+    old_sc = scales[at(pids)]      # [C, H]
+    new_sc = new_scales[at(pids)]
 
     def requant(pages):
         ratio = old_sc / jnp.maximum(new_sc, _SCALE_EPS)
-        got = jnp.take(pages, pids, axis=0).astype(jnp.float32)
+        got = pages[at(pids)].astype(jnp.float32)
         req = jnp.clip(
             jnp.round(got * ratio[..., None, None]), -_Q_MAX, _Q_MAX
         ).astype(jnp.int8)
-        return pages.at[pids].set(req)
+        return pages.at[at(pids)].set(req)
 
     # Steady-state decode rarely moves a scale (a page's amax settles
     # after its first rows): skip the page-sized read+rewrite entirely
@@ -147,8 +150,18 @@ def quantized_row_scatter(pages, scales, rows, pids, offs):
         jnp.round(rows / jnp.maximum(new_sc[..., None], _SCALE_EPS)),
         -_Q_MAX, _Q_MAX,
     ).astype(jnp.int8)
-    pages = pages.at[pids, :, offs, :].set(q_rows)
-    return pages, new_scales
+    return pages, new_scales, q_rows
+
+
+def quantized_row_scatter(pages, scales, rows, pids, offs):
+    """Scatter ``rows [C, H, hd]`` into a ONE-LAYER int8 pool
+    ``pages [P, H, page, hd]`` at ``(pids[c], offs[c])`` under
+    :func:`quantize_rows`' scale protocol. :func:`append_n` vmaps it
+    over the layer axis; the serving programs, whose pool rides a layer
+    scan's carry whole, call :func:`quantize_rows` with their layer and
+    write the rows in place (``layers/tp_attn.py``)."""
+    pages, scales, q_rows = quantize_rows(pages, scales, rows, pids, offs)
+    return pages.at[pids, :, offs, :].set(q_rows), scales
 
 
 # vmap of the row scatter over a leading layer axis — append_n's

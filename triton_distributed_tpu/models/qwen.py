@@ -22,6 +22,7 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from triton_distributed_tpu.layers.tp_attn import (
@@ -261,43 +262,79 @@ class Qwen3:
         logits = self._logits(params, x)
         return logits, KVCache(k=k_new, v=v_new, kv_len=cache.kv_len + 1)
 
-    def _decode_shard_paged(self, params, tokens, cache, *, mode: Mode):
-        """One decode step over a :class:`PagedKVCache`, per-shard.
+    def _scan_layers_paged(self, params, x, cache, attn_fn, mode: Mode,
+                           layer_xs=()):
+        """The layer scan of every program over a :class:`PagedKVCache`.
 
-        Same layer scan as :meth:`_decode_shard`, but the attention
-        appends through the page table and reads the pool directly
-        (``paged_flash_decode``). Parity: the reference megakernel's
-        paged decode (``mega_triton_kernel/models/paged_kv_cache.py``).
+        The pools (and an int8 pool's scales; ``None`` on a full-width
+        one, which ``lax.scan`` threads through as an empty subtree)
+        ride the scan's CARRY whole, ``[L, P, hkv_loc, page, hd]``, and
+        the layer index comes in as an ``xs`` scalar: ``attn_fn(attn
+        params, h, k_pages, v_pages, layer, k_scale, v_scale, ar,
+        *layer_xs[l])`` writes its rows and reads its pages in place at
+        (layer, page) and hands the same arrays back. So the donated
+        pool IS the loop's buffer and a step moves only the rows and
+        pages it touches. Never pass the pool as ``xs``/``ys``: XLA then
+        slices each layer's pool out of the input, stacks it into a
+        second pool and copies that onto the donated one — at Qwen3-4B
+        with 4 slots 14 ms of a 27 ms decode step, and 2.3 GiB of
+        temporaries (docs/serving.md "Paged KV cache").
+
+        Returns ``(x, k_pages, v_pages, k_scale, v_scale)``.
         """
-        from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
-
         cfg = self.cfg
-        x = self._embed(params, tokens)
         ar = "pallas_ar" if mode == "pallas" else "xla_ar"
 
         def layer_fn(carry, inp):
-            x = carry
-            # kp/vp: [P, hkv_loc, page, hd] layer pool; ks/vs are the
-            # int8 per-page-per-head scales, or None on a full-width
-            # pool (lax.scan threads the empty subtree through).
-            lp, kp, vp, ks, vs = inp
+            x, kp, vp, ks, vs = carry
+            # Pin the carried pool row-major, the layout it is donated
+            # in: left free, XLA gives the loop's pool the layout of a
+            # chunk's transposed update and re-lays the whole pool out
+            # before and after the loop.
+            kp, vp = (
+                with_layout_constraint(p, Layout(tuple(range(p.ndim))))
+                for p in (kp, vp)
+            )
+            lp, layer, *per_layer = inp
             h = rms_norm(x, lp.ln1, cfg.rms_eps)
-            a, kp, vp, ks, vs = tp_attn_decode_paged(
-                lp.attn, h, kp, vp, cache.page_table, cache.kv_len,
-                self.dims, axis=self.axis, mode=ar, ctx=self.ctx,
-                k_scale=ks, v_scale=vs,
+            a, kp, vp, ks, vs = attn_fn(
+                lp.attn, h, kp, vp, layer, ks, vs, ar, *per_layer
             )
             x = x + a
             h = rms_norm(x, lp.ln2, cfg.rms_eps)
             x = x + self._mlp_fwd(lp.mlp, h, ar)
-            return x, (kp, vp, ks, vs)
+            return (x, kp, vp, ks, vs), None
 
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer_fn, x,
-            (params.layers, cache.k_pages, cache.v_pages,
-             cache.k_scale, cache.v_scale),
+        carry, _ = jax.lax.scan(
+            layer_fn,
+            (x, cache.k_pages, cache.v_pages, cache.k_scale, cache.v_scale),
+            (params.layers, jnp.arange(cfg.num_layers, dtype=jnp.int32),
+             *layer_xs),
         )
-        x = rms_norm(x, params.norm, cfg.rms_eps)
+        return carry
+
+    def _decode_shard_paged(self, params, tokens, cache, *, mode: Mode):
+        """One decode step over a :class:`PagedKVCache`, per-shard.
+
+        Same layer math as :meth:`_decode_shard`, but the attention
+        appends through the page table and reads the pool directly
+        (``paged_flash_decode``), in place at (layer, page)
+        (:meth:`_scan_layers_paged`). Parity: the reference megakernel's
+        paged decode (``mega_triton_kernel/models/paged_kv_cache.py``).
+        """
+        from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
+
+        def attn(ap, h, kp, vp, layer, ks, vs, ar):
+            return tp_attn_decode_paged(
+                ap, h, kp, vp, layer, cache.page_table, cache.kv_len,
+                self.dims, axis=self.axis, mode=ar, ctx=self.ctx,
+                k_scale=ks, v_scale=vs,
+            )
+
+        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
+            params, self._embed(params, tokens), cache, attn, mode
+        )
+        x = rms_norm(x, params.norm, self.cfg.rms_eps)
         logits = self._logits(params, x)
         return logits, PagedKVCache(
             k_pages=k_new, v_pages=v_new,
@@ -385,7 +422,8 @@ class Qwen3:
         ``last_idx`` the chunk index whose logits are returned (the
         prompt's last real token on the final chunk; ignored upstream on
         earlier chunks). Same layer scan as :meth:`_decode_shard_paged`
-        with chunk attention against prefix pages + chunk.
+        (:meth:`_scan_layers_paged`: the pool in the carry, addressed at
+        (layer, page)) with chunk attention against prefix pages + chunk.
 
         ``tree_mask [C, C]``/``tree_depth [C]`` put the chunk in tree
         mode (speculative tree verify): rows are draft-tree nodes in DFS
@@ -401,7 +439,6 @@ class Qwen3:
         cfg = self.cfg
         x = self._embed(params, tokens)  # [C, d]
         table_row = cache.page_table[slot]
-        ar = "pallas_ar" if mode == "pallas" else "xla_ar"
         rope_pos = attn_bias = None
         if tree_mask is not None:
             c = tokens.shape[0]
@@ -417,25 +454,16 @@ class Qwen3:
             )  # [C, S_kv]
             rope_pos = q_offset + tree_depth
 
-        def layer_fn(carry, inp):
-            x = carry
-            lp, kp, vp, ks, vs = inp  # ks/vs: int8 scales or None
-            h = rms_norm(x, lp.ln1, cfg.rms_eps)
-            a, kp, vp, ks, vs = tp_attn_prefill_paged_chunk(
-                lp.attn, h, kp, vp, table_row, q_offset, self.dims,
+        def attn(ap, h, kp, vp, layer, ks, vs, ar):
+            return tp_attn_prefill_paged_chunk(
+                ap, h, kp, vp, layer, table_row, q_offset, self.dims,
                 kv_pages=kv_pages, axis=self.axis, mode=ar, ctx=self.ctx,
                 k_scale=ks, v_scale=vs, q_end=new_len,
                 rope_pos=rope_pos, attn_bias=attn_bias,
             )
-            x = x + a
-            h = rms_norm(x, lp.ln2, cfg.rms_eps)
-            x = x + self._mlp_fwd(lp.mlp, h, ar)
-            return x, (kp, vp, ks, vs)
 
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer_fn, x,
-            (params.layers, cache.k_pages, cache.v_pages,
-             cache.k_scale, cache.v_scale),
+        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
+            params, x, cache, attn, mode
         )
         x = rms_norm(x, params.norm, cfg.rms_eps)
         if all_logits:
@@ -540,32 +568,20 @@ class Qwen3:
         invisible to the batched decode step."""
         from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
 
-        cfg = self.cfg
-        x = self._embed(params, tokens)  # [C, d]
-        ar = "pallas_ar" if mode == "pallas" else "xla_ar"
-
-        def layer_fn(carry, inp):
-            x = carry
-            lp, kp, vp, ks, vs, kc, vc, ksc, vsc = inp
-            h = rms_norm(x, lp.ln1, cfg.rms_eps)
-            a, kp, vp, ks, vs = tp_attn_prefill_paged_chunk_cold(
-                lp.attn, h, kp, vp, table_row, kc, vc, s_cold, q_offset,
+        # The cold window is read-only and per layer: it stays ``xs``.
+        def attn(ap, h, kp, vp, layer, ks, vs, ar, kc, vc, ksc, vsc):
+            return tp_attn_prefill_paged_chunk_cold(
+                ap, h, kp, vp, layer, table_row, kc, vc, s_cold, q_offset,
                 self.dims, axis=self.axis, mode=ar, ctx=self.ctx,
                 k_scale=ks, v_scale=vs, ks_cold=ksc, vs_cold=vsc,
                 q_end=q_end,
             )
-            x = x + a
-            h = rms_norm(x, lp.ln2, cfg.rms_eps)
-            x = x + self._mlp_fwd(lp.mlp, h, ar)
-            return x, (kp, vp, ks, vs)
 
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer_fn, x,
-            (params.layers, cache.k_pages, cache.v_pages,
-             cache.k_scale, cache.v_scale, k_cold, v_cold,
-             ks_cold, vs_cold),
+        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
+            params, self._embed(params, tokens), cache, attn, mode,
+            layer_xs=(k_cold, v_cold, ks_cold, vs_cold),
         )
-        x = rms_norm(x, params.norm, cfg.rms_eps)
+        x = rms_norm(x, params.norm, self.cfg.rms_eps)
         x_last = jnp.take(x, last_idx, axis=0)
         logits = self._logits(params, x_last[None])[0]
         return logits, PagedKVCache(
@@ -639,31 +655,18 @@ class Qwen3:
         batched ``kv_len``/``page_table`` are untouched."""
         from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
 
-        cfg = self.cfg
-        x = self._embed(params, token)  # [1, d]
-        ar = "pallas_ar" if mode == "pallas" else "xla_ar"
-
-        def layer_fn(carry, inp):
-            x = carry
-            lp, kp, vp, ks, vs, kc, vc, ksc, vsc = inp
-            h = rms_norm(x, lp.ln1, cfg.rms_eps)
-            a, kp, vp, ks, vs = tp_attn_decode_sharded(
-                lp.attn, h, kp, vp, table_row, kv_len_loc, kc, vc,
+        def attn(ap, h, kp, vp, layer, ks, vs, ar, kc, vc, ksc, vsc):
+            return tp_attn_decode_sharded(
+                ap, h, kp, vp, layer, table_row, kv_len_loc, kc, vc,
                 s_cold, self.dims, axis=self.axis, mode=ar, ctx=self.ctx,
                 k_scale=ks, v_scale=vs, ks_cold=ksc, vs_cold=vsc,
             )
-            x = x + a
-            h = rms_norm(x, lp.ln2, cfg.rms_eps)
-            x = x + self._mlp_fwd(lp.mlp, h, ar)
-            return x, (kp, vp, ks, vs)
 
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer_fn, x,
-            (params.layers, cache.k_pages, cache.v_pages,
-             cache.k_scale, cache.v_scale, k_cold, v_cold,
-             ks_cold, vs_cold),
+        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
+            params, self._embed(params, token), cache, attn, mode,
+            layer_xs=(k_cold, v_cold, ks_cold, vs_cold),
         )
-        x = rms_norm(x, params.norm, cfg.rms_eps)
+        x = rms_norm(x, params.norm, self.cfg.rms_eps)
         logits = self._logits(params, x)  # [1, V]
         return logits, PagedKVCache(
             k_pages=k_new, v_pages=v_new, page_table=cache.page_table,

@@ -45,12 +45,14 @@ def _decode_body(
     *,
     sm_scale: float,
     chunk_k: int,
+    num_chunks: int,
 ):
     b = pl.program_id(0)
     ci = pl.program_id(2)
     start = ci * chunk_k
-    # This (sequence, kv head, chunk)'s slot in the flattened scales.
-    si = (b * pl.num_programs(1) + pl.program_id(1)) * pl.num_programs(2) + ci
+    # This (sequence, kv head, chunk)'s slot in the flattened scales
+    # (``num_chunks`` a sequence: the paged grid may stop short of it).
+    si = (b * pl.num_programs(1) + pl.program_id(1)) * num_chunks + ci
     valid = kv_len_ref[b] - start  # may be <=0 (fully masked chunk)
 
     @pl.when(valid > 0)
@@ -201,7 +203,7 @@ def flash_decode(
         scalars += [k_scale.reshape(-1), v_scale.reshape(-1)]
     kernel = functools.partial(
         _decode_kernel_q if quant else _decode_kernel,
-        sm_scale=sm_scale, chunk_k=chunk_k,
+        sm_scale=sm_scale, chunk_k=chunk_k, num_chunks=num_chunks,
     )
     o_parts, lse_parts = pl.pallas_call(
         kernel,
@@ -238,14 +240,15 @@ def flash_decode(
 
 def paged_flash_decode(
     q: jax.Array,        # [B, Hq, D]
-    k_pages: jax.Array,  # [P, Hkv, page, D] — page pool (one layer)
+    k_pages: jax.Array,  # [L, P, Hkv, page, D] whole pool, or [P, Hkv, page, D]
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, pages_per_seq] int32
     kv_len: jax.Array,      # [B] int32 — valid context length
     *,
+    layer: jax.Array | int | None = None,  # which layer of a 5-D pool
     sm_scale: float | None = None,
     return_lse: bool = False,
-    k_scale: jax.Array | None = None,  # [P, Hkv] f32 — per-page-per-head
+    k_scale: jax.Array | None = None,  # [(L,) P, Hkv] f32 — per-page-per-head
     v_scale: jax.Array | None = None,
     interpret=None,
 ):
@@ -258,14 +261,46 @@ def paged_flash_decode(
     dereference it — ``block ci of sequence b`` fetches pool page
     ``table[b, ci]``, so the kernel body is exactly the dense split-KV
     kernel with ``chunk_k = page_size`` and no gather materializes.
+    The grid walks a sequence's table only as far as the longest live
+    sequence's last page (a dynamic bound on the page axis).
 
     With ``k_scale``/``v_scale`` (the pool's per-page-per-head int8
     scales), the K/V blocks are int8 codes and each program fetches its
     page's scale through the SAME table indirection, dequantizing
     in-register after QK^T / P·V — the decode step streams HALF the
     bf16 pool's HBM bytes and full-width KV never exists.
+
+    The pool is addressed in place by (layer, page): given the WHOLE
+    ``[L, P, Hkv, page, D]`` pool (scales ``[L, P, Hkv]``) and ``layer``
+    (traced — a layer scan's index), page ``i`` of that layer is row
+    ``layer * P + i`` of the pool seen as ``[L * P, Hkv, page, D]`` (a
+    bitcast), so the layer rides into the kernel inside the page table,
+    ``table + layer * P``, and only the pages a sequence holds are ever
+    read. A caller that slices ``pool[layer]`` for this call makes XLA
+    materialize that layer's whole pool first, every layer of every
+    step. (Carrying the layer as a third scalar-prefetch operand with
+    blocks ``(None, 1, 1, page, D)`` reads the same pages; on the v5e
+    the served step measured 0.29 ms longer that way, PERF.md "PR 27".)
     """
     b, hq, d = q.shape
+    if (k_pages.ndim == 5) != (layer is not None):
+        raise ValueError(
+            "a 5-D pool needs layer=, a 4-D one-layer pool takes none "
+            f"(pool rank {k_pages.ndim}, layer {layer!r})"
+        )
+    for name, sc in (("k_scale", k_scale), ("v_scale", v_scale)):
+        if sc is not None and sc.shape != k_pages.shape[:-2]:
+            raise ValueError(
+                f"{name} shape {sc.shape} != the pool's per-page layout "
+                f"{k_pages.shape[:-2]}"
+            )
+    if layer is not None:
+        n_layers, p = k_pages.shape[:2]
+        page_table = page_table + jnp.asarray(layer, jnp.int32) * p
+        k_pages, v_pages, k_scale, v_scale = (
+            None if a is None else a.reshape(n_layers * p, *a.shape[2:])
+            for a in (k_pages, v_pages, k_scale, v_scale)
+        )
     p, hkv, page, _ = k_pages.shape
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
@@ -277,11 +312,6 @@ def paged_flash_decode(
     quant = k_scale is not None
     if quant != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be passed together")
-    for name, sc in (("k_scale", k_scale), ("v_scale", v_scale)):
-        if quant and sc.shape != (p, hkv):
-            raise ValueError(
-                f"{name} shape {sc.shape} != per-page layout {(p, hkv)}"
-            )
 
     resolved = interpret_mode() if interpret is None else interpret
     if resolved and exporting_portable():
@@ -298,7 +328,15 @@ def paged_flash_decode(
         )
 
     qg = q.reshape(b, hkv, group, d)
-    grid = (b, hkv, pps)
+    # The walk over a sequence's table entries ends at the LONGEST live
+    # sequence's last page (a dynamic grid bound), not at ``pps``: every
+    # grid step fetches its K and V block from HBM whether or not the
+    # body has use for it, and with the pool in HBM that was 130 us a
+    # layer at 4 slots x 8 heads x 32 entries where contexts of a few
+    # hundred tokens need a tenth (PERF.md "PR 27"). The chunks never
+    # walked are masked out of the merge below.
+    live_chunks = jnp.clip(pl.cdiv(jnp.max(kv_len), page), 1, pps)
+    grid = (b, hkv, live_chunks)
     in_specs = [
         pl.BlockSpec((1, 1, group, d), lambda b, h, ci, *_: (b, h, 0, 0)),
         # The paged part: block ci of row b is pool page
@@ -324,7 +362,7 @@ def paged_flash_decode(
         ]
     kernel = functools.partial(
         _paged_decode_kernel_q if quant else _paged_decode_kernel,
-        sm_scale=sm_scale, chunk_k=page,
+        sm_scale=sm_scale, chunk_k=page, num_chunks=pps,
     )
     o_parts, lse_parts = pl.pallas_call(
         kernel,
@@ -352,6 +390,11 @@ def paged_flash_decode(
         interpret=resolved,
     )(*scalars, qg, k_pages, v_pages)
 
+    # Chunks past the grid's end were never written: weight them 0, as
+    # the body does for the chunks past a sequence's own length.
+    walked = jnp.arange(pps) < live_chunks
+    lse_parts = jnp.where(walked[:, None], lse_parts, _NEG_INF)
+    o_parts = jnp.where(walked[:, None, None], o_parts, 0.0)
     o, lse = lse_combine(o_parts, lse_parts, part_axis=2)
     o = o.reshape(b, hq, d).astype(q.dtype)
     if return_lse:
@@ -369,11 +412,19 @@ def _paged_decode_kernel_q(kv_len_ref, table_ref, *args, **kw):
     return _decode_kernel_q(kv_len_ref, *args, **kw)
 
 
-def pages_to_dense(pages: jax.Array, page_table: jax.Array) -> jax.Array:
+def pages_to_dense(
+    pages: jax.Array, page_table: jax.Array, layer=None
+) -> jax.Array:
     """Gather a page pool ``[..., P, H, page, d]`` into a dense
     ``[..., B, H, S, d]`` view through the table. Single source of the
-    gather layout — ``models.paged_kv_cache.as_dense`` delegates here."""
-    g = jnp.take(pages, page_table, axis=-4)  # [..., B, pps, H, page, d]
+    gather layout — ``models.paged_kv_cache.as_dense`` delegates here.
+    With ``layer`` the pool is the whole ``[L, P, H, page, d]`` and the
+    view is that layer's: ONE gather at (layer, page) that reads the
+    table's pages only, never a slice of the layer's pool."""
+    if layer is None:
+        g = jnp.take(pages, page_table, axis=-4)  # [..., B, pps, H, page, d]
+    else:
+        g = pages[layer, page_table]              # [B, pps, H, page, d]
     g = jnp.swapaxes(g, -4, -3)               # [..., B, H, pps, page, d]
     s = g.shape
     return g.reshape(*s[:-3], s[-3] * s[-2], s[-1])

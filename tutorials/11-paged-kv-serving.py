@@ -7,8 +7,10 @@ attention task through a page table.
 TPU design: the pool is one array ``[L, P, Hkv, page, hd]``; the page
 table rides as a scalar-prefetch operand and ``paged_flash_decode``'s
 K/V BlockSpec index maps dereference it — block ``ci`` of sequence ``b``
-fetches pool page ``table[b, ci]``, so attention reads the pool
-directly and NO dense gather ever materializes. Three consumers share
+fetches pool page ``table[b, ci]`` of the layer addressed (the model's
+layer scan carries the whole pool and hands the kernel ``layer=``), so
+attention reads the pool in place and NO dense gather or per-layer
+slice ever materializes. Three consumers share
 the design: the model decode step (``decode_step`` dispatches on cache
 type), ``Engine(paged=True)`` serving, and the megakernel (per-row page
 DMAs in its attention block loop).
