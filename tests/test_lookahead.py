@@ -1,0 +1,308 @@
+"""The one-step lookahead of the single-step decode round
+(docs/serving.md "The decode loop").
+
+The round dispatches step N+1 off step N's on-device greedy tokens
+before it fetches step N's, whenever that is exactly what the serial
+loop would have run next. Both sides of that choice are held here: the
+lookahead against the serial round token for token, the cases in which
+it must not engage, and the events the host cannot foresee while a
+step is in flight. The serial round is the same code with the choice
+answered "no" (``serial`` below), which is what the parent commit ran.
+
+A CPU run gives counts and tokens, never a time: the gain is a chip
+matter (PERF.md).
+"""
+
+import jax
+import numpy as np
+import pytest
+
+from triton_distributed_tpu.models import AutoLLM
+from triton_distributed_tpu.models.continuous import (
+    ContinuousEngine,
+    Request,
+    _StepLaunch,
+)
+from triton_distributed_tpu.obs import metrics as obs_metrics
+from triton_distributed_tpu.runtime import mesh as mesh_mod
+from triton_distributed_tpu.runtime.faults import FaultPlan
+
+RNG = np.random.default_rng(30)
+# One prompt length and two shared prefixes: few programs to compile,
+# and radix hits when the prefix cache is on.
+HEADS = [RNG.integers(1, 200, size=16).astype(np.int32) for _ in range(2)]
+
+
+def prompt(head: int, tail_seed: int) -> np.ndarray:
+    tail = np.random.default_rng(tail_seed).integers(1, 200, size=8)
+    return np.concatenate([HEADS[head], tail.astype(np.int32)])
+
+
+# Staggered lengths on 2 slots: slots finish and are re-admitted mid-run,
+# a first token finishes a request, a round holds 1 and 2 live slots.
+# (A program call costs a third of a second here whatever its size, so
+# the batches are short.)
+MIXED = [(prompt(i % 2, i), g) for i, g in enumerate([7, 2, 9, 4, 1])]
+LONE = prompt(0, 99)
+
+
+@pytest.fixture(scope="module")
+def model():
+    """ONE tiny model for the module: the jitted programs live on it,
+    so every engine here shares one compile."""
+    ctx = mesh_mod.initialize_distributed(tp=1, devices=jax.devices()[:1])
+    yield AutoLLM.from_pretrained("tiny", ctx=ctx)
+    mesh_mod.finalize_distributed()
+
+
+@pytest.fixture(scope="module")
+def greedy(model):
+    """What ``LONE`` decodes to, alone and undisturbed."""
+    out = engine(model).run([(LONE, 8)])[0]
+    assert out[3] not in out[:3]  # as a stop token it stops once, at 3
+    return out
+
+
+def engine(model, **kw) -> ContinuousEngine:
+    kw.setdefault("max_batch", 2)
+    kw.setdefault("page_size", 16)
+    kw.setdefault("max_length", 64)
+    return ContinuousEngine(model, **kw)
+
+
+def serial(eng: ContinuousEngine) -> ContinuousEngine:
+    """The parent's round: the same engine, never looking ahead."""
+    eng._may_look_ahead = lambda step: False
+    return eng
+
+
+def run(eng, reqs):
+    """``(tokens, status)`` of each request, and the engine's stats."""
+    res = eng.run(reqs, results=True)
+    assert eng.audit() == []
+    return [(r.tokens.tolist(), r.status) for r in res], eng.last_stats
+
+
+# -- (a) the lookahead is the serial round, token for token ---------------
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_lookahead_matches_serial_round(model, prefix_cache, kv_dtype):
+    kw = dict(prefix_cache=prefix_cache, kv_dtype=kv_dtype)
+    want, s_stats = run(serial(engine(model, **kw)), MIXED)
+    got, stats = run(engine(model, **kw), MIXED)
+    assert got == want
+    assert [len(t) for t, _ in got] == [g for _, g in MIXED]
+    # The same steps, most of them dispatched a round early, none wasted.
+    assert stats["decode_steps"] == s_stats["decode_steps"]
+    assert s_stats["lookahead_steps"] == 0
+    assert stats["lookahead_steps"] >= stats["decode_steps"] // 2
+    assert stats["lookahead_discarded"] == 0
+    if prefix_cache:
+        assert stats["prefix_hit_tokens"] == s_stats["prefix_hit_tokens"] > 0
+
+
+# -- (b) where it must not engage ------------------------------------------
+
+
+def _sampled(model):
+    reqs = lambda: [  # noqa: E731 — fresh Requests for each engine
+        Request(LONE, 6, temperature=0.8, top_k=20),
+        Request(prompt(1, 5), 10),
+    ]
+    return (engine(model, seed=7), engine(model, seed=7)), reqs
+
+
+def _speculative(model):
+    rep = np.tile(np.asarray([5, 9, 2, 4], np.int32), 6)
+    reqs = lambda: [(rep, 10), (LONE, 6)]  # noqa: E731
+    return (engine(model, speculative=3), engine(model, speculative=3)), reqs
+
+
+def _long_context(model):
+    long_prompt = np.random.default_rng(8).integers(
+        1, 200, size=88).astype(np.int32)
+    kw = dict(max_length=128, rank_page_budget=64, tier_bytes=32 << 20,
+              num_pages=8)
+    reqs = lambda: [(long_prompt, 4), (LONE, 3)]  # noqa: E731
+    return (engine(model, **kw), engine(model, **kw)), reqs
+
+
+def _fault_plan(model):
+    reqs = lambda: [(LONE, 10), (prompt(1, 5), 6)]  # noqa: E731
+    return (engine(model), engine(model)), reqs
+
+
+@pytest.mark.parametrize(
+    "case", [_sampled, _speculative, _long_context, _fault_plan])
+def test_lookahead_stays_out(model, case):
+    """A sampled slot, a speculative plan, a sharded long-context slot
+    or an armed FaultPlan: the round keeps the parent's serial order
+    and gives the parent's outputs."""
+    (parent, eng), reqs = case(model)
+    want, _ = run(serial(parent), reqs())
+    if case is _fault_plan:
+        # Armed, with no rule: the seams fire into a plan that counts.
+        with FaultPlan(seed=0) as plan:
+            got, stats = run(eng, reqs())
+        assert plan.hits["engine.decode"] == stats["decode_steps"]
+    else:
+        got, stats = run(eng, reqs())
+    assert got == want
+    assert all(status == "ok" for _, status in got)
+    if case is _sampled:
+        # Nothing looks ahead while the sampled request lives (its 6
+        # tokens); the greedy one then goes on alone: its step 6 comes
+        # from the host's tokens, 7 to 9 are dispatched a round early.
+        assert stats["lookahead_steps"] == 3
+    else:
+        assert stats["lookahead_steps"] == 0
+    if case is _long_context:
+        assert stats["longctx_sharded_slots"] == 1
+    assert stats["lookahead_discarded"] == 0
+
+
+# -- (c) what the host cannot foresee while a step is in flight -----------
+
+
+class Watch:
+    """Records, at every page release of a slot, whether the in-flight
+    step had left the device (``no page is freed or retired to the radix
+    tree before the drain``), and every token a sink was handed."""
+
+    def __init__(self, eng):
+        self.eng, self.unsettled, self.frames = eng, [], []
+        for name in ("_evict", "_teardown_slot"):
+            setattr(eng, name, self._guarded(name, getattr(eng, name)))
+
+    def _guarded(self, name, fn):
+        def call(req):
+            pend = self.eng._pend
+            if isinstance(pend, _StepLaunch) and pend.host is None:
+                self.unsettled.append(name)
+            return fn(req)
+        return call
+
+    def sink(self, at: int, then):
+        def on_token(i, t):
+            self.frames.append((i, t))
+            if i == at:
+                then()
+        return on_token
+
+
+def _stop_token(eng, watch, greedy):
+    eng.eos_id = int(greedy[3])
+    return Request(LONE, 8, on_token=watch.sink(-1, None))
+
+
+def _cancel(eng, watch, greedy):
+    return Request(LONE, 8, ticket_id="t-30",
+                   on_token=watch.sink(3, lambda: eng.cancel(["t-30"])))
+
+
+def _deadline(eng, watch, greedy):
+    req = Request(LONE, 8)
+
+    def expire():
+        req.deadline_at = 0.0
+    req.on_token = watch.sink(3, expire)
+    return req
+
+
+def _nan_row(eng, watch, greedy):
+    launch = eng._launch_step
+
+    def poisoned(tok, active, n_active):
+        step = launch(tok, active, n_active)
+        if eng.stats["decode_steps"] == 3:  # the step that gives token 3
+            finite = np.asarray(step.finite).copy()
+            finite[0] = False
+            step.finite = finite
+        return step
+    eng._launch_step = poisoned
+    return Request(LONE, 8, on_token=watch.sink(-1, None))
+
+
+@pytest.mark.parametrize(
+    "event,status,kept,prefix_cache",
+    [(_stop_token, "ok", 4, False), (_stop_token, "ok", 4, True),
+     (_cancel, "cancelled", 4, True),
+     (_deadline, "deadline_exceeded", 4, True),
+     (_nan_row, "nan_logits", 3, True)])
+def test_slot_ends_while_a_step_is_in_flight(model, greedy, event, status,
+                                             kept, prefix_cache):
+    """The slot's token of the in-flight step is never emitted and is
+    counted; the parent's serial round gives the same tokens and status;
+    no page goes back before the device has left the step; the engine
+    serves the next batch."""
+    other = (prompt(1, 5), 6)
+    outcomes = {}
+    for name in ("serial", "ahead"):
+        eng = engine(model, prefix_cache=prefix_cache)
+        if name == "serial":
+            serial(eng)
+        watch = Watch(eng)
+        req = event(eng, watch, greedy)
+        out, stats = run(eng, [req, other])
+        assert out[0] == (greedy[:kept].tolist(), status)
+        assert [t for _, t in watch.frames] == out[0][0]
+        assert out[1][1] == "ok"
+        assert watch.unsettled == []
+        outcomes[name] = (out, stats)
+        # The next batch, on the same engine.
+        eng.eos_id = None
+        eng._launch_step = type(eng)._launch_step.__get__(eng)
+        again, _ = run(eng, [(LONE, 4)])
+        assert again == [(greedy[:4].tolist(), "ok")]
+    (s_out, s_stats), (a_out, a_stats) = outcomes["serial"], outcomes["ahead"]
+    assert a_out == s_out
+    assert s_stats["lookahead_discarded"] == 0
+    assert a_stats["lookahead_discarded"] == 1
+    assert a_stats["lookahead_steps"] > 0
+
+
+def test_host_and_device_kv_len_agree_after_every_drain(model, greedy):
+    """``audit()`` compares the two wherever nothing is in flight: here
+    after each admission's drain, mid-run, with live slots."""
+    eng = engine(model, prefix_cache=True)
+    admit, audits = eng._try_admit, []
+
+    def audited(queue):
+        done = admit(queue)
+        if eng._pend is None and any(r is not None for r in eng._slots):
+            audits.append(eng.audit())
+        return done
+    eng._try_admit = audited
+    eng.eos_id = int(greedy[3])
+    run(eng, [(LONE, 8)] + MIXED)
+    assert len(audits) >= 3 and all(a == [] for a in audits)
+    assert eng.last_stats["lookahead_discarded"] >= 1
+
+
+# -- (d) the engagement rate and where the counters show -------------------
+
+
+def test_engagement_rate_and_counters(model, fresh_telemetry):
+    from triton_distributed_tpu.serving.server import ModelServer, request
+
+    eng = engine(model)
+    _, stats = run(eng, [(prompt(1, 40), 21)])
+    # 20 steps: the first from the host's tokens, the rest a round early.
+    assert stats["decode_steps"] == 20
+    assert stats["lookahead_steps"] == 19  # 0.95 of them
+    assert stats["lookahead_discarded"] == 0
+    snap = obs_metrics.default_registry().snapshot()
+    assert (snap["tdt_engine_lookahead_steps_total"]["series"][0]["value"]
+            == stats["lookahead_steps"])
+    server = ModelServer(eng).start()
+    try:
+        m = request(server.host, server.port, {"cmd": "metrics"})
+        assert "tdt_engine_lookahead_steps_total" in m["prometheus"]
+        assert "tdt_engine_lookahead_discarded_total" in m["prometheus"]
+        s = request(server.host, server.port, {"cmd": "stats"})
+        assert s["stats"]["lookahead_steps"] == stats["lookahead_steps"]
+    finally:
+        request(server.host, server.port, {"cmd": "shutdown"})
+        server.shutdown()

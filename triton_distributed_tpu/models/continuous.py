@@ -84,6 +84,7 @@ from triton_distributed_tpu.models.prefix_cache import (
 from triton_distributed_tpu.models.qwen import Mode, Qwen3
 from triton_distributed_tpu.runtime.faults import (
     FaultError,
+    active_plan,
     fault_point,
     mutate_point,
 )
@@ -362,6 +363,31 @@ class _MegaLaunch:
     trace_ids: dict
 
 
+@dataclasses.dataclass
+class _StepLaunch:
+    """A dispatched — possibly still in-flight — single decode step:
+    what the one-step lookahead parks in ``_pend`` between a step's
+    dispatch and the round that emits its tokens. ``toks`` (the greedy
+    argmax, still on the device) is the NEXT step's token input.
+    ``reqs`` is the request each slot held at dispatch: a slot that no
+    longer holds it at the drain ended first (stop token, non-finite
+    row, cancel, deadline), and its token is discarded."""
+
+    reqs: list
+    logits: object      # [max_batch, V] device
+    finite: object      # [max_batch] device all-finite mask
+    toks: object        # [max_batch] device greedy tokens
+    lc_changed: bool    # a sharded long-context slot changed at dispatch
+    host: tuple | None = None
+
+    def fetch(self) -> tuple:
+        """THE host sync of a step: ``(finite, tokens)`` as numpy,
+        fetched once. After it the device has left the step."""
+        if self.host is None:
+            self.host = (np.asarray(self.finite), np.array(self.toks))
+        return self.host
+
+
 class ContinuousEngine(MegaDispatch):
     """Admission/eviction serving loop over the paged pool.
 
@@ -450,7 +476,12 @@ class ContinuousEngine(MegaDispatch):
         else:
             self._ring = None
             self._ring_gauge = None
-        self._pend = None  # in-flight resident launch (depth-1 pipeline)
+        # The depth-1 pipeline's one slot: an in-flight resident launch
+        # (``_MegaLaunch``) or a looked-ahead single step
+        # (``_StepLaunch``). Everything that mutates slot, table or pool
+        # state comes through ``_drain_pend`` / ``_settle_pend`` /
+        # ``_abort_pend`` first.
+        self._pend = None
         # Device task tracer (docs/observability.md "Device task
         # tracer"): mega launches carry an in-kernel trace ring; every
         # launch's ring is folded into tdt_mega_task_seconds and kept
@@ -802,6 +833,12 @@ class ContinuousEngine(MegaDispatch):
             "deadline_expired": 0,
             "nonfinite_logits": 0,
             "decode_faults": 0,
+            # One-step lookahead of the single-step round (docs/
+            # serving.md "The decode loop"): steps dispatched before
+            # the step before's tokens were fetched, and slot-tokens of
+            # such a step thrown away because the slot ended first.
+            "lookahead_steps": 0,
+            "lookahead_discarded": 0,
             # Megakernel fast-path ledger (mode="mega" only): fused
             # NS-step launches vs single-step fallback rounds.
             "mega_launches": 0,
@@ -1596,14 +1633,71 @@ class ContinuousEngine(MegaDispatch):
                           step=self.stats["decode_steps"], _ring=False)
 
     def _decode_round(self, active: np.ndarray, n_active: int) -> bool:
-        """The round itself, in the three phases its spans name: the
+        """The round itself, in the three phases its spans name: a
         step's dispatch, the blocking fetch, and the host's sampling
-        and token frames."""
-        fault_point("engine.decode", step=self.stats["decode_steps"])
+        and token frames.
+
+        The step whose tokens this round emits is the one a round
+        before looked ahead to and parked in ``_pend``, or, with none
+        parked, one dispatched here from the host's ``_tok``. Before
+        its tokens are fetched the NEXT step is dispatched off its
+        on-device greedy tokens whenever that is exactly what the
+        serial loop would run next (:meth:`_may_look_ahead`): the
+        device then finds its next step queued, and the host's fetch,
+        NaN guard, slot walk and token frames overlap it."""
+        step, self._pend = self._pend, None
         with trace_span("engine:dispatch", _ring=False):
-            logits, self.cache = self._decode_step(
-                jnp.asarray(self._tok), self.cache
-            )
+            if step is None:
+                step = self._launch_step(
+                    jnp.asarray(self._tok), active, n_active
+                )
+            if self._may_look_ahead(step):
+                # Parked BEFORE the emit below: whatever that raises
+                # reaches the step guard with the in-flight step still
+                # owned, so ``_abort_pend`` blocks on it before the
+                # teardown frees pages it appends to.
+                self._pend = self._launch_step(step.toks, active, n_active)
+                self._bump("lookahead_steps")
+        return self._emit_step(step)
+
+    def _may_look_ahead(self, step: _StepLaunch) -> bool:
+        """Whether the step AFTER ``step`` may be dispatched before
+        ``step``'s tokens reach the host: only when it is exactly what
+        the serial loop would run next. Its input must already be on
+        the device (every live slot decodes greedily and was in
+        ``step`` with the same request), the slot set must be known
+        (no live slot reaches ``gen_len`` with ``step``'s token, no
+        admission is mid-prefill), and nothing may sit between a step
+        and its tokens (sharded long-context logits spliced on the
+        host, a speculative plan, a resident ring session, mega rounds
+        planned from host truth, an armed ``FaultPlan``). What the host
+        cannot foresee (a stop token, a non-finite row, a cancel, a
+        deadline) costs the slot's token of the in-flight step, never
+        an emitted one (:meth:`_emit_step`)."""
+        if (self.speculative or self._longctx or self._ring is not None
+                or self.mode == "mega" or active_plan() is not None):
+            return False
+        for slot, req in enumerate(self._slots):
+            if req is None:
+                if self._table[slot, 0]:
+                    # Mapped before ``_slots`` holds it: an admission is
+                    # prefilling into this row between our rounds.
+                    return False
+                continue
+            if (req is not step.reqs[slot]
+                    or len(req.out) + 1 >= req.gen_len
+                    or self._request_sampling(req)[0] > 0.0):
+                return False
+        return True
+
+    def _launch_step(self, tok, active: np.ndarray,
+                     n_active: int) -> _StepLaunch:
+        """Dispatch one decode step on ``tok [max_batch]`` (the host's
+        ``_tok`` uploaded, or the step before's greedy tokens still on
+        the device) and the program that reduces its logits to a finite
+        mask and the greedy tokens. Nothing here waits for the device."""
+        fault_point("engine.decode", step=self.stats["decode_steps"])
+        logits, self.cache = self._decode_step(tok, self.cache)
         logits = mutate_point(
             "engine.logits", logits, step=self.stats["decode_steps"]
         )
@@ -1625,17 +1719,40 @@ class ContinuousEngine(MegaDispatch):
         # One device program computes the finite mask AND the greedy
         # base tokens, so the NaN guard adds no extra host-sync round
         # trip to the hot decode loop.
+        finite, toks = tdt_finite_greedy(logits)
+        return _StepLaunch(list(self._slots), logits, finite, toks,
+                           lc_changed)
+
+    def _emit_step(self, step: _StepLaunch) -> bool:
+        """Fetch one dispatched step's tokens and emit them through the
+        normal guard / sample / retire paths. Returns whether slot
+        state changed. A slot that ended since the dispatch is ``None``
+        by now, so every walk below skips it: its token is dropped."""
         with trace_span("engine:fetch", _ring=False):
-            finite, greedy_base = tdt_finite_greedy(logits)
-            finite = np.asarray(finite)
-            greedy_base = np.array(greedy_base)
-        # One token per active slot, less the slots the guard fails.
-        with trace_span("engine:sample_emit", emitted=n_active,
+            finite, toks = step.fetch()
+        ended = sum(r is not None and self._slots[s] is not r
+                    for s, r in enumerate(step.reqs))
+        if ended:
+            self._bump("lookahead_discarded", ended)
+        ahead = self._pend
+        if ahead is not None and any(
+                r is not None
+                and (not finite[s] or toks[s] == self.eos_id)
+                for s, r in enumerate(self._slots)):
+            # A slot ends on this very token (stop token, non-finite
+            # row) while the next step is in flight and appending to
+            # its pages: the device leaves that step before any page
+            # goes back to the pool or the radix tree. It stays parked;
+            # the slot's token in it is dropped at its own drain.
+            ahead.fetch()
+        # One token per live slot, less the slots the guard fails.
+        with trace_span("engine:sample_emit",
+                        emitted=sum(r is not None for r in self._slots),
                         _ring=False):
             failed = self._guard_logits(finite)
-            nxt = self._sample_slots(logits, greedy_base)
+            nxt = self._sample_slots(step.logits, toks)
             changed = self._process(lambda slot: [nxt[slot]])
-        return changed or bool(failed) or lc_changed
+        return changed or bool(failed) or step.lc_changed
 
     def _guard_logits(self, finite: np.ndarray) -> list[int]:
         """Per-slot NaN/Inf guard on a batched decode output: fail ONLY
@@ -1852,9 +1969,9 @@ class ContinuousEngine(MegaDispatch):
             pending = set(self._cancelled)
         fault_point("engine.cancel", pending=len(pending))
         if self._pend is not None:
-            # Cancellation tears slots down — the in-flight resident
-            # launch still reads their table rows; sync first.
-            self._drain_pend()
+            # Cancellation tears slots down — the in-flight launch
+            # still reads their table rows; sync first.
+            self._settle_pend()
         consumed: set[str] = set()
         changed = False
         for r in list(queue):
@@ -1890,8 +2007,9 @@ class ContinuousEngine(MegaDispatch):
                 r is not None and r.deadline_at is not None
                 and now > r.deadline_at for r in self._slots):
             # The expiry is about to tear a slot down mid-pipeline;
-            # sync first (the drain may even finish it naturally).
-            self._drain_pend()
+            # sync first (a resident launch's drain may even finish it
+            # naturally).
+            self._settle_pend()
         changed = False
         for req in list(self._slots):
             if req is None or req.deadline_at is None:
@@ -2888,21 +3006,45 @@ class ContinuousEngine(MegaDispatch):
         )
 
     def _drain_pend(self) -> bool:
-        """THE sync point of the resident pipeline: fetch the pending
-        launch's emitted tokens and run the normal emit/retire paths.
-        Every slot-state mutation site (_try_admit, _apply_cancels,
-        _expire_deadlines, _handoff_sweep, _update_snapshot_buffer,
-        run()'s teardown) comes through here before touching state an
-        in-flight launch still reads."""
+        """THE sync point of the depth-1 pipeline: fetch the pending
+        launch's (or looked-ahead step's) emitted tokens and run the
+        normal emit/retire paths. Every slot-state mutation site
+        (_try_admit, _handoff_sweep, _update_snapshot_buffer; through
+        ``_settle_pend`` _apply_cancels and _expire_deadlines; through
+        ``_abort_pend`` run()'s teardown and the step guard) comes
+        through here before touching state an in-flight launch still
+        reads."""
         pend, self._pend = self._pend, None
         if pend is None:
             return False
-        return self._drain_launch(pend)
+        if isinstance(pend, _MegaLaunch):
+            return self._drain_launch(pend)
+        with self._round_span(sum(r is not None for r in self._slots)):
+            changed = self._emit_step(pend)
+        if changed:
+            # No round's caller follows this drain with a sync of its
+            # own: a slot it retired must leave the device table before
+            # the next step appends through the stale row.
+            self._sync_tables()
+        return changed
+
+    def _settle_pend(self) -> None:
+        """Sync point before the HOST ends slots on its own clock (a
+        cancel, an expired deadline). A resident launch drains: its
+        tokens were issued before the event and count. A looked-ahead
+        step only has to have left the device before the slots' pages
+        go back; it stays parked, and the ended slots' tokens in it are
+        discarded at its drain, so the request ends with exactly the
+        tokens the serial round would have given it."""
+        if isinstance(self._pend, _StepLaunch):
+            self._pend.fetch()
+        else:
+            self._drain_pend()
 
     def _abort_pend(self) -> None:
         """Teardown-path drain: block on (then discard) the in-flight
-        resident launch so no exit path leaves a launch reading slot
-        state the teardown is about to reuse."""
+        launch so no exit path leaves one reading slot state the
+        teardown is about to reuse."""
         pend, self._pend = self._pend, None
         if pend is None:
             return
@@ -2910,6 +3052,9 @@ class ContinuousEngine(MegaDispatch):
             jax.block_until_ready(pend.toks)
         except Exception:  # noqa: BLE001 — teardown is best-effort
             pass
+        if isinstance(pend, _StepLaunch):
+            self._bump("lookahead_discarded",
+                       sum(r is not None for r in pend.reqs))
 
     def _drain_launch(self, pend: _MegaLaunch) -> bool:
         """Fetch one launch's outputs and emit/retire through the
@@ -3181,7 +3326,7 @@ class ContinuousEngine(MegaDispatch):
         finally:
             self._handoff_at = None
             self._round = 0
-            # Block on (and discard) any in-flight resident launch
+            # Block on (and discard) any in-flight launch
             # BEFORE teardown reuses the state it reads.
             self._abort_pend()
             if self._ring is not None:
@@ -3268,7 +3413,9 @@ class ContinuousEngine(MegaDispatch):
         read; the slot keeps decoding. ``target_digest`` enables the
         prefix delta: payload for pages the target's radix digest
         already covers is omitted. Call between runs or from the
-        engine's own thread at a round boundary."""
+        engine's own thread at a round boundary with nothing parked in
+        ``_pend`` (host ``_kv_len`` runs a launch ahead of ``out``
+        while one is in flight; the engine's own exports drain first)."""
         from triton_distributed_tpu.models import slot_state
 
         return slot_state.export_slot(
@@ -3291,7 +3438,7 @@ class ContinuousEngine(MegaDispatch):
         from triton_distributed_tpu.models import slot_state
 
         if self._pend is not None:
-            # Snapshots read slot KV the in-flight resident launch is
+            # Snapshots read slot KV the in-flight launch is
             # still appending to; sync the pipeline first.
             self._drain_pend()
         snaps: dict[str, dict] = {}
@@ -3379,7 +3526,7 @@ class ContinuousEngine(MegaDispatch):
         from triton_distributed_tpu.models import slot_state
 
         if self._pend is not None:
-            # Exports read slot KV the in-flight resident launch is
+            # Exports read slot KV the in-flight launch is
             # still appending to; sync the pipeline first.
             self._drain_pend()
         active = [s for s in range(self.max_batch)
@@ -3493,6 +3640,18 @@ class ContinuousEngine(MegaDispatch):
                     problems.append(
                         f"slot {slot} table row disagrees with its "
                         "request's page list"
+                    )
+        if self._pend is None and any(r is not None for r in self._slots):
+            # With nothing in flight the device's own counters are the
+            # host's: a drain that left them apart would append the
+            # next row in the wrong place.
+            dev = np.asarray(self.cache.kv_len)
+            for slot, req in enumerate(self._slots):
+                if (req is not None and slot not in self._longctx
+                        and int(dev[slot]) != int(self._kv_len[slot])):
+                    problems.append(
+                        f"slot {slot}: device kv_len {int(dev[slot])} != "
+                        f"host {int(self._kv_len[slot])}"
                     )
         if problems and raise_on_violation:
             raise PoolAuditError("; ".join(problems))

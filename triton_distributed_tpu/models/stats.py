@@ -105,6 +105,15 @@ STAT_METRICS = {
     "decode_faults": ("tdt_engine_decode_faults_total",
                       "Exceptions isolated by the decode-phase step "
                       "guard."),
+    # One-step lookahead of the single-step decode round (docs/
+    # serving.md "The decode loop"): its share of ``decode_steps`` is
+    # the engagement rate.
+    "lookahead_steps": ("tdt_engine_lookahead_steps_total",
+                        "Decode steps dispatched before the previous "
+                        "step's tokens were fetched."),
+    "lookahead_discarded": ("tdt_engine_lookahead_discarded_total",
+                            "Slot-tokens of an in-flight step thrown "
+                            "away because the slot ended first."),
     # Megakernel serving fast path (docs/megakernel.md "Serving fast
     # path"): NS-step fused launches vs the rounds that had to fall
     # back to single-step decode (max_length tail, top-k/top-p slots).

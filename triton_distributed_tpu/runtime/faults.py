@@ -728,6 +728,13 @@ _ACTIVE: FaultPlan | None = None
 _LOCK = threading.Lock()
 
 
+def active_plan() -> FaultPlan | None:
+    """The plan armed right now, if any. The engine's decode round
+    keeps its serial order under one: a rule counts the hits of a seam
+    and expects each to see the state of the step before."""
+    return _ACTIVE
+
+
 def fault_point(seam: str, **ctx) -> None:
     """A raise-style seam: no-op unless a plan is active and armed."""
     plan = _ACTIVE
