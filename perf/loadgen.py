@@ -85,11 +85,9 @@ class LoadSpec:
     agentic_motif: int = 6
     agentic_repeats: int = 3
     # The class name ``"document"`` is special too: those requests
-    # become long-context document jobs — the shared prefix plus a
-    # unique body of ``doc_min``..``doc_max`` tokens (10k+ by default;
-    # tests scale the knobs down), the workload the long-context
-    # serving path (cp prefill, sharded slots — docs/serving.md
-    # "Long-context serving") exists for. Body draws land AFTER the
+    # become long document jobs — the shared prefix plus a unique
+    # body of ``doc_min``..``doc_max`` tokens (10k+ by default; tests
+    # scale the knobs down). Body draws land AFTER the
     # agentic motif draws, so mixes without "document" (and all
     # pre-mix specs) keep bit-identical traces.
     doc_min: int = 10240
@@ -194,7 +192,7 @@ def generate_trace(spec: LoadSpec) -> list[dict]:
                     prefixes[pi] + motifs[pi] * spec.agentic_repeats
                 )
         if "document" in names:
-            # Long-context document class: shared prefix + a unique
+            # Document class: shared prefix + a unique
             # 10k+-token body (row order, after the agentic draws —
             # the same stream-compatibility contract as above).
             for row in trace:

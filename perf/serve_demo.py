@@ -126,10 +126,6 @@ def _fleet_demo(args) -> int:
             child += ["--speculative", str(args.speculative)]
         if args.tier_bytes:
             child += ["--tier-bytes", str(args.tier_bytes)]
-        if args.cp > 1:
-            child += ["--cp", str(args.cp)]
-        if args.rank_page_budget:
-            child += ["--rank-page-budget", str(args.rank_page_budget)]
         if args.tier_dir:
             # Restart-safe from one flag: children must export
             # snapshots for the supervisor's resume store (which
@@ -331,21 +327,6 @@ def main(argv=None) -> int:
                    "'Streaming & cancellation'). Without --replicas/"
                    "--fleet the demo serves a ContinuousEngine (the "
                    "fixed-batch Engine has no per-token emission).")
-    p.add_argument("--cp", type=int, default=1, metavar="N",
-                   help="context-parallel chunked prefill width for the "
-                   "continuous engines (docs/serving.md 'Long-context "
-                   "serving'): one request's prompt is split across N "
-                   "virtual ranks and the per-block KV exchange hides "
-                   "under the next block's attention; excluded with "
-                   "--mode mega and --speculative")
-    p.add_argument("--rank-page-budget", type=int, default=0,
-                   metavar="TOKENS",
-                   help="per-rank resident KV budget in tokens for "
-                   "sharded long-context slots (docs/serving.md "
-                   "'Long-context serving'): a slot whose KV would "
-                   "exceed it keeps a resident paged window and "
-                   "demotes cold pages to the KV tier — needs "
-                   "--tier-bytes/--tier-dir and --replicas/--fleet")
     p.add_argument("--request-timeout", type=float, default=0.0,
                    help="with --replicas: router-observed replica "
                    "timeout (seconds; 0 = off — a cold compile must "
@@ -366,49 +347,10 @@ def main(argv=None) -> int:
         p.error(
             "--speculative and --mode mega do not compose (the NS-step "
             "fused launch advances all slots in lockstep and already "
-            "amortizes per-step dispatch, and the resident work ring "
+            "amortizes per-step dispatch, and the resident pipeline "
             "splices whole slots between rounds — never a mid-launch "
             "verify/rollback; docs/megakernel.md 'Resident decode'); "
             "drop --speculative or use --mode xla/pallas"
-        )
-    longctx = args.cp > 1 or args.rank_page_budget
-    if args.cp < 1:
-        p.error("--cp takes a width >= 1")
-    if longctx:
-        # The run_server refusal set, mirrored (fail fast BY FLAG NAME
-        # before any model loads — docs/serving.md 'Long-context
-        # serving'). --cpu coerces mega→xla, which does compose.
-        if args.mode == "mega" and not args.cpu:
-            p.error(
-                "--cp/--rank-page-budget and --mode mega do not "
-                "compose (the megakernel's fused NS-step launch owns "
-                "the whole batch; context-parallel prefill and "
-                "sharded-slot decode ride the chunked paged path); "
-                "use --mode xla/pallas"
-            )
-        if args.speculative:
-            p.error(
-                "--cp/--rank-page-budget and --speculative do not "
-                "compose (draft/verify rollback assumes whole-slot "
-                "resident KV); drop one"
-            )
-        if args.model == "stub":
-            p.error(
-                "--cp/--rank-page-budget need a real engine (stub "
-                "children generate without a KV cache); use a real "
-                "--model"
-            )
-        if not (args.replicas or args.stream or args.fleet
-                or args.prefill_replicas or args.decode_replicas):
-            p.error(
-                "--cp/--rank-page-budget ride the continuous serving "
-                "stack only (the fixed-batch Engine prefills in one "
-                "shot); add --replicas N, --fleet N or --stream"
-            )
-    if args.rank_page_budget and not (args.tier_bytes or args.tier_dir):
-        p.error(
-            "--rank-page-budget demotes cold pages to the KV tier; "
-            "add --tier-bytes N and/or --tier-dir DIR"
         )
     if (args.tier_bytes or args.tier_dir) and not (
             args.fleet or args.replicas):
@@ -546,8 +488,6 @@ def main(argv=None) -> int:
                 temperature=0.0, prefix_cache=True,
                 kv_dtype=args.kv_dtype, speculative=args.speculative,
                 kernel_trace=kernel_trace,
-                cp=args.cp,
-                rank_page_budget=args.rank_page_budget,
                 tier=shared_tier,
                 tier_bytes=args.tier_bytes,
                 tier_dir=(os.path.join(args.tier_dir, f"r{i}")
@@ -565,7 +505,6 @@ def main(argv=None) -> int:
             model, max_batch=2, max_length=1024, mode=mode,
             temperature=0.0, prefix_cache=True, kv_dtype=args.kv_dtype,
             speculative=args.speculative, kernel_trace=kernel_trace,
-            cp=args.cp,
         )
     else:
         eng = Engine(model, temperature=0.0, mode=mode,

@@ -8,13 +8,18 @@ For each slot count it compiles the served decode step and one chunk
 prefill (256 tokens, 2 gathered pages) with a donated cache for a
 described v5e, and prints what ``memory_analysis`` says beside the
 ``copy`` / ``dynamic-slice`` / fusion instructions whose result has the
-shape of the whole KV pool or of one layer of it. The pool is addressed
+shape of the whole KV pool or of one layer of it, and a hash of each
+program's lowered text (``tdt_finite_greedy``'s too): equal hashes on
+two checkouts unpacked, in turn, at the SAME path mean the same
+programs (a Pallas kernel's serialized body names its source file and
+line; callers' lines are kept out of it). The pool is addressed
 in place by (layer, page) (``Qwen3._scan_layers_paged``): there are none,
 and ``temp`` is a megabyte, as long as no layer scan takes the pool as
 ``xs`` and nothing scatters rows into it (layers/tp_attn.py "in-place
 writers"). Tracked in git though ``prof/`` is scratch: the test imports
 :func:`pool_shaped_moves` from here.
 """
+import hashlib
 import os
 import re
 import sys
@@ -66,8 +71,12 @@ def main(argv):
     from jax.experimental import topologies
 
     jax.config.update("jax_enable_compilation_cache", False)
+    # Locations name the op's own frame, not its callers': the hash
+    # then does not move with a line added above a caller.
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
 
     from triton_distributed_tpu.models.config import get_config
+    from triton_distributed_tpu.models.continuous import tdt_finite_greedy
     from triton_distributed_tpu.models.paged_kv_cache import (
         PagedKVCache,
         paged_cache_specs,
@@ -115,13 +124,20 @@ def main(argv):
             out_specs=(jax.P(), specs),
         )
         programs = (
-            ("decode", step, (params, sds((b,), jnp.int32), cache)),
+            ("decode", step, (params, sds((b,), jnp.int32), cache), (2,)),
             ("chunk256", chunk, (params, sds((256,), jnp.int32), cache,
-                                 i32, i32, i32, i32)),
+                                 i32, i32, i32, i32), (2,)),
+            ("finite_greedy", tdt_finite_greedy,
+             (sds((b, cfg.vocab_size), jnp.float32),), ()),
         )
-        for label, fn, args in programs:
+        for label, fn, args, donate in programs:
             try:
-                c = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+                # A cached trace keeps the location of its FIRST call
+                # site: without this the chunk's kernel names a line of
+                # the decode step's caller.
+                jax.clear_caches()
+                lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
+                c = lowered.compile()
             except Exception as e:  # noqa: BLE001 — report what the compiler refuses
                 print(name, "tp", tp, mode, kv, "B", b, label, "REFUSED:",
                       str(e).splitlines()[0][:200], flush=True)
@@ -139,6 +155,8 @@ def main(argv):
                 "| pool-shaped moves:",
                 pool_shaped_moves(txt, pool_shape, "s8" if quant else "bf16")
                 or 0,
+                "| lowered sha256",
+                hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16],
                 flush=True,
             )
 

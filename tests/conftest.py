@@ -134,7 +134,7 @@ _FILE_ORDER = [
     "test_layers.py", "test_native.py", "test_obs.py", "test_router.py",
     "test_fleet.py", "test_migration.py", "test_kv_tier.py",
     "test_kv_fabric.py", "test_goodput.py", "test_pools.py",
-    "test_multihost.py", "test_long_context.py", "test_chip_smoke.py",
+    "test_multihost.py", "test_chip_smoke.py",
     "test_attention.py", "test_p2p.py", "test_kv_quant.py",
     "test_speculative.py", "test_tree_spec.py", "test_kernel_trace.py",
     "test_resident.py",

@@ -203,23 +203,59 @@ def test_nan_logits_guard(ctx4):
     assert eng.audit() == []
 
 
-def test_oversized_request_isolated(ctx4):
-    """A request that can never fit gets a structured `unservable`
-    result (results mode) while the rest of the batch serves; legacy
-    mode still raises ValueError up front."""
-    model, eng = tiny_engine(ctx4)
-    gold_a = golden(model, P_A, 4)
-    results = eng.run(
-        [(np.asarray(P_A, np.int32), 4),
-         (np.zeros(60, np.int32), 16)],  # 76 > max_length 64
-        results=True,
+@pytest.fixture(scope="module")
+def own_model():
+    """ONE tiny model on a mesh of its own (never the current context,
+    which the per-test ``ctx4`` fixtures set and clear): its jitted
+    programs are shared by every engine built on it."""
+    import jax
+
+    from triton_distributed_tpu.runtime import mesh as mesh_mod
+
+    ctx = mesh_mod.initialize_distributed(
+        tp=1, devices=jax.devices()[:1], set_as_current=False
     )
-    assert results[0].ok
-    np.testing.assert_array_equal(results[0].tokens, gold_a)
+    return AutoLLM.from_pretrained("tiny", ctx=ctx)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+@pytest.mark.parametrize("prefix_cache", [False, True])
+@pytest.mark.parametrize(
+    "oversized,why",
+    [((60, 16), "exceeds max_length"),     # 76 tokens > max_length 64
+     ((40, 16), "pool capacity is 3"),     # fits a row: 4 pages of 3
+     ((70, 16), "exceeds max_length")],    # both: the length is named
+    ids=["max_length", "pool", "both"])
+def test_oversized_request_isolated(own_model, oversized, why,
+                                    prefix_cache, kv_dtype):
+    """A request that can never fit (too long for a table row, too many
+    pages for the pool, or both) gets a structured `unservable` result
+    (results mode) while the rest of the batch is served token for
+    token; legacy mode still raises ValueError up front. There is one
+    admission path: what does not fit is refused, never split."""
+    def engine():
+        return ContinuousEngine(
+            own_model, max_batch=2, page_size=16, max_length=64,
+            num_pages=3, prefix_cache=prefix_cache, kv_dtype=kv_dtype,
+        )
+    fits = [(np.asarray(P_A, np.int32), 4), (np.asarray(P_B, np.int32), 4)]
+    gold = engine().run(fits)
+    if kv_dtype is None:
+        np.testing.assert_array_equal(gold[0], golden(own_model, P_A, 4))
+    big = (np.zeros(oversized[0], np.int32), oversized[1])
+    eng = engine()
+    results = eng.run([fits[0], big, fits[1]], results=True)
+    for res, want in zip((results[0], results[2]), gold):
+        assert res.ok and res.error is None
+        np.testing.assert_array_equal(res.tokens, want)
     assert results[1].status == "unservable"
-    assert "exceeds max_length" in results[1].reason
-    with pytest.raises(ValueError, match="exceeds max_length"):
-        eng.run([(np.zeros(60, np.int32), 16)])
+    assert why in results[1].reason
+    err = results[1].error  # structured RequestError channel
+    assert err is not None and err.status == "unservable"
+    assert len(results[1].tokens) == 0
+    assert eng.last_stats["failed_requests"] == 1
+    with pytest.raises(ValueError, match=why):
+        eng.run([big])
     assert eng.audit() == []
 
 

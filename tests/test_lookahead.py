@@ -120,26 +120,16 @@ def _speculative(model):
     return (engine(model, speculative=3), engine(model, speculative=3)), reqs
 
 
-def _long_context(model):
-    long_prompt = np.random.default_rng(8).integers(
-        1, 200, size=88).astype(np.int32)
-    kw = dict(max_length=128, rank_page_budget=64, tier_bytes=32 << 20,
-              num_pages=8)
-    reqs = lambda: [(long_prompt, 4), (LONE, 3)]  # noqa: E731
-    return (engine(model, **kw), engine(model, **kw)), reqs
-
-
 def _fault_plan(model):
     reqs = lambda: [(LONE, 10), (prompt(1, 5), 6)]  # noqa: E731
     return (engine(model), engine(model)), reqs
 
 
-@pytest.mark.parametrize(
-    "case", [_sampled, _speculative, _long_context, _fault_plan])
+@pytest.mark.parametrize("case", [_sampled, _speculative, _fault_plan])
 def test_lookahead_stays_out(model, case):
-    """A sampled slot, a speculative plan, a sharded long-context slot
-    or an armed FaultPlan: the round keeps the parent's serial order
-    and gives the parent's outputs."""
+    """A sampled slot, a speculative plan or an armed FaultPlan: the
+    round keeps the parent's serial order and gives the parent's
+    outputs."""
     (parent, eng), reqs = case(model)
     want, _ = run(serial(parent), reqs())
     if case is _fault_plan:
@@ -158,8 +148,6 @@ def test_lookahead_stays_out(model, case):
         assert stats["lookahead_steps"] == 3
     else:
         assert stats["lookahead_steps"] == 0
-    if case is _long_context:
-        assert stats["longctx_sharded_slots"] == 1
     assert stats["lookahead_discarded"] == 0
 
 
@@ -281,7 +269,153 @@ def test_host_and_device_kv_len_agree_after_every_drain(model, greedy):
     assert eng.last_stats["lookahead_discarded"] >= 1
 
 
-# -- (d) the engagement rate and where the counters show -------------------
+# -- (d) every site that changes slot, table or pool state syncs `_pend` ----
+
+
+class SiteSpy:
+    """Wraps one site that mutates slot, table or pool state and the
+    calls through which it does so. Counts the entries that found a
+    launch parked in ``_pend``, and names every mutation made while
+    that launch was still the device's: not drained where the site
+    drains (``settle=False``), not even fetched where a looked-ahead
+    step may stay parked (``_settle_pend``'s sites)."""
+
+    def __init__(self, eng, patch, site, settle=False):
+        self.eng, self.patch, self.settle = eng, patch, settle
+        self.depth = self.parked = self.mutations = 0
+        self.early = []
+        fn = getattr(eng, site)
+
+        def entered(*a, **kw):
+            self.parked += eng._pend is not None
+            self.depth += 1
+            try:
+                return fn(*a, **kw)
+            finally:
+                self.depth -= 1
+        patch.setattr(eng, site, entered)
+
+    def _in_flight(self) -> bool:
+        pend = self.eng._pend
+        if self.settle and isinstance(pend, _StepLaunch):
+            return pend.host is None
+        return pend is not None
+
+    def mutator(self, owner, name):
+        fn = getattr(owner, name)
+
+        def call(*a, **kw):
+            if self.depth:
+                self.mutations += 1
+                if self._in_flight():
+                    self.early.append(name)
+            return fn(*a, **kw)
+        self.patch.setattr(owner, name, call)
+
+
+def _site_try_admit(eng, spy, greedy):
+    # A stop token ends LONE while the next step is parked: the queued
+    # third request is admitted into its slot.
+    eng.eos_id = int(greedy[3])
+    spy.mutator(eng, "_admit")
+    return [(LONE, 8), (prompt(1, 5), 6), (prompt(0, 7), 3)], ["ok"] * 3
+
+
+def _site_apply_cancels(eng, spy, greedy):
+    spy.mutator(eng, "_teardown_slot")
+    sink = Watch(eng).sink(3, lambda: eng.cancel(["t-32"]))
+    return ([Request(LONE, 12, ticket_id="t-32", on_token=sink),
+             (prompt(1, 5), 6)], ["cancelled", "ok"])
+
+
+def _site_expire_deadlines(eng, spy, greedy):
+    spy.mutator(eng, "_teardown_slot")
+    req = Request(LONE, 12)
+
+    def expire():
+        req.deadline_at = 0.0
+    req.on_token = Watch(eng).sink(3, expire)
+    return [req, (prompt(1, 5), 6)], ["deadline_exceeded", "ok"]
+
+
+def _site_handoff_sweep(eng, spy, greedy):
+    spy.mutator(eng, "_migrate_out")
+    eng.request_handoff(after_rounds=3)
+    return [(LONE, 12), (prompt(1, 5), 12)], ["migrated"] * 2
+
+
+def _site_update_snapshot_buffer(eng, spy, greedy):
+    from triton_distributed_tpu.models import slot_state
+
+    spy.patch.setattr(eng, "snapshot_every", 1)
+    spy.mutator(slot_state, "export_slot")
+    return ([Request(LONE, 8, ticket_id="a"),
+             Request(prompt(1, 5), 6, ticket_id="b")], ["ok"] * 2)
+
+
+def _site_step_guard(eng, spy, greedy):
+    # The teardown path: the second emit raises with the next launch
+    # parked; the guard has to reclaim it before any slot is torn down.
+    spy.mutator(eng, "_teardown_slot")
+    process, calls = eng._process, []
+
+    def raising(slot_tokens):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("injected emit fault")
+        return process(slot_tokens)
+    spy.patch.setattr(eng, "_process", raising)
+    return [(LONE, 10), (prompt(1, 5), 10)], ["failed"] * 2
+
+
+@pytest.fixture(scope="module")
+def kinds(model):
+    """One engine a launch kind for all six sites (a mega engine
+    compiles its launch programs anew): ``step`` parks a looked-ahead
+    ``_StepLaunch``, ``mega`` a resident ``_MegaLaunch``."""
+    built = {}
+
+    def get(kind):
+        if kind not in built:
+            kw = dict(mode="mega", resident=True, ns=2) \
+                if kind == "mega" else {}
+            built[kind] = engine(model, prefix_cache=True, **kw)
+        return built[kind]
+    return get
+
+
+@pytest.mark.parametrize("kind", ["step", "mega"])
+@pytest.mark.parametrize(
+    "site,settle",
+    [(_site_try_admit, False), (_site_apply_cancels, True),
+     (_site_expire_deadlines, True), (_site_handoff_sweep, False),
+     (_site_update_snapshot_buffer, False), (_site_step_guard, False)])
+def test_state_mutation_syncs_pend_first(kinds, greedy, monkeypatch, kind,
+                                         site, settle):
+    """Whatever mutates slot, table or pool state comes through
+    ``_drain_pend`` / ``_settle_pend`` / ``_abort_pend`` first, for both
+    kinds of launch the one ``_pend`` slot parks; the engine serves the
+    next batch."""
+    eng = kinds(kind)
+    spy = SiteSpy(eng, monkeypatch, site.__name__[len("_site"):], settle)
+    try:
+        reqs, statuses = site(eng, spy, greedy)
+        got, _ = run(eng, reqs)
+    finally:
+        eng.eos_id = None
+    assert [status for _, status in got] == statuses
+    assert spy.parked > 0      # the site did meet a launch in flight
+    assert spy.mutations > 0   # and did change state
+    assert spy.early == []     # never before the device had left it
+    assert eng._pend is None
+    monkeypatch.undo()
+    again, _ = run(eng, [(LONE, 4)])
+    assert again[0][1] == "ok"
+    if kind == "step":
+        assert again[0][0] == greedy[:4].tolist()
+
+
+# -- (e) the engagement rate and where the counters show -------------------
 
 
 def test_engagement_rate_and_counters(model, fresh_telemetry):

@@ -468,6 +468,66 @@ def test_continuous_run_populates_latency_histograms(ctx4):
     assert {"admit", "evict", "deadline"} <= kinds
 
 
+def _series_named_in_docs() -> set:
+    """Every `tdt_*` series a table row of docs/observability.md names
+    (rows open with the name; `a` / `b` pairs and `{label}` suffixes
+    included, `tdt_engine_{...}` families left to their prose)."""
+    import os
+    import re
+
+    path = os.path.join(os.path.dirname(__file__), "..", "docs",
+                        "observability.md")
+    names = set()
+    with open(path) as f:
+        for line in f:
+            if line.startswith("| `tdt_"):
+                head = line.split("|")[1]
+                names |= set(re.findall(r"`(tdt_[a-z0-9_]*[a-z0-9])[`{]",
+                                        head))
+    return names
+
+
+def _series_defined_in_package() -> set:
+    import glob
+    import os
+    import re
+
+    pkg = os.path.join(os.path.dirname(__file__), "..",
+                       "triton_distributed_tpu")
+    names = set()
+    for path in glob.glob(os.path.join(pkg, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            names |= set(re.findall(r'"(tdt_[a-z0-9_]+)"', f.read()))
+    return names
+
+
+@pytest.mark.parametrize("side", ["registry", "docs"])
+def test_series_catalog_holds_only_what_exists(ctx4, side):
+    """The engine registers, and docs/observability.md's tables name,
+    only series the program can move: nothing of the emulated work ring
+    or the virtual-rank long-context path, and no row for a series the
+    package does not define."""
+    gone = ("tdt_cp_", "tdt_longctx_", "tdt_mega_ring_")
+    if side == "registry":
+        from triton_distributed_tpu.models.stats import (
+            STAT_METRIC_ALIASES,
+            STAT_METRICS,
+        )
+
+        _tiny_continuous(ctx4)
+        names = set(obs_metrics.default_registry().snapshot())
+        catalog = {name for name, _ in STAT_METRICS.values()}
+        catalog |= {name for aliases in STAT_METRIC_ALIASES.values()
+                    for name, _ in aliases}
+        assert catalog <= names  # pre-touched: a cold scrape shows all
+    else:
+        names = _series_named_in_docs()
+        assert len(names) > 80
+        assert names <= _series_defined_in_package(), (
+            sorted(names - _series_defined_in_package()))
+    assert not [n for n in names if n.startswith(gone)]
+
+
 def test_core_stats_keys_unified(ctx4):
     """Satellite (ISSUE 5): Engine.last_stats and
     ContinuousEngine.last_stats expose ONE shared core key set

@@ -1,32 +1,27 @@
-"""Resident megakernel decode (ISSUE 19): host work ring, in-kernel
-top-k/top-p, batch-bucket launches, device-side stop-token retire.
+"""Resident megakernel decode (ISSUE 19): pipelined NS-step launches,
+in-kernel top-k/top-p, batch-bucket launches, device-side stop-token
+retire.
 
-Coverage contract (ISSUE 19 acceptance):
-- WorkRing semantics: publish-then-consume round protocol, monotonic
-  doorbell, loud overflow (a dropped admit/retire item would
-  desynchronize the device scheduler from the engine's slot state);
-- ``validate_ring``'s doorbell-gap check: a RING_POLL record that
-  observed a doorbell the host did not publish for that launch flags
-  as a stale ring snapshot;
-- the new ``tdt_mega_*`` ring/retire series pre-touch to 0 at engine
+Coverage contract:
+- ``validate_ring`` keeps every record's ``mid`` inside its clock
+  interval;
+- the ``tdt_mega_*`` resident series pre-touch to 0 at engine
   construction (the PR 15 convention: a cold counter must READ 0 on
   the dashboard, not be missing), and
   ``tdt_mega_single_step_fallbacks_total`` scrapes 0 after a PURE
   SAMPLED mega run — the in-kernel filter replaced the fallback;
 - both serving CLIs refuse --speculative × --mode mega with the
-  ring-splice reason (the flag-name substring is pinned by
-  test_tools.py; THIS file pins the new wording);
+  slot-splice reason (the flag-name substring is pinned by
+  test_tools.py; THIS file pins the wording);
 - device-side stop-token retire: a slot hitting eos mid-multi-step
   retires with no host round trip, its pages flow back through the
   normal teardown path (radix tree receives the chain, pool audit
   clean), and the co-batched survivor's tokens are bit-exact;
 - batch-bucket launches emit bit-identical tokens to the full-width
-  program; the resident pipeline's rings validate gap-free against
-  their published doorbells;
-- review hardening: ``consume`` stops at the publish snapshot and
-  ``flush`` drains fallback rounds host-side (a persistently
-  falling-back workload must not overflow the ring and wedge the
-  engine), no-op filter knobs (top_k >= V, top_p == 1) never force the
+  program; the resident pipeline serves the non-resident engine's
+  tokens at every batch bucket, greedy and sampled, and its traced
+  launches validate against the scheduled order;
+- no-op filter knobs (top_k >= V, top_p == 1) never force the
   filtered program or the tp>1 fallback, and a drain that faults
   reaches the step guard with the just-issued launch parked in
   ``_pend`` (no orphaned in-flight launch).
@@ -36,12 +31,6 @@ import jax
 import numpy as np
 import pytest
 
-from triton_distributed_tpu.megakernel.ring import (
-    RING_ADMIT,
-    RING_CANCEL,
-    RING_RETIRE,
-    WorkRing,
-)
 from triton_distributed_tpu.models import AutoLLM
 from triton_distributed_tpu.models.engine import Engine
 from triton_distributed_tpu.obs import kernel_trace as kt
@@ -59,96 +48,29 @@ def ctx1():
 # -- host-side units (no model) -----------------------------------------
 
 
-def test_work_ring_semantics():
-    """The round protocol: push N items, publish bumps the doorbell and
-    snapshots [doorbell, head, tail, occupancy], consume drains oldest
-    first with monotonic seqs; overflow raises instead of dropping."""
-    ring = WorkRing(capacity=4)
-    ring.push(RING_ADMIT, 0, 12)
-    ring.push(RING_RETIRE, 1, 7)
-    ring.push(RING_CANCEL, 2)
-    snap = ring.publish()
-    assert snap.dtype == np.int32
-    assert snap.tolist() == [1, 0, 3, 3]
-    items = ring.consume()
-    assert [(i.kind, i.slot, i.arg) for i in items] == [
-        (RING_ADMIT, 0, 12), (RING_RETIRE, 1, 7), (RING_CANCEL, 2, 0),
-    ]
-    assert [i.seq for i in items] == [0, 1, 2]
-    assert ring.occupancy == 0 and ring.peak_occupancy == 3
-    # Empty round: the doorbell still advances (the kernel must be able
-    # to tell "round with no work" from "no round").
-    assert ring.publish().tolist() == [2, 3, 3, 0]
-    # Wrap past capacity, then overflow loudly.
-    for n in range(4):
-        ring.push(RING_ADMIT, n)
-    with pytest.raises(RuntimeError, match="work ring full"):
-        ring.push(RING_ADMIT, 9)
-    ring.publish()
-    assert [i.slot for i in ring.consume()] == [0, 1, 2, 3]
-
-
-def test_work_ring_publish_snapshot_and_flush():
-    """``consume`` drains exactly up to the last publish's tail
-    snapshot — items pushed after the doorbell stay host-owned for the
-    next round — and ``flush`` drains everything without moving the
-    doorbell (the single-step-fallback path)."""
-    ring = WorkRing(capacity=4)
-    ring.push(RING_ADMIT, 0)
-    ring.publish()
-    ring.push(RING_RETIRE, 1)  # after the publish: the NEXT round's
-    items = ring.consume()
-    assert [(i.kind, i.slot) for i in items] == [(RING_ADMIT, 0)]
-    assert ring.occupancy == 1  # the unpublished item is still queued
-    ring.publish()
-    assert [i.slot for i in ring.consume()] == [1]
-    # Nothing published since the drain: consume is empty even with
-    # items queued; flush takes them all, doorbell untouched.
-    ring.push(RING_CANCEL, 2)
-    ring.push(RING_ADMIT, 3)
-    assert ring.consume() == []
-    bell = ring.doorbell
-    flushed = ring.flush()
-    assert [i.slot for i in flushed] == [2, 3]
-    assert ring.occupancy == 0 and ring.doorbell == bell
-    assert ring.flush() == []
-
-
 def _rec(index, opcode, begin, end, mid=0, task_id=None):
     return kt.TaskRecord(0, 0, index, task_id or index, opcode, 0, 0,
                          begin, end, mid)
 
 
-def test_validate_ring_doorbell_gap_check():
-    """RING_POLL's mid column carries the OBSERVED doorbell, not a
-    clock tick: it is exempt from the mid-in-interval check, and with
-    ``doorbell=`` it must equal the published value exactly."""
+def test_validate_ring_mid_clock_check():
+    """A record's stamped ``mid`` is a clock tick of its own task: one
+    outside ``[begin, end]`` is flagged, one inside (or unstamped) is
+    not."""
     from triton_distributed_tpu.megakernel.task import TaskType
 
-    poll = int(TaskType.RING_POLL)
-    other = int(TaskType.LM_HEAD)
-    records = [
-        _rec(0, poll, 10, 20, mid=7),       # mid=doorbell, outside clock
-        _rec(1, other, 20, 40, mid=30),
-    ]
-    assert kt.validate_ring(records) == []
-    assert kt.validate_ring(records, doorbell=7) == []
-    problems = kt.validate_ring(records, doorbell=8)
-    assert len(problems) == 1 and "stale ring snapshot" in problems[0]
-    # A non-poll record's mid stays clock-checked.
-    bad = [_rec(0, other, 10, 20, mid=99)]
+    op = int(TaskType.LM_HEAD)
+    assert kt.validate_ring(
+        [_rec(0, op, 10, 20), _rec(1, op, 20, 40, mid=30)]) == []
+    bad = [_rec(0, op, 10, 20, mid=99)]
     assert any("outside" in p for p in kt.validate_ring(bad))
-    # overlap_report summarizes the polls and their doorbell range.
-    rep = kt.overlap_report(records)
-    assert rep["ring_polls"] == 1
-    assert rep["ring_doorbell_min"] == rep["ring_doorbell_max"] == 7
 
 
-def test_cli_refusals_carry_ring_splice_reason(capsys):
-    """Both CLIs still refuse --speculative × --mode mega as an
-    argparse error (exit 2, before any model load), and the message now
-    explains the RESIDENT reason: the work ring splices whole slots
-    between rounds, never a mid-launch verify/rollback."""
+def test_cli_refusals_carry_splice_reason(capsys):
+    """Both CLIs refuse --speculative × --mode mega as an argparse
+    error (exit 2, before any model load), and the message explains
+    the RESIDENT reason: the pipeline splices whole slots between
+    rounds, never a mid-launch verify/rollback."""
     from perf import serve_demo
     from triton_distributed_tpu.serving import run_server
 
@@ -158,7 +80,7 @@ def test_cli_refusals_carry_ring_splice_reason(capsys):
         assert exc.value.code == 2
         err = capsys.readouterr().err
         assert "--speculative and --mode mega" in err
-        assert "work ring splices whole slots" in err
+        assert "resident pipeline splices whole slots" in err
 
 
 def test_resident_knob_validation(capsys, ctx1):
@@ -183,7 +105,7 @@ def test_resident_knob_validation(capsys, ctx1):
                          mode="mega", ns=0)
 
 
-def test_ring_metrics_pretouch(fresh_telemetry, ctx1):
+def test_resident_metrics_pretouch(fresh_telemetry, ctx1):
     """Engine construction alone pre-touches the resident-decode
     catalog: every new series reads 0 from the first scrape (PR 15
     convention), including the fallback counter the acceptance gate
@@ -197,9 +119,6 @@ def test_ring_metrics_pretouch(fresh_telemetry, ctx1):
     text = obs_metrics.prometheus_text()
     for name in (
         "tdt_mega_single_step_fallbacks_total",
-        "tdt_mega_ring_items_total",
-        "tdt_mega_ring_doorbells_total",
-        "tdt_mega_ring_host_drains_total",
         "tdt_mega_device_retires_total",
         "tdt_mega_resident_rounds_total",
         "tdt_mega_bucket_launches_total",
@@ -284,17 +203,52 @@ def test_bucket_launch_bit_exact(ctx1):
         np.testing.assert_array_equal(b, np.asarray(gold))
 
 
-@pytest.mark.slow
-def test_resident_pipeline_ring_gap_free(ctx1):
-    """Resident decode: round i+1 issues off round i's device outputs
-    (mega_resident_rounds), admit/retire items flow through the work
-    ring, every traced launch's ring validates gap-free against the
-    doorbell the host published for it, and tokens stay bit-exact."""
+_PROMPTS = [np.asarray(p, np.int32) for p in (
+    [5, 9, 2, 4], [7, 1, 3, 8, 6, 2, 4, 9], [3, 3, 8, 1, 6], [2, 7, 4],
+)]
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("bucket", [1, 2, 4])
+def test_resident_matches_non_resident(ctx1, bucket, sampled):
+    """The resident pipeline (launch i+1 issued off launch i's device
+    outputs, before launch i drains) serves the non-resident engine's
+    tokens, token for token, at every batch bucket of a 4-slot engine,
+    greedy and seeded-sampled (the slow tests below hold the greedy
+    tokens to the unfused goldens)."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
     model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
-    prompts = [np.asarray([5, 9, 2, 4], np.int32),
-               np.asarray([7, 1, 3, 8, 6, 2, 4, 9], np.int32)]
+    prompts = _PROMPTS[:bucket]
+    knobs = dict(temperature=0.8, seed=3) if sampled else {}
+
+    def run(resident):
+        eng = ContinuousEngine(
+            model, max_batch=4, page_size=16, max_length=64,
+            mode="mega", ns=2, resident=resident, **knobs,
+        )
+        outs = eng.run([(p, 6) for p in prompts])
+        assert eng.audit() == []
+        return outs, eng.stats
+
+    outs_res, st = run(True)
+    outs_plain, st_plain = run(False)
+    assert st["mega_resident_rounds"] > 0, st
+    assert st_plain["mega_resident_rounds"] == 0, st_plain
+    for a, b in zip(outs_res, outs_plain):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.slow
+def test_resident_pipeline_trace_validates(ctx1):
+    """Resident decode under the device task tracer: round i+1 issues
+    off round i's device outputs (mega_resident_rounds), every traced
+    launch's records validate, and tokens stay bit-exact."""
+    from triton_distributed_tpu.models.continuous import ContinuousEngine
+
+    model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
+    prompts = _PROMPTS[:2]
     golds = [
         Engine(model, temperature=0.0).serve(p[None], gen_len=6)[0, len(p):]
         for p in prompts
@@ -306,20 +260,11 @@ def test_resident_pipeline_ring_gap_free(ctx1):
     outs = eng.run([(p, 6) for p in prompts])
     for got, gold in zip(outs, golds):
         np.testing.assert_array_equal(got, np.asarray(gold))
-    st = eng.stats
-    assert st["mega_resident_rounds"] > 0, st
-    assert st["mega_ring_items"] >= 4, st       # 2 admits + 2 retires
-    assert st["mega_ring_doorbells"] > 0, st
+    assert eng.stats["mega_resident_rounds"] > 0, eng.stats
     launches = eng.kernel_trace_launches()
     assert launches
-    belled = 0
     for ln in launches:
-        assert kt.validate_ring(ln.get_records(), doorbell=ln.doorbell) == []
-        belled += ln.doorbell is not None
-    assert belled > 0
-    # Doorbells climb monotonically across the resident session.
-    bells = [ln.doorbell for ln in launches if ln.doorbell is not None]
-    assert bells == sorted(bells) and len(set(bells)) == len(bells)
+        assert kt.validate_ring(ln.get_records()) == []
 
 
 @pytest.mark.slow
@@ -348,37 +293,6 @@ def test_sampled_run_scrapes_zero_fallbacks(fresh_telemetry, ctx1):
     assert reg.get("tdt_mega_filtered_rounds_total").value() > 0
     assert "tdt_mega_single_step_fallbacks_total 0" in \
         obs_metrics.prometheus_text()
-
-
-@pytest.mark.slow
-def test_persistent_fallback_drains_ring(ctx1):
-    """A resident session whose every round falls back to single-step
-    (ns=1 + filtered sampling can never compose a fused launch) must
-    drain the work ring host-side: before the fix the admit/retire
-    items were only consumed inside ``_launch_mega``, so a workload
-    that persistently fell back overflowed the ring after ``capacity``
-    items and the RuntimeError wedged every subsequent round."""
-    from triton_distributed_tpu.models.continuous import ContinuousEngine
-
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
-    eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64, mode="mega",
-        resident=True, ns=1, temperature=0.8, top_k=5, top_p=0.9, seed=3,
-    )
-    # 4 requests push 4 admits + 4 retires: twice the shrunken
-    # capacity, so any round that fails to drain overflows quickly.
-    eng._ring = WorkRing(capacity=4)
-    prompt = np.asarray([5, 9, 2, 4], np.int32)
-    results = eng.run([(prompt, 4)] * 4, results=True)
-    assert all(r.ok for r in results), [r.status for r in results]
-    assert all(len(r.tokens) == 4 for r in results)
-    st = eng.stats
-    assert st["mega_fallback_steps"] > 0, st
-    assert st["mega_ring_items"] == 8, st
-    assert st["mega_ring_host_drains"] == 8, st
-    assert st["mega_ring_doorbells"] == 0, st  # no fused launch ever
-    assert eng._ring.occupancy == 0  # empty at rest after teardown
-    assert eng.audit() == []
 
 
 @pytest.mark.slow

@@ -138,6 +138,87 @@ def test_continuous_batching_oversubscribed_pool(ctx4):
         small.run([(np.zeros(48, np.int32), 16)])
 
 
+def test_max_length_page_size_validation(ctx4):
+    """A misaligned (max_length, page_size) pair must refuse at
+    construction NAMING BOTH VALUES — before it, ``pps`` silently
+    truncated and the tail tokens had no page."""
+    from triton_distributed_tpu.models.continuous import ContinuousEngine
+
+    lc_model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
+    with pytest.raises(ValueError, match=r"100.*not a multiple.*16"):
+        ContinuousEngine(
+            lc_model, max_batch=1, page_size=16, max_length=100
+        )
+    # Engine validates against the model's cfg.max_length (128 for
+    # tiny) — 48 does not divide it.
+    with pytest.raises(ValueError, match=r"max_length=128.*page_size=48"):
+        Engine(lc_model, paged=True, page_size=48)
+    with pytest.raises(ValueError, match=r"max_length.*page_size"):
+        Engine(lc_model, paged=True, page_size=16).serve(
+            [np.arange(1, 9, dtype=np.int32)], gen_len=1, max_length=100
+        )
+
+
+@pytest.mark.parametrize(
+    "argv", [["--cp", "2"], ["--rank-page-budget", "512"]],
+    ids=["cp", "rank_page_budget"])
+def test_run_server_has_no_long_context_flags(argv, capsys):
+    """What does not fit a slot is refused (`unservable`), never
+    sharded: the flags that chose the virtual-rank path are gone, not
+    parked behind a refusal of their own."""
+    from triton_distributed_tpu.serving import run_server
+
+    with pytest.raises(SystemExit) as exc:
+        run_server.main(["--model", "stub", *argv])
+    assert exc.value.code == 2
+    assert "unrecognized arguments: " + argv[0] in capsys.readouterr().err
+
+
+def test_engine_has_no_long_context_options():
+    import inspect
+
+    from triton_distributed_tpu.models.continuous import ContinuousEngine
+
+    options = list(inspect.signature(ContinuousEngine.__init__).parameters)
+    assert "cp" not in options and "rank_page_budget" not in options
+    assert len(options) - 2 <= 27  # self and the model aside
+
+
+def test_snapshot_longer_than_a_slot_is_unservable(ctx4):
+    """A snapshot holding more KV than a slot's table row has pages
+    for (what the sharded slot's stitched export could ship) is refused
+    with a structured `unservable` error while the rest of the batch is
+    served; nothing of it reaches the pool."""
+    from triton_distributed_tpu.models.continuous import (
+        ContinuousEngine,
+        Request,
+    )
+    from triton_distributed_tpu.models.slot_state import SlotSnapshot
+
+    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
+    eng = ContinuousEngine(model, max_batch=2, page_size=16, max_length=64)
+    L, _, hkv, page, hd = eng.cache.k_pages.shape
+    pages = np.zeros((L, 5, hkv, page, hd), eng.cache.k_pages.dtype)
+    long_prompt = np.arange(1, 71, dtype=np.int32)  # 70 tokens: 5 pages
+    snap = SlotSnapshot(
+        prompt=long_prompt, out=[3], gen_len=8, kv_len=70, page_size=16,
+        kv_dtype=None, k_pages=pages, v_pages=pages,
+    )
+    p = np.asarray([5, 9, 2, 4], np.int32)
+    gold = Engine(model, temperature=0.0).serve(p[None], gen_len=4)[0, 4:]
+    free = len(eng.pool.free)
+    results = eng.run(
+        [Request(long_prompt, 8, snapshot=snap.to_wire()), (p, 4)],
+        results=True,
+    )
+    assert results[0].status == "unservable"
+    assert results[0].error.status == "unservable"
+    assert "exceeds max_length" in results[0].reason
+    np.testing.assert_array_equal(results[1].tokens, np.asarray(gold))
+    assert eng.stats["migrated_in"] == 0
+    assert len(eng.pool.free) == free and eng.audit() == []
+
+
 @pytest.mark.slow
 def test_continuous_batching_mega_multi(ctx4):
     """mode="mega" continuous serving decodes in NS-token chunks

@@ -146,3 +146,110 @@ def test_distributed_flash_decode_2level(ctx2x4, rng, method):
     ref = gqa_decode_reference(q, kc, vc, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
                                rtol=2e-5)
+
+
+def test_ring_attention_bf16(ctx4, rng):
+    """Causal ring attention in bf16 vs the dense causal reference —
+    at serving's own dtype."""
+    from triton_distributed_tpu.ops.attention import (
+        mha_reference,
+        ring_attention,
+    )
+
+    s, hq, hkv, hd = 256, 4, 2, 64
+    q = jnp.asarray(rng.standard_normal((hq, s, hd)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((hkv, s, hd)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((hkv, s, hd)), jnp.bfloat16)
+    f = ctx4.shard_map(
+        functools.partial(
+            ring_attention, axis="tp", causal=True, block_q=64,
+            block_k=64,
+        ),
+        in_specs=(P(None, "tp", None),) * 3,
+        out_specs=P(None, "tp", None),
+    )
+    out = f(q, k, v)
+    assert out.dtype == jnp.bfloat16
+    ref = mha_reference(
+        q[None].astype(jnp.float32), k[None].astype(jnp.float32),
+        v[None].astype(jnp.float32), causal=True,
+    )[0]
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), atol=5e-2,
+        rtol=5e-2,
+    )
+
+
+def test_distributed_flash_decode_2level_bf16(ctx2x4, rng):
+    """Two-level (DCN×ICI) decode merge in bf16 vs the dense golden —
+    at serving's own dtype."""
+    from triton_distributed_tpu.ops.attention import (
+        distributed_flash_decode_2level,
+        gqa_decode_reference,
+    )
+
+    b, hq, hkv, s, hd = 2, 4, 2, 256, 64
+    q = jnp.asarray(rng.standard_normal((b, hq, hd)), jnp.bfloat16)
+    kc = jnp.asarray(rng.standard_normal((b, hkv, s, hd)), jnp.bfloat16)
+    vc = jnp.asarray(rng.standard_normal((b, hkv, s, hd)), jnp.bfloat16)
+    lens = jnp.asarray([200, 37], jnp.int32)
+    f = ctx2x4.shard_map(
+        functools.partial(
+            distributed_flash_decode_2level, inner_axis="tp",
+            outer_axis="dp", chunk_k=32, method="xla", ctx=ctx2x4,
+        ),
+        in_specs=(P(), P(None, None, ("dp", "tp"), None),
+                  P(None, None, ("dp", "tp"), None), P()),
+        out_specs=P(),
+    )
+    out = f(q, kc, vc, lens)
+    assert out.dtype == jnp.bfloat16
+    ref = gqa_decode_reference(
+        q.astype(jnp.float32), kc.astype(jnp.float32),
+        vc.astype(jnp.float32), lens,
+    )
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), atol=5e-2,
+        rtol=5e-2,
+    )
+
+
+def test_distributed_flash_decode_2level_int8(ctx2x4, rng):
+    """Two-level decode over int8 shards with per-chunk scales: each
+    rank dequantizes in-kernel, the (O, LSE) combine is unchanged."""
+    from triton_distributed_tpu.models.paged_kv_cache import quantize_pages
+    from triton_distributed_tpu.ops.attention import (
+        distributed_flash_decode_2level,
+        gqa_decode_reference,
+    )
+
+    b, hq, hkv, s, hd, chunk = 2, 4, 2, 256, 64, 32
+    q = jnp.asarray(rng.standard_normal((b, hq, hd)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, hkv, s, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, hkv, s, hd)), jnp.float32)
+    lens = jnp.asarray([180, 47], jnp.int32)
+    k_q, k_sc = quantize_pages(k.reshape(b, hkv, s // chunk, chunk, hd))
+    v_q, v_sc = quantize_pages(v.reshape(b, hkv, s // chunk, chunk, hd))
+    def shard_fn(q, k, v, lens, ks, vs):
+        return distributed_flash_decode_2level(
+            q, k, v, lens, inner_axis="tp", outer_axis="dp",
+            chunk_k=chunk, method="xla", k_scale=ks, v_scale=vs,
+            ctx=ctx2x4,
+        )
+
+    f = ctx2x4.shard_map(
+        shard_fn,
+        in_specs=(P(), P(None, None, ("dp", "tp"), None),
+                  P(None, None, ("dp", "tp"), None), P(),
+                  P(None, None, ("dp", "tp")),
+                  P(None, None, ("dp", "tp"))),
+        out_specs=P(),
+    )
+    out = f(
+        q, k_q.reshape(b, hkv, s, hd), v_q.reshape(b, hkv, s, hd),
+        lens, k_sc, v_sc,
+    )
+    ref = gqa_decode_reference(q, k, v, lens)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=0.1, rtol=0.1
+    )

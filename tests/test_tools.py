@@ -360,19 +360,15 @@ def test_kernel_trace_modules_compile():
 
 def test_resident_modules_compile():
     """ISSUE-19: the resident-decode pieces must byte-compile — the
-    work ring is imported lazily from the engine's mega round loop (a
-    syntax error would surface mid-serve, not at import), and the
-    bench that writes the resident section of perf/MEGA_SERVE.json
-    rides along (repo convention: perf harnesses fail tier-1, not a
-    chip run)."""
+    engine's mega round loop, and the bench that writes the resident
+    section of perf/MEGA_SERVE.json rides along (repo convention: perf
+    harnesses fail tier-1, not a chip run)."""
     import os
     import subprocess
     import sys
 
     root = os.path.join(os.path.dirname(__file__), "..")
     targets = [
-        os.path.join(root, "triton_distributed_tpu", "megakernel",
-                     "ring.py"),
         os.path.join(root, "triton_distributed_tpu", "models",
                      "continuous.py"),
         os.path.join(root, "perf", "mega_serve_bench.py"),
@@ -608,22 +604,6 @@ def test_tier1_marker_audit():
         f"multi-host suite has too few tier-1-runnable tests: "
         f"{mh_fast}"
     )
-    # ISSUE-20: the long-context suite (cp-prefill bit-exactness +
-    # ring validation, sharded-slot decode/tier paging, gather-stitch
-    # snapshot round-trip, bf16/int8 kernel parity, document loadgen
-    # class) rides with the fleet-family suites, ahead of the
-    # interpret tail, and must carry tier-1-runnable tests — a
-    # sharded-decode or exchange-schedule regression has to FAIL
-    # tier-1, not wait for a long_context_bench run.
-    assert "test_long_context.py" in order
-    assert (order.index("test_kv_tier.py")
-            < order.index("test_long_context.py")
-            < order.index("test_serving.py"))
-    lc_fast = fast_tests("test_long_context.py")
-    assert len(lc_fast) >= 5, (
-        f"long-context suite has too few tier-1-runnable tests: "
-        f"{lc_fast}"
-    )
     # ISSUE-16: the tree-speculation suite rides right behind the
     # linear-speculation suite (shared tiny-model jit warmup), ahead of
     # the interpret tail, and must carry tier-1-runnable tests — a
@@ -658,12 +638,12 @@ def test_tier1_marker_audit():
         f"device-tracer suite has too few tier-1-runnable tests: "
         f"{kt_fast}"
     )
-    # ISSUE-19: the resident-decode suite (work-ring protocol, doorbell
-    # validation, metric pre-touch, CLI refusal wording, knob guards)
-    # rides right behind the tracer suite whose validate_ring it
-    # extends, ahead of the interpret tail, and must carry tier-1-
-    # runnable tests — a ring-desync or fallback regression has to
-    # FAIL tier-1, not wait for a mega_serve_bench run.
+    # ISSUE-19: the resident-decode suite (resident == non-resident
+    # tokens, metric pre-touch, CLI refusal wording, knob guards) rides
+    # right behind the tracer suite whose validate_ring it uses, ahead
+    # of the interpret tail, and must carry tier-1-runnable tests — a
+    # pipeline or fallback regression has to FAIL tier-1, not wait for
+    # a mega_serve_bench run.
     assert "test_resident.py" in order
     assert (order.index("test_kernel_trace.py")
             < order.index("test_resident.py")
@@ -672,41 +652,6 @@ def test_tier1_marker_audit():
     assert len(res_fast) >= 5, (
         f"resident-decode suite has too few tier-1-runnable tests: "
         f"{res_fast}"
-    )
-
-
-def test_long_context_modules_compile():
-    """ISSUE-20: the long-context serving stack must byte-compile —
-    long_context.py/slot_state.py/continuous.py are imported by the
-    engine's admission path (a syntax error takes serving down at
-    import time), the cp/sharded attention substrate rides in ops and
-    layers, and the bench that writes perf/LONG_CONTEXT.json rides
-    along (repo convention: perf harnesses fail tier-1, not a chip
-    run)."""
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.join(os.path.dirname(__file__), "..")
-    pkg = os.path.join(root, "triton_distributed_tpu")
-    targets = [
-        os.path.join(pkg, "models", "long_context.py"),
-        os.path.join(pkg, "models", "continuous.py"),
-        os.path.join(pkg, "models", "slot_state.py"),
-        os.path.join(pkg, "models", "qwen.py"),
-        os.path.join(pkg, "layers", "tp_attn.py"),
-        os.path.join(pkg, "ops", "attention", "ring_attention.py"),
-        os.path.join(pkg, "ops", "attention", "flash_decode.py"),
-        os.path.join(root, "perf", "loadgen.py"),
-        os.path.join(root, "perf", "long_context_bench.py"),
-    ]
-    proc = subprocess.run(
-        [sys.executable, "-m", "compileall", "-q", "-f", *targets],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert proc.returncode == 0, (
-        f"long-context modules failed to compile:\n"
-        f"{proc.stdout}\n{proc.stderr}"
     )
 
 

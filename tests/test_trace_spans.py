@@ -219,6 +219,42 @@ def test_every_program_of_the_served_payload_has_a_name_of_its_own(session):
     assert {"tdt_decode_step", "tdt_prefill_chunk"} <= set(ours)
 
 
+# What the parent commit of PR 32 compiled and traced for this payload
+# (read from a run of this fixture on its checkout). A PR that changes a
+# program or the round's host order on purpose rewrites these lists; one
+# that only deletes code no served configuration reaches leaves them.
+ROUND = ["engine:decode_round", "engine:dispatch", "engine:fetch",
+         "engine:sample_emit"]
+ADMIT = ["engine:admit", "prefix_cache:admit", "prefix_cache:chunk"]
+PARENT = {
+    # name -> programs compiled under it (one a signature).
+    "programs": {"tdt_set_params": 1, "tdt_prefill_chunk": 3,
+                 "tdt_decode_step": 3, "tdt_finite_greedy": 1},
+    # The one traced batch, in start order: both admissions (the second
+    # prompt's three chunks step the first request between them), then
+    # the rounds the longer generation needs, then the audit.
+    "spans": (["scheduler:batch"] + ADMIT + ADMIT + ROUND
+              + ["prefix_cache:chunk"] + ROUND + ["prefix_cache:chunk"]
+              + ROUND * 9 + ["engine:audit"]),
+}
+
+
+@pytest.mark.parametrize("what", sorted(PARENT))
+def test_served_payload_runs_the_parents_programs_in_the_parents_order(
+        session, what):
+    if what == "programs":
+        got = {}
+        for name in session.compiled:
+            if name.startswith("tdt_"):
+                got[name] = got.get(name, 0) + 1
+    else:
+        (batch,) = session.named("scheduler:batch")
+        got = [n for _, _, n in sorted(
+            (s, -d, n) for _, n, s, d in session.form["host"]
+            if n in SPANS and inside((s, d), batch))]
+    assert got == PARENT[what]
+
+
 def calls(tree, attr):
     for node in ast.walk(tree):
         if isinstance(node, ast.Call):

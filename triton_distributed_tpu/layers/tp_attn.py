@@ -345,215 +345,6 @@ def tp_attn_prefill_paged_chunk(
     return out, k_pages, v_pages, k_scale, v_scale
 
 
-def tp_attn_prefill_paged_chunk_cold(
-    params: TPAttnParams,
-    x: jax.Array,           # [C, d] replicated — one chunk of ONE sequence
-    k_pages: jax.Array,     # [L, P, hkv_loc, page, hd] — the WHOLE pool shard
-    v_pages: jax.Array,
-    layer: jax.Array,       # scalar int32 — the layer addressed in the pool
-    table_row: jax.Array,   # [budget_pages] int32 — the slot's RESIDENT row
-    k_cold: jax.Array,      # [hkv_loc, S_bucket, hd] — demoted-page window
-    v_cold: jax.Array,
-    s_cold: jax.Array,      # scalar int32 — valid cold tokens (≤ S_bucket)
-    q_offset: jax.Array,    # scalar int32 — ABSOLUTE chunk start position
-    dims: TPAttnDims,
-    *,
-    axis: str = "tp",
-    mode: Mode = "xla_ar",
-    ctx: DistContext | None = None,
-    k_scale: jax.Array | None = None,   # [L, P, hkv_loc] f32 — int8 scales
-    v_scale: jax.Array | None = None,
-    ks_cold: jax.Array | None = None,   # [hkv_loc, S_bucket/page] f32
-    vs_cold: jax.Array | None = None,
-    q_end: jax.Array | None = None,     # scalar int32 — absolute end of REAL rows
-):
-    """Chunked-prefill step for a SHARDED long-context slot (inside
-    ``shard_map``): the slot's history is split between ``s_cold``
-    tier-demoted tokens (a read-only dense window, pool dtype + per-page
-    scales, absolute positions ``[0, s_cold)``) and the resident paged
-    region addressed by ``table_row`` at LOCAL positions (absolute
-    position − ``s_cold``). The chunk's queries rope/mask at ABSOLUTE
-    positions; attention runs as two partials merged by
-    :func:`~triton_distributed_tpu.ops.attention.flash_decode.lse_combine`
-    — the distributed-flash-decode combine, which is exactly what a real
-    cross-rank sharded slot computes (each rank contributes its
-    (o, lse) partial): cold columns are fully visible to every chunk row
-    (they all precede it), masked only past ``s_cold`` (the bucket tail
-    is garbage), while the resident view keeps causal masking at the
-    local offset.
-
-    ``S_bucket`` is a power-of-two page bucket so compile count stays
-    logarithmic in cold length; ``s_cold`` is traced. With
-    ``s_cold == 0`` the cold partial is fully masked and the combine
-    returns the resident partial bit-exactly (weight 1 vs 0).
-    Returns ``(out [C, d], k_pages, v_pages, k_scale, v_scale)``.
-    """
-    c = x.shape[0]
-    page = k_pages.shape[3]
-    s_bucket = k_cold.shape[1]
-    quant = k_scale is not None
-    qkv = jnp.dot(x, params.wqkv, preferred_element_type=jnp.float32).astype(
-        x.dtype
-    )
-    q, k, v = dims.split_qkv(qkv)  # [C, h, hd]
-    q = _rms_head(q, params.q_norm)
-    k = _rms_head(k, params.k_norm)
-    pos = q_offset + jnp.arange(c, dtype=jnp.int32)  # [C] absolute
-    q = apply_rope(q.swapaxes(0, 1), pos, dims.rope_theta)  # [h, C, hd]
-    k = apply_rope(k.swapaxes(0, 1), pos, dims.rope_theta)
-    v = v.swapaxes(0, 1)
-
-    # Write at LOCAL resident positions, in place. Final-chunk
-    # right-padding may run past the resident capacity; those rows (and
-    # any row that would land before the resident window — impossible by
-    # the engine's demote contract, but cheap to guard) go to the trash
-    # page.
-    lpos = pos - s_cold
-    n_real = None if q_end is None else q_end - q_offset
-    k_pages, k_scale = _write_chunk(
-        k_pages, k_scale, k.swapaxes(0, 1), layer, table_row, lpos[0], n_real
-    )
-    v_pages, v_scale = _write_chunk(
-        v_pages, v_scale, v.swapaxes(0, 1), layer, table_row, lpos[0], n_real
-    )
-
-    from triton_distributed_tpu.ops.attention.flash_decode import (
-        lse_combine,
-        pages_to_dense,
-    )
-
-    # Resident partial: causal at the LOCAL offset (rows live at local
-    # positions lpos), over the resident dense view.
-    k_dense = pages_to_dense(k_pages, table_row[None], layer)  # [1,h,S_res,hd]
-    v_dense = pages_to_dense(v_pages, table_row[None], layer)
-    if quant:
-        ks_dense = k_scale[layer, table_row].T[None]
-        vs_dense = v_scale[layer, table_row].T[None]
-        o_res, lse_res = flash_attention(
-            q[None], k_dense, v_dense, causal=True, kv_offset=lpos[0],
-            block_k=page, k_scale=ks_dense, v_scale=vs_dense,
-            return_lse=True,
-        )
-    else:
-        o_res, lse_res = flash_attention(
-            q[None], k_dense, v_dense, causal=True, kv_offset=lpos[0],
-            block_k=page, return_lse=True,
-        )
-    # Cold partial: every chunk row sees every VALID cold column (all of
-    # them precede the chunk); the bucket tail past s_cold is masked.
-    cold_mask = jnp.where(
-        jnp.arange(s_bucket, dtype=jnp.int32)[None, :] < s_cold, 0.0, -1e30
-    ) * jnp.ones((c, 1), jnp.float32)  # [C, S_bucket]
-    if quant:
-        o_cold, lse_cold = flash_attention(
-            q[None], k_cold[None], v_cold[None], causal=False,
-            block_k=page, k_scale=ks_cold[None], v_scale=vs_cold[None],
-            bias=cold_mask, return_lse=True,
-        )
-    else:
-        o_cold, lse_cold = flash_attention(
-            q[None], k_cold[None], v_cold[None], causal=False,
-            block_k=page, bias=cold_mask, return_lse=True,
-        )
-    o, _ = lse_combine(
-        jnp.stack([o_cold.astype(jnp.float32), o_res.astype(jnp.float32)]),
-        jnp.stack([lse_cold, lse_res]),
-        part_axis=0,
-    )
-    o = o[0]  # [h, C, hd]
-    o_flat = o.swapaxes(0, 1).reshape(c, dims.hq_loc * dims.head_dim)
-    o_flat = o_flat.astype(x.dtype)
-    if mode in ("xla", "xla_ar"):
-        part = jnp.dot(o_flat, params.wo, preferred_element_type=jnp.float32)
-        out = jax.lax.psum(part.astype(x.dtype), axis)
-    else:
-        out = gemm_ar(o_flat, params.wo, axis=axis, ctx=ctx)
-    return out, k_pages, v_pages, k_scale, v_scale
-
-
-def tp_attn_decode_sharded(
-    params: TPAttnParams,
-    x: jax.Array,           # [1, d] replicated — the slot's new token
-    k_pages: jax.Array,     # [L, P, hkv_loc, page, hd] — the WHOLE pool shard
-    v_pages: jax.Array,
-    layer: jax.Array,       # scalar int32 — the layer addressed in the pool
-    table_row: jax.Array,   # [budget_pages] int32 — the slot's RESIDENT row
-    kv_len_loc: jax.Array,  # [1] int32 — tokens in the resident region
-    k_cold: jax.Array,      # [hkv_loc, S_bucket, hd] — demoted-page window
-    v_cold: jax.Array,
-    s_cold: jax.Array,      # [1] int32 — valid cold tokens (≤ S_bucket)
-    dims: TPAttnDims,
-    *,
-    axis: str = "tp",
-    mode: Mode = "xla_ar",
-    ctx: DistContext | None = None,
-    k_scale: jax.Array | None = None,   # [L, P, hkv_loc] f32 — int8 scales
-    v_scale: jax.Array | None = None,
-    ks_cold: jax.Array | None = None,   # [hkv_loc, S_bucket/page] f32
-    vs_cold: jax.Array | None = None,
-):
-    """Decode step for ONE sharded long-context slot (inside
-    ``shard_map``): the new token appends at its LOCAL resident position
-    (absolute position = ``s_cold + kv_len_loc``, which is where rope
-    evaluates), then attention runs as two partials —
-    :func:`paged_flash_decode` over the resident pages and
-    :func:`flash_decode` over the cold dense window — merged by
-    ``lse_combine``, the exact two-partition shape of
-    ``distributed_flash_decode``'s gather-merge with the cold window
-    standing in for the remote rank's shard. Returns
-    ``(out [1, d], k_pages, v_pages, k_scale, v_scale)``.
-    """
-    from triton_distributed_tpu.ops.attention import paged_flash_decode
-    from triton_distributed_tpu.ops.attention.flash_decode import (
-        flash_decode as dense_flash_decode,
-        lse_combine,
-    )
-
-    page = k_pages.shape[3]
-    qkv = jnp.dot(x, params.wqkv, preferred_element_type=jnp.float32).astype(
-        x.dtype
-    )
-    q, k, v = dims.split_qkv(qkv)  # [1, h, hd]
-    q = _rms_head(q, params.q_norm)
-    k = _rms_head(k, params.k_norm)
-    pos_abs = s_cold + kv_len_loc  # [1] absolute position of the new token
-    q = apply_rope(q, pos_abs[:, None], dims.rope_theta)
-    k = apply_rope(k, pos_abs[:, None], dims.rope_theta)
-
-    pids = jnp.take(table_row, kv_len_loc // page)
-    k_pages, k_scale = _append_rows(
-        k_pages, k_scale, k, layer, pids, kv_len_loc % page
-    )
-    v_pages, v_scale = _append_rows(
-        v_pages, v_scale, v, layer, pids, kv_len_loc % page
-    )
-
-    o_res, lse_res = paged_flash_decode(
-        q, k_pages, v_pages, table_row[None], kv_len_loc + 1, layer=layer,
-        return_lse=True, k_scale=k_scale, v_scale=v_scale,
-    )
-    o_cold, lse_cold = dense_flash_decode(
-        q, k_cold[None], v_cold[None], s_cold, chunk_k=page,
-        return_lse=True,
-        k_scale=None if ks_cold is None else ks_cold[None],
-        v_scale=None if vs_cold is None else vs_cold[None],
-    )
-    o, _ = lse_combine(
-        jnp.stack([o_cold.astype(jnp.float32), o_res.astype(jnp.float32)]),
-        jnp.stack([lse_cold, lse_res]),
-        part_axis=0,
-    )
-    o_flat = o.reshape(1, dims.hq_loc * dims.head_dim).astype(x.dtype)
-    if mode in ("xla", "xla_ar"):
-        part = jnp.dot(o_flat, params.wo, preferred_element_type=jnp.float32)
-        out = jax.lax.psum(part.astype(x.dtype), axis)
-    elif mode in ("pallas", "pallas_ar"):
-        out = gemm_ar(o_flat, params.wo, axis=axis, ctx=ctx)
-    else:
-        raise ValueError(f"unknown mode {mode!r}")
-    return out, k_pages, v_pages, k_scale, v_scale
-
-
 def tp_attn_decode(
     params: TPAttnParams,
     x: jax.Array,        # [B, d] replicated — one new token per sequence

@@ -167,13 +167,6 @@ class MegaDims:
     # page, and finished slots retire at the next host drain without a
     # KV-rollback round trip.
     eos: bool = False
-    # Host work ring (resident decode): a ``ring_state [4]`` i32
-    # scalar-prefetch operand ``[doorbell, head, tail, occupancy]``
-    # published by ``megakernel.ring.WorkRing`` and a RING_POLL task
-    # prepended to the graph that stamps the observed doorbell into its
-    # trace record — the proof hook that every round consumed the ring
-    # state the host rang for it (see ring.py for the hardware story).
-    ring: bool = False
     # Race-provocation fixture (parity: the reference's for_correctness
     # sleeps / straggler_option): lag this rank's LM-head argmax
     # exchange so a peer missing a wait reads stale candidates.
@@ -415,9 +408,6 @@ class KernelCtx:
         # stop_step output the LM head stamps.
         self.stop_tok: Any = None
         self.stop_out: Any = None
-        # Work-ring snapshot [4] i32 (None unless dims.ring):
-        # [doorbell, head, tail, occupancy] as published by the host.
-        self.ring_state: Any = None
         # cross_prefetch SMEM flags: slot 0 of col/rowstage already
         # holds the current task's tile 0 (started by the previous
         # task's prefetch block; the stream skips its own start).
@@ -480,9 +470,8 @@ def make_mega_kernel(
         *rest,
     ):
         # Paged mode inserts the page table as a 4th scalar-prefetch
-        # operand; eos adds the stop-token row and ring the work-ring
-        # snapshot after it (both scalar-prefetch — SMEM-resident for
-        # the LM head's / RING_POLL's scalar reads); prefill mode
+        # operand; eos adds the stop-token row after it (scalar-prefetch
+        # — SMEM-resident for the LM head's scalar reads); prefill mode
         # inserts the embedded prompt rows x0 before the weights. The
         # operand order is otherwise identical.
         if dims.page:
@@ -493,10 +482,6 @@ def make_mega_kernel(
             stop_tok, *rest = rest
         else:
             stop_tok = None
-        if dims.ring:
-            ring_state, *rest = rest
-        else:
-            ring_state = None
         (
             embed, wqkv, wo, w1, w2, lm_head,              # ANY (HBM)
             ln1, ln2, normf, qn, kn,                       # VMEM (small)
@@ -577,7 +562,6 @@ def make_mega_kernel(
         kctx.noise = noise
         kctx.sampcfg = sampcfg
         kctx.stop_tok, kctx.stop_out = stop_tok, stop_out
-        kctx.ring_state = ring_state
         kctx.toks_out = toks_out
         kctx.embed, kctx.wqkv, kctx.wo = embed, wqkv, wo
         kctx.w1, kctx.w2, kctx.lm_head = w1, w2, lm_head
@@ -703,10 +687,9 @@ def build_mega_call(
     hkv, hd = dims.hkv_loc, dims.head_dim
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # task_tab, kv_len, tokens [+ page_table] [+ stop_tok]
-        # [+ ring_state] — all SMEM-resident scalar prefetch.
-        num_scalar_prefetch=(3 + int(bool(dims.page)) + int(dims.eos)
-                             + int(dims.ring)),
+        # task_tab, kv_len, tokens [+ page_table] [+ stop_tok] — all
+        # SMEM-resident scalar prefetch.
+        num_scalar_prefetch=3 + int(bool(dims.page)) + int(dims.eos),
         # Outer grid dim = decode steps within the launch (1 unless
         # multi-step): one task table serves every step, the kernel
         # reads the step index from program_id(0).
@@ -981,14 +964,14 @@ def build_mega_call(
     # scales]) followed by the cache operands (kc, vc[, ksc, vsc]) —
     # variadic so the wq8/kv_quant paths' extra scale operands flow
     # through without per-mode signature edits. The caller-facing order
-    # is ``(kv_len, tokens, [page_table], [stop_tok], [ring_state],
-    # [x0], [noise], [sampcfg], *wargs)``; the mode operands are
+    # is ``(kv_len, tokens, [page_table], [stop_tok], [x0], [noise],
+    # [sampcfg], *wargs)``; the mode operands are
     # re-sited into the kernel's canonical operand order here (the
     # scalar-prefetch block up front, x0/noise/sampcfg just before the
     # cache block) — ONE wrapper instead of a per-mode branch ladder,
     # so new mode compositions cannot silently miss a re-site.
     nc = 4 if dims.kv_quant else 2  # trailing cache-block operand count
-    n_pre = int(bool(dims.page)) + int(dims.eos) + int(dims.ring)
+    n_pre = int(bool(dims.page)) + int(dims.eos)
     n_mid = int(dims.prefill) + int(dims.sampled) + int(dims.filtered)
 
     def run(kv_len, tokens, *args):

@@ -56,17 +56,6 @@ def trace_mid(kctx):
         kctx.trace_out[kctx.step, kctx.t, TR_MID] = trace_tick(kctx)
 
 
-def trace_stamp(kctx, value):
-    """Stamp an arbitrary VALUE (not a clock read) into the current
-    task's ``mid`` column — the RING_POLL task records the doorbell it
-    observed so ``validate_ring`` can prove the round consumed the
-    ring state the host published (mid-as-payload records are exempt
-    from the decoder's begin<=mid<=end clock check by opcode). No-op
-    when untraced, same as :func:`trace_mid`."""
-    if getattr(kctx.dims, "trace", False) and kctx.trace_out is not None:
-        kctx.trace_out[kctx.step, kctx.t, TR_MID] = value
-
-
 def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     """f32 RMS-norm (matches ``models.qwen.rms_norm``)."""
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
@@ -1559,20 +1548,3 @@ def barrier_body(kctx):
     return body
 
 
-@register_task(TaskType.RING_POLL)
-def ring_poll_body(kctx):
-    """Observe the host work ring (dims.ring): stamp the published
-    doorbell from the scalar-prefetch ``[doorbell, head, tail,
-    occupancy]`` snapshot into this task's trace mid column, proving
-    the round ran against the ring state the host rang for it
-    (validate_ring's doorbell check). Under interpret/CPU this is the
-    whole task — the ring is consumed host-side at round boundaries;
-    on hardware this is where the persistent loop spins on the
-    doorbell semaphore and splices admitted slots into the task
-    table (megakernel/ring.py module docs)."""
-
-    def body():
-        if kctx.ring_state is not None and kctx.dims.trace:
-            trace_stamp(kctx, kctx.ring_state[0])
-
-    return body

@@ -162,9 +162,6 @@ class ModelBuilder:
     def make_barrier(self, **kw) -> int:
         return self._add(TaskType.BARRIER, **kw)
 
-    def make_ring_poll(self, **kw) -> int:
-        return self._add(TaskType.RING_POLL, **kw)
-
     def build_decoder_graph(self) -> None:
         """The standard decode-step chain (parity:
         ``models/qwen3.py:108`` build_fwd). With ``dims.moe`` the MLP
@@ -174,13 +171,6 @@ class ModelBuilder:
         bytes fly under the second half of the expert GEMMs and the
         final wait blocks only after the next weight stream's tile-0
         DMA is in flight (docs/megakernel.md "MoE serving")."""
-        if self.dims.ring:
-            # Ring-enabled rounds observe the host work ring FIRST: the
-            # doorbell snapshot this task stamps is the proof that the
-            # round ran against the ring state the host published for
-            # it; on hardware this is where the resident loop spins and
-            # splices admitted slots into the table (ring.py docs).
-            self.make_ring_poll()
         if self.dims.n_ranks > 1:
             # Entry barrier: the first ALLREDUCE issues remote puts into
             # peers' VMEM scratch; without this, launch skew could land a

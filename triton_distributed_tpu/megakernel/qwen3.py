@@ -618,7 +618,7 @@ class MegaQwen3:
         page: int = 0, straggler_rank: int | None = None,
         kv_quant: bool = False, num_pages: int = 0,
         valid_arg: bool = False, trace: bool = False,
-        filtered: bool = False, eos: bool = False, ring: bool = False,
+        filtered: bool = False, eos: bool = False,
     ):
         """``nsteps`` greedy decode steps in ONE kernel launch.
 
@@ -663,13 +663,10 @@ class MegaQwen3:
         the shard fn clamps that slot's appended rows to ``stop_step +
         1`` and a carried ``halt`` flag zeroes halted slots' appends in
         later launches (resident pipelining — docs/megakernel.md
-        "Resident decode"); ``ring=True`` adds the work-ring snapshot
-        ``[doorbell, head, tail, occupancy]`` i32 argument observed by
-        the graph's leading RING_POLL task (megakernel/ring.py).
+        "Resident decode").
         """
-        if (eos or ring) and not page:
-            raise ValueError("eos/ring modes ride the paged serving "
-                             "path only")
+        if eos and not page:
+            raise ValueError("eos mode rides the paged serving path only")
         if eos and not valid_arg:
             raise ValueError("eos needs valid_arg: device retire clamps "
                              "the per-slot kept-row counts")
@@ -679,7 +676,6 @@ class MegaQwen3:
         dims = dataclasses.replace(
             base, nsteps=nsteps, v_real=V, sampled=sampled,
             straggler_rank=straggler_rank, filtered=filtered, eos=eos,
-            ring=ring,
         )
         mb = ModelBuilder(
             dims, cfg=self.cfg, axis=m.axis, ctx=m.ctx,
@@ -699,13 +695,12 @@ class MegaQwen3:
             def shard_fn(params: Qwen3Params, tokens,
                          cache: PagedKVCache, *extra):
                 # Serving extras, in argument order (all optional):
-                # n_valid, stop_tok, halt, ring_state, noise, sampcfg.
+                # n_valid, stop_tok, halt, noise, sampcfg.
                 ex = list(extra)
                 n_valid = ex.pop(0) if valid_arg else None
                 stop_tok = ex.pop(0) if eos else None
                 halt = ex.pop(0) if eos else None
-                ring_state = ex.pop(0) if ring else None
-                pre = [a for a in (stop_tok, ring_state) if a is not None]
+                pre = [stop_tok] if eos else []
                 outs = per_shard(
                     cache.kv_len, tokens, cache.page_table, *pre, *ex,
                     *kernel_args(params), cache.k_pages, cache.v_pages,
@@ -785,7 +780,6 @@ class MegaQwen3:
             raise ValueError("valid_arg rides the paged append only")
         extra_specs = (P(),) if valid_arg else ()
         extra_specs += (P(), P()) if eos else ()      # stop_tok, halt
-        extra_specs += (P(),) if ring else ()         # ring snapshot
         extra_specs += (P(None, None, ax),) if sampled else ()
         extra_specs += (P(),) if filtered else ()     # sampcfg [B, 4]
         out_specs = (P(), P(None, ax), specs)
@@ -815,7 +809,7 @@ class MegaQwen3:
         self, batch: int, s_max: int, nsteps: int, sampled: bool = False,
         page: int = 0, kv_quant: bool = False, num_pages: int = 0,
         valid_arg: bool = False, trace: bool = False,
-        filtered: bool = False, eos: bool = False, ring: bool = False,
+        filtered: bool = False, eos: bool = False,
     ):
         """Jitted multi-step fn ``f(params, tokens, cache[, n_valid]
         [, noise]) → (tokens [nsteps, B], last_logits [B, V], cache
@@ -829,17 +823,17 @@ class MegaQwen3:
         rows route to the trash page — see ``append_n``). ``trace``
         appends the device task ring ``[tp, NS, T, 8]`` to the returns
         (docs/observability.md "Device task tracer"). ``filtered``/
-        ``eos``/``ring`` are the resident-serving modes — see
+        ``eos`` are the resident-serving modes — see
         :meth:`build_multi`. Cached per the full option tuple."""
         key = self._multi_key(batch, s_max, nsteps, sampled, page,
                               kv_quant, num_pages, valid_arg, trace,
-                              filtered, eos, ring)
+                              filtered, eos)
         if key not in self._jit:
             self._jit[key] = self.build_multi(
                 batch, s_max, nsteps, sampled, page,
                 kv_quant=kv_quant, num_pages=num_pages,
                 valid_arg=valid_arg, trace=trace,
-                filtered=filtered, eos=eos, ring=ring,
+                filtered=filtered, eos=eos,
             )
             # Scheduled order for this build, for trace consumers
             # (obs/kernel_trace.validate_ring's dependency check).
@@ -849,12 +843,12 @@ class MegaQwen3:
     @staticmethod
     def _multi_key(batch, s_max, nsteps, sampled=False, page=0,
                    kv_quant=False, num_pages=0, valid_arg=False,
-                   trace=False, filtered=False, eos=False, ring=False):
+                   trace=False, filtered=False, eos=False):
         """The ONE multi-build cache key — shared by
         :meth:`decode_multi_fn` and :meth:`multi_task_order` so the
         two can never disagree on what identifies a build."""
         return ("multi", batch, s_max, nsteps, sampled, page, kv_quant,
-                num_pages, valid_arg, trace, filtered, eos, ring)
+                num_pages, valid_arg, trace, filtered, eos)
 
     def multi_task_order(self, *args, **kw):
         """The scheduled task order of a multi-step build — same

@@ -100,17 +100,6 @@ class TaskType(enum.IntEnum):
     MOE_FFN = 15     # one local expert's SwiGLU FFN; arg0: local expert id
     A2A_SEND = 16    # start combine puts of a phase partial; arg0: phase
     A2A_WAIT = 17    # prefetch next tile-0, wait partials, x += sum
-    # Resident decode (docs/megakernel.md "Resident decode"): the first
-    # task of every ring-enabled round observes the host work ring's
-    # doorbell (a scalar-prefetch [4] i32 ``[doorbell, head, tail,
-    # occupancy]`` snapshot) and stamps it into its trace record's mid
-    # column, so the decoder can prove every round consumed the ring
-    # state the host published for it (validate_ring's doorbell check).
-    # Under interpret/CPU the ring is consumed at round boundaries —
-    # the operand is re-prefetched per launch; on hardware the same
-    # task is where the persistent loop would spin on the doorbell
-    # semaphore and splice admitted slots into the task table.
-    RING_POLL = 18   # observe host work-ring doorbell; stamp into trace
 
 
 # Resource class used by the zig-zag scheduler: tasks whose cost is
@@ -119,7 +108,7 @@ class TaskType(enum.IntEnum):
 COMM_TASKS = frozenset({
     TaskType.ALLREDUCE, TaskType.BARRIER, TaskType.EMBED,
     TaskType.AR_SEND, TaskType.AR_WAIT,
-    TaskType.A2A_SEND, TaskType.A2A_WAIT, TaskType.RING_POLL,
+    TaskType.A2A_SEND, TaskType.A2A_WAIT,
 })
 
 

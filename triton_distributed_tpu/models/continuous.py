@@ -295,34 +295,6 @@ class Request:
         )
 
 
-# Tier-key namespace for sharded long-context slots: every demoted page
-# of one sharded admission keys as "<uid>:<page-index>", so a slot
-# re-admitted into the same engine (or a second sharded slot) can never
-# collide with a predecessor's leftovers.
-_LONG_UIDS = itertools.count(1)
-
-
-@dataclasses.dataclass
-class _LongSlot:
-    """Sharded-slot bookkeeping (docs/serving.md "Long-context
-    serving"): a slot whose KV exceeds ``rank_page_budget`` keeps a
-    RESIDENT paged window (``req.pages``, local positions) plus
-    ``cold`` pages demoted to the KV tier. Host ``_kv_len[slot]`` stays
-    the ABSOLUTE sequence length; the resident region holds
-    ``_kv_len[slot] - cold * page_size`` tokens. The DEVICE kv_len /
-    table row for the slot are forced to zero (``_sync_tables``) —
-    batched decode must treat a sharded slot as empty (its append lands
-    on the trash page; its batched logits are overwritten by the
-    per-slot sharded program's)."""
-
-    uid: int
-    cold: int = 0           # pages demoted (tokens [0, cold*page) cold)
-    # Cached cold window: (k, v, ks, vs, bucket_pages) device arrays
-    # [L, Hkv, bucket_pages*page, hd] (scales [L, Hkv, bucket_pages]);
-    # invalidated (None) whenever another page demotes.
-    view: tuple | None = None
-
-
 @dataclasses.dataclass
 class _MegaPlan:
     """One composed megakernel launch: the row mapping (launch row →
@@ -359,7 +331,6 @@ class _MegaLaunch:
     halt: object | None  # [B] halt bits chained into the next launch
     ring: object | None  # device trace ring (kernel_trace only)
     t0: float
-    doorbell: int | None
     trace_ids: dict
 
 
@@ -377,7 +348,6 @@ class _StepLaunch:
     logits: object      # [max_batch, V] device
     finite: object      # [max_batch] device all-finite mask
     toks: object        # [max_batch] device greedy tokens
-    lc_changed: bool    # a sharded long-context slot changed at dispatch
     host: tuple | None = None
 
     def fetch(self) -> tuple:
@@ -439,8 +409,6 @@ class ContinuousEngine(MegaDispatch):
         ns: int = 8,
         mega_buckets: bool = True,
         resident: bool = False,
-        cp: int = 1,
-        rank_page_budget: int = 0,
     ):
         self.model = model
         self.mode = mode
@@ -449,10 +417,9 @@ class ContinuousEngine(MegaDispatch):
         # NS launch width becomes a knob (perf/mega_serve_bench.py
         # sweeps it), batch buckets give a 2-slot round a 2-wide launch
         # program instead of the max_batch-wide one, and
-        # ``resident=True`` pipelines launches through a host work
-        # ring — the host pushes admit/retire/cancel items + one
-        # doorbell per round, issues launch i+1 off launch i's device
-        # outputs, and syncs only to drain emitted tokens.
+        # ``resident=True`` pipelines launches: the host issues launch
+        # i+1 off launch i's device outputs and syncs only to drain
+        # emitted tokens.
         if int(ns) < 1:
             raise ValueError(f"ns must be >= 1, got {ns}")
         self.NS = int(ns)
@@ -461,21 +428,9 @@ class ContinuousEngine(MegaDispatch):
         if resident and mode != "mega":
             raise ValueError(
                 "resident=True requires mode='mega' (the resident loop "
-                "pipelines megakernel NS-step launches through the host "
-                "work ring; the xla/pallas decode paths have no device "
-                "loop to keep resident)"
+                "pipelines megakernel NS-step launches; the xla/pallas "
+                "decode paths have no device loop to keep resident)"
             )
-        if resident:
-            from triton_distributed_tpu.megakernel.ring import WorkRing
-
-            self._ring = WorkRing()
-            self._ring_gauge = obs_metrics.gauge(
-                "tdt_mega_ring_occupancy",
-                "Host work-ring occupancy at the last doorbell publish.",
-            )
-        else:
-            self._ring = None
-            self._ring_gauge = None
         # The depth-1 pipeline's one slot: an in-flight resident launch
         # (``_MegaLaunch``) or a looked-ahead single step
         # (``_StepLaunch``). Everything that mutates slot, table or pool
@@ -507,7 +462,7 @@ class ContinuousEngine(MegaDispatch):
                 "speculative=K does not compose with mode='mega': the "
                 "NS-step fused launch advances all slots in lockstep "
                 "and already amortizes per-step dispatch — the resident "
-                "work ring splices whole slots between rounds, never "
+                "pipeline splices whole slots between rounds, never "
                 "a mid-launch verify/rollback; run speculative with "
                 "mode='xla'/'pallas', or drop speculative to serve "
                 "through the megakernel (docs/megakernel.md)"
@@ -553,71 +508,6 @@ class ContinuousEngine(MegaDispatch):
             )
         self.pps = self.max_length // page_size
         self.max_queue = max_queue
-        # Long-context serving (docs/serving.md "Long-context
-        # serving"): ``cp`` shards one request's prefill over cp
-        # virtual ranks with the block-KV exchange fired split-phase
-        # under the next block's attention; ``rank_page_budget``
-        # (TOKENS per rank) turns over-budget slots into SHARDED slots
-        # — a resident paged window plus tier-demoted cold pages,
-        # decoded through the lse_combine partial merge.
-        self.cp = int(cp)
-        if self.cp < 1:
-            raise ValueError(f"cp must be >= 1, got {cp}")
-        if self.cp > 1 and (mode == "mega" or resident or speculative):
-            raise ValueError(
-                "cp > 1 composes with the chunked xla/pallas prefill "
-                "path only: mode='mega', resident=True and "
-                "speculative=K all drive the slot through programs "
-                "that bypass the per-chunk exchange schedule"
-            )
-        if self.cp > 1 and not prefix_cache:
-            raise ValueError(
-                "cp > 1 requires prefix_cache=True: context-parallel "
-                "prefill rides the chunked suffix-prefill path, and "
-                "without the radix tree admission prefills dense in "
-                "one batched program (no per-chunk exchange to "
-                "overlap)"
-            )
-        self.cp_tracer = None  # CPTracer of the LAST cp>1 prefill
-        self.rank_page_budget = int(rank_page_budget)
-        if self.rank_page_budget:
-            if self.rank_page_budget % page_size:
-                raise ValueError(
-                    f"rank_page_budget {self.rank_page_budget} is not "
-                    f"a multiple of page_size {page_size}: the budget "
-                    f"is demoted page-by-page, so a ragged budget "
-                    f"would strand a partial page — pick an aligned "
-                    f"pair"
-                )
-            if self.rank_page_budget < 2 * page_size:
-                raise ValueError(
-                    f"rank_page_budget {self.rank_page_budget} must "
-                    f"cover >= 2 pages of page_size {page_size} (one "
-                    f"write page + one full page to demote)"
-                )
-            if tier is None and not (tier_bytes or tier_dir):
-                raise ValueError(
-                    "rank_page_budget requires a KV tier (tier=, "
-                    "tier_bytes= or tier_dir=): demoted cold pages "
-                    "must land somewhere a fault can bring them back "
-                    "from"
-                )
-            if mode == "mega" or resident or speculative:
-                raise ValueError(
-                    "rank_page_budget composes with the xla/pallas "
-                    "decode paths only: sharded slots decode through "
-                    "a per-slot partial-merge program the mega/"
-                    "resident/speculative launchers do not run"
-                )
-            if round_chunk(page_size) != page_size:
-                raise ValueError(
-                    f"rank_page_budget needs a chunk-alignable "
-                    f"page_size (multiple of 16, or of 128 past 128); "
-                    f"got {page_size}"
-                )
-        self.budget_pages = self.rank_page_budget // page_size
-        # slot -> _LongSlot for slots decoding in sharded mode.
-        self._longctx: dict[int, "_LongSlot"] = {}
         # Handoff-burst batching (docs/scale-out.md "Disaggregated
         # pools & autoscaling"): an armed drain sweep exports every
         # active slot through ONE concatenated page gather
@@ -695,7 +585,7 @@ class ContinuousEngine(MegaDispatch):
             1, self.max_length
         )
         # Lazy megakernel multi-step programs, keyed by (sampled,
-        # filtered, eos, ring, bucket_B) — greedy rounds must not
+        # filtered, eos, bucket_B) — greedy rounds must not
         # consume PRNG keys, or temperature=0 runs would lose their
         # seeded determinism, and each batch bucket compiles its own
         # (narrower) program.
@@ -846,12 +736,9 @@ class ContinuousEngine(MegaDispatch):
             # Device task tracer: launches whose ring was decoded.
             "mega_trace_launches": 0,
             # Resident-decode ledger (docs/megakernel.md "Resident
-            # decode"): work-ring items/doorbells, in-kernel filtered
-            # rounds, device stop-token retires, pipelined resident
-            # rounds, and bucket-program launches.
-            "mega_ring_items": 0,
-            "mega_ring_doorbells": 0,
-            "mega_ring_host_drains": 0,
+            # decode"): in-kernel filtered rounds, device stop-token
+            # retires, pipelined resident rounds, and bucket-program
+            # launches.
             "mega_device_retires": 0,
             "mega_resident_rounds": 0,
             "mega_bucket_launches": 0,
@@ -881,20 +768,6 @@ class ContinuousEngine(MegaDispatch):
             # KV fabric (docs/scale-out.md "KV fabric"): the subset of
             # tier_faults whose entry came from a PEER replica's tier.
             "tier_remote_pages": 0,
-            # Long-context ledger (docs/serving.md "Long-context
-            # serving"): cp>1 prefills and their split-phase exchange
-            # accounting, plus sharded-slot admissions, cold-page
-            # demotes/faults and per-slot decode programs.
-            "cp_prefills": 0,
-            "cp_blocks": 0,
-            "cp_exchange_bytes": 0,
-            "cp_exchange_us": 0,
-            "cp_hidden_us": 0,
-            "longctx_sharded_slots": 0,
-            "longctx_demoted_pages": 0,
-            "longctx_tier_faults": 0,
-            "longctx_tier_bytes": 0,
-            "longctx_decode_steps": 0,
         }
 
     @property
@@ -963,20 +836,6 @@ class ContinuousEngine(MegaDispatch):
 
     # -- slot management -------------------------------------------------
 
-    def _ring_push(self, kind: str, slot: int, arg: int = 0) -> None:
-        """Queue one work item for the resident device loop — no-op
-        without a ring. ``kind``: "admit" | "retire" | "cancel". The
-        next launch's doorbell publish covers it (megakernel/ring.py)."""
-        if self._ring is None:
-            return
-        from triton_distributed_tpu.megakernel import ring as _ring_mod
-
-        kinds = {"admit": _ring_mod.RING_ADMIT,
-                 "retire": _ring_mod.RING_RETIRE,
-                 "cancel": _ring_mod.RING_CANCEL}
-        self._ring.push(kinds[kind], slot, arg)
-        self._bump("mega_ring_items")
-
     def _sync_tables(self) -> None:
         self._free_pages_gauge.set(len(self.pool.free))
         # COPIES, not views: ``jnp.asarray`` on the CPU backend may
@@ -988,21 +847,10 @@ class ContinuousEngine(MegaDispatch):
         # probability scaled with host work between dispatch and the
         # first output fetch). Explicit copies give the device arrays
         # their own storage.
-        table = self._table.copy()
-        kv_len = self._kv_len.copy()
-        for slot in self._longctx:
-            # Sharded slots are INVISIBLE to the batched decode step:
-            # host truth keeps the resident row + absolute length (the
-            # audit and the per-slot sharded program read those), but
-            # the device copies go to zero so the batched append lands
-            # on the trash page and the batched attention sees an empty
-            # sequence (its logits are spliced over anyway).
-            table[slot] = 0
-            kv_len[slot] = 0
         self.cache = dataclasses.replace(
             self.cache,
-            page_table=jnp.asarray(table),
-            kv_len=jnp.asarray(kv_len),
+            page_table=jnp.asarray(self._table.copy()),
+            kv_len=jnp.asarray(self._kv_len.copy()),
         )
 
     def _admit(
@@ -1017,14 +865,6 @@ class ContinuousEngine(MegaDispatch):
             req.timeline.stamp_admit()
         if req.snapshot is not None:
             return self._admit_import(req, slot)
-        if self._sharded_eligible(req):
-            if m is not None:
-                # Sharded slots never map tree pages (their pages cycle
-                # through the tier); a match computed before routing
-                # here (e.g. the import-fallback replay) releases its
-                # pins instead of leaking them.
-                self.prefix.release_match(m)
-            return self._admit_sharded(req, slot)
         if self.prefix is not None:
             return self._admit_prefix(req, slot, m)
         s = len(req.prompt)
@@ -1206,15 +1046,10 @@ class ContinuousEngine(MegaDispatch):
                 self._sync_tables()
             return self.cache
 
-        if self.cp > 1:
-            logits, chunks = self._prefill_suffix_cp(
-                slot, prompt, start, between_chunks
-            )
-        else:
-            logits, self.cache, chunks = prefill_suffix_chunks(
-                self.model, self.cache, slot, prompt, start,
-                self.prefill_chunk, self._prefill_mode, between_chunks,
-            )
+        logits, self.cache, chunks = prefill_suffix_chunks(
+            self.model, self.cache, slot, prompt, start,
+            self.prefill_chunk, self._prefill_mode, between_chunks,
+        )
         self._kv_len[slot] = len(prompt)
         self._bump("prefill_tokens", len(prompt) - start)
         self._bump("prefill_chunks", chunks)
@@ -1223,402 +1058,10 @@ class ContinuousEngine(MegaDispatch):
                        (len(prompt) - start) * self._moe_k)
         return logits
 
-    def _prefill_suffix_cp(self, slot: int, prompt: np.ndarray,
-                           start: int, between_chunks):
-        """Context-parallel chunked prefill (docs/serving.md
-        "Long-context serving"): the suffix runs through the SAME
-        ``prefill_suffix_chunks`` call sequence as cp=1 — cp>1 logits
-        are bit-exact by construction — with block ``i`` owned by
-        virtual rank ``i % cp`` and block i's freshly written KV pages
-        staged toward rank ``(i+1) % cp`` on a background thread WHILE
-        the main thread blocks on block i+1's attention compute (the
-        split-phase AR_SEND/AR_WAIT discipline at serving granularity;
-        ``models/long_context.py``). The tracer lands in
-        ``self.cp_tracer`` for ``cp_overlap_report``/
-        ``validate_cp_ring``. Returns ``(logits, chunks)``."""
-        from triton_distributed_tpu.models import long_context as lc
-
-        suffix = len(prompt) - start
-        # No explicit chunk width → one block per rank (round_chunk
-        # keeps the width a legal chunk shape; the last block absorbs
-        # the rounding remainder).
-        width = self.prefill_chunk or round_chunk(-(-suffix // self.cp))
-        tracer = lc.CPTracer()
-        exch = lc.SplitPhaseExchange(tracer, self.cp)
-        page = self.page_size
-        state = {"blk": 0, "off": start, "t0": time.perf_counter_ns()}
-
-        def stamp_attn(blk: int, t1: int) -> None:
-            r = lc.cp_block_rank(blk, self.cp)
-            tracer.record(lc.CP_ATTN, blk, r, r, state["t0"], t1)
-
-        def cp_between(cache, new_len):
-            # Block on the chunk program just dispatched — that is
-            # block ``blk``'s attention window; the PREVIOUS block's
-            # staging thread has been running underneath it.
-            jax.block_until_ready(cache.k_pages)
-            t1 = time.perf_counter_ns()
-            blk = state["blk"]
-            stamp_attn(blk, t1)
-            # Receive barrier for the oldest in-flight exchange (the
-            # pipeline is one block deep: join i-1 before staging i).
-            exch.join_oldest()
-            # Stage block ``blk``'s pages toward its successor rank.
-            # The jnp.take gathers are enqueued HERE — before the
-            # decode interleave and the next chunk program donate the
-            # cache — and materialize on the staging thread.
-            first = state["off"] // page
-            last = -(-new_len // page) - 1
-            ids = jnp.asarray(
-                self._table[slot, first:last + 1], jnp.int32
-            )
-            arrays = [
-                jnp.take(cache.k_pages, ids, axis=1),
-                jnp.take(cache.v_pages, ids, axis=1),
-            ]
-            if cache.quantized:
-                arrays += [
-                    jnp.take(cache.k_scale, ids, axis=1),
-                    jnp.take(cache.v_scale, ids, axis=1),
-                ]
-            exch.dispatch(blk, arrays)
-            out = between_chunks(cache, new_len)
-            state["blk"] = blk + 1
-            state["off"] = new_len
-            state["t0"] = time.perf_counter_ns()
-            return out
-
-        logits, self.cache, chunks = prefill_suffix_chunks(
-            self.model, self.cache, slot, prompt, start,
-            width, self._prefill_mode, cp_between,
-        )
-        jax.block_until_ready(logits)
-        stamp_attn(state["blk"], time.perf_counter_ns())
-        # The final block is the ring's tail — nothing consumes its KV
-        # during prefill, so it is not exchanged (validate_cp_ring
-        # expects exchanges for blocks 0..n-2 only).
-        exch.join_all()
-        self.cp_tracer = tracer
-        rep = lc.cp_overlap_report(tracer)
-        self._bump("cp_prefills")
-        self._bump("cp_blocks", chunks)
-        self._bump("cp_exchange_bytes", rep["exchange_bytes"])
-        self._bump("cp_exchange_us", rep["send_ns"] // 1000)
-        self._bump("cp_hidden_us", rep["hidden_ns"] // 1000)
-        return logits, chunks
-
-    # -- sharded long-context slots ---------------------------------------
-    #
-    # docs/serving.md "Long-context serving": with ``rank_page_budget``
-    # set, a request whose KV needs more pages than the budget admits in
-    # SHARDED mode — a resident paged window of at most ``budget_pages``
-    # pages (local positions, the slot's own explicit table row) plus
-    # cold pages demoted to the KV tier, faulted back on demand as a
-    # read-only dense window. Prefill and decode both run per-slot
-    # programs that merge the (cold, resident) attention partials with
-    # ``lse_combine`` — the distributed-flash-decode combine — so the
-    # logits are what one giant resident slot would compute.
-
-    def _sharded_eligible(self, req: Request) -> bool:
-        """Whether ``req`` must admit in sharded long-context mode:
-        budgeted engine, not a snapshot resume, and a KV footprint the
-        budget cannot hold resident."""
-        return (
-            self.budget_pages > 0
-            and req.snapshot is None
-            and self._needed_pages(len(req.prompt), req.gen_len)
-            > self.budget_pages
-        )
-
-    def _alloc_pages(self, n: int) -> list:
-        """Allocate ``n`` pool pages for a sharded slot — through the
-        radix tree's reclaim path when a prefix cache is on (cold tree
-        pages yield, exactly as admission allocation does), straight
-        from the pool otherwise."""
-        if self.prefix is not None:
-            pages = self.prefix.allocate(n)
-            if pages is None:
-                raise RuntimeError(
-                    f"page pool exhausted ({n} pages for a sharded slot)"
-                )
-            return pages
-        return self.pool.allocate(n)
-
-    def _admit_sharded(self, req: Request, slot: int):
-        """Admit an over-budget request as a SHARDED slot: chunk-prefill
-        one page at a time through ``prefill_paged_chunk_cold``,
-        demoting the oldest resident page to the KV tier whenever the
-        resident window hits the budget. Host ``_kv_len``/``_table``
-        keep absolute-length/resident-row truth; the device copies stay
-        zero (``_sync_tables``) so the batched decode never touches the
-        slot. Returns the first sampled token."""
-        s = len(req.prompt)
-        page = self.page_size
-        ls = _LongSlot(uid=next(_LONG_UIDS))
-        req.slot = slot  # before any allocation: teardown keys off it
-        self._longctx[slot] = ls
-        self._table[slot] = 0
-        self._kv_len[slot] = 0
-        self._sync_tables()
-        if req.timeline is not None:
-            req.timeline.stamp_first_chunk()
-        logits = None
-        off = 0
-        while off < s:
-            take = min(page, s - off)
-            kv_loc = off - ls.cold * page
-            if kv_loc == self.budget_pages * page:
-                self._demote_front(slot, ls, req)
-                kv_loc -= page
-            if kv_loc == len(req.pages) * page:
-                req.pages = req.pages + self._alloc_pages(1)
-                self._table[slot, len(req.pages) - 1] = req.pages[-1]
-            row = np.zeros(self.budget_pages, np.int32)
-            row[: len(req.pages)] = req.pages
-            k_c, v_c, ks_c, vs_c, _bucket = self._cold_view(ls)
-            buf = np.zeros(page, np.int32)
-            buf[:take] = req.prompt[off: off + take]
-            with trace_span("longctx:chunk", slot=slot, offset=off,
-                            cold=ls.cold):
-                logits, self.cache = self.model.prefill_paged_chunk_cold(
-                    buf, row, off, off + take, take - 1, self.cache,
-                    k_c, v_c, ks_c, vs_c, s_cold=ls.cold * page,
-                    mode=self._prefill_mode,
-                )
-            off += take
-            self._kv_len[slot] = off
-            if off < s and self._step_guard(self._decode_once):
-                # Chunked-prefill contract: the running batch keeps
-                # decoding between this slot's chunks.
-                self._sync_tables()
-        self._bump("admitted")
-        self._bump("prefill_tokens", s)
-        self._bump("prefill_chunks", -(-s // page))
-        self._bump("longctx_sharded_slots")
-        if self._moe_k:
-            self._bump("moe_routed_tokens", s * self._moe_k)
-        obs_events.emit("admit", slot=slot, prompt_len=s, matched=0,
-                        trace_id=req.trace_id)
-        self._slots[slot] = req
-        return self._sample_req(req, logits)
-
-    def _demote_front(self, slot: int, ls: _LongSlot, req: Request) -> None:
-        """Demote the slot's oldest (full) resident page to the KV
-        tier: the page's KV + scales ship as a ``prefix_payload`` keyed
-        ``<uid>:<cold-index>`` under ``LONGCTX_KIND``, the pool page
-        frees, and the cold window grows by one page. The payload's
-        chain is the page's OWN ``page_size`` tokens, so fault-back and
-        the audit can cross-check content against the sequence."""
-        from triton_distributed_tpu.models import kv_tier
-
-        page = self.page_size
-        pid = int(req.pages[0])
-        start = ls.cold * page
-        seq = [int(t) for t in req.prompt] + [int(t) for t in req.out]
-        k, v, ks, vs = gather_pages(self.cache, [pid])
-        payload = kv_tier.prefix_payload(
-            seq[start: start + page], page, self.kv_dtype,
-            k[:, 0], v[:, 0],
-            None if ks is None else ks[:, 0],
-            None if vs is None else vs[:, 0],
-        )
-        if self._tier_fp is not None:
-            payload["model_fp"] = self._tier_fp
-        key = f"{ls.uid}:{ls.cold}"
-        if not self.tier.put(kv_tier.LONGCTX_KIND, key, payload):
-            raise RuntimeError(
-                f"KV tier refused cold page {key} of sharded slot {slot}"
-            )
-        self.pool.release([pid])
-        req.pages = req.pages[1:]
-        self._table[slot] = 0
-        self._table[slot, : len(req.pages)] = req.pages
-        ls.cold += 1
-        ls.view = None
-        self._bump("longctx_demoted_pages")
-        obs_events.emit("longctx_demote", slot=slot, page=pid,
-                        cold=ls.cold)
-
-    def _cold_view(self, ls: _LongSlot):
-        """The slot's cold window as device arrays: every demoted page
-        faulted back from the tier (``tdt_longctx_tier_faults_total``
-        counts each page read) and stitched — in absolute order — into
-        a power-of-two page bucket (log-many compiled programs over a
-        slot's life; the tail past ``cold`` pages is zero and masked by
-        the kernels' ``s_cold``). Cached until the next demote. Returns
-        ``(k, v, ks, vs, bucket_pages)``."""
-        from triton_distributed_tpu.models import kv_tier
-
-        page = self.page_size
-        n = ls.cold
-        bucket = 1
-        while bucket < n:
-            bucket *= 2
-        if ls.view is not None and ls.view[4] == bucket:
-            return ls.view
-        kp = self.cache.k_pages  # [L, P, Hkv, page, hd]
-        n_layers, _p, hkv, _page, hd = kp.shape
-        dt = np.dtype(kp.dtype)
-        k_np = np.zeros((n_layers, hkv, bucket * page, hd), dt)
-        v_np = np.zeros((n_layers, hkv, bucket * page, hd), dt)
-        quant = self.cache.quantized
-        ks_np = np.zeros((n_layers, hkv, bucket), np.float32) if quant \
-            else None
-        vs_np = np.zeros((n_layers, hkv, bucket), np.float32) if quant \
-            else None
-        for i in range(n):
-            key = f"{ls.uid}:{i}"
-            payload = self.tier.get(kv_tier.LONGCTX_KIND, key)
-            if payload is None:
-                raise RuntimeError(
-                    f"cold page {key} missing from the KV tier (a "
-                    "sharded slot's cold window cannot be rebuilt)"
-                )
-            if (self._tier_fp is not None
-                    and payload.get("model_fp") != self._tier_fp):
-                raise RuntimeError(
-                    f"cold page {key} was produced under different "
-                    "model weights"
-                )
-            _chain, _ps, _dt, k1, v1, ks1, vs1 = (
-                kv_tier.decode_prefix_payload(payload)
-            )
-            k_np[:, :, i * page:(i + 1) * page, :] = k1
-            v_np[:, :, i * page:(i + 1) * page, :] = v1
-            if quant:
-                ks_np[:, :, i] = ks1
-                vs_np[:, :, i] = vs1
-            self._bump("longctx_tier_faults")
-            self._bump("longctx_tier_bytes",
-                       kv_tier.payload_nbytes(payload))
-        view = (
-            jnp.asarray(k_np), jnp.asarray(v_np),
-            None if ks_np is None else jnp.asarray(ks_np),
-            None if vs_np is None else jnp.asarray(vs_np),
-            bucket,
-        )
-        ls.view = view
-        return view
-
-    def _longctx_decode(self, logits):
-        """One sharded decode step per sharded slot, run after the
-        batched step (which saw the slot as empty): append the slot's
-        pending token at its local resident position — demoting /
-        allocating a page when the append needs room — and splice the
-        per-slot partial-merge logits over the batched row BEFORE the
-        NaN guard and sampling read them. Returns
-        ``(logits, changed)``."""
-        page = self.page_size
-        changed = False
-        for slot in sorted(self._longctx):
-            ls = self._longctx.get(slot)
-            req = self._slots[slot]
-            if ls is None or req is None:
-                continue
-            try:
-                # _kv_len was already bumped for this step: rows cached
-                # before the append = _kv_len - 1, all absolute.
-                kv_loc = int(self._kv_len[slot]) - 1 - ls.cold * page
-                if kv_loc == self.budget_pages * page:
-                    self._demote_front(slot, ls, req)
-                    kv_loc -= page
-                if kv_loc == len(req.pages) * page:
-                    req.pages = req.pages + self._alloc_pages(1)
-                    self._table[slot, len(req.pages) - 1] = req.pages[-1]
-                row = np.zeros(self.budget_pages, np.int32)
-                row[: len(req.pages)] = req.pages
-                k_c, v_c, ks_c, vs_c, _bucket = self._cold_view(ls)
-                lg, self.cache = self.model.decode_step_sharded(
-                    np.asarray([self._tok[slot]], np.int32), self.cache,
-                    row, kv_loc, k_c, v_c, ks_c, vs_c,
-                    s_cold=ls.cold * page, mode=self.mode,
-                )
-                self._bump("longctx_decode_steps")
-            except Exception as e:  # noqa: BLE001 — per-slot isolation
-                self._fail(
-                    req, "failed",
-                    f"sharded decode: {type(e).__name__}: {e}",
-                )
-                changed = True
-                continue
-            logits = logits.at[slot].set(lg[0])
-        return logits, changed
-
-    def _drop_longctx(self, slot: int) -> None:
-        """Forget a sharded slot's bookkeeping and delete its tier
-        entries (cold pages belong to exactly ONE live request — they
-        are not a cache; leftovers would leak tier capacity)."""
-        ls = self._longctx.pop(slot, None)
-        if ls is None:
-            return
-        if self.tier is not None:
-            from triton_distributed_tpu.models import kv_tier
-
-            for i in range(ls.cold):
-                self.tier.delete(kv_tier.LONGCTX_KIND, f"{ls.uid}:{i}")
-
-    def _audit_longctx(self) -> list[str]:
-        """Sharded-slot invariants, folded into :meth:`audit`: every
-        sharded entry has a live request, resident pages within budget,
-        local length within resident capacity, and every cold page
-        present in the tier."""
-        from triton_distributed_tpu.models import kv_tier
-
-        problems: list[str] = []
-        page = self.page_size
-        for slot, ls in self._longctx.items():
-            req = self._slots[slot]
-            if req is None:
-                problems.append(
-                    f"longctx: slot {slot} sharded but has no request"
-                )
-                continue
-            if len(req.pages) > self.budget_pages:
-                problems.append(
-                    f"longctx: slot {slot} holds {len(req.pages)} "
-                    f"resident pages > budget {self.budget_pages}"
-                )
-            kv_loc = int(self._kv_len[slot]) - ls.cold * page
-            if not 0 <= kv_loc <= len(req.pages) * page:
-                problems.append(
-                    f"longctx: slot {slot} local kv {kv_loc} outside "
-                    f"resident capacity {len(req.pages) * page}"
-                )
-            if self.tier is not None:
-                for i in range(ls.cold):
-                    key = f"{ls.uid}:{i}"
-                    if not self.tier.contains(kv_tier.LONGCTX_KIND, key):
-                        problems.append(
-                            f"longctx: slot {slot} cold page {key} "
-                            "missing from the KV tier"
-                        )
-        if self.tier is not None and self._tier_owned:
-            live = {
-                str(ls.uid) for ls in self._longctx.values()
-            }
-            for key in self.tier.keys(kv_tier.LONGCTX_KIND):
-                if key.split(":", 1)[0] not in live:
-                    problems.append(
-                        f"longctx: stale tier entry {key} (no live "
-                        "sharded slot owns it)"
-                    )
-        return problems
-
     def _decode_once(self) -> bool:
         """One single-step decode of every active slot; appends sampled
         tokens and evicts finished requests. Returns whether slot state
         changed (caller decides when to re-admit/sync)."""
-        if self._ring is not None:
-            # Single-step fallback under a resident session: this round
-            # applies slot state directly on the host, so no device
-            # loop will ever observe the queued admit/retire/cancel
-            # items — drain them here (no doorbell) or a workload that
-            # persistently falls back (e.g. filtered sampling at
-            # tp > 1, or ns=1) overflows the ring and wedges every
-            # subsequent round.
-            flushed = self._ring.flush()
-            if flushed:
-                self._bump("mega_ring_host_drains", len(flushed))
         active = np.asarray([r is not None for r in self._slots], np.int32)
         if not active.any():
             return False
@@ -1668,14 +1111,13 @@ class ContinuousEngine(MegaDispatch):
         ``step`` with the same request), the slot set must be known
         (no live slot reaches ``gen_len`` with ``step``'s token, no
         admission is mid-prefill), and nothing may sit between a step
-        and its tokens (sharded long-context logits spliced on the
-        host, a speculative plan, a resident ring session, mega rounds
-        planned from host truth, an armed ``FaultPlan``). What the host
+        and its tokens (a speculative plan, mega rounds planned from
+        host truth, an armed ``FaultPlan``). What the host
         cannot foresee (a stop token, a non-finite row, a cancel, a
         deadline) costs the slot's token of the in-flight step, never
         an emitted one (:meth:`_emit_step`)."""
-        if (self.speculative or self._longctx or self._ring is not None
-                or self.mode == "mega" or active_plan() is not None):
+        if (self.speculative or self.mode == "mega"
+                or active_plan() is not None):
             return False
         for slot, req in enumerate(self._slots):
             if req is None:
@@ -1708,20 +1150,11 @@ class ContinuousEngine(MegaDispatch):
         self._bump("decode_steps")
         if self._moe_k:
             self._bump("moe_routed_tokens", n_active * self._moe_k)
-        # Sharded long-context slots were invisible to the batched step
-        # (device table/kv_len masked to the trash page): run their
-        # per-slot partial-merge decode now and splice the real logits
-        # over the batched rows BEFORE the NaN guard and sampling.
-        if self._longctx:
-            logits, lc_changed = self._longctx_decode(logits)
-        else:
-            lc_changed = False
         # One device program computes the finite mask AND the greedy
         # base tokens, so the NaN guard adds no extra host-sync round
         # trip to the hot decode loop.
         finite, toks = tdt_finite_greedy(logits)
-        return _StepLaunch(list(self._slots), logits, finite, toks,
-                           lc_changed)
+        return _StepLaunch(list(self._slots), logits, finite, toks)
 
     def _emit_step(self, step: _StepLaunch) -> bool:
         """Fetch one dispatched step's tokens and emit them through the
@@ -1752,7 +1185,7 @@ class ContinuousEngine(MegaDispatch):
             failed = self._guard_logits(finite)
             nxt = self._sample_slots(step.logits, toks)
             changed = self._process(lambda slot: [nxt[slot]])
-        return changed or bool(failed) or step.lc_changed
+        return changed or bool(failed)
 
     def _guard_logits(self, finite: np.ndarray) -> list[int]:
         """Per-slot NaN/Inf guard on a batched decode output: fail ONLY
@@ -1799,17 +1232,7 @@ class ContinuousEngine(MegaDispatch):
         slot = req.slot
         self._finish_obs(req)  # status "ok": _evict only runs on success
         obs_events.emit("evict", slot=slot, tokens_out=len(req.out))
-        self._ring_push("retire", slot, len(req.out))
-        if slot in self._longctx:
-            # A sharded slot's resident pages hold a LOCAL window (the
-            # cold prefix lives in the tier) — useless as a prefix
-            # chain, so they go straight back to the pool and the tier
-            # entries are deleted with the slot.
-            req.pages = truncate_pages(
-                self.pool, req.pages, 0, self.page_size
-            )
-            self._drop_longctx(slot)
-        elif self.prefix is not None:
+        if self.prefix is not None:
             self._retire_to_prefix(req)
         else:
             # Full truncation: every private page goes back to the pool
@@ -1857,8 +1280,6 @@ class ContinuousEngine(MegaDispatch):
         failed request's KV is suspect (non-finite logits, a partial
         verify chunk) and caching it would poison later matches."""
         slot = req.slot
-        self._ring_push("retire", slot, len(req.out))
-        self._drop_longctx(slot)
         truncate_pages(
             self.pool, req.pages, 0, self.page_size,
             shared=len(req.shared_nodes),
@@ -1986,7 +1407,6 @@ class ContinuousEngine(MegaDispatch):
                 continue
             if req.ticket_id in pending:
                 consumed.add(req.ticket_id)
-                self._ring_push("cancel", req.slot)
                 self._fail(
                     req, "cancelled",
                     f"cancelled by client after {len(req.out)} generated "
@@ -2569,11 +1989,6 @@ class ContinuousEngine(MegaDispatch):
                     progress = True
                     break
                 need = self._needed_pages(len(head.prompt), head.gen_len)
-                sharded = self._sharded_eligible(head)
-                if sharded:
-                    # A sharded slot holds at most the resident budget;
-                    # the rest of its KV lives in the tier.
-                    need = self.budget_pages
                 m = None
                 if head.snapshot is not None:
                     # Migration import does its own (prefix-delta)
@@ -2587,15 +2002,6 @@ class ContinuousEngine(MegaDispatch):
                         self._bump("admission_stalls")
                         progress = False
                         break
-                elif sharded:
-                    avail = len(self.pool.free) + (
-                        self.prefix.reclaimable_pages()
-                        if self.prefix is not None else 0
-                    )
-                    if need > avail:
-                        self._bump("admission_stalls")
-                        progress = False
-                        break  # head-of-line waits for budget pages
                 elif self.prefix is not None:
                     if self.tier is not None:
                         # Durable-tier fault-back (docs/serving.md
@@ -2635,8 +2041,6 @@ class ContinuousEngine(MegaDispatch):
                     self._admit_failure(req, m, e)
                     progress = True
                     break
-                if req.status == "ok":
-                    self._ring_push("admit", slot, len(req.prompt))
                 if first is None:
                     # Snapshot path: either resumed mid-generation (its
                     # pending token is already out[-1]) or failed inside
@@ -2916,20 +2320,8 @@ class ContinuousEngine(MegaDispatch):
         if plan.eos:
             extra.append(jnp.asarray(plan.stop_tok))
             extra.append(halt_in)
-        doorbell = None
-        if self._ring is not None:
-            # One doorbell per round; everything pushed since the last
-            # publish is this round's splice (ring.py documents the
-            # hardware spin this stands in for).
-            state = self._ring.publish()
-            doorbell = int(state[0])
-            self._ring_gauge.set(int(state[3]))
-            self._bump("mega_ring_doorbells")
-            self._ring.consume()
-            extra.append(jnp.asarray(state))
         fn = self._mega_multi_fn(
-            plan.sampled, filtered=plan.filtered, eos=plan.eos,
-            ring=self._ring is not None, B=plan.B,
+            plan.sampled, filtered=plan.filtered, eos=plan.eos, B=plan.B,
         )
         nv = jnp.asarray(plan.n_valid)
         t0 = time.monotonic()
@@ -3001,8 +2393,7 @@ class ContinuousEngine(MegaDispatch):
         )
         return _MegaLaunch(
             plan=plan, toks=toks, cache=new_cache, ss=ss, halt=halt,
-            ring=ring_arr, t0=t0, doorbell=doorbell,
-            trace_ids=trace_ids,
+            ring=ring_arr, t0=t0, trace_ids=trace_ids,
         )
 
     def _drain_pend(self) -> bool:
@@ -3077,7 +2468,6 @@ class ContinuousEngine(MegaDispatch):
             # per-run stats ledger + registry mirror ride _bump.
             self._record_kernel_trace(
                 pend.ring, pend.t0, wall_s, self.NS, pend.trace_ids,
-                doorbell=pend.doorbell,
             )
             self._bump("mega_trace_launches")
         col = {slot: i for i, slot in enumerate(plan.rows) if slot >= 0}
@@ -3099,8 +2489,7 @@ class ContinuousEngine(MegaDispatch):
         return self._process(slot_tokens)
 
     def _mega_multi_fn(self, sampled: bool, *, filtered: bool = False,
-                       eos: bool = False, ring: bool = False,
-                       B: int | None = None):
+                       eos: bool = False, B: int | None = None):
         """The NS-step launch program (built lazily, cached per full
         option tuple — the batch bucket B is part of the key). The
         sampled wrapper draws the Gumbel noise INSIDE the jit —
@@ -3111,11 +2500,11 @@ class ContinuousEngine(MegaDispatch):
         ``sampling.sample`` at ``top_p=1, top_k=0``). With
         ``filtered``, the per-row ``sampcfg`` rides along and the
         in-kernel bisection filter restricts that argmax to the host
-        ``filter_logits`` keep-set. ``eos``/``ring`` append the device
-        stop-token operands and the work-ring snapshot. Unified call
+        ``filter_logits`` keep-set. ``eos`` appends the device
+        stop-token operands. Unified call
         shape past the base four args: ``fn(params, tok, cache,
         n_valid[, extra_tuple][, key, temps, sampcfg])``."""
-        key = (sampled, filtered, eos, ring, B or self.max_batch)
+        key = (sampled, filtered, eos, B or self.max_batch)
         fn = self._multi_fns.get(key)
         if fn is not None:
             return fn
@@ -3126,7 +2515,7 @@ class ContinuousEngine(MegaDispatch):
             page=self.page_size, kv_quant=self.kv_dtype is not None,
             num_pages=int(self.cache.k_pages.shape[1]),
             valid_arg=True, trace=self.kernel_trace,
-            filtered=filtered, eos=eos, ring=ring,
+            filtered=filtered, eos=eos,
         )
         if sampled:
             NS = self.NS
@@ -3145,7 +2534,7 @@ class ContinuousEngine(MegaDispatch):
                 return base(params, tok, cache, n_valid, *extra, *tail)
 
             fn = jax.jit(tdt_mega_round, donate_argnums=(2,))
-        elif eos or ring:
+        elif eos:
             def tdt_mega_round(params, tok, cache, n_valid, extra):
                 return base(params, tok, cache, n_valid, *extra)
 
@@ -3251,10 +2640,6 @@ class ContinuousEngine(MegaDispatch):
                 self._fail(r, "unservable", msg)
                 continue
             need = self._needed_pages(len(r.prompt), r.gen_len)
-            if self._sharded_eligible(r):
-                # Sharded admission only ever holds the resident
-                # budget; the cold remainder lives in the KV tier.
-                need = self.budget_pages
             if need > self._capacity:
                 msg = (
                     f"request needs {need} pages; "
@@ -3329,14 +2714,6 @@ class ContinuousEngine(MegaDispatch):
             # Block on (and discard) any in-flight launch
             # BEFORE teardown reuses the state it reads.
             self._abort_pend()
-            if self._ring is not None:
-                # The resident session ends with the batch: items still
-                # queued (the final retires, teardown cancels) have no
-                # future doorbell to ride — drain them host-side so the
-                # ring is empty at rest.
-                flushed = self._ring.flush()
-                if flushed:
-                    self._bump("mega_ring_host_drains", len(flushed))
             # Crash-safe teardown: NO exit path — injected fault,
             # engine bug, KeyboardInterrupt — leaves a slot holding
             # pages, a dangling tree pin, or a stale device table; the
@@ -3619,7 +2996,6 @@ class ContinuousEngine(MegaDispatch):
         if self.tier is not None:
             problems += [f"tier: {p}" for p in self.tier.audit()]
             problems += self._audit_tier()
-        problems += self._audit_longctx()
         problems += audit_pool(
             self.pool, self.pool.num_pages, owners, shared=shared,
             reserved=(0,),
@@ -3647,7 +3023,7 @@ class ContinuousEngine(MegaDispatch):
             # next row in the wrong place.
             dev = np.asarray(self.cache.kv_len)
             for slot, req in enumerate(self._slots):
-                if (req is not None and slot not in self._longctx
+                if (req is not None
                         and int(dev[slot]) != int(self._kv_len[slot])):
                     problems.append(
                         f"slot {slot}: device kv_len {int(dev[slot])} != "

@@ -136,23 +136,20 @@ class MegaDispatch:
 
     def _record_kernel_trace(
         self, ring, t0: float, wall_s: float, nsteps: int,
-        trace_ids: dict | None = None, doorbell: int | None = None,
+        trace_ids: dict | None = None,
     ) -> None:
         """Fold one launch's device ring into telemetry: the inline
         work is vectorized over the raw ring (gap check, per-opcode
         durations, measured overlap → registry); the launch is kept
         (bounded deque) with the ring attached, records decoding
-        lazily for ``kernel_trace_summary`` and the merged timeline.
-        ``doorbell`` carries the work-ring doorbell published for a
-        resident round — ``validate_ring`` checks the RING_POLL task
-        observed exactly it (no stale ring snapshot)."""
+        lazily for ``kernel_trace_summary`` and the merged timeline."""
         from triton_distributed_tpu.obs import kernel_trace as _kt
 
         self._trace_launch_n += 1
         launch = _kt.KernelTraceLaunch(
             wall_s=wall_s, t0=t0, trace_ids=trace_ids or {},
             nsteps=nsteps, launch=self._trace_launch_n,
-            ring=np.asarray(ring), doorbell=doorbell,
+            ring=np.asarray(ring),
         )
         self._kernel_traces.append(launch)
         _kt.observe_launch(launch)

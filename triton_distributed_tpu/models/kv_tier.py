@@ -62,13 +62,6 @@ _MAGIC = b"TDT1"
 
 PREFIX_KIND = "prefix"
 SNAP_KIND = "snap"
-# Cold pages of a LIVE sharded long-context slot (docs/serving.md
-# "Long-context serving"). Entries are per-page ``prefix_payload``
-# dicts keyed "<uid>:<page-index>"; they belong to exactly one running
-# request and are deleted at its teardown, so the disk prune (which
-# only bounds PREFIX/SNAP) never reaps a page a live decode still
-# needs.
-LONGCTX_KIND = "longctx"
 
 
 class TierIntegrityError(RuntimeError):

@@ -30,10 +30,8 @@ from triton_distributed_tpu.layers.tp_attn import (
     TPAttnParams,
     tp_attn_decode,
     tp_attn_decode_paged,
-    tp_attn_decode_sharded,
     tp_attn_prefill,
     tp_attn_prefill_paged_chunk,
-    tp_attn_prefill_paged_chunk_cold,
 )
 from triton_distributed_tpu.layers.tp_mlp import TPMLPParams, tp_mlp_fwd
 from triton_distributed_tpu.models.config import ModelConfig
@@ -262,16 +260,15 @@ class Qwen3:
         logits = self._logits(params, x)
         return logits, KVCache(k=k_new, v=v_new, kv_len=cache.kv_len + 1)
 
-    def _scan_layers_paged(self, params, x, cache, attn_fn, mode: Mode,
-                           layer_xs=()):
+    def _scan_layers_paged(self, params, x, cache, attn_fn, mode: Mode):
         """The layer scan of every program over a :class:`PagedKVCache`.
 
         The pools (and an int8 pool's scales; ``None`` on a full-width
         one, which ``lax.scan`` threads through as an empty subtree)
         ride the scan's CARRY whole, ``[L, P, hkv_loc, page, hd]``, and
         the layer index comes in as an ``xs`` scalar: ``attn_fn(attn
-        params, h, k_pages, v_pages, layer, k_scale, v_scale, ar,
-        *layer_xs[l])`` writes its rows and reads its pages in place at
+        params, h, k_pages, v_pages, layer, k_scale, v_scale, ar)``
+        writes its rows and reads its pages in place at
         (layer, page) and hands the same arrays back. So the donated
         pool IS the loop's buffer and a step moves only the rows and
         pages it touches. Never pass the pool as ``xs``/``ys``: XLA then
@@ -295,10 +292,10 @@ class Qwen3:
                 with_layout_constraint(p, Layout(tuple(range(p.ndim))))
                 for p in (kp, vp)
             )
-            lp, layer, *per_layer = inp
+            lp, layer = inp
             h = rms_norm(x, lp.ln1, cfg.rms_eps)
             a, kp, vp, ks, vs = attn_fn(
-                lp.attn, h, kp, vp, layer, ks, vs, ar, *per_layer
+                lp.attn, h, kp, vp, layer, ks, vs, ar
             )
             x = x + a
             h = rms_norm(x, lp.ln2, cfg.rms_eps)
@@ -308,8 +305,7 @@ class Qwen3:
         carry, _ = jax.lax.scan(
             layer_fn,
             (x, cache.k_pages, cache.v_pages, cache.k_scale, cache.v_scale),
-            (params.layers, jnp.arange(cfg.num_layers, dtype=jnp.int32),
-             *layer_xs),
+            (params.layers, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
         )
         return carry
 
@@ -544,181 +540,6 @@ class Qwen3:
             jnp.asarray(slot, jnp.int32), jnp.asarray(q_offset, jnp.int32),
             jnp.asarray(new_len, jnp.int32), jnp.asarray(last_idx, jnp.int32),
             *tree_args,
-        )
-
-    # -- sharded long-context slot programs ------------------------------
-    #
-    # A slot whose KV exceeds the per-rank page budget splits into a
-    # RESIDENT paged window (local positions, its own explicit
-    # ``table_row`` — the slot's pages are not in the batched device
-    # table) and a COLD dense window of tier-demoted pages (pool dtype +
-    # per-page scales, read-only). Both programs merge the two attention
-    # partials with ``lse_combine`` — the distributed-flash-decode
-    # combine shape (docs/serving.md "Long-context serving").
-
-    def _prefill_chunk_cold_shard(
-        self, params, tokens, cache, k_cold, v_cold, ks_cold, vs_cold,
-        table_row, s_cold, q_offset, q_end, last_idx, *, mode: Mode,
-    ):
-        """Chunk-prefill a SHARDED slot, per-shard: like
-        :meth:`_prefill_chunk_shard` but the KV scatter lands at LOCAL
-        resident positions through the explicit ``table_row`` and the
-        attention adds the cold-window partial. The batched device
-        ``kv_len``/``page_table`` are untouched — a sharded slot is
-        invisible to the batched decode step."""
-        from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
-
-        # The cold window is read-only and per layer: it stays ``xs``.
-        def attn(ap, h, kp, vp, layer, ks, vs, ar, kc, vc, ksc, vsc):
-            return tp_attn_prefill_paged_chunk_cold(
-                ap, h, kp, vp, layer, table_row, kc, vc, s_cold, q_offset,
-                self.dims, axis=self.axis, mode=ar, ctx=self.ctx,
-                k_scale=ks, v_scale=vs, ks_cold=ksc, vs_cold=vsc,
-                q_end=q_end,
-            )
-
-        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
-            params, self._embed(params, tokens), cache, attn, mode,
-            layer_xs=(k_cold, v_cold, ks_cold, vs_cold),
-        )
-        x = rms_norm(x, params.norm, self.cfg.rms_eps)
-        x_last = jnp.take(x, last_idx, axis=0)
-        logits = self._logits(params, x_last[None])[0]
-        return logits, PagedKVCache(
-            k_pages=k_new, v_pages=v_new, page_table=cache.page_table,
-            kv_len=cache.kv_len, k_scale=ks_new, v_scale=vs_new,
-        )
-
-    def prefill_paged_chunk_cold(
-        self,
-        tokens,          # [C] int32 — one (padded) suffix chunk
-        table_row,       # [budget_pages] int32 — the slot's resident row
-        q_offset: int,   # absolute chunk start
-        q_end: int,      # absolute end of REAL rows
-        last_idx: int,
-        cache,           # PagedKVCache
-        k_cold, v_cold,  # [L, Hkv, S_bucket, hd] pool-dtype cold window
-        ks_cold=None, vs_cold=None,  # [L, Hkv, S_bucket/page] f32
-        s_cold: int = 0,             # valid cold tokens (≤ S_bucket)
-        mode: Mode = "xla",
-    ):
-        """Jitted sharded-slot chunk prefill. Keyed on chunk width, the
-        cold bucket width (a power-of-two page count — log-many
-        programs over a prompt's life) and the resident row length;
-        offsets and ``s_cold`` ride as traced operands."""
-        from triton_distributed_tpu.models.paged_kv_cache import (
-            paged_cache_specs,
-        )
-
-        quant = cache.k_scale is not None
-        s_bucket = int(k_cold.shape[2])
-        row_len = int(table_row.shape[0])
-        key = ("chunk_cold", mode, int(tokens.shape[0]), quant, s_bucket,
-               row_len)
-        if key not in self._prefill_jit:
-            cold_spec = P(None, self.axis, None, None)
-            scale_spec = P(None, self.axis, None) if quant else None
-            f = self.ctx.shard_map(
-                functools.partial(self._prefill_chunk_cold_shard,
-                                  mode=mode),
-                in_specs=(
-                    self.param_specs, P(),
-                    paged_cache_specs(self.axis, quant),
-                    cold_spec, cold_spec, scale_spec, scale_spec,
-                    P(), P(), P(), P(), P(),
-                ),
-                out_specs=(P(), paged_cache_specs(self.axis, quant)),
-            )
-            def tdt_longctx_chunk(p, t, c, kc, vc, ksc, vsc, tr, sc, o, e,
-                                  li):
-                return f(p, t, c, kc, vc, ksc, vsc, tr, sc, o, e, li)
-
-            self._prefill_jit[key] = jax.jit(
-                tdt_longctx_chunk, donate_argnums=(2,)
-            )
-        return self._prefill_jit[key](
-            self.params, jnp.asarray(tokens, jnp.int32), cache,
-            k_cold, v_cold, ks_cold, vs_cold,
-            jnp.asarray(table_row, jnp.int32),
-            jnp.asarray(s_cold, jnp.int32),
-            jnp.asarray(q_offset, jnp.int32),
-            jnp.asarray(q_end, jnp.int32),
-            jnp.asarray(last_idx, jnp.int32),
-        )
-
-    def _decode_shard_sharded(
-        self, params, token, cache, k_cold, v_cold, ks_cold, vs_cold,
-        table_row, kv_len_loc, s_cold, *, mode: Mode,
-    ):
-        """One decode step of ONE sharded slot, per-shard: resident
-        paged partial + cold dense partial, ``lse_combine``d. The
-        batched ``kv_len``/``page_table`` are untouched."""
-        from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
-
-        def attn(ap, h, kp, vp, layer, ks, vs, ar, kc, vc, ksc, vsc):
-            return tp_attn_decode_sharded(
-                ap, h, kp, vp, layer, table_row, kv_len_loc, kc, vc,
-                s_cold, self.dims, axis=self.axis, mode=ar, ctx=self.ctx,
-                k_scale=ks, v_scale=vs, ks_cold=ksc, vs_cold=vsc,
-            )
-
-        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
-            params, self._embed(params, token), cache, attn, mode,
-            layer_xs=(k_cold, v_cold, ks_cold, vs_cold),
-        )
-        x = rms_norm(x, params.norm, self.cfg.rms_eps)
-        logits = self._logits(params, x)  # [1, V]
-        return logits, PagedKVCache(
-            k_pages=k_new, v_pages=v_new, page_table=cache.page_table,
-            kv_len=cache.kv_len, k_scale=ks_new, v_scale=vs_new,
-        )
-
-    def decode_step_sharded(
-        self,
-        token,           # [1] int32 — the slot's new token
-        cache,           # PagedKVCache
-        table_row,       # [budget_pages] int32
-        kv_len_loc: int,  # tokens in the resident region
-        k_cold, v_cold,  # [L, Hkv, S_bucket, hd] pool-dtype cold window
-        ks_cold=None, vs_cold=None,
-        s_cold: int = 0,
-        mode: Mode = "xla",
-    ):
-        """Jitted sharded-slot decode step → ``(logits [1, V], cache)``.
-        Keyed on the cold bucket width and resident row length."""
-        from triton_distributed_tpu.models.paged_kv_cache import (
-            paged_cache_specs,
-        )
-
-        quant = cache.k_scale is not None
-        s_bucket = int(k_cold.shape[2])
-        row_len = int(table_row.shape[0])
-        key = ("sharded", mode, quant, s_bucket, row_len)
-        if key not in self._decode_jit:
-            cold_spec = P(None, self.axis, None, None)
-            scale_spec = P(None, self.axis, None) if quant else None
-            f = self.ctx.shard_map(
-                functools.partial(self._decode_shard_sharded, mode=mode),
-                in_specs=(
-                    self.param_specs, P(),
-                    paged_cache_specs(self.axis, quant),
-                    cold_spec, cold_spec, scale_spec, scale_spec,
-                    P(), P(), P(),
-                ),
-                out_specs=(P(), paged_cache_specs(self.axis, quant)),
-            )
-            def tdt_longctx_step(p, t, c, kc, vc, ksc, vsc, tr, kl, sc):
-                return f(p, t, c, kc, vc, ksc, vsc, tr, kl, sc)
-
-            self._decode_jit[key] = jax.jit(
-                tdt_longctx_step, donate_argnums=(2,)
-            )
-        return self._decode_jit[key](
-            self.params, jnp.asarray(token, jnp.int32), cache,
-            k_cold, v_cold, ks_cold, vs_cold,
-            jnp.asarray(table_row, jnp.int32),
-            jnp.asarray([kv_len_loc], jnp.int32),
-            jnp.asarray([s_cold], jnp.int32),
         )
 
     # -- jitted SPMD entry points ----------------------------------------

@@ -283,73 +283,6 @@ def _build_snapshot(engine, req, kv_len: int, skip: int,
     )
 
 
-def _export_sharded(engine, slot: int, ls) -> SlotSnapshot:
-    """Gather-stitch export of a SHARDED long-context slot
-    (docs/scale-out.md "Sharded-slot migration"): the resident pages
-    come off the device with the usual ``gather_pages``, the cold pages
-    fault back from the KV tier, and the two stitch — in absolute
-    token order, cold prefix first — into one PLAIN snapshot. The
-    importer needs no sharding support: the snapshot is
-    indistinguishable from one exported off a big-pool engine, so any
-    replica with the capacity admits it as an ordinary slot.
-
-    The prefix delta is deliberately skipped (full payload ships): a
-    sharded slot's leading pages live in the tier, not the tree, so
-    digest cover cannot be pinned on import."""
-    from triton_distributed_tpu.models import kv_tier
-
-    req = engine._slots[slot]
-    if req is None:
-        raise SnapshotError(f"slot {slot} has no active request")
-    kv_len = int(engine._kv_len[slot])
-    page = int(engine.page_size)
-    valid = -(-kv_len // page)
-    cold = int(ls.cold)
-    n_res = valid - cold
-    if not 0 <= n_res <= len(req.pages):
-        raise SnapshotError(
-            f"sharded slot {slot}: {valid} valid pages, {cold} cold, "
-            f"{len(req.pages)} resident — geometry is inconsistent"
-        )
-    ship_ids = [int(p) for p in req.pages[:n_res]]
-    if ship_ids:
-        k_res, v_res, ks_res, vs_res = gather_pages(engine.cache,
-                                                    ship_ids)
-    else:
-        k_res = v_res = ks_res = vs_res = None
-    k_cold, v_cold, ks_cold, vs_cold = [], [], [], []
-    for i in range(cold):
-        key = f"{ls.uid}:{i}"
-        payload = engine.tier.get(kv_tier.LONGCTX_KIND, key)
-        if payload is None:
-            raise SnapshotError(
-                f"sharded slot {slot}: cold page {key} missing from "
-                "the KV tier"
-            )
-        _chain, _ps, _dt, k1, v1, ks1, vs1 = (
-            kv_tier.decode_prefix_payload(payload)
-        )
-        k_cold.append(k1)
-        v_cold.append(v1)
-        ks_cold.append(ks1)
-        vs_cold.append(vs1)
-    parts_k = ([np.stack(k_cold, axis=1)] if cold else []) \
-        + ([k_res] if k_res is not None else [])
-    parts_v = ([np.stack(v_cold, axis=1)] if cold else []) \
-        + ([v_res] if v_res is not None else [])
-    k = np.concatenate(parts_k, axis=1) if parts_k else None
-    v = np.concatenate(parts_v, axis=1) if parts_v else None
-    ks = vs = None
-    if engine.cache.quantized:
-        parts_ks = ([np.stack(ks_cold, axis=1)] if cold else []) \
-            + ([ks_res] if ks_res is not None else [])
-        parts_vs = ([np.stack(vs_cold, axis=1)] if cold else []) \
-            + ([vs_res] if vs_res is not None else [])
-        ks = np.concatenate(parts_ks, axis=1) if parts_ks else None
-        vs = np.concatenate(parts_vs, axis=1) if parts_vs else None
-    return _build_snapshot(engine, req, kv_len, 0, k, v, ks, vs)
-
-
 def export_slot(engine, slot: int, *, target_digest=None) -> SlotSnapshot:
     """Snapshot ``slot``'s live request from ``engine`` (pure read — the
     slot keeps decoding; teardown is the caller's decision). Call at a
@@ -362,9 +295,6 @@ def export_slot(engine, slot: int, *, target_digest=None) -> SlotSnapshot:
     ``from_prefix_pages`` records how many the import must instead pin
     from its own tree."""
     fault_point("migrate.export", slot=slot)
-    ls = getattr(engine, "_longctx", {}).get(slot)
-    if ls is not None:
-        return _export_sharded(engine, slot, ls)
     req, kv_len, skip, ship_ids = _export_plan(engine, slot,
                                                target_digest)
     if ship_ids:
@@ -390,15 +320,8 @@ def export_slots_batch(engine, slots, *,
     serial path — filter first when sweeping."""
     plans = []
     out: dict = {}
-    longctx = getattr(engine, "_longctx", {})
     for slot in slots:
         fault_point("migrate.export", slot=slot)
-        ls = longctx.get(slot)
-        if ls is not None:
-            # Sharded slots stitch resident + tier pages per slot — no
-            # batched gather to amortize; route them individually.
-            out[slot] = _export_sharded(engine, slot, ls)
-            continue
         plans.append((slot, *_export_plan(engine, slot, target_digest)))
     all_ids: list[int] = []
     for _slot, _req, _kv_len, _skip, ship_ids in plans:

@@ -126,20 +126,9 @@ STAT_METRICS = {
     "mega_trace_launches": ("tdt_mega_trace_launches_total",
                             "Megakernel launches whose device trace "
                             "ring was decoded."),
-    # Resident decode (docs/megakernel.md "Resident decode"): the host
-    # work ring, in-kernel filtered sampling, batch-bucket launch
-    # programs, and device-side stop-token retire.
-    "mega_ring_items": ("tdt_mega_ring_items_total",
-                        "Admit/retire/cancel work items pushed into "
-                        "the host work ring."),
-    "mega_ring_doorbells": ("tdt_mega_ring_doorbells_total",
-                            "Work-ring doorbell publishes (one per "
-                            "resident round)."),
-    "mega_ring_host_drains": ("tdt_mega_ring_host_drains_total",
-                              "Work-ring items drained host-side "
-                              "(single-step fallback rounds, batch "
-                              "teardown) — no device loop observed "
-                              "them."),
+    # Resident decode (docs/megakernel.md "Resident decode"): in-kernel
+    # filtered sampling, batch-bucket launch programs, and device-side
+    # stop-token retire.
     "mega_device_retires": ("tdt_mega_device_retires_total",
                             "Slots retired by the in-kernel stop-token "
                             "test (no host round trip)."),
@@ -185,43 +174,6 @@ STAT_METRICS = {
                           "Tier pages faulted back from a PEER replica "
                           "over the KV fabric (subset of "
                           "tdt_tier_faulted_pages_total)."),
-    # Long-context serving (docs/serving.md "Long-context serving"):
-    # context-parallel prefill (cp>1 — one request's prompt chunks
-    # round-robined over cp virtual ranks, per-block KV exchange fired
-    # split-phase under the next block's attention) and sharded-slot
-    # decode (a slot whose KV exceeds the per-rank page budget keeps a
-    # resident paged window plus tier-backed cold pages, merged by
-    # log-sum-exp partial combine each step).
-    "cp_prefills": ("tdt_cp_prefills_total",
-                    "Context-parallel (cp>1) prefills run."),
-    "cp_blocks": ("tdt_cp_blocks_total",
-                  "Prefill chunks executed under a cp>1 plan."),
-    "cp_exchange_bytes": ("tdt_cp_exchange_bytes_total",
-                          "KV bytes staged through the split-phase "
-                          "cp block exchange."),
-    "cp_exchange_us": ("tdt_cp_exchange_us_total",
-                       "Wall microseconds spent in cp KV-exchange "
-                       "send windows (tracer-stamped)."),
-    "cp_hidden_us": ("tdt_cp_hidden_us_total",
-                     "Microseconds of cp KV-exchange overlapped "
-                     "under attention compute (subset of "
-                     "tdt_cp_exchange_us_total)."),
-    "longctx_sharded_slots": ("tdt_longctx_sharded_slots_total",
-                              "Slots admitted in sharded (over-budget) "
-                              "long-context mode."),
-    "longctx_demoted_pages": ("tdt_longctx_demoted_pages_total",
-                              "Cold KV pages of live long slots "
-                              "demoted to the KV tier."),
-    "longctx_tier_faults": ("tdt_longctx_tier_faults_total",
-                            "Cold pages faulted back from the KV tier "
-                            "to rebuild a long slot's attention "
-                            "window."),
-    "longctx_tier_bytes": ("tdt_longctx_tier_bytes_total",
-                           "Payload bytes faulted back for long-slot "
-                           "cold windows."),
-    "longctx_decode_steps": ("tdt_longctx_decode_steps_total",
-                             "Per-slot sharded decode programs run "
-                             "(cold + resident partial merge)."),
 }
 
 # Extra registry names mirroring the SAME counter as a STAT_METRICS

@@ -80,8 +80,7 @@ class StubEngine:
 
     def __init__(self, *, num_pages: int = 128, page_size: int = 16,
                  vocab: int = 211, delay_s: float = 0.0,
-                 max_batch: int = 0,
-                 prefill_delay_per_ktok: float = 0.0):
+                 max_batch: int = 0):
         self.pool = PagePool(num_pages)
         self.page_size = int(page_size)
         self.prefix = PrefixCache(self.pool, self.page_size)
@@ -92,13 +91,6 @@ class StubEngine:
         # incremental snapshot buffer below has real partial progress
         # for a mid-batch SIGKILL to leave behind.
         self.delay_s = float(delay_s)
-        # Prompt-proportional prefill wall floor (seconds per 1024
-        # COLD prompt tokens): a real engine's prefill scales with the
-        # uncached prompt, so a 10k-token document blocks its batch
-        # ~10x longer than a chat turn — the head-of-line effect the
-        # long-context bench's SLO-scheduler arms measure. 0 keeps the
-        # historical shape (prompts are free).
-        self.prefill_delay_per_ktok = float(prefill_delay_per_ktok)
         # Decode-slot capacity model: a real engine runs at most
         # `max_batch` slots per continuous-batching round, so an
         # N-request batch costs ceil(N / max_batch) rounds of wall
@@ -276,9 +268,6 @@ class StubEngine:
         # none) — only a cold start pays the prefill.
         stats["prefill_tokens"] += 0 if out else s - matched
         stats["prefix_hit_tokens"] += matched
-        cold = 0 if out else s - matched
-        if self.prefill_delay_per_ktok and cold:
-            time.sleep(self.prefill_delay_per_ktok * cold / 1024.0)
         ctx = toks + out
         # prefill→decode handoff: emit only the admission token, then
         # export (the engine's prefill_only contract). Never re-armed

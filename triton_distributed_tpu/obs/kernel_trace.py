@@ -87,7 +87,6 @@ _AR_WAIT = int(TaskType.AR_WAIT)
 _ALLREDUCE = int(TaskType.ALLREDUCE)
 _A2A_SEND = int(TaskType.A2A_SEND)
 _A2A_WAIT = int(TaskType.A2A_WAIT)
-_RING_POLL = int(TaskType.RING_POLL)
 
 
 class TaskRecord:
@@ -185,27 +184,18 @@ def decode_trace(trace, strict: bool = True) -> list[TaskRecord]:
     return records
 
 
-def validate_ring(
-    records: list[TaskRecord], order=None, doorbell: int | None = None,
-) -> list[str]:
+def validate_ring(records: list[TaskRecord], order=None) -> list[str]:
     """Structural checks over decoded records; returns violation
     strings (empty == consistent).
 
     - every record's clock interval is well-formed (``begin < end``,
-      ``mid`` inside it when stamped — EXCEPT RING_POLL records, whose
-      mid column carries the observed work-ring doorbell, not a clock
-      tick);
+      ``mid`` inside it when stamped);
     - per (rank, step) the launch order is clock-monotonic (the grid is
       sequential: record i+1 must begin at/after record i ended);
     - with ``order`` (the scheduled ``list[Task]``), every scoreboard
       edge holds on the clock: ``begin[consumer] >= end[producer]``
       within a step, and step s+1's records all begin after step s's
-      last end (the cross-step dependency the multi-step band implies);
-    - with ``doorbell`` (the value ``WorkRing.publish`` returned for
-      this launch), every RING_POLL record must have stamped exactly
-      it — a mismatch means a round ran against a ring snapshot the
-      host did not publish for it (the doorbell-gap check; the resident
-      loop's proof that no round consumed stale ring state).
+      last end (the cross-step dependency the multi-step band implies).
     """
     problems: list[str] = []
     by_rs: dict[tuple, list[TaskRecord]] = {}
@@ -219,14 +209,7 @@ def validate_ring(
                     f"rank{rank} step{step} t{rec.index} {rec.op}: "
                     f"begin {rec.begin} >= end {rec.end}"
                 )
-            if rec.opcode == _RING_POLL:
-                if doorbell is not None and rec.mid != doorbell:
-                    problems.append(
-                        f"rank{rank} step{step} t{rec.index} RING_POLL: "
-                        f"observed doorbell {rec.mid} != published "
-                        f"{doorbell} (stale ring snapshot)"
-                    )
-            elif rec.mid and not (rec.begin <= rec.mid <= rec.end):
+            if rec.mid and not (rec.begin <= rec.mid <= rec.end):
                 problems.append(
                     f"rank{rank} step{step} t{rec.index} {rec.op}: mid "
                     f"{rec.mid} outside [{rec.begin}, {rec.end}]"
@@ -290,18 +273,12 @@ def overlap_report(records: list[TaskRecord]) -> dict:
     the wait). Exposed = the blocked remainder (``[mid, end]`` of the
     wait; the whole comm phase of a fused exchange).
     ``hidden_fraction`` aggregates every window; the ``a2a_*`` keys
-    break the A2A family out (what perf/MOE_SERVE.json reports). The
-    ``ring_*`` keys summarize RING_POLL records (resident decode):
-    poll count and the doorbell range they observed — a resident
-    session's launches should show doorbells climbing 1, 2, 3, … with
-    no repeats within a launch.
+    break the A2A family out (what perf/MOE_SERVE.json reports).
     """
     windows = 0
     comm = hidden = exposed = 0
     a2a_windows = 0
     a2a_comm = a2a_hidden = a2a_exposed = 0
-    ring_polls = 0
-    ring_doorbells: set[int] = set()
     by_rs: dict[tuple, list[TaskRecord]] = {}
     for rec in records:
         by_rs.setdefault((rec.rank, rec.step), []).append(rec)
@@ -363,9 +340,6 @@ def overlap_report(records: list[TaskRecord]) -> dict:
                 windows += 1
                 comm += rec.mid - rec.begin
                 exposed += rec.mid - rec.begin
-            elif rec.opcode == _RING_POLL:
-                ring_polls += 1
-                ring_doorbells.add(rec.mid)
     return {
         "windows": windows,
         "comm_ticks": int(comm),
@@ -378,13 +352,6 @@ def overlap_report(records: list[TaskRecord]) -> dict:
         "a2a_exposed_ticks": int(a2a_exposed),
         "a2a_hidden_fraction": (
             (a2a_hidden / a2a_comm) if a2a_comm else None
-        ),
-        "ring_polls": ring_polls,
-        "ring_doorbell_min": (
-            min(ring_doorbells) if ring_doorbells else None
-        ),
-        "ring_doorbell_max": (
-            max(ring_doorbells) if ring_doorbells else None
         ),
     }
 
@@ -443,8 +410,6 @@ def _overlap_report_array(arr: np.ndarray) -> dict | None:
         windows += int(fused.sum())
         comm += c
         exposed += c
-    rp = ops == _RING_POLL
-    rp_mids = mids[rp]
     return {
         "windows": windows,
         "comm_ticks": comm,
@@ -458,13 +423,6 @@ def _overlap_report_array(arr: np.ndarray) -> dict | None:
         "a2a_hidden_ticks": 0,
         "a2a_exposed_ticks": 0,
         "a2a_hidden_fraction": None,
-        "ring_polls": int(rp.sum()),
-        "ring_doorbell_min": (
-            int(rp_mids.min()) if rp_mids.size else None
-        ),
-        "ring_doorbell_max": (
-            int(rp_mids.max()) if rp_mids.size else None
-        ),
     }
 
 
@@ -489,10 +447,6 @@ class KernelTraceLaunch:
     launch: int = 0
     records: list[TaskRecord] | None = None
     ring: np.ndarray | None = None
-    # Work-ring doorbell the host published for this launch (resident
-    # decode; None = ring-less launch). validate_ring checks every
-    # RING_POLL record stamped exactly this value.
-    doorbell: int | None = None
 
     def get_records(self) -> list[TaskRecord]:
         if self.records is None:

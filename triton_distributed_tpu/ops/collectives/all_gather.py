@@ -50,9 +50,10 @@ def _ring_kernel(x_ref, o_ref, copy_sem, send_sems, recv_sems, *, axis: str):
     """Unidirectional ring: at step s forward the chunk received at step
     s-1 to the right neighbor; chunks land at their global row offset.
 
-    Equivalent role: ``cp_engine_producer_all_gather_ring_push_1d``
-    (reference ``allgather.py:140``), with the copy engine replaced by the
-    ICI DMA engine and the tile barrier by per-step recv semaphores.
+    Equivalent role: the reference's copy-engine 1-D ring-push
+    all-gather producer (``allgather.py:140``), with the copy engine
+    replaced by the ICI DMA engine and the tile barrier by per-step
+    recv semaphores.
 
     All refs live in ANY/HBM and every byte moves by DMA — the kernel is
     pure orchestration, so payload size is bounded by HBM, not VMEM.

@@ -107,9 +107,8 @@ def main(argv=None) -> int:
                    "granularity; perf/mega_serve_bench.py sweeps it.")
     p.add_argument("--resident", action="store_true",
                    help="with --mode mega: resident decode — pipeline "
-                   "round i+1's launch before draining round i, with "
-                   "admit/retire/cancel flowing through the host work "
-                   "ring (docs/megakernel.md 'Resident decode'). "
+                   "round i+1's launch before draining round i "
+                   "(docs/megakernel.md 'Resident decode'). "
                    "Continuous-batching engines only.")
     p.add_argument("--kv-dtype", default=None, choices=["int8"],
                    help="int8-quantized paged KV pool (docs/serving.md "
@@ -252,24 +251,6 @@ def main(argv=None) -> int:
                    "time; 0 = unbounded). Gives a stub replica FINITE "
                    "throughput so capacity benches can saturate it "
                    "(perf/pools_bench.py)")
-    p.add_argument("--cp", type=int, default=1, metavar="N",
-                   help="context-parallel prefill width (docs/"
-                   "serving.md 'Long-context serving'): shard ONE "
-                   "request's chunked prefill over N virtual ranks "
-                   "with the block-KV exchange overlapped under the "
-                   "next block's attention. Continuous engines only; "
-                   "excluded with --mode mega, --resident, "
-                   "--speculative and --model stub.")
-    p.add_argument("--rank-page-budget", type=int, default=0,
-                   metavar="TOKENS",
-                   help="per-rank resident KV budget in tokens "
-                   "(docs/serving.md 'Long-context serving'): a "
-                   "request whose KV exceeds it serves as a SHARDED "
-                   "slot — resident pages up to the budget, cold "
-                   "pages demoted to the KV tier and faulted back on "
-                   "demand. Requires --tier-bytes/--tier-dir and the "
-                   "continuous stack; excluded with --mode mega, "
-                   "--resident, --speculative and --model stub.")
     p.add_argument("--slo-ttft-ms", type=float, default=0.0,
                    help="default-class SLO deadline on WIRE-side time "
                    "to first token, milliseconds (0 = unbounded); the "
@@ -301,59 +282,21 @@ def main(argv=None) -> int:
             "--speculative and --mode mega do not compose: the "
             "megakernel's NS-step fused launch advances all slots in "
             "lockstep and already amortizes per-step dispatch, and "
-            "the resident work ring splices whole slots between "
+            "the resident pipeline splices whole slots between "
             "rounds — never a mid-launch verify/rollback "
             "(docs/megakernel.md 'Resident decode'). Drop "
             "--speculative or use --mode xla/pallas."
         )
     if args.resident and args.mode != "mega":
         # Same fail-fast convention: resident decode IS the megakernel's
-        # work-ring round loop — there is nothing to make resident on
+        # pipelined round loop — there is nothing to make resident on
         # the xla/pallas paths, and silently ignoring the flag would
         # leave an operator believing the pipelined dispatch is on.
         p.error("--resident requires --mode mega (resident decode is "
-                "the megakernel's work-ring round loop; "
+                "the megakernel's pipelined round loop; "
                 "docs/megakernel.md 'Resident decode')")
     if args.ns < 1:
         p.error("--ns must be >= 1")
-    # Long-context flags (docs/serving.md "Long-context serving") —
-    # the same fail-fast-by-flag-name convention: every path that
-    # would silently ignore them refuses up front.
-    if args.cp < 1:
-        p.error("--cp takes a width >= 1")
-    longctx = args.cp > 1 or args.rank_page_budget
-    if longctx:
-        if args.model == "stub":
-            p.error(
-                "--cp/--rank-page-budget do nothing on --model stub "
-                "(the control-plane stub runs no attention to shard); "
-                "use a real --model."
-            )
-        if args.mode == "mega" or args.resident:
-            p.error(
-                "--cp/--rank-page-budget compose with the xla/pallas "
-                "paths only: --mode mega and --resident drive slots "
-                "through fused programs that bypass the per-chunk "
-                "exchange schedule and the sharded partial-merge "
-                "decode. Drop those flags or use --mode xla/pallas."
-            )
-        if args.speculative:
-            p.error(
-                "--cp/--rank-page-budget and --speculative do not "
-                "compose (verify chunks bypass the sharded-slot "
-                "programs); drop one."
-            )
-        if not (args.continuous or args.replicas or args.fleet > 0):
-            p.error(
-                "--cp/--rank-page-budget ride the continuous serving "
-                "stack only: add --continuous, --replicas N, or "
-                "--fleet N."
-            )
-    if args.rank_page_budget and not (args.tier_bytes or args.tier_dir):
-        p.error(
-            "--rank-page-budget needs a KV tier for the demoted cold "
-            "pages: add --tier-bytes N and/or --tier-dir DIR."
-        )
     # --model moe: the Qwen3MoE serving alias (tiny-moe preset so a
     # laptop/CI run needs no checkpoint), sized by the knob overrides.
     model_name, overrides = resolve_model_args(
@@ -553,11 +496,6 @@ def main(argv=None) -> int:
                 child += ["--moe-intermediate", str(args.moe_intermediate)]
             if args.tier_bytes:
                 child += ["--tier-bytes", str(args.tier_bytes)]
-            if args.cp > 1:
-                child += ["--cp", str(args.cp)]
-            if args.rank_page_budget:
-                child += ["--rank-page-budget",
-                          str(args.rank_page_budget)]
 
             def make_spec(name: str, role: str = "mixed") -> ReplicaSpec:
                 argv_i = list(child)
@@ -767,7 +705,6 @@ def main(argv=None) -> int:
                 kernel_trace=kernel_trace,
                 ns=args.ns, resident=args.resident,
                 snapshot_every=args.snapshot_every,
-                cp=args.cp, rank_page_budget=args.rank_page_budget,
                 tier=shared_tier,
                 tier_bytes=args.tier_bytes,
                 tier_dir=(os.path.join(args.tier_dir, f"r{i}")
@@ -820,7 +757,6 @@ def main(argv=None) -> int:
             kernel_trace=kernel_trace,
             ns=args.ns, resident=args.resident,
             snapshot_every=args.snapshot_every,
-            cp=args.cp, rank_page_budget=args.rank_page_budget,
             tier_bytes=args.tier_bytes, tier_dir=args.tier_dir,
             fabric=fabric,
         )
