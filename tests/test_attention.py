@@ -128,35 +128,139 @@ def test_paged_flash_decode_layer_addressed(rng, layer):
         paged_flash_decode(q, k_pool[0], v_pool[0], table, lens, layer=0)
 
 
-def test_paged_flash_decode_walk_ends_at_longest_sequence(rng):
-    """The grid's page axis stops at the longest live sequence's last
-    page (a dynamic bound): short contexts in a long table give the
-    dense answer, and the chunks never walked (NaN-filled by interpret
-    mode) leave no trace in it."""
-    from triton_distributed_tpu.ops.attention import (
-        gqa_decode_reference,
-        paged_flash_decode,
-    )
+# Context lengths of three sequences over a table of 8 pages of 16: what
+# the one-axis walk over live (slot, page) pairs has to get right.
+_WALK_LENS = {
+    "one_full_among_short": [16 * 8, 3, 40],   # a context of pps * page
+    "full_context_last": [5, 20, 16 * 8],
+    "dead_slot": [5, 20, 1],                   # the engine's idle row
+    "all_dead": [1, 1, 1],
+    "exact_page_multiples": [16, 48, 32],
+    "all_equal": [40, 40, 40],
+}
+
+
+@pytest.mark.parametrize("return_lse", [True, False], ids=["lse", "no_lse"])
+@pytest.mark.parametrize("pool_form", ["one_layer", "layer_of_pool"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_WALK_LENS))
+def test_paged_flash_decode_walk(rng, case, dtype, pool_form, return_lse):
+    """One grid step per live (slot, page) pair, every KV head in the
+    block, the softmax online across a sequence's pages: ragged contexts
+    in a long table give the dense answer, a short or dead row costs
+    (and reads) its own pages only, and the table entries never walked
+    (their pages NaN here) leave no trace. ``layer_of_pool``: the served
+    form reads what the one-layer call reads from ``pool[layer]``, bit
+    for bit."""
+    from triton_distributed_tpu.ops.attention import paged_flash_decode
     from triton_distributed_tpu.ops.attention.flash_decode import (
         pages_to_dense,
     )
 
-    b, hq, hkv, d, page, pps = 3, 4, 2, 64, 16, 8
+    n_layers, layer = 3, 1
+    b, hq, hkv, d, page, pps = 3, 8, 2, 64, 16, 8
+    lens = _WALK_LENS[case]
     p = b * pps + 1
-    table = jnp.asarray(
-        1 + rng.permutation(p - 1).reshape(b, pps), jnp.int32)
+    table = 1 + rng.permutation(p - 1).reshape(b, pps)
+    k_pool = rng.standard_normal((n_layers, p, hkv, page, d))
+    v_pool = rng.standard_normal((n_layers, p, hkv, page, d))
+    # Poison every page no live (slot, page) pair names: a walk that
+    # fetched one for use would carry the NaN into the answer.
+    live = {0} | {int(table[i, c]) for i, n in enumerate(lens)
+                  for c in range(-(-n // page))}
+    dead = [i for i in range(p) if i not in live]
+    k_pool[:, dead] = v_pool[:, dead] = np.nan
+    k_pool, v_pool = (jnp.asarray(a, dtype) for a in (k_pool, v_pool))
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+
+    one_layer = jax.jit(
+        lambda: paged_flash_decode(
+            q, k_pool[layer], v_pool[layer], table, lens,
+            return_lse=return_lse)
+    )()
+    got = one_layer
+    if pool_form == "layer_of_pool":
+        got = jax.jit(
+            lambda lyr: paged_flash_decode(
+                q, k_pool, v_pool, table, lens, layer=lyr,
+                return_lse=return_lse)
+        )(jnp.asarray(layer, jnp.int32))
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(one_layer)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # Rows past a length are masked in the reference; NaN * 0 is not 0.
+    k_d, v_d = (
+        jnp.nan_to_num(pages_to_dense(a[layer], table))
+        for a in (k_pool, v_pool)
+    )
+    gold, gold_lse = gqa_decode_reference(q, k_d, v_d, lens, return_lse=True)
+    out, lse = got if return_lse else (got, None)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    tol = 2e-5 if dtype == "float32" else 2e-2  # bf16: P rounds for P.V
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(gold, np.float32),
+        atol=tol, rtol=tol)
+    if return_lse:
+        assert lse.shape == (b, hq) and lse.dtype == jnp.float32
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(gold_lse), atol=2e-5, rtol=2e-5)
+
+
+def test_paged_flash_decode_hoisted_walk(rng):
+    """``walk=`` (the served step derives it once, outside its layer
+    scan) is the walk the call would derive itself, bit for bit; one
+    built for another batch or table is refused by its shape."""
+    from triton_distributed_tpu.ops.attention import (
+        paged_decode_walk,
+        paged_flash_decode,
+    )
+
+    b, hq, hkv, d, page, pps = 3, 8, 2, 64, 16, 8
+    p = b * pps + 1
+    table = jnp.asarray(1 + rng.permutation(p - 1).reshape(b, pps), jnp.int32)
     k_pool = jnp.asarray(rng.standard_normal((p, hkv, page, d)), jnp.float32)
     v_pool = jnp.asarray(rng.standard_normal((p, hkv, page, d)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.float32)
-    for lens in ([5, 20, 1], [1, 1, 1], [16 * 8, 3, 40]):
-        lens = jnp.asarray(lens, jnp.int32)
-        out, lse = paged_flash_decode(
-            q, k_pool, v_pool, table, lens, return_lse=True)
-        gold, gold_lse = gqa_decode_reference(
-            q, pages_to_dense(k_pool, table), pages_to_dense(v_pool, table),
-            lens, return_lse=True)
-        assert np.isfinite(np.asarray(out)).all()
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(gold), atol=2e-5, rtol=2e-5)
-        np.testing.assert_allclose(
-            np.asarray(lse), np.asarray(gold_lse), atol=2e-5, rtol=2e-5)
+    lens = jnp.asarray([16 * 8, 3, 40], jnp.int32)
+    want = paged_flash_decode(q, k_pool, v_pool, table, lens)
+    got = paged_flash_decode(
+        q, k_pool, v_pool, table, lens,
+        walk=paged_decode_walk(lens, page, pps))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="paged_decode_walk"):
+        paged_flash_decode(
+            q, k_pool, v_pool, table, lens,
+            walk=paged_decode_walk(lens[:2], page, pps))
+
+
+@pytest.mark.parametrize("lens,page,pps,steps", [
+    # The parent's grid took slots x heads x the LONGEST walk: 4 x 8 x 32
+    # = 1,024 steps for one context of 4,096 among three of 300.
+    ([4096, 300, 300, 300], 128, 32, 41),
+    ([411, 300, 520, 180], 128, 32, 4 + 3 + 5 + 2),  # chat-closed8's shape
+    ([128, 256, 129, 1], 128, 32, 1 + 2 + 2 + 1),    # exact multiples
+    ([1, 1, 1, 1, 1, 1, 1, 1], 128, 32, 8),          # eight idle rows
+    ([0, 5000], 128, 32, 1 + 32),      # an empty row still writes; the
+                                       # table's end bounds a walk
+    ([40, 40, 40], 16, 8, 9),
+], ids=["one_long_among_short", "closed8", "page_multiples", "idle",
+        "clipped", "equal"])
+def test_paged_decode_walk_counts_live_pairs(lens, page, pps, steps):
+    """The kernel's grid as a COUNT: ``sum(ceil(len / page))`` steps, in
+    slot order, each naming a table entry inside its sequence's length
+    (what tells a long walk from a short one; the wrapper sizes its grid
+    with this same function)."""
+    from triton_distributed_tpu.ops.attention import paged_decode_walk
+
+    slot, page_of, n = paged_decode_walk(jnp.asarray(lens), page, pps)
+    n = int(n)
+    per_slot = [min(max(-(-x // page), 1), pps) for x in lens]
+    assert n == steps == sum(per_slot)
+    assert slot.shape == page_of.shape == (len(lens) * pps,)
+    want = [(i, c) for i, k in enumerate(per_slot) for c in range(k)]
+    assert list(zip(np.asarray(slot)[:n].tolist(),
+                    np.asarray(page_of)[:n].tolist())) == want
+    # Entries past the grid's end are never read, and stay in range.
+    assert (np.asarray(slot) < len(lens)).all()
+    assert (np.asarray(page_of) < pps).all() and (np.asarray(page_of) >= 0).all()

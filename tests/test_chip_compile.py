@@ -19,6 +19,7 @@ refuses on bf16 operands and which no chip run sets.
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -86,26 +87,37 @@ def compile_for_chip(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("pool_form", ["one_layer", "layer_of_pool"])
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_flash_decode(chip, kv, pool_form):
+@pytest.mark.parametrize("kv,pool_form,slots,hkv", [
+    ("bf16", "one_layer", BATCH, HKV),
+    ("int8", "one_layer", BATCH, HKV),
+    ("bf16", "layer_of_pool", BATCH, HKV),   # the benchmark's cells
+    ("int8", "layer_of_pool", BATCH, HKV),
+    ("bf16", "layer_of_pool", 8, HKV),       # --max-batch 8
+    ("int8", "layer_of_pool", 8, HKV),
+    ("bf16", "layer_of_pool", 8, HKV // 4),  # one shard of --tp 4
+    ("int8", "layer_of_pool", 8, HKV // 4),
+])
+def test_paged_flash_decode(chip, kv, pool_form, slots, hkv):
     """``layer_of_pool``: the served form — the whole 36-layer pool and
     a traced layer index, which rides into the kernel inside the page
-    table (``table + layer * P`` over the ``[L * P, ...]`` view)."""
+    table (``table + layer * P`` over the ``[L * P, ...]`` view). One
+    kernel at every shape: its blocks follow what it sees in its
+    operands (``(1, Hkv, page, d)``: all of a page's heads)."""
     from triton_distributed_tpu.ops.attention.flash_decode import (
         paged_flash_decode,
     )
 
     lead = (36,) if pool_form == "layer_of_pool" else ()
     kv_dtype = BF16 if kv == "bf16" else jnp.int8
-    pages = sds(chip, (*lead, NUM_PAGES, HKV, PAGE, D), kv_dtype)
-    args = [sds(chip, (BATCH, HQ, D), BF16), pages, pages,
-            sds(chip, (BATCH, PPS), jnp.int32),
-            sds(chip, (BATCH,), jnp.int32)]
+    pool_shape = (*lead, slots * PPS + 1, hkv, PAGE, D)
+    pages = sds(chip, pool_shape, kv_dtype)
+    args = [sds(chip, (slots, hkv * (HQ // HKV), D), BF16), pages, pages,
+            sds(chip, (slots, PPS), jnp.int32),
+            sds(chip, (slots,), jnp.int32)]
     if lead:
         args.append(sds(chip, (), jnp.int32))
     if kv == "int8":
-        scale = sds(chip, (*lead, NUM_PAGES, HKV), jnp.float32)
+        scale = sds(chip, pool_shape[:-2], jnp.float32)
         args += [scale, scale]
 
     def fn(q, k, v, t, n, *rest):
@@ -117,8 +129,13 @@ def test_paged_flash_decode(chip, kv, pool_form):
     text = compile_for_chip(fn, *args)
     assert "tpu_custom_call" in text
     # The kernel reads pages of the pool it was given: nothing
-    # pool-shaped is sliced or copied for it.
-    assert not _pool_shaped_moves(text, (*lead, NUM_PAGES, HKV, PAGE, D), kv)
+    # pool-shaped is sliced or copied for it, and the K / V operands of
+    # the custom call are the pool's own bytes (its [L * P, ...] view).
+    assert not _pool_shaped_moves(text, pool_shape, kv)
+    call = next(ln for ln in text.splitlines() if "custom-call(" in ln
+                and "tpu_custom_call" in ln)
+    flat = ",".join(map(str, (math.prod(pool_shape[:-3]), *pool_shape[-3:])))
+    assert call.count(f"{'bf16' if kv == 'bf16' else 's8'}[{flat}]") == 2
 
 
 def _pool_shaped_moves(hlo_text, pool_shape, kv="bf16"):

@@ -75,16 +75,23 @@ def _random_pool(rng, p, hkv, page, d):
     return k, v
 
 
-def test_paged_flash_decode_int8_parity(rng):
+@pytest.mark.parametrize("lens", [
+    [16 * 4, 21],   # a full table beside a short row
+    [37, 1],        # ragged, and the engine's idle row
+    [16, 48],       # exact page multiples
+    [5, 5],
+], ids=["full_and_short", "ragged_dead_slot", "page_multiples", "equal"])
+def test_paged_flash_decode_int8_parity(rng, lens):
     """In-kernel dequant == reference over the dequantized view (float
-    tolerance) == full-width reference (quant tolerance)."""
+    tolerance) == full-width reference (quant tolerance), over ragged
+    lengths: every (slot, page) step reads ITS page's scales."""
     b, hq, hkv, page, pps, p, d = 2, 8, 2, 16, 4, 9, 32
     q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.float32)
     k_pool, v_pool = _random_pool(rng, p, hkv, page, d)
     table = jnp.asarray(
         rng.permutation(p - 1)[: b * pps].reshape(b, pps) + 0, jnp.int32
     )
-    lens = jnp.asarray([page * pps, 21], jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     k_q, k_sc = quantize_pages(k_pool)
     v_q, v_sc = quantize_pages(v_pool)
     out = paged_flash_decode(
@@ -111,6 +118,36 @@ def test_paged_flash_decode_int8_parity(rng):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref_full), atol=0.1, rtol=0.1
     )
+
+
+@pytest.mark.parametrize("lens", [[16 * 4, 21], [37, 1]],
+                         ids=["full_and_short", "ragged_dead_slot"])
+def test_paged_flash_decode_int8_bf16_q_keeps_p_in_f32(rng, lens):
+    """The served ``--kv-dtype int8`` form: bf16 q over int8 codes. The
+    codes and q are exact in bf16 and the MXU accumulates in f32, so
+    QK^T is the f32-q call's; P stays f32 for P.V. What is left between
+    the two calls is the rounding of the OUTPUT to bf16 — a P rounded to
+    bf16 moves a tenth of the elements off that rounding."""
+    b, hq, hkv, page, pps, p, d = 2, 8, 2, 16, 4, 9, 32
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), jnp.bfloat16)
+    k_pool, v_pool = _random_pool(rng, p, hkv, page, d)
+    table = jnp.asarray(
+        rng.permutation(p - 1)[: b * pps].reshape(b, pps), jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    k_q, k_sc = quantize_pages(k_pool)
+    v_q, v_sc = quantize_pages(v_pool)
+    out = paged_flash_decode(
+        q, k_q, v_q, table, lens, k_scale=k_sc, v_scale=v_sc)
+    wide = paged_flash_decode(
+        q.astype(jnp.float32), k_q, v_q, table, lens,
+        k_scale=k_sc, v_scale=v_sc)
+    assert out.dtype == jnp.bfloat16 and wide.dtype == jnp.float32
+    got = np.asarray(out, np.float32)
+    want = np.asarray(wide.astype(jnp.bfloat16), np.float32)
+    # One bf16 step at most anywhere, and off the rounding almost never
+    # (an f32 sum in another order can sit on a rounding boundary).
+    np.testing.assert_allclose(got, want, atol=0, rtol=2 ** -7)
+    assert np.mean(got != want) < 0.02
 
 
 @pytest.mark.parametrize("layer", [1, 2], ids=["middle", "last"])

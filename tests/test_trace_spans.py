@@ -76,6 +76,13 @@ def session(tmp_path_factory):
                                 .removeprefix("jit(").removesuffix(")"))
 
     jax.monitoring.register_event_duration_secs_listener(on_compile)
+    # ``PARENT["programs"]`` below is a COUNT of compile events, and a
+    # compile event fires only on a cache miss: ``tdt_finite_greedy`` is
+    # a module-level jit, so where an earlier file on this xdist worker
+    # (``--dist loadfile`` deals files by the whole run's test count)
+    # compiled it at this shape, it is missing from the count and the
+    # test fails for a reason no served program has. Start cold.
+    jax.clear_caches()
     ctx = mesh_mod.initialize_distributed(tp=1, devices=jax.devices()[:1])
     model = AutoLLM.from_pretrained("tiny", ctx=ctx)
     # Chunked prefill (16 tokens a chunk), so that the second request's

@@ -406,6 +406,7 @@ def tp_attn_decode_paged(
     kv_len: jax.Array,      # [B] int32
     dims: TPAttnDims,
     *,
+    walk,  # paged_decode_walk(kv_len + 1, page, pages_per_seq)
     axis: str = "tp",
     mode: Mode = "pallas_ar",
     ctx: DistContext | None = None,
@@ -424,7 +425,10 @@ def tp_attn_decode_paged(
     the step's ``B`` rows land in place at ``(layer, page_table[i, pos //
     page], :, pos % page, :)`` and the kernel reads pages at ``(layer,
     page)``, so a step moves the rows it writes and the pages it
-    attends, never a layer's pool.
+    attends, never a layer's pool. ``walk`` is the kernel's grid for
+    this step's lengths (the appended row counted): the caller derives
+    it once a step, outside its layer scan, and it is required here so
+    that no layer derives it again.
 
     With ``k_scale``/``v_scale`` (int8 pool) the append quantizes each
     new row into its page (growing the page scale, requantizing when it
@@ -457,7 +461,7 @@ def tp_attn_decode_paged(
 
     o = paged_flash_decode(
         q, k_pages, v_pages, page_table, kv_len + 1, layer=layer,
-        k_scale=k_scale, v_scale=v_scale,
+        walk=walk, k_scale=k_scale, v_scale=v_scale,
     )
     o_flat = o.reshape(b, dims.hq_loc * dims.head_dim).astype(x.dtype)
     if mode in ("xla", "xla_ar"):

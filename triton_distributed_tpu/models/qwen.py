@@ -319,12 +319,21 @@ class Qwen3:
         paged decode (``mega_triton_kernel/models/paged_kv_cache.py``).
         """
         from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
+        from triton_distributed_tpu.ops.attention import paged_decode_walk
+
+        # The attention kernel's grid (its live (slot, page) pairs, each
+        # row's appended token included) follows kv_len alone: derived
+        # here, once a step, not in each of the scan's layers.
+        walk = paged_decode_walk(
+            cache.kv_len + 1, cache.k_pages.shape[3],
+            cache.page_table.shape[1],
+        )
 
         def attn(ap, h, kp, vp, layer, ks, vs, ar):
             return tp_attn_decode_paged(
                 ap, h, kp, vp, layer, cache.page_table, cache.kv_len,
                 self.dims, axis=self.axis, mode=ar, ctx=self.ctx,
-                k_scale=ks, v_scale=vs,
+                k_scale=ks, v_scale=vs, walk=walk,
             )
 
         x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(

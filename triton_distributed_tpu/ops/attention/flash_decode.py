@@ -14,6 +14,11 @@ a scalar-prefetch ``kv_len``. The combine is a log-sum-exp merge —
 intra-chip over the chunk axis, and for the distributed form across the
 ``sp`` mesh axis after an all-gather of the (O, LSE) partials (XLA
 collective or our Pallas ring — the device-initiated putmem analog).
+
+The PAGED form (:func:`paged_flash_decode`, the served decode step's
+kernel) is a kernel of its own: one grid step per live (sequence, page)
+pair with every KV head of the page in one block, and the merge over a
+sequence's pages done online inside the kernel.
 """
 
 from __future__ import annotations
@@ -45,14 +50,12 @@ def _decode_body(
     *,
     sm_scale: float,
     chunk_k: int,
-    num_chunks: int,
 ):
     b = pl.program_id(0)
     ci = pl.program_id(2)
     start = ci * chunk_k
-    # This (sequence, kv head, chunk)'s slot in the flattened scales
-    # (``num_chunks`` a sequence: the paged grid may stop short of it).
-    si = (b * pl.num_programs(1) + pl.program_id(1)) * num_chunks + ci
+    # This (sequence, kv head, chunk)'s slot in the flattened scales.
+    si = (b * pl.num_programs(1) + pl.program_id(1)) * pl.num_programs(2) + ci
     valid = kv_len_ref[b] - start  # may be <=0 (fully masked chunk)
 
     @pl.when(valid > 0)
@@ -203,7 +206,7 @@ def flash_decode(
         scalars += [k_scale.reshape(-1), v_scale.reshape(-1)]
     kernel = functools.partial(
         _decode_kernel_q if quant else _decode_kernel,
-        sm_scale=sm_scale, chunk_k=chunk_k, num_chunks=num_chunks,
+        sm_scale=sm_scale, chunk_k=chunk_k,
     )
     o_parts, lse_parts = pl.pallas_call(
         kernel,
@@ -238,6 +241,113 @@ def flash_decode(
     return o
 
 
+def paged_decode_walk(kv_len: jax.Array, page: int, pps: int):
+    """The grid of :func:`paged_flash_decode`: one step per LIVE (slot,
+    page) pair, ordered by slot.
+
+    Returns ``(slot [B * pps], page_of [B * pps], steps)``: grid step
+    ``i < steps`` attends sequence ``slot[i]``'s table entry
+    ``page_of[i]``; ``steps = sum_b clip(ceil(kv_len[b] / page), 1,
+    pps)`` (an empty row still gets the one step that writes its
+    zeros). Entries past ``steps`` are in range and never read. The
+    lists depend on ``kv_len`` alone, so a caller that runs the kernel
+    once a layer derives them ONCE a step, outside its layer scan, and
+    hands them in as ``walk=``: as XLA ops inside the call they would
+    run again in every layer.
+    """
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    b = kv_len.shape[0]
+    pages = jnp.clip(pl.cdiv(kv_len, page), 1, pps)
+    ends = jnp.cumsum(pages)
+    step = jnp.arange(b * pps, dtype=jnp.int32)
+    slot = jnp.minimum(
+        jnp.searchsorted(ends, step, side="right").astype(jnp.int32), b - 1
+    )
+    page_of = jnp.minimum(step - (ends - pages)[slot], pps - 1)
+    return slot, page_of, ends[-1]
+
+
+def _paged_decode_kernel(
+    kv_len_ref,  # [B] int32 SMEM (scalar prefetch)
+    table_ref,   # [B, pps] int32 SMEM — consumed by the index maps
+    slot_ref,    # [B * pps] int32 SMEM — the walk: sequence of step i
+    page_ref,    # [B * pps] int32 SMEM — ... and its table entry
+    *refs,
+    sm_scale: float,
+    quant: bool,
+    return_lse: bool,
+):
+    """One live (slot, page) pair: every KV head of the page against
+    that sequence's q heads, folded into the sequence's running softmax
+    (the pairs of a sequence are consecutive grid steps, so its
+    accumulators and output block stay resident in VMEM)."""
+    if quant:
+        # [B * pps * Hkv] f32 SMEM — the table's pages' scales.
+        ks_ref, vs_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, *refs = refs  # q/o [1, Hkv, group, d]
+    if return_lse:                            # k/v [1, Hkv, page, d]
+        lse_ref, *refs = refs                 # [1, Hkv, group, 1]
+    m_ref, l_ref, acc_ref = refs  # VMEM f32 [Hkv, group, 1 | 1 | d]
+    i = pl.program_id(0)
+    b, ci = slot_ref[i], page_ref[i]
+    pps = table_ref.shape[1]
+    hkv, page = k_ref.shape[1:3]
+    valid = kv_len_ref[b] - ci * page  # keys of this page that count
+
+    @pl.when(ci == 0)
+    def _first_page():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(valid > 0)
+    def _accumulate():
+        # The MXU takes the pool's own dtype (bf16 x bf16 products are
+        # exact in the f32 accumulator; an f32 upcast buys nothing and
+        # costs a multi-pass matmul). int8 codes widen exactly for
+        # QK^T; their P.V keeps f32 operands (below).
+        dt = jnp.promote_types(q_ref.dtype, k_ref.dtype)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[2], page), 1)
+        for h in range(hkv):
+            # In-register dequant: the symmetric per-page-per-head scale
+            # is a scalar, so it folds into the softmax multiplier AFTER
+            # QK^T and into the accumulator AFTER P·V — full-width KV
+            # never exists anywhere (not even in VMEM).
+            si = (b * pps + ci) * hkv + h
+            s = jax.lax.dot_general(
+                q_ref[0, h].astype(dt), k_ref[0, h].astype(dt),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) * (sm_scale * ks_ref[si] if quant else sm_scale)
+            s = jnp.where(cols < valid, s, _NEG_INF)  # [group, page]
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            if quant:
+                # P stays f32 against the widened codes, as in the dense
+                # body: with bf16 q, ``dt`` would round P to 8 bits.
+                pv = jnp.dot(
+                    p, v_ref[0, h].astype(jnp.float32),
+                    preferred_element_type=jnp.float32,
+                ) * vs_ref[si]
+            else:
+                pv = jnp.dot(
+                    p.astype(dt), v_ref[0, h].astype(dt),
+                    preferred_element_type=jnp.float32,
+                )
+            m_ref[h] = m_new
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + pv
+
+    # The sequence's last live page (or its table's last entry).
+    @pl.when((valid <= page) | (ci == pps - 1))
+    def _last_page():
+        l = jnp.maximum(l_ref[...], 1e-30)  # an empty row reads 0
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        if return_lse:
+            lse_ref[0] = m_ref[...] + jnp.log(l)
+
+
 def paged_flash_decode(
     q: jax.Array,        # [B, Hq, D]
     k_pages: jax.Array,  # [L, P, Hkv, page, D] whole pool, or [P, Hkv, page, D]
@@ -246,6 +356,7 @@ def paged_flash_decode(
     kv_len: jax.Array,      # [B] int32 — valid context length
     *,
     layer: jax.Array | int | None = None,  # which layer of a 5-D pool
+    walk=None,  # paged_decode_walk(kv_len, page, pages_per_seq), hoisted
     sm_scale: float | None = None,
     return_lse: bool = False,
     k_scale: jax.Array | None = None,  # [(L,) P, Hkv] f32 — per-page-per-head
@@ -256,19 +367,30 @@ def paged_flash_decode(
 
     Parity: the reference megakernel's paged decode
     (``mega_triton_kernel/models/paged_kv_cache.py:58`` + its attention
-    task reading through the page table). TPU design: the page table
-    rides as a scalar-prefetch operand and the K/V BlockSpec index maps
-    dereference it — ``block ci of sequence b`` fetches pool page
-    ``table[b, ci]``, so the kernel body is exactly the dense split-KV
-    kernel with ``chunk_k = page_size`` and no gather materializes.
-    The grid walks a sequence's table only as far as the longest live
-    sequence's last page (a dynamic bound on the page axis).
+    task reading through the page table). TPU design: the grid is ONE
+    axis with a step per live (sequence, page) pair —
+    ``sum_b ceil(kv_len[b] / page)`` steps (:func:`paged_decode_walk`),
+    a dynamic bound — and each step carries every KV head of its page.
+    The walk and the page table ride as scalar-prefetch operands and the
+    BlockSpec index maps dereference them: step ``i`` fetches pool page
+    ``table[slot[i], page_of[i]]`` as one ``(1, Hkv, page, D)`` block (in
+    the pool's layout all heads of a page are one contiguous run: 256 KB
+    at Qwen3-4B), so no gather materializes, a short sequence costs its
+    own pages and not the longest one's, and nothing is fetched for a
+    table entry past a sequence's length. The body is a static loop over
+    the KV heads (the GQA group rides the sublanes, q block
+    ``[Hkv, group, D]``) feeding the MXU the pool's own dtype with f32
+    accumulation, the softmax in f32 and ONLINE across a sequence's
+    pages: its pairs are consecutive steps, so the running max, sum and
+    accumulator stay in VMEM and ``o [B, Hq, D]`` (and the LSE) leave
+    the kernel finished — no partials, no merge in XLA.
 
     With ``k_scale``/``v_scale`` (the pool's per-page-per-head int8
-    scales), the K/V blocks are int8 codes and each program fetches its
-    page's scale through the SAME table indirection, dequantizing
-    in-register after QK^T / P·V — the decode step streams HALF the
-    bf16 pool's HBM bytes and full-width KV never exists.
+    scales), the K/V blocks are int8 codes and each step reads its
+    page's scales (gathered through the SAME table indirection, SMEM
+    scalars), dequantizing in-register after QK^T / P·V — the decode
+    step streams HALF the bf16 pool's HBM bytes and full-width KV never
+    exists.
 
     The pool is addressed in place by (layer, page): given the WHOLE
     ``[L, P, Hkv, page, D]`` pool (scales ``[L, P, Hkv]``) and ``layer``
@@ -327,89 +449,69 @@ def paged_flash_decode(
             q, k_d, v_d, kv_len, sm_scale=sm_scale, return_lse=return_lse
         )
 
-    qg = q.reshape(b, hkv, group, d)
-    # The walk over a sequence's table entries ends at the LONGEST live
-    # sequence's last page (a dynamic grid bound), not at ``pps``: every
-    # grid step fetches its K and V block from HBM whether or not the
-    # body has use for it, and with the pool in HBM that was 130 us a
-    # layer at 4 slots x 8 heads x 32 entries where contexts of a few
-    # hundred tokens need a tenth (PERF.md "PR 27"). The chunks never
-    # walked are masked out of the merge below.
-    live_chunks = jnp.clip(pl.cdiv(jnp.max(kv_len), page), 1, pps)
-    grid = (b, hkv, live_chunks)
-    in_specs = [
-        pl.BlockSpec((1, 1, group, d), lambda b, h, ci, *_: (b, h, 0, 0)),
-        # The paged part: block ci of row b is pool page
-        # table[b, ci].
-        pl.BlockSpec(
-            (1, 1, page, d),
-            lambda b, h, ci, _, tab, *__: (tab[b, ci], h, 0, 0),
-        ),
-        pl.BlockSpec(
-            (1, 1, page, d),
-            lambda b, h, ci, _, tab, *__: (tab[b, ci], h, 0, 0),
-        ),
-    ]
-    scalars = [kv_len, page_table]
+    slot, page_of, steps = (
+        paged_decode_walk(kv_len, page, pps) if walk is None else walk
+    )
+    if slot.shape != (b * pps,) or page_of.shape != (b * pps,):
+        raise ValueError(
+            f"walk of {slot.shape} / {page_of.shape} entries is not "
+            f"paged_decode_walk's for {b} sequences of {pps} pages"
+        )
+    scalars = [kv_len, page_table, slot, page_of]
     if quant:
         # Scales follow their pages through the table HERE, in XLA (a
         # [B, pps, Hkv] gather of f32), and reach the kernel as
-        # flattened SMEM scalars in the dense kernel's
-        # (sequence, head, chunk) order — see flash_decode.
+        # flattened SMEM scalars: a (1, 1, 1) VMEM block of the scale
+        # array is below Mosaic's (8, 128) tile and is refused.
         scalars += [
-            jnp.swapaxes(jnp.take(sc, page_table, axis=0), 1, 2).reshape(-1)
+            jnp.take(sc, page_table, axis=0).reshape(-1)
             for sc in (k_scale, v_scale)
         ]
-    kernel = functools.partial(
-        _paged_decode_kernel_q if quant else _paged_decode_kernel,
-        sm_scale=sm_scale, chunk_k=page, num_chunks=pps,
-    )
-    o_parts, lse_parts = pl.pallas_call(
-        kernel,
+
+    def of_slot(i, _, tab, slot, page_of, *__):
+        return slot[i], 0, 0, 0
+
+    def of_page(i, _, tab, slot, page_of, *__):
+        # The paged part: the pair's block is pool page
+        # table[slot, page_of], all of its heads.
+        return tab[slot[i], page_of[i]], 0, 0, 0
+
+    out_specs = [pl.BlockSpec((1, hkv, group, d), of_slot)]
+    out_shape = [jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype)]
+    if return_lse:
+        out_specs.append(pl.BlockSpec((1, hkv, group, 1), of_slot))
+        out_shape.append(jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel,
+            sm_scale=sm_scale, quant=quant, return_lse=return_lse,
+        ),
         name="tdt_flash_decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec(
-                    (1, 1, 1, group, d), lambda b, h, ci, *_: (b, h, ci, 0, 0)
-                ),
-                pl.BlockSpec(
-                    (1, 1, pps, group), lambda b, h, ci, *_: (b, h, 0, 0)
-                ),
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((1, hkv, group, d), of_slot),
+                pl.BlockSpec((1, hkv, page, d), of_page),
+                pl.BlockSpec((1, hkv, page, d), of_page),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((hkv, group, 1), jnp.float32),
+                pltpu.VMEM((hkv, group, 1), jnp.float32),
+                pltpu.VMEM((hkv, group, d), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, pps, group, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, pps, group), jnp.float32),
-        ],
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=resolved,
-    )(*scalars, qg, k_pages, v_pages)
-
-    # Chunks past the grid's end were never written: weight them 0, as
-    # the body does for the chunks past a sequence's own length.
-    walked = jnp.arange(pps) < live_chunks
-    lse_parts = jnp.where(walked[:, None], lse_parts, _NEG_INF)
-    o_parts = jnp.where(walked[:, None, None], o_parts, 0.0)
-    o, lse = lse_combine(o_parts, lse_parts, part_axis=2)
-    o = o.reshape(b, hq, d).astype(q.dtype)
+    )(*scalars, q.reshape(b, hkv, group, d), k_pages, v_pages)
+    o = out[0].reshape(b, hq, d)
     if return_lse:
-        return o, lse.reshape(b, hq)
+        return o, out[1].reshape(b, hq)
     return o
-
-
-def _paged_decode_kernel(kv_len_ref, table_ref, *args, **kw):
-    del table_ref  # consumed by the BlockSpec index maps
-    return _decode_kernel(kv_len_ref, *args, **kw)
-
-
-def _paged_decode_kernel_q(kv_len_ref, table_ref, *args, **kw):
-    del table_ref  # consumed by the BlockSpec index maps
-    return _decode_kernel_q(kv_len_ref, *args, **kw)
 
 
 def pages_to_dense(

@@ -6,8 +6,9 @@ attention task through a page table.
 
 TPU design: the pool is one array ``[L, P, Hkv, page, hd]``; the page
 table rides as a scalar-prefetch operand and ``paged_flash_decode``'s
-K/V BlockSpec index maps dereference it — block ``ci`` of sequence ``b``
-fetches pool page ``table[b, ci]`` of the layer addressed (the model's
+K/V BlockSpec index maps dereference it — the grid step of sequence
+``b``'s entry ``ci`` fetches pool page ``table[b, ci]``, all of its KV
+heads in one block, of the layer addressed (the model's
 layer scan carries the whole pool and hands the kernel ``layer=``), so
 attention reads the pool in place and NO dense gather or per-layer
 slice ever materializes. Three consumers share
