@@ -105,13 +105,17 @@ def stream_one(host: str, port: int, prompt, gen_len: int, rec: Record, *,
 
 
 def _callers(host, port, reqs, clients: int, end: float, records: list,
-             kw: dict) -> list:
+             kw: dict, start: float | None = None,
+             stagger_s: float = 0.0) -> list:
     """Start ``clients`` threads that each send the deck's next request
-    when their last one ends, until ``end``."""
+    when their last one ends, until ``end``. Caller k sends its first at
+    ``start + k * stagger_s`` (at once where ``start`` is None)."""
     deck = iter(reqs)
     lock = threading.Lock()
 
-    def caller():
+    def caller(k):
+        if start is not None:
+            time.sleep(max(start + k * stagger_s - time.monotonic(), 0))
         while True:
             due = time.monotonic()
             if due >= end:
@@ -124,8 +128,8 @@ def _callers(host, port, reqs, clients: int, end: float, records: list,
                 records.append(rec)
             stream_one(host, port, r.prompt, r.gen_len, rec, **kw)
 
-    threads = [threading.Thread(target=caller, daemon=True)
-               for _ in range(clients)]
+    threads = [threading.Thread(target=caller, args=(k,), daemon=True)
+               for k in range(clients)]
     for th in threads:
         th.start()
     return threads
@@ -163,9 +167,9 @@ def drive(host: str, port: int, reqs: list, traffic: dict, seconds: float,
             th.start()
             threads.append(th)
     else:
-        time.sleep(max(t0 - time.monotonic(), 0))  # callers start together
         threads = _callers(host, port, reqs, int(traffic["clients"]),
-                           t0 + seconds, records, kw)
+                           t0 + seconds, records, kw, start=t0,
+                           stagger_s=float(traffic.get("stagger_ms", 0)) / 1e3)
     deadline = t0 + seconds + drain_s
     for th in threads:
         th.join(max(deadline - time.monotonic(), 0.0))

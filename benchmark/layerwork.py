@@ -4,7 +4,7 @@ by the per-layer readers, so that they all count the same work."""
 
 from __future__ import annotations
 
-from benchmark import server, work, xplane
+from benchmark import server, xplane
 
 GENERATED = "tdt_engine_generated_tokens_total"
 DECODE_STEPS = "tdt_engine_decode_steps_total"
@@ -73,8 +73,9 @@ def prefill_work(ctx: dict) -> tuple | None:
 
 
 def flops(ctx: dict) -> float | None:
-    """FLOPs the traced span's prefill and decoded tokens require."""
-    config = ctx["cell"].config
+    """FLOPs the traced span's prefill and decoded tokens require, by
+    the configuration's own work counts."""
+    config, work = ctx["cell"].config, ctx["cell"].work
     dec, pre = decode_work(ctx), prefill_work(ctx)
     if dec is None and pre is None:
         return None

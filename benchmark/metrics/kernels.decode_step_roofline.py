@@ -3,7 +3,7 @@
 memory bandwidth; FLOPs over peak; the larger) over the device time of
 the decode-step programs. Layer: kernels."""
 
-from benchmark import layerwork, work
+from benchmark import layerwork
 
 DECODE_PROGRAM = r"decode"
 
@@ -18,7 +18,7 @@ def read(ctx):
     # fewer at its edges: the work of as many steps as were timed.
     per_step = 1.0 / dec[0]
     n = len(steps)
-    least, _bound = work.decode_least_seconds(
+    least, _bound = ctx["cell"].work.decode_least_seconds(
         ctx["cell"].config, n, int(dec[1] * per_step * n),
         int(dec[2] * per_step * n), ctx["peak"], ctx["chips"])
     return 100.0 * least / seconds

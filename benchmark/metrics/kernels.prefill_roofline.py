@@ -2,7 +2,7 @@
 prefilled in the traced span over peak (or their bytes over bandwidth,
 if larger) over those programs' device time. Layer: kernels."""
 
-from benchmark import layerwork, work
+from benchmark import layerwork
 
 PREFILL_PROGRAM = r"prefill"
 
@@ -14,6 +14,6 @@ def read(ctx):
     seconds = sum(d for _, d in chunks) / 1e9
     if pre is None or seconds <= 0:
         return None
-    least, _bound = work.prefill_least_seconds(
+    least, _bound = ctx["cell"].work.prefill_least_seconds(
         ctx["cell"].config, pre[0], pre[1], ctx["peak"], ctx["chips"])
     return 100.0 * least / seconds

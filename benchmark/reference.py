@@ -20,6 +20,25 @@ token predict itself).
 It runs after the window, layer by layer over the sampled requests, on
 all the chips the cell has: weights are sharded over a 1-D mesh by
 ``jax.jit``'s own partitioner, activations replicated.
+
+The contract a configuration's reference module keeps (its file names
+the module under ``"reference"``; the harness calls these two and
+nothing else, and hands ``weights`` from the first to the second
+unopened):
+
+``make_weights(config, seed, devices)``
+    the seed's weights, made on ``devices`` in the dtype they are served
+    in; ``config`` is the configuration file as a dict.
+``judge(config, weights, samples, pad_to, rows_pad, *, block=None,
+control=None, per_token=False)``
+    the reference once over each ``(prompt, served tokens)`` pair of
+    ``samples``, ``block`` pairs to a forward pass padded to ``pad_to``
+    positions and ``rows_pad`` served tokens. Returns a dict with the
+    numbers the cell's traffic file may limit (``logit_gap_max``,
+    ``logit_gap_mean``) and ``tokens_compared``; with ``control`` the
+    same numbers for the picks of the reference computed in that lower
+    precision, under ``"control"``; with ``per_token`` every token's
+    reading under ``"tokens"``.
 """
 
 from __future__ import annotations
@@ -47,8 +66,11 @@ class Dims:
     dtype: str
 
     @classmethod
-    def of(cls, config: dict) -> "Dims":
-        """From a configuration file's published keys."""
+    def of(cls, config) -> "Dims":
+        """From a configuration file's published keys (or the ``Dims``
+        themselves)."""
+        if isinstance(config, cls):
+            return config
         return cls(
             vocab=config["vocab_size"], d=config["hidden_size"],
             ffn=config["intermediate_size"],
@@ -84,13 +106,15 @@ def mesh_of(devices) -> Mesh:
     return Mesh(np.asarray(devices), ("r",))
 
 
-def make_weights(m: Dims, seed: int, devices) -> dict:
-    """The seed's weights, each made on the devices in one jitted call
-    in the dtype they are served in."""
+def draw(layout: dict, n_keys: int, dtype: str, seed: int, devices) -> dict:
+    """Tensors of ``layout`` (name -> key index, shape, scale or None for
+    ``fan_in ** -0.5``, sharded axis or None) from ``n_keys`` splits of
+    the seed's key, each made on the devices in one jitted call in
+    ``dtype``."""
     mesh = mesh_of(devices)
-    keys = jax.random.split(jax.random.key(seed), 9)
+    keys = jax.random.split(jax.random.key(seed), n_keys)
     out = {}
-    for name, (ki, shape, scale, axis) in _layout(m).items():
+    for name, (ki, shape, scale, axis) in layout.items():
         if axis is not None and shape[axis] % len(devices):
             axis = None
         spec = [None] * len(shape)
@@ -102,10 +126,17 @@ def make_weights(m: Dims, seed: int, devices) -> dict:
                            out_shardings=NamedSharding(mesh, P(*spec)))
         def rnd(k, shape, s):
             return (jax.random.normal(k, shape, jnp.float32) * s).astype(
-                m.dtype)
+                dtype)
 
         out[name] = rnd(keys[ki], shape, s)
     return out
+
+
+def make_weights(config, seed: int, devices) -> dict:
+    """The seed's weights, each made on the devices in one jitted call
+    in the dtype they are served in."""
+    m = Dims.of(config)
+    return draw(_layout(m), 9, m.dtype, seed, devices)
 
 
 def _rms(x, eps):
@@ -174,8 +205,10 @@ def _attend(m: Dims, q, k, v):
     return jax.lax.map(block, (blocks, starts)).reshape(S, m.hq, m.hd)
 
 
-def _layer(m: Dims, mode: str, x, lw):
-    """One decoder layer over whole sequences: x [B, S, d] float32."""
+def attention(m, mode: str, x, lw):
+    """The attention half of a decoder layer over whole sequences, with
+    its residual: x [B, S, d] float32. ``m`` gives hq, hkv, hd, eps and
+    theta; ``lw`` the layer's wq, wk, wv and wo."""
     B, S, _ = x.shape
     h = _rms(x, m.eps)  # norm scales are all one
     q = _mm(h, lw["wq"], mode).reshape(B, S, m.hq, m.hd)
@@ -189,7 +222,12 @@ def _layer(m: Dims, mode: str, x, lw):
         vq, vs = _q8(v, -1)
         k, v = kq.astype(jnp.float32) * ks, vq.astype(jnp.float32) * vs
     o = jax.lax.map(lambda qkv: _attend(m, *qkv), (q, k, v))
-    x = x + _mm(o.reshape(B, S, m.hq * m.hd), lw["wo"], mode)
+    return x + _mm(o.reshape(B, S, m.hq * m.hd), lw["wo"], mode)
+
+
+def _layer(m: Dims, mode: str, x, lw):
+    """One decoder layer over whole sequences: x [B, S, d] float32."""
+    x = attention(m, mode, x, lw)
     h = _rms(x, m.eps)
     act = jax.nn.silu(_mm(h, lw["gate"], mode)) * _mm(h, lw["up"], mode)
     return x + _mm(act, lw["w2"], mode)
@@ -242,7 +280,7 @@ def _gaps(logits, served):
             top2[:, 0] - top2[:, 1], jnp.max(jnp.abs(logits)))
 
 
-def _judge_block(m, weights, samples, n_seqs, pad_to, rows_pad, control):
+def _judge_block(forward, samples, n_seqs, pad_to, rows_pad, control):
     """One forward pass over at most ``n_seqs`` sampled requests, padded
     to fixed shapes. Per served token: its gap, whether it is the
     reference's best, the reference's lead; with ``control`` also the gap
@@ -265,15 +303,14 @@ def _judge_block(m, weights, samples, n_seqs, pad_to, rows_pad, control):
     rows_a = np.asarray(rows + [0] * pad, np.int32)
     cols_a = np.asarray(cols + [0] * pad, np.int32)
     served_a = jnp.asarray(served + [0] * pad, jnp.int32)
-    logits = forward_logits(m, weights, tokens, rows_a, cols_a)
+    logits = forward(tokens, rows_a, cols_a, "f32")
     gap, best, lead, top = _gaps(logits, served_a)
     out = {"gap": np.asarray(gap)[:n],
            "is_best": np.asarray(best)[:n] == np.asarray(served),
            "lead": np.asarray(lead)[:n], "row": np.asarray(rows),
            "col": np.asarray(cols), "absmax": float(top)}
     if control:
-        low = forward_logits(m, weights, tokens, rows_a, cols_a,
-                             mode=control)
+        low = forward(tokens, rows_a, cols_a, control)
         low_best = jnp.argmax(low, axis=-1)
         out["control_gap"] = np.asarray(_gaps(logits, low_best)[0])[:n]
     return out
@@ -288,20 +325,34 @@ def readings(gap: np.ndarray) -> dict:
             "not_best_share": float((gap > 0).mean())}
 
 
-def judge(m: Dims, weights: dict, samples: list, pad_to: int,
-          rows_pad: int, *, block: int | None = None,
-          control: str | None = None, per_token: bool = False) -> dict:
+def judge(config, weights: dict, samples: list, pad_to: int,
+          rows_pad: int, **kw) -> dict:
+    """``judge_with`` this module's forward pass over ``weights``."""
+    m = Dims.of(config)
+    return judge_with(
+        lambda tokens, rows, cols, mode: forward_logits(
+            m, weights, tokens, rows, cols, mode=mode),
+        samples, pad_to, rows_pad, **kw)
+
+
+def judge_with(forward, samples: list, pad_to: int, rows_pad: int, *,
+               block: int | None = None, control: str | None = None,
+               per_token: bool = False) -> dict:
     """Run the reference once over each sampled prompt with its served
     tokens, ``block`` requests to a forward pass (all in one where None;
     ``rows_pad`` is a block's room for served tokens). ``samples`` is a
-    list of ``(prompt, served)``. Returns the widest and mean gap by
+    list of ``(prompt, served)``; ``forward(tokens [B, S], rows [N],
+    cols [N], mode)`` gives the logits [N, V] at positions ``(rows[n],
+    cols[n])`` in precision ``mode`` (``f32``, or the control's): the
+    one thing an architecture's reference brings to the comparison.
+    Returns the widest and mean gap by
     which a served token's logit lies below the reference's best and the
     share of served tokens that are not its best; with ``control``
     (``int8``) also those of the tokens which the reference in that
     precision puts first at the same positions, under ``control``; with
     ``per_token`` every token's reading, under ``tokens``."""
     block = block or len(samples)
-    parts = [_judge_block(m, weights, samples[i: i + block], block, pad_to,
+    parts = [_judge_block(forward, samples[i: i + block], block, pad_to,
                           rows_pad, control)
              for i in range(0, len(samples), block)]
     gap = np.concatenate([p["gap"] for p in parts])

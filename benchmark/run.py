@@ -177,14 +177,13 @@ def decide(read: dict, limits: dict) -> tuple:
 
 def check_outputs(cell, sample, weights, control=None,
                   per_token=False) -> dict:
-    """The reference over every finished request of the window."""
-    from benchmark import reference
-
-    dims = reference.Dims.of(cell.config)
+    """The configuration's reference over every finished request of the
+    window."""
     block, pad_to, rows_pad = pad_sizes(cell)
     t = time.monotonic()
-    read = reference.judge(dims, weights, sample, pad_to, rows_pad,
-                           block=block, control=control, per_token=per_token)
+    read = cell.reference.judge(cell.config, weights, sample, pad_to,
+                                rows_pad, block=block, control=control,
+                                per_token=per_token)
     read["reference_s"] = time.monotonic() - t
     return read
 
@@ -192,10 +191,8 @@ def check_outputs(cell, sample, weights, control=None,
 def reference_weights(cell, weight_seed: int):
     import jax
 
-    from benchmark import reference
-
-    return reference.make_weights(reference.Dims.of(cell.config),
-                                  weight_seed, jax.devices()[:cell.chips])
+    return cell.reference.make_weights(cell.config, weight_seed,
+                                       jax.devices()[:cell.chips])
 
 
 def window(host, port, cell, reqs, seconds, compiles, trace_dir=None) -> dict:
@@ -286,6 +283,15 @@ def run(args) -> int:
         compiled=ctx["compiled_names"],
         **stats.summary(records, t0, args.seconds),
         **{k: v for k, v in e2e.items()})
+    # What the scheduler's simulation (benchmark/sim.py) is held against:
+    # when each request was due, got its first token and ended, in ms
+    # from the window's start.
+    say(phase="requests", columns=["i", "due", "ttft", "done", "prompt",
+                                   "output"],
+        rows=[[r.i, round((r.due - t0) * 1e3, 1),
+               round((r.token_ts[0] - r.due) * 1e3, 1) if r.token_ts else None,
+               round((r.done - t0) * 1e3, 1) if r.done else None,
+               r.prompt_len, r.gen_len] for r in records])
     sample = finished(records, reqs)
     attempted = len(records)
     failed = sum(not r.ok for r in records)

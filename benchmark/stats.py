@@ -68,7 +68,9 @@ def end_to_end(records, t0: float, seconds: float) -> dict:
            tokens_in_window(records, t0, t0 + seconds) / seconds}
     if tt:
         out["ttft_p50_ms"] = percentile(tt, 50) * 1e3
+        out["ttft_p75_ms"] = percentile(tt, 75) * 1e3
         out["ttft_p90_ms"] = percentile(tt, 90) * 1e3
+        out["ttft_mean_ms"] = sum(tt) / len(tt) * 1e3
     if gaps:
         out["token_gap_p99_ms"] = percentile(gaps, 99) * 1e3
         out["token_gap_p95_ms"] = percentile(gaps, 95) * 1e3

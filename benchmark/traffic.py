@@ -18,6 +18,11 @@ Parameters a cell file may give (all data; a new mix is a new file):
 ``burst_size``      open loop: arrivals come ``burst_size`` at a time,
                     at the same mean rate (1 = Poisson)
 ``clients``         closed loop: callers
+``stagger_ms``      closed loop: caller k sends its first request
+                    ``k * stagger_ms`` after the window's start
+                    (default 0: together). Callers that start together
+                    race for the first batch, which takes the 1 to 4 of
+                    them it finds
 ``deck``            closed loop: requests prepared (more than a window
                     can finish)
 ``block``           requests to a block (default: all in one). Sizes and

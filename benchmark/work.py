@@ -1,6 +1,19 @@
 """Operations and bytes the model's arithmetic requires, from its
 shapes alone. Kept with the benchmark: no program code is asked how
-much work it did, only how many steps and tokens it ran."""
+much work it did, only how many steps and tokens it ran.
+
+These are the counts of the dense GQA decoder. The contract a
+configuration's work module keeps (its file names the module under
+``"work"``; ``layerwork.flops`` and the roofline readers call these four
+and nothing else, ``config`` being the configuration file as a dict and
+``peak`` a ``peaks.Peak``):
+
+``decode_flops(config, tokens, context_sum)``
+``prefill_flops(config, prompt_lens)``
+``decode_least_seconds(config, steps, tokens, context_sum, peak, chips=1)``
+``prefill_least_seconds(config, prompt_lens, chunks, peak, chips=1)``
+    the last two return ``(seconds, "memory" | "compute")``.
+"""
 
 from __future__ import annotations
 

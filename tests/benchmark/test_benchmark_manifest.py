@@ -89,3 +89,14 @@ def test_every_cell_reports_setup_another_metric_and_a_layer(manifest):
         e2e = [m["name"] for m in cell.end_to_end]
         assert "setup_s" in e2e and len(e2e) >= 2
         assert cell.per_layer
+
+
+def test_every_per_layer_metric_moves_a_metric_its_cells_report(manifest):
+    for w in manifest["workloads"]:
+        cell = cells.load_cell(w["name"])
+        reported = {m["name"] for m in cell.end_to_end}
+        for m in cell.per_layer:
+            assert m["moves"] in reported, (w["name"], m["name"], m["moves"])
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        assert set(m.get("workloads", [])) <= {
+            w["name"] for w in manifest["workloads"]}

@@ -6,16 +6,18 @@ import os
 
 import pytest
 
-from benchmark import cells, peaks, server, xplane
+from benchmark import cells, layerwork, peaks, server, xplane
 from benchmark.stats import Record
 
 MS = 1e6
 CONFIG = cells.load_json(os.path.join(cells.HERE, "configs", "qwen3-4b.json"))
 
 
-def ctx_of(trace=True):
+def ctx_of(trace=True, config=CONFIG):
     cell = cells.Cell(name="x", chips=1, config_name="qwen3-4b",
-                      config=CONFIG, traffic={}, end_to_end=[], per_layer=[])
+                      config=config, traffic={}, end_to_end=[], per_layer=[],
+                      work=cells.load_module(config["work"],
+                                             ["benchmark", "tests/benchmark"]))
     # Two requests decode together for 100 steps inside the traced span;
     # one of them was prefilled (512 tokens) inside it too.
     recs = []
@@ -90,6 +92,48 @@ def test_readers_on_the_handmade_run():
     assert 0 < pre < 100
     mfu = read("model.step_mfu", ctx)
     assert 0 < mfu < 5
+
+
+def test_the_work_readers_count_by_the_configurations_own_module():
+    """The same run read through another architecture's work counts (the
+    tests' sparse-expert ones): the readers keep their arithmetic and
+    divide by that module's numbers."""
+    here = os.path.dirname(__file__)
+    moe = cells.load_json(os.path.join(here, "data", "tiny-moe.config.json"))
+    ctx, dense = ctx_of(config=moe), ctx_of()
+    work = ctx["cell"].work
+    assert work.__file__.endswith("tiny_moe_work.py")
+    steps, tokens, context_sum = layerwork.decode_work(ctx)
+    assert (steps, tokens) == (100, 200)  # 201 tokens less one first token
+    least, bound = work.decode_least_seconds(moe, steps, tokens, context_sum,
+                                             ctx["peak"], 1)
+    assert bound == "memory"
+    got = read("kernels.decode_step_roofline", ctx)
+    assert got == pytest.approx(100 * least / 2.0)  # 100 steps of 20 ms
+    assert got < read("kernels.decode_step_roofline", dense) / 1e3
+    flops = (work.decode_flops(moe, tokens, context_sum)
+             + work.prefill_flops(moe, [512]))
+    assert read("model.step_mfu", ctx) == pytest.approx(
+        100 * flops / (3.1 * 197e12))
+
+
+@pytest.mark.parametrize("name", [
+    "entry.shed_share", "scheduler.queue_wait_p50_ms",
+    "scheduler.batch_occupancy", "scheduler.batch_wait_p50_ms"])
+def test_a_split_reader_reads_what_its_twin_reads(name):
+    """`<name>.open` is the same reading under the name that an open-loop
+    cell reports (it moves another end-to-end metric there)."""
+    ctx = ctx_of()
+    ctx["counters_window_1"] = dict(
+        ctx["counters_window_1"], tdt_request_batch_wait_seconds=ctx[
+            "counters_window_1"]["tdt_request_queue_wait_seconds"])
+    ctx["counters_window_0"] = dict(
+        ctx["counters_window_0"], tdt_request_batch_wait_seconds=ctx[
+            "counters_window_0"]["tdt_request_queue_wait_seconds"])
+    got = read(name + ".open", ctx)
+    assert got is not None and got == read(name, ctx)
+    ctx["counters_window_1"] = dict(ctx["counters_window_0"])
+    assert read(name + ".open", ctx) is None
 
 
 def test_readers_return_nothing_without_their_source():

@@ -49,6 +49,29 @@ def test_weights_are_the_programs_bit_for_bit(tiny_model):
         assert np.all(np.asarray(ones) == 1.0)  # the reference skips them
 
 
+def test_sparse_weights_are_the_programs_bit_for_bit(tiny_model):
+    """The second architecture's reference (`data/tiny_moe_reference.py`,
+    loaded as the harness loads it) draws the program's `tiny-moe`
+    weights from the seed: router and experts too."""
+    from benchmark import cells
+    from triton_distributed_tpu.models import AutoLLM
+
+    config = cells.load_json(os.path.join(DATA, "tiny-moe.config.json"))
+    moe = cells.load_module(config["reference"], ["tests/benchmark"])
+    p = AutoLLM.from_pretrained("tiny-moe", ctx=tiny_model.ctx,
+                                seed=SEED % (2**31 - 1)).params
+    w = moe.make_weights(config, SEED % (2**31 - 1), jax.devices()[:1])
+    qkv = np.concatenate([w["wq"], w["wk"], w["wv"]], axis=2)
+    assert np.array_equal(qkv, p.layers.attn.wqkv)
+    assert np.array_equal(np.concatenate([w["gate"], w["up"]], axis=3),
+                          p.layers.mlp.w1)
+    for mine, theirs in ((w["wo"], p.layers.attn.wo),
+                         (w["router"], p.layers.mlp.w_router),
+                         (w["w2"], p.layers.mlp.w2), (w["embed"], p.embed),
+                         (w["lm_head"], p.lm_head[:, :256])):
+        assert np.array_equal(mine, theirs)
+
+
 def test_prefill_then_decode_through_the_paged_cache_agrees(tiny_model):
     """Paged chunked prefill of a 40-token prompt (three chunks, the last
     ragged), then five teacher-forced paged decode steps, against ONE

@@ -55,6 +55,38 @@ def test_window_rate_counts_tokens_received_inside_the_window():
     assert e["token_gap_p99_ms"] == pytest.approx(50.0)
 
 
+def test_the_four_ttft_statistics():
+    recs = [rec(i, 0.0, w, 2, 0.05) for i, w in
+            enumerate((0.1, 0.2, 0.3, 0.4, 2.0))]
+    e = stats.end_to_end(recs, 0.0, 10.0)
+    assert e["ttft_p50_ms"] == pytest.approx(300.0)
+    assert e["ttft_p75_ms"] == pytest.approx(400.0)
+    assert e["ttft_p90_ms"] == pytest.approx(400.0 + 0.6 * 1600.0)
+    assert e["ttft_mean_ms"] == pytest.approx(600.0)
+    recs[4].status = "failed:no_summary_before_drain_limit"
+    e = stats.end_to_end(recs, 0.0, 10.0)  # a failed request gives none
+    assert e["ttft_mean_ms"] == pytest.approx(250.0)
+    assert e["ttft_p75_ms"] == pytest.approx(325.0)
+
+
+def test_one_arrival_across_a_batch_end_moves_the_mean_by_its_share():
+    """Why a cell may bound the mean where a percentile is a coin: 40
+    requests, 30 served at once (60 ms) and 10 that waited for a batch
+    (0.5-1.4 s). The 30th by rank arrives just before or just after a
+    batch's end: 60 ms or 1.2 s."""
+    waits = [0.06] * 29 + [0.5 + 0.1 * k for k in range(10)]
+    before = stats.end_to_end(
+        [rec(i, 0.0, w, 2, 0.05) for i, w in enumerate(waits + [0.06])],
+        0.0, 10.0)
+    after = stats.end_to_end(
+        [rec(i, 0.0, w, 2, 0.05) for i, w in enumerate(waits + [1.2])],
+        0.0, 10.0)
+    assert after["ttft_p75_ms"] > 3 * before["ttft_p75_ms"]
+    assert after["ttft_mean_ms"] / before["ttft_mean_ms"] == pytest.approx(
+        1 + 1.14 / sum(waits + [0.06]), rel=1e-6)
+    assert after["ttft_mean_ms"] < 1.11 * before["ttft_mean_ms"]
+
+
 def test_failed_requests_count_and_give_no_latency():
     recs = steady()
     recs[3].status = "failed:retries_exhausted"

@@ -134,3 +134,35 @@ def test_every_committed_cell_file_generates():
         reqs = traffic.generate(spec, 2**31 + 5, 40, 151936)
         assert reqs, path
         assert traffic.histogram(reqs)["n"] == len(reqs)
+
+
+@pytest.mark.parametrize("stagger_ms", [None, 50])
+def test_closed_loop_callers_start_stagger_ms_apart(monkeypatch, stagger_ms):
+    """`stagger_ms` is a traffic parameter of the closed loop: caller k
+    sends its first request k * stagger_ms after the window's start, and
+    is timed from then; without it all start together."""
+    import time
+
+    from benchmark import client
+
+    def served(host, port, prompt, gen_len, rec, **kw):
+        time.sleep(0.4)
+        rec.status, rec.done = "ok", time.monotonic()
+        return rec
+
+    monkeypatch.setattr(client, "stream_one", served)
+    spec = {"loop": "closed", "clients": 4, "deck": 8,
+            "classes": [{"share": 1.0,
+                         "prompt": {"dist": "uniform", "min": 4, "max": 8},
+                         "output": {"dist": "uniform", "min": 2, "max": 4}}]}
+    if stagger_ms is not None:
+        spec["stagger_ms"] = stagger_ms
+    reqs = traffic.generate(spec, 5, 1.0, 100)
+    t0 = time.monotonic() + 0.05
+    recs = client.drive("h", 0, reqs, spec, 0.3, t0)
+    assert len(recs) == 4 and all(r.ok for r in recs)  # the window closed
+    starts = sorted(r.due - t0 for r in recs)
+    want = [k * (stagger_ms or 0) / 1e3 for k in range(4)]
+    assert starts == pytest.approx(want, abs=0.02)
+    assert [r.i for r in sorted(recs, key=lambda r: r.due)] == (
+        [0, 1, 2, 3] if stagger_ms else sorted(r.i for r in recs))
