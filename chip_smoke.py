@@ -52,6 +52,14 @@ MODES = {
     "mega": ["--mode", "mega"],
     "mega-resident": ["--mode", "mega", "--resident"],
 }
+# One chip's share of dots.vlm1.inst's language model (latent attention,
+# 16 of 256 experts held), as the benchmark's cell serves it: the
+# `latent` phase (``--modes latent``; not in a default run).
+LATENT = {
+    "model": "rednote-hilab/dots.vlm1.inst", "slots": 32,
+    "cut": ["--num-layers", "5", "--first-k-dense", "1",
+            "--experts-held", "16", "--vocab-rows", "16160"],
+}
 ONE_CHIP_MODEL = "Qwen/Qwen3-4B"   # largest dense preset one 16 GB chip holds
 FOUR_CHIP_MODEL = "Qwen/Qwen3-8B"  # 16.4 GB bf16: the preset that needs four
 
@@ -375,6 +383,86 @@ def kernel_checks(cfg) -> None:
         require(err <= tol, f"{name}: max abs err {err} > {tol}")
 
 
+def latent_phase() -> None:
+    """The cut latent-attention preset: ``tdt_mla_decode_paged`` against
+    the plain absorbed formula at the preset's widths (all slots, uneven
+    contexts over a shuffled table), then one server lifetime at
+    ``LATENT["slots"]`` decode slots: as many requests as slots, a
+    repeat that hits the radix tree, stats and audit."""
+    from triton_distributed_tpu.models.config import get_config
+    from triton_distributed_tpu.ops.attention.mla_decode import (
+        mla_decode_reference,
+        mla_paged_decode,
+    )
+    from triton_distributed_tpu.runtime import mesh
+    from triton_distributed_tpu.serving.run_server import resolve_model_args
+
+    name, overrides = resolve_model_args(
+        LATENT["model"], **{
+            k[2:].replace("-", "_"): int(v) for k, v in zip(
+                LATENT["cut"][::2], LATENT["cut"][1::2])})
+    cfg = get_config(name, **overrides)
+    dt, b = cfg.dtype, LATENT["slots"]
+    page, pps = page_of(cfg), 4
+    n_pages = b * pps + 1
+    ks = iter(jax.random.split(jax.random.key(SEED), 4))
+
+    def rnd(*shape):
+        return jax.random.normal(next(ks), shape, jnp.float32).astype(dt)
+
+    h, rank, rope = cfg.num_q_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    q_lat, q_rope = rnd(b, h, rank), rnd(b, h, rope)
+    c_pages, r_pages = rnd(n_pages, 1, page, rank), rnd(n_pages, 1, rope, page)
+    rng = np.random.default_rng(SEED)
+    table = jnp.asarray(rng.permutation(np.arange(1, n_pages))
+                        .reshape(b, pps).astype(np.int32))
+    kv_len = jnp.asarray(
+        [1, page, page + 3, pps * page]
+        + rng.integers(1, pps * page + 1, size=b - 4).tolist(), jnp.int32)
+    scale = (cfg.qk_nope_head_dim + rope) ** -0.5
+    err = max_abs(
+        mla_paged_decode(q_lat, q_rope, c_pages, r_pages, table, kv_len,
+                         sm_scale=scale),
+        mla_decode_reference(q_lat, q_rope, c_pages, r_pages, table, kv_len,
+                             sm_scale=scale))
+    tol = KERNEL_TOL[jnp.dtype(dt).name]
+    emit(phase="latent_kernel", heads=[h, rank, rope], slots=b,
+         dtype=jnp.dtype(dt).name, tolerance=tol,
+         max_abs_err={"mla_decode_paged": err})
+    require(err <= tol, f"mla_decode_paged: max abs err {err} > {tol}")
+
+    unit = cfg.max_length // 16
+    prompts = [rng.integers(0, cfg.vocab_size, size=unit + 7 * i).tolist()
+               for i in range(b)]
+    payload = {"requests": prompts, "gen_lens": [4 + i % 5 for i in range(b)]}
+    with running_server(["--model", LATENT["model"], "--continuous",
+                         "--max-batch", str(b), *LATENT["cut"]]) as (
+                             ask, start_s):
+        check_on_chip(mesh.current_context())
+        cold, first_s = ask(payload)
+        n_tokens = check_response(cold, payload, cfg.vocab_size)
+        warm, warm_s = ask(payload)
+        n_tokens += check_response(warm, payload, cfg.vocab_size)
+        stats = ask({"cmd": "stats"})[0]["stats"]
+        require(stats["prefix_hit_tokens"] > 0, "no radix hit on the repeat")
+        require(stats["moe_decode_local_rows"] > 0
+                and stats["moe_decode_experts_touched"] > 0,
+                f"the expert share counted nothing: {stats}")
+        problems = ask({"cmd": "audit"})[0]["problems"]
+        require(problems == [], f"audit: {problems}")
+    emit(phase="latent_serve", model=LATENT["model"], cut=LATENT["cut"],
+         slots=b, listening_after_s=round(start_s, 2),
+         first_response_s_with_compile=round(first_s, 2),
+         warm_repeat_s=round(warm_s, 2), tokens_generated=n_tokens,
+         decode_steps=stats["decode_steps"],
+         kv_bytes_per_token=stats["kv_bytes_per_token"],
+         local_rows=stats["moe_decode_local_rows"],
+         experts_touched=stats["moe_decode_experts_touched"],
+         warm_repeat_reproduced_cold_tokens=(
+             warm["outputs"] == cold["outputs"]),
+         peak_bytes_in_use=peak_bytes())
+
+
 def paged_logits(model, prompt, forced, mode: str, kv_dtype=None):
     """Logits ``[1 + len(forced), V]``: the prompt's last position
     through paged chunked prefill, then each teacher-forced token
@@ -676,13 +764,15 @@ def main(argv=None) -> int:
                    f"{FOUR_CHIP_MODEL} with --chips 4)")
     p.add_argument("--modes", default=",".join(MODES),
                    help="comma-separated serving phases to run, of "
-                   f"{list(MODES)} (one chip only)")
+                   f"{list(MODES)} (one chip only), or 'latent': the cut "
+                   "latent-attention preset at 32 slots and its kernel")
     p.add_argument("--chips", type=int, default=1, choices=[1, 4])
     args = p.parse_args(argv)
     modes = [m for m in args.modes.split(",") if m]
     for m in modes:
-        if m not in MODES:
-            p.error(f"unknown mode {m!r}; choose from {list(MODES)}")
+        if m not in MODES and m != "latent":
+            p.error(f"unknown mode {m!r}; choose from "
+                    f"{[*MODES, 'latent']}")
 
     # Fail, never hang: past the limit every thread's stack is dumped
     # and the process exits non-zero.
@@ -727,11 +817,15 @@ def run(chips: int, model: str | None, modes: list[str]) -> dict:
         replica_check(model or ONE_CHIP_MODEL, 4)
     else:
         for mode in modes:
-            serve_phase(model or ONE_CHIP_MODEL, mode)
+            if mode == "latent":
+                latent_phase()
+            else:
+                serve_phase(model or ONE_CHIP_MODEL, mode)
             # The phase's model must be gone before the next is built:
             # the chip does not hold two.
             gc.collect()
-        logit_checks(model or ONE_CHIP_MODEL)
+        if modes != ["latent"]:
+            logit_checks(model or ONE_CHIP_MODEL)
     return facts
 
 

@@ -226,6 +226,119 @@ def test_served_step_addresses_pool_in_place(chip, qwen3_4b, program, slots,
     assert not _pool_shaped_moves(text, pool_shape, kv)
 
 
+# dots.vlm1.inst's language model cut to one chip's share, as the cell
+# `dots-vlm1-ep16.docs-closed` serves it (benchmark/configs).
+DOTS_CUT = dict(num_layers=5, first_k_dense=1, experts_held=16,
+                vocab_size=16160)
+DOTS_SLOTS = 32
+
+
+def test_mla_paged_decode_kernel(chip):
+    """``tdt_mla_decode_paged`` at the published widths: 128 heads over
+    a latent row of 512 + 64, page 128, the whole five-layer pool and a
+    traced layer index."""
+    from triton_distributed_tpu.ops.attention.mla_decode import (
+        mla_paged_decode,
+    )
+
+    pages = DOTS_SLOTS * PPS + 1
+    text = compile_for_chip(
+        lambda ql, qr, c, r, t, n, l: mla_paged_decode(
+            ql, qr, c, r, t, n, sm_scale=0.1352, layer=l),
+        sds(chip, (DOTS_SLOTS, 128, 512), BF16),
+        sds(chip, (DOTS_SLOTS, 128, 64), BF16),
+        sds(chip, (5, pages, 1, PAGE, 512), BF16),
+        sds(chip, (5, pages, 1, 64, PAGE), BF16),
+        sds(chip, (DOTS_SLOTS, PPS), jnp.int32),
+        sds(chip, (DOTS_SLOTS,), jnp.int32), sds(chip, (), jnp.int32))
+    assert "tdt_mla_decode_paged" in text and "tpu_custom_call" in text
+
+
+@pytest.fixture
+def dots_share(chip):
+    """The cut preset on the described chip, parameter shapes only."""
+    from triton_distributed_tpu.models.config import get_config
+    from triton_distributed_tpu.models.latent_moe import LatentMoE
+
+    model = LatentMoE(
+        get_config("rednote-hilab/dots.vlm1.inst", **DOTS_CUT), ctx=chip)
+    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
+    model.params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, model.param_shardings,
+    )
+    return model
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk3584", "weights"])
+def test_latent_share_fits_the_chip(chip, dots_share, program):
+    """The 32-slot decode step, the widest chunk the cell sends (3,584
+    tokens over the slot's 32 pages) and the weight-init program, at the
+    cell's shapes: under the v5e's 15.75 GB, the latent pool carried in
+    place (its rotary part transposed, the page axis on the lanes: 1,152
+    bytes a token a layer in HBM, none of it padding)."""
+    from triton_distributed_tpu.models.paged_kv_cache import (
+        PagedKVCache,
+        paged_cache_specs,
+    )
+
+    model = dots_share
+    pages = DOTS_SLOTS * PPS + 1
+    lat = sds(chip, (5, pages, 1, PAGE, 512), BF16)
+    rot = sds(chip, (5, pages, 1, 64, PAGE), BF16)
+    cache = PagedKVCache(
+        k_pages=lat, v_pages=rot,
+        page_table=sds(chip, (DOTS_SLOTS, PPS), jnp.int32),
+        kv_len=sds(chip, (DOTS_SLOTS,), jnp.int32),
+    )
+    i32 = sds(chip, (), jnp.int32)
+    donate = (2,)
+    if program == "decode":
+        fn = model.decode_fn_paged("xla")
+        args = (model.params, sds(chip, (DOTS_SLOTS,), jnp.int32), cache)
+    elif program == "chunk3584":
+        specs = paged_cache_specs("tp")
+        fn = chip.shard_map(
+            functools.partial(model._prefill_chunk_shard, mode="xla",
+                              kv_pages=PPS),
+            in_specs=(model.param_specs, P(), specs, P(), P(), P(), P()),
+            out_specs=(P(), specs),
+        )
+        args = (model.params, sds(chip, (3584,), jnp.int32), cache,
+                i32, i32, i32, i32)
+    else:
+        # One program a tensor (LatentMoE.init_params): what is built so
+        # far plus the next tensor's temporaries never passes the chip.
+        from triton_distributed_tpu.models.latent_moe import (
+            tdt_draw_weights,
+            weight_layout,
+        )
+
+        built = peak = 0
+        for name, lead, mat, scale in weight_layout(model.cfg):
+            keys = jax.eval_shape(
+                lambda: jax.random.split(jax.random.key(0), math.prod(lead)))
+            mem = tdt_draw_weights.lower(
+                keys, lead, mat, scale or mat[-2] ** -0.5, "bfloat16",
+            ).compile().memory_analysis()
+            built += mem.output_size_in_bytes
+            peak = max(peak, built + mem.temp_size_in_bytes)
+        print("weights built %.3f peak %.3f GB" % (built / 1e9, peak / 1e9))
+        assert 9.0e9 < built < 9.3e9 and peak < 15.75e9
+        return
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(program, "args %.3f temp %.3f out %.3f alias %.3f GB" % tuple(
+        x / 1e9 for x in (mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+                          mem.output_size_in_bytes, mem.alias_size_in_bytes)))
+    assert total < 15.75e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _pool_shaped_moves(text, lat.shape, "bf16")
+    assert not _pool_shaped_moves(text, rot.shape, "bf16")
+
+
 @pytest.mark.parametrize(
     "variant", ["prefill", "traced_offset_chunk", "int8_chunk", "tree_bias"]
 )

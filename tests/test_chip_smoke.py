@@ -103,6 +103,27 @@ def test_phases_pass_at_tiny_size_when_steered(steered, capsys):
     assert logits["int8_exceeds_full_width_tolerance"]
 
 
+def test_latent_phase_at_tiny_size_when_steered(steered, monkeypatch, capsys):
+    """``--modes latent`` at the `tiny-mla-moe` preset with a share of 4
+    of its 16 experts: the absorbed decode kernel against the plain
+    formula, then the cut preset served on every slot."""
+    monkeypatch.setattr(steered, "LATENT", {
+        "model": "tiny-mla-moe", "slots": 6,
+        "cut": ["--experts-held", "4", "--expert-offset", "8"]})
+    assert steered.main(["--modes", "latent"]) == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    assert lines[-1]["ok"] is True
+    kernel = next(ln for ln in lines if ln.get("phase") == "latent_kernel")
+    assert kernel["max_abs_err"]["mla_decode_paged"] <= kernel["tolerance"]
+    served = next(ln for ln in lines if ln.get("phase") == "latent_serve")
+    assert served["slots"] == 6 and served["tokens_generated"] > 0
+    assert served["kv_bytes_per_token"] == (32 + 16) * 4 * 3
+    assert 0 < served["experts_touched"] <= served["local_rows"]
+    assert served["warm_repeat_reproduced_cold_tokens"]
+    assert not any(ln.get("phase") == "logits" for ln in lines)
+
+
 def test_a_phase_that_raises_fails_the_run(steered, monkeypatch, capsys):
     def broken(model, mode):
         raise RuntimeError(f"phase {mode} broke")

@@ -235,8 +235,15 @@ ROUND = ["engine:decode_round", "engine:dispatch", "engine:fetch",
 ADMIT = ["engine:admit", "prefix_cache:admit", "prefix_cache:chunk"]
 PARENT = {
     # name -> programs compiled under it (one a signature).
+    # (Since PR 35: `tdt_kv_copy_page`, because a prefix-cache engine
+    # compiles its copy-on-write program when it is built, not at the
+    # first partial-page hit in the middle of serving; and ONE
+    # `tdt_decode_step` for three, because the host's table, lengths and
+    # tokens are committed to the mesh like a step's own outputs, so the
+    # step has one signature wherever its inputs come from.)
     "programs": {"tdt_set_params": 1, "tdt_prefill_chunk": 3,
-                 "tdt_decode_step": 3, "tdt_finite_greedy": 1},
+                 "tdt_decode_step": 1, "tdt_finite_greedy": 1,
+                 "tdt_kv_copy_page": 1},
     # The one traced batch, in start order: both admissions (the second
     # prompt's three chunks step the first request between them), then
     # the rounds the longer generation needs, then the audit.

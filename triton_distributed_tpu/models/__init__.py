@@ -1,7 +1,8 @@
 """Models + serving (parity: reference ``python/triton_dist/models/``).
 
 ``AutoLLM`` mirrors ``models/__init__.py:32-48`` — dispatch by model
-name/config to Qwen3 dense or MoE, loading HF weights when a checkpoint
+name/config to Qwen3 dense or MoE, or to the latent-attention expert
+model (``LatentMoE``, presets with a ``kv_lora_rank``), loading HF weights when a checkpoint
 directory is given and random-initializing otherwise (the reference's
 perf scripts also run on random weights).
 """
@@ -72,7 +73,11 @@ class AutoLLM:
             model.set_params(load_hf_state_dict(cfg, state, n))
             return model
         cfg = get_config(name_or_path, **overrides)
-        if cfg.num_experts:
+        if cfg.kv_lora_rank:
+            from triton_distributed_tpu.models.latent_moe import LatentMoE
+
+            model = LatentMoE(cfg, axis=axis, ctx=ctx)
+        elif cfg.num_experts:
             from triton_distributed_tpu.models.qwen_moe import Qwen3MoE
 
             model = Qwen3MoE(cfg, axis=axis, ctx=ctx)

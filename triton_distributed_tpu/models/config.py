@@ -36,6 +36,38 @@ class ModelConfig:
     # DEFAULT is false — checkpoint loading must follow the config, not
     # assume).
     norm_topk_prob: bool = True
+    # Latent attention (DeepSeek-V3 block; 0 = the GQA layer). Queries go
+    # through a rank-``q_lora_rank`` bottleneck; keys and values are
+    # rebuilt from ONE cached row of ``kv_lora_rank`` normed latents
+    # plus ``qk_rope_head_dim`` rotary dims shared by every head.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (factor 0 = plain RoPE at ``rope_theta``).
+    yarn_factor: float = 0.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_original_max: int = 4096
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # Leading dense layers before the expert layers (0 = uniform).
+    first_k_dense: int = 0
+    # Expert layers of the DeepSeek-V3 kind: shared experts beside the
+    # routed ones, ``sigmoid`` scores with a choice-only bias, the best
+    # ``topk_group`` of ``n_group`` groups kept, weights times
+    # ``routed_scaling_factor``.
+    n_shared_experts: int = 0
+    scoring_func: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # One expert-parallel rank's share: experts ``[expert_offset,
+    # expert_offset + experts_held)`` of ``num_experts`` live here (0 =
+    # all of them). The router keeps all its outputs.
+    experts_held: int = 0
+    expert_offset: int = 0
     # runtime
     max_length: int = 4096
     dtype: jnp.dtype = jnp.bfloat16
@@ -77,6 +109,21 @@ _PRESETS: dict[str, dict] = {
         num_q_heads=32, num_kv_heads=4, head_dim=128,
         num_experts=128, num_experts_per_tok=8, moe_intermediate_size=768,
     ),
+    # rednote-hilab/dots.vlm1.inst's language model (the DeepSeek-V3
+    # block), as published: 61 layers, of which three leading dense.
+    # One chip serves a share of it (docs/serving.md "One rank's share").
+    "rednote-hilab/dots.vlm1.inst": dict(
+        vocab_size=129280, hidden_size=7168, intermediate_size=18432,
+        num_layers=61, num_q_heads=128, num_kv_heads=128, head_dim=192,
+        rope_theta=1e4, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        yarn_factor=40.0, yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+        yarn_original_max=4096, yarn_mscale=1.0, yarn_mscale_all_dim=1.0,
+        first_k_dense=3, num_experts=256, num_experts_per_tok=8,
+        moe_intermediate_size=2048, n_shared_experts=1,
+        scoring_func="sigmoid", n_group=8, topk_group=4,
+        routed_scaling_factor=2.5,
+    ),
     # Tiny configs for tests / CPU-simulator runs.
     "tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -88,6 +135,17 @@ _PRESETS: dict[str, dict] = {
         num_q_heads=8, num_kv_heads=4, head_dim=32, max_length=128,
         num_experts=8, num_experts_per_tok=2, moe_intermediate_size=64,
         dtype=jnp.float32,
+    ),
+    "tiny-mla-moe": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=3,
+        num_q_heads=4, num_kv_heads=4, head_dim=48, rope_theta=1e4,
+        max_length=256, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        yarn_factor=40.0, yarn_original_max=64, yarn_mscale_all_dim=1.0,
+        first_k_dense=1, num_experts=16, num_experts_per_tok=4,
+        moe_intermediate_size=32, n_shared_experts=1,
+        scoring_func="sigmoid", n_group=4, topk_group=2,
+        routed_scaling_factor=2.5, dtype=jnp.float32,
     ),
 }
 

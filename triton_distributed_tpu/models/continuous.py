@@ -348,13 +348,17 @@ class _StepLaunch:
     logits: object      # [max_batch, V] device
     finite: object      # [max_batch] device all-finite mask
     toks: object        # [max_batch] device greedy tokens
+    counts: object = None  # [2] device: an expert share's step sums
     host: tuple | None = None
 
     def fetch(self) -> tuple:
         """THE host sync of a step: ``(finite, tokens)`` as numpy,
-        fetched once. After it the device has left the step."""
+        fetched once (an expert share's two sums with them, kept in
+        ``counts``). After it the device has left the step."""
         if self.host is None:
             self.host = (np.asarray(self.finite), np.array(self.toks))
+            if self.counts is not None:
+                self.counts = np.asarray(self.counts)
         return self.host
 
 
@@ -413,6 +417,25 @@ class ContinuousEngine(MegaDispatch):
         self.model = model
         self.mode = mode
         self.mega_cfg = mega_cfg
+        # A model whose step returns sums beside its logits (an expert
+        # share's two counts, ``decode_step_counted``) has that ONE
+        # decode program; they ride each step's ``fetch()``.
+        self._counted_step = getattr(model, "decode_step_counted", None)
+        if getattr(model.cfg, "kv_lora_rank", 0):
+            # A latent-attention model has the single-step paged
+            # programs only: refuse the rest by the flag that asks.
+            name = model.cfg.model_name
+            for flag, asked in (
+                ("--mode mega", mode == "mega"),
+                ("--kv-dtype int8",
+                 (kv_dtype or model.cfg.kv_dtype) == "int8"),
+                ("--speculative", bool(speculative)),
+            ):
+                if asked:
+                    raise ValueError(
+                        f"{flag}: {name} has no such path (latent "
+                        "attention serves the xla/pallas single-step "
+                        "programs over a full-width latent pool)")
         # Resident decode (docs/megakernel.md "Resident decode"): the
         # NS launch width becomes a knob (perf/mega_serve_bench.py
         # sweeps it), batch buckets give a 2-slot round a 2-wide launch
@@ -437,6 +460,13 @@ class ContinuousEngine(MegaDispatch):
         # state comes through ``_drain_pend`` / ``_settle_pend`` /
         # ``_abort_pend`` first.
         self._pend = None
+        # The requests of the running batch that wait for a slot (the
+        # queue of ``run()``; empty between runs).
+        self._waiting = ()
+        # Requests whose slot ended under a looked-ahead step that was
+        # still appending to their pages, each with that step: the pages
+        # go back once the device has left it (``_release_ended``).
+        self._ended: list = []
         # Device task tracer (docs/observability.md "Device task
         # tracer"): mega launches carry an in-kernel trace ring; every
         # launch's ring is folded into tdt_mega_task_seconds and kept
@@ -532,6 +562,11 @@ class ContinuousEngine(MegaDispatch):
         self._tok = np.zeros((max_batch,), np.int32)
         self._slots: list[Request | None] = [None] * max_batch
         self.prefix = PrefixCache(self.pool, page_size) if prefix_cache else None
+        if prefix_cache:
+            # Compile the copy-on-write program now (the trash page onto
+            # itself), not at the first partial-page hit in the middle
+            # of serving: one small program a pool shape.
+            self.cache = copy_page(self.cache, 0, 0)
         # Durable KV tier (docs/serving.md "Tiered KV"): a host-RAM
         # (and optionally disk) PageStore behind the radix tree —
         # evicted prefix pages spill into it instead of dropping to
@@ -655,6 +690,21 @@ class ContinuousEngine(MegaDispatch):
             model.cfg.num_experts_per_tok
             if getattr(model.cfg, "num_experts", 0) else 0
         )
+        if getattr(model.cfg, "experts_held", 0) or getattr(
+                model.cfg, "kv_lora_rank", 0):
+            from triton_distributed_tpu.models.paged_kv_cache import (
+                kv_bytes_per_token,
+            )
+
+            obs_metrics.gauge(
+                "tdt_moe_experts_held",
+                "Routed experts whose weights this rank holds (of the "
+                "router's num_experts).",
+            ).set(model.cfg.experts_held or model.cfg.num_experts)
+            obs_metrics.gauge(
+                "tdt_kv_row_bytes",
+                "Logical bytes of one token's cache row in one layer.",
+            ).set(kv_bytes_per_token(self.cache) / model.cfg.num_layers)
         self.snapshot_every = int(snapshot_every)
         self._handoff_at: int | None = None
         self._round = 0
@@ -757,6 +807,8 @@ class ContinuousEngine(MegaDispatch):
             # lossless serving paths, surfaced so a capacity-mode EP
             # experiment can never hide overflow.
             "moe_routed_tokens": 0,
+            "moe_decode_local_rows": 0,
+            "moe_decode_experts_touched": 0,
             "a2a_dropped": 0,
             # Durable KV tier ledger (docs/serving.md "Tiered KV"):
             # evictions demoted to the tier, and admissions extended by
@@ -847,10 +899,16 @@ class ContinuousEngine(MegaDispatch):
         # probability scaled with host work between dispatch and the
         # first output fetch). Explicit copies give the device arrays
         # their own storage.
+        # Committed to the mesh like a step's own outputs: a step then
+        # has ONE signature whether its table comes from here or from
+        # the step before (an uncommitted table met by on-device tokens,
+        # the round after a slot ended under a step in flight, would
+        # compile the step once more in the middle of serving).
+        put = self.model.ctx.replicate
         self.cache = dataclasses.replace(
             self.cache,
-            page_table=jnp.asarray(self._table.copy()),
-            kv_len=jnp.asarray(self._kv_len.copy()),
+            page_table=put(self._table.copy()),
+            kv_len=put(self._kv_len.copy()),
         )
 
     def _admit(
@@ -1092,7 +1150,8 @@ class ContinuousEngine(MegaDispatch):
         with trace_span("engine:dispatch", _ring=False):
             if step is None:
                 step = self._launch_step(
-                    jnp.asarray(self._tok), active, n_active
+                    self.model.ctx.replicate(self._tok.copy()), active,
+                    n_active
                 )
             if self._may_look_ahead(step):
                 # Parked BEFORE the emit below: whatever that raises
@@ -1106,19 +1165,26 @@ class ContinuousEngine(MegaDispatch):
     def _may_look_ahead(self, step: _StepLaunch) -> bool:
         """Whether the step AFTER ``step`` may be dispatched before
         ``step``'s tokens reach the host: only when it is exactly what
-        the serial loop would run next. Its input must already be on
-        the device (every live slot decodes greedily and was in
-        ``step`` with the same request), the slot set must be known
-        (no live slot reaches ``gen_len`` with ``step``'s token, no
-        admission is mid-prefill), and nothing may sit between a step
-        and its tokens (a speculative plan, mega rounds planned from
-        host truth, an armed ``FaultPlan``). What the host
+        the serial loop would run next for the slots that go on. Its
+        input must already be on the device (every live slot decodes
+        greedily and was in ``step`` with the same request), the slot
+        set must be known (no admission is mid-prefill), somebody must
+        go on (a step whose every live slot reaches ``gen_len`` with
+        ``step``'s token would be run for nobody) and nobody may wait
+        for the slot of one that ends (the serial loop would admit it
+        into that very step), and nothing may sit
+        between a step and its tokens (a speculative plan, mega rounds
+        planned from host truth, an armed ``FaultPlan``). A slot that
+        ends with ``step``'s token, by its length as by what the host
         cannot foresee (a stop token, a non-finite row, a cancel, a
-        deadline) costs the slot's token of the in-flight step, never
-        an emitted one (:meth:`_emit_step`)."""
+        deadline), costs its row of the in-flight step, never an
+        emitted token (:meth:`_emit_step`): with 32 slots a request
+        ends every tenth round, and a round that waits for it leaves
+        the device idle for the host's 6 ms (PERF.md "PR 35")."""
         if (self.speculative or self.mode == "mega"
                 or active_plan() is not None):
             return False
+        goes_on = ends = False
         for slot, req in enumerate(self._slots):
             if req is None:
                 if self._table[slot, 0]:
@@ -1127,10 +1193,17 @@ class ContinuousEngine(MegaDispatch):
                     return False
                 continue
             if (req is not step.reqs[slot]
-                    or len(req.out) + 1 >= req.gen_len
                     or self._request_sampling(req)[0] > 0.0):
                 return False
-        return True
+            if len(req.out) + 1 < req.gen_len:
+                goes_on = True
+            else:
+                ends = True
+        # A slot that ends leaves its row of the next step unused. With
+        # a request waiting for that slot the serial loop admits it
+        # first and runs the next step WITH it: looking ahead would
+        # spend a step more.
+        return goes_on and not (ends and self._waiting)
 
     def _launch_step(self, tok, active: np.ndarray,
                      n_active: int) -> _StepLaunch:
@@ -1139,7 +1212,9 @@ class ContinuousEngine(MegaDispatch):
         the device) and the program that reduces its logits to a finite
         mask and the greedy tokens. Nothing here waits for the device."""
         fault_point("engine.decode", step=self.stats["decode_steps"])
-        logits, self.cache = self._decode_step(tok, self.cache)
+        logits, self.cache, *counts = (
+            self._decode_step(tok, self.cache) if self._counted_step is None
+            else self._counted_step(tok, self.cache, self.mode))
         logits = mutate_point(
             "engine.logits", logits, step=self.stats["decode_steps"]
         )
@@ -1154,7 +1229,7 @@ class ContinuousEngine(MegaDispatch):
         # base tokens, so the NaN guard adds no extra host-sync round
         # trip to the hot decode loop.
         finite, toks = tdt_finite_greedy(logits)
-        return _StepLaunch(list(self._slots), logits, finite, toks)
+        return _StepLaunch(list(self._slots), logits, finite, toks, *counts)
 
     def _emit_step(self, step: _StepLaunch) -> bool:
         """Fetch one dispatched step's tokens and emit them through the
@@ -1163,21 +1238,24 @@ class ContinuousEngine(MegaDispatch):
         by now, so every walk below skips it: its token is dropped."""
         with trace_span("engine:fetch", _ring=False):
             finite, toks = step.fetch()
+        self._release_ended()  # the device has left ``step``
+        if step.counts is not None:
+            self._bump("moe_decode_local_rows", int(step.counts[0]))
+            self._bump("moe_decode_experts_touched", int(step.counts[1]))
         ended = sum(r is not None and self._slots[s] is not r
                     for s, r in enumerate(step.reqs))
         if ended:
             self._bump("lookahead_discarded", ended)
-        ahead = self._pend
-        if ahead is not None and any(
-                r is not None
-                and (not finite[s] or toks[s] == self.eos_id)
+        if self._pend is not None and any(
+                r is not None and not finite[s]
                 for s, r in enumerate(self._slots)):
-            # A slot ends on this very token (stop token, non-finite
-            # row) while the next step is in flight and appending to
-            # its pages: the device leaves that step before any page
-            # goes back to the pool or the radix tree. It stays parked;
-            # the slot's token in it is dropped at its own drain.
-            ahead.fetch()
+            # The guard below tears a non-finite slot down BEFORE the
+            # round's frames go out, while the next step is in flight
+            # and appending to its pages: the device leaves that step
+            # first. (A slot that ends ON this round's token, by its
+            # length or a stop token, ends after the frames:
+            # ``_process``.)
+            self._pend.fetch()
         # One token per live slot, less the slots the guard fails.
         with trace_span("engine:sample_emit",
                         emitted=sum(r is not None for r in self._slots),
@@ -1209,7 +1287,7 @@ class ContinuousEngine(MegaDispatch):
     def _process(self, slot_tokens) -> bool:
         """Append per-slot tokens; evict on gen_len/eos. Returns whether
         slot state changed."""
-        changed = False
+        finished = []
         emitted = 0
         for slot, req in enumerate(self._slots):
             if req is None:
@@ -1221,29 +1299,68 @@ class ContinuousEngine(MegaDispatch):
                 self._tok[slot] = int(t)
                 if req.spec is not None:
                     req.spec.observe((int(t),))
-                if self._maybe_finish(req, int(t)):
-                    changed = True
+                if self._ends(req, int(t)):
+                    finished.append(req)
                     break
         if emitted:
             self._bump("generated_tokens", emitted)
-        return changed
+        # Every slot's frames are out. A step looked ahead to is still
+        # appending to the finished slots' pages: it stays parked, the
+        # slots' tokens in it are dropped at its own drain, and the
+        # pages go back when the device has left it (``_evict``). The
+        # host waits for nothing here: waiting for that step before the
+        # frames held every slot's token back by a whole step (30.6 ms
+        # for 15.0 in a tenth of the rounds at 32 slots), waiting after
+        # them left the device idle for the host's 2.3 ms (PERF.md
+        # "PR 35").
+        after = self._pend if isinstance(self._pend, _StepLaunch) else None
+        for req in finished:
+            self._evict(req, after)
+        return bool(finished)
 
-    def _evict(self, req: Request) -> None:
+    def _evict(self, req: Request, after: _StepLaunch | None = None) -> None:
+        """End a finished request's slot. With ``after``, a looked-ahead
+        step still in flight that appends to the slot's pages, the slot
+        is free at once (the next step is dispatched on a table that
+        no longer maps it) and the pages go back when the device has
+        left ``after``: :meth:`_release_ended`, at that step's fetch, a
+        round later and behind the step after it."""
         slot = req.slot
         self._finish_obs(req)  # status "ok": _evict only runs on success
         obs_events.emit("evict", slot=slot, tokens_out=len(req.out))
+        self._table[slot] = 0  # back to the trash page
+        self._kv_len[slot] = 0
+        self._slots[slot] = None
+        req.slot = None
+        if after is not None and after.host is None:
+            self._ended.append((req, after))
+        else:
+            self._release(req)
+
+    def _release(self, req: Request) -> None:
+        """A finished request's pages go back: to the radix tree, or to
+        the pool. No step in flight may still append to them."""
         if self.prefix is not None:
             self._retire_to_prefix(req)
         else:
             # Full truncation: every private page goes back to the pool
             # (the 0-token case of the speculative rollback helper).
-            req.pages = truncate_pages(
-                self.pool, req.pages, 0, self.page_size
-            )
-        self._table[slot] = 0  # back to the trash page
-        self._kv_len[slot] = 0
-        req.pages, req.slot = [], None
-        self._slots[slot] = None
+            truncate_pages(self.pool, req.pages, 0, self.page_size)
+        req.pages = []
+
+    def _release_ended(self, every: bool = False) -> None:
+        """Release the pages of the requests whose slot ended under a
+        step the device has left by now (``every``: nothing is in
+        flight any more)."""
+        if not self._ended:
+            return
+        waiting = []
+        for req, step in self._ended:
+            if every or step.host is not None:
+                self._release(req)
+            else:
+                waiting.append((req, step))
+        self._ended = waiting
 
     # -- failure isolation -----------------------------------------------
 
@@ -1950,9 +2067,14 @@ class ContinuousEngine(MegaDispatch):
         bursts[slot] = emitted
         return False
 
+    def _ends(self, req: Request, t: int) -> bool:
+        """Whether token ``t``, just appended, completed ``req``
+        (gen_len or eos)."""
+        return req.done or (self.eos_id is not None and t == self.eos_id)
+
     def _maybe_finish(self, req: Request, t: int) -> bool:
-        """Evict ``req`` if token ``t`` completed it (gen_len or eos)."""
-        if req.done or (self.eos_id is not None and t == self.eos_id):
+        """Evict ``req`` if token ``t`` completed it."""
+        if self._ends(req, t):
             self._evict(req)  # free pages NOW
             return True
         return False
@@ -2429,6 +2551,7 @@ class ContinuousEngine(MegaDispatch):
         tokens the serial round would have given it."""
         if isinstance(self._pend, _StepLaunch):
             self._pend.fetch()
+            self._release_ended()
         else:
             self._drain_pend()
 
@@ -2438,11 +2561,13 @@ class ContinuousEngine(MegaDispatch):
         teardown is about to reuse."""
         pend, self._pend = self._pend, None
         if pend is None:
+            self._release_ended(every=True)
             return
         try:
             jax.block_until_ready(pend.toks)
         except Exception:  # noqa: BLE001 — teardown is best-effort
             pass
+        self._release_ended(every=True)
         if isinstance(pend, _StepLaunch):
             self._bump("lookahead_discarded",
                        sum(r is not None for r in pend.reqs))
@@ -2652,6 +2777,7 @@ class ContinuousEngine(MegaDispatch):
             if r.deadline_s is not None:
                 r.deadline_at = t0 + float(r.deadline_s)
         queue = deque(r for r in reqs if r.status == "ok")
+        self._waiting = queue  # what `_may_look_ahead` may not starve
 
         try:
             # Cancellations that landed before the batch (the server's
@@ -2711,6 +2837,7 @@ class ContinuousEngine(MegaDispatch):
         finally:
             self._handoff_at = None
             self._round = 0
+            self._waiting = ()
             # Block on (and discard) any in-flight launch
             # BEFORE teardown reuses the state it reads.
             self._abort_pend()
@@ -2977,11 +3104,18 @@ class ContinuousEngine(MegaDispatch):
             n_sh = len(req.shared_nodes)
             owners[f"slot{slot}"] = [int(p) for p in req.pages[n_sh:]]
             shared[f"slot{slot}"] = [int(p) for p in req.pages[:n_sh]]
+        holders = list(self._slots)
+        for i, (req, _step) in enumerate(self._ended):
+            # Ended under a step in flight: the pages are still theirs.
+            n_sh = len(req.shared_nodes)
+            owners[f"ended{i}"] = [int(p) for p in req.pages[n_sh:]]
+            shared[f"ended{i}"] = [int(p) for p in req.pages[:n_sh]]
+            holders.append(req)
         if self.prefix is not None:
             problems += self.prefix.audit()
             owners["tree"] = [n.page for n in self.prefix.walk()]
             pin_counts: Counter = Counter()
-            for req in self._slots:
+            for req in holders:
                 if req is None:
                     continue
                 for node in req.shared_nodes:

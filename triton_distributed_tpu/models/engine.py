@@ -79,6 +79,9 @@ def prefill_suffix_chunks(
     from triton_distributed_tpu.runtime.profiling import trace_span
 
     s = len(prompt)
+    # A model may round its chunk widths coarser than the tile's grain
+    # (fewer programs to compile): its own ``round_chunk``.
+    round_chunk = getattr(model, "round_chunk", round_chunk)
     c = round_chunk(chunk_width) if chunk_width else round_chunk(s - start)
     page = int(cache.k_pages.shape[3])
     pps = int(cache.page_table.shape[1])

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -213,6 +214,15 @@ def init_paged_cache(
     batching admits/evicts per request, possibly with ``num_pages``
     oversubscribed below ``batch_size * pages_per_seq``).
 
+    A latent-attention model (``cfg.kv_lora_rank``) caches ONE row a
+    token a layer, shared by all heads: ``k_pages [L, P, 1, page,
+    kv_lora_rank]`` holds the normed latents and ``v_pages [L, P, 1,
+    qk_rope_head_dim, page]`` the shared rotary keys, TRANSPOSED (the
+    page axis on the TPU's 128 lanes: a 64-wide row would be padded to
+    128 in HBM). Both are pools of one "head", so the whole-page
+    utilities below, the radix cache and :func:`audit_pool` serve them
+    unchanged.
+
     ``kv_dtype="int8"`` (or ``cfg.kv_dtype``; the explicit argument
     wins) allocates the pool as int8 plus per-page-per-head
     ``k_scale``/``v_scale`` arrays — roughly half the bf16 pool's HBM
@@ -241,6 +251,16 @@ def init_paged_cache(
     shape = (
         cfg.num_layers, num_pages, cfg.num_kv_heads, page_size, cfg.head_dim
     )
+    v_shape = shape
+    if cfg.kv_lora_rank:
+        if resolved_kv == "int8":
+            raise ValueError(
+                "--kv-dtype int8 has no latent-pool path: "
+                f"{cfg.model_name} caches latent rows, and the int8 scale "
+                "protocol is per page and KV head"
+            )
+        shape = (cfg.num_layers, num_pages, 1, page_size, cfg.kv_lora_rank)
+        v_shape = (*shape[:3], cfg.qk_rope_head_dim, page_size)
     spec = (None, None, axis, None, None)
     pool_dtype = jnp.int8 if resolved_kv == "int8" else cfg.dtype
     if resolved_kv == "int8":
@@ -253,7 +273,7 @@ def init_paged_cache(
         k_scale = v_scale = None
     cache = PagedKVCache(
         k_pages=ctx.shard(jnp.zeros(shape, pool_dtype), *spec),
-        v_pages=ctx.shard(jnp.zeros(shape, pool_dtype), *spec),
+        v_pages=ctx.shard(jnp.zeros(v_shape, pool_dtype), *spec),
         page_table=ctx.replicate(jnp.asarray(table)),
         kv_len=ctx.replicate(jnp.zeros((batch_size,), jnp.int32)),
         k_scale=k_scale,
@@ -266,12 +286,17 @@ def kv_bytes_per_token(cache: PagedKVCache) -> float:
     """HBM bytes one cached token costs across K+V pools (+ scale
     overhead when quantized) — the quantity steady-state decode streams
     per token per step. Computed from the GLOBAL array shapes (the pool
-    is head-sharded; shapes here are pre-shard)."""
-    L, _p, H, page, hd = cache.k_pages.shape
-    per = (
-        cache.k_pages.dtype.itemsize + cache.v_pages.dtype.itemsize
-    ) * L * H * hd
+    is head-sharded; shapes here are pre-shard). A pool's bytes a token
+    are its page's over the page size, whatever the page's own layout
+    (a latent model's rotary pool is ``[.., rope, page]``)."""
+    page = cache.k_pages.shape[3]
+    L = cache.k_pages.shape[0]
+    per = L * sum(
+        a.dtype.itemsize * math.prod(a.shape[2:]) // page
+        for a in (cache.k_pages, cache.v_pages)
+    )
     if cache.quantized:
+        H = cache.k_pages.shape[2]
         per += (
             cache.k_scale.dtype.itemsize + cache.v_scale.dtype.itemsize
         ) * L * H / page

@@ -260,7 +260,20 @@ class Qwen3:
         logits = self._logits(params, x)
         return logits, KVCache(k=k_new, v=v_new, kv_len=cache.kv_len + 1)
 
-    def _scan_layers_paged(self, params, x, cache, attn_fn, mode: Mode):
+    def _layer_groups(self, params) -> list:
+        """The model's layers as groups of like layers, in order: each
+        ``(stacked layer params, ffn)`` with ``ffn(mlp params, h, mode,
+        aux) -> (y, aux)``. One group here; a model whose leading layers
+        differ from the rest (dense before expert layers) returns one
+        group a kind, and :meth:`_scan_layers_paged` runs them through
+        the one carried pool under one running layer index."""
+        return [(
+            params.layers,
+            lambda mp, h, ar, aux: (self._mlp_fwd(mp, h, ar), aux),
+        )]
+
+    def _scan_layers_paged(self, params, x, cache, attn_fn, mode: Mode,
+                           aux=None, groups=None):
         """The layer scan of every program over a :class:`PagedKVCache`.
 
         The pools (and an int8 pool's scales; ``None`` on a full-width
@@ -277,36 +290,47 @@ class Qwen3:
         with 4 slots 14 ms of a 27 ms decode step, and 2.3 GiB of
         temporaries (docs/serving.md "Paged KV cache").
 
-        Returns ``(x, k_pages, v_pages, k_scale, v_scale)``.
+        One scan a group of :meth:`_layer_groups`, each carrying the
+        same pool on and indexing it from where the group before
+        stopped. ``aux`` is whatever the groups' ffns thread through
+        the layers (an expert layer's counts; ``None`` otherwise);
+        ``groups`` overrides :meth:`_layer_groups`.
+
+        Returns ``(x, k_pages, v_pages, k_scale, v_scale, aux)``.
         """
         cfg = self.cfg
         ar = "pallas_ar" if mode == "pallas" else "xla_ar"
+        carry = (x, cache.k_pages, cache.v_pages, cache.k_scale,
+                 cache.v_scale, aux)
+        start = 0
+        for layers, ffn in groups or self._layer_groups(params):
+            n = jax.tree.leaves(layers)[0].shape[0]
 
-        def layer_fn(carry, inp):
-            x, kp, vp, ks, vs = carry
-            # Pin the carried pool row-major, the layout it is donated
-            # in: left free, XLA gives the loop's pool the layout of a
-            # chunk's transposed update and re-lays the whole pool out
-            # before and after the loop.
-            kp, vp = (
-                with_layout_constraint(p, Layout(tuple(range(p.ndim))))
-                for p in (kp, vp)
-            )
-            lp, layer = inp
-            h = rms_norm(x, lp.ln1, cfg.rms_eps)
-            a, kp, vp, ks, vs = attn_fn(
-                lp.attn, h, kp, vp, layer, ks, vs, ar
-            )
-            x = x + a
-            h = rms_norm(x, lp.ln2, cfg.rms_eps)
-            x = x + self._mlp_fwd(lp.mlp, h, ar)
-            return (x, kp, vp, ks, vs), None
+            def layer_fn(carry, inp, ffn=ffn):
+                x, kp, vp, ks, vs, aux = carry
+                # Pin the carried pool row-major, the layout it is
+                # donated in: left free, XLA gives the loop's pool the
+                # layout of a chunk's transposed update and re-lays the
+                # whole pool out before and after the loop.
+                kp, vp = (
+                    with_layout_constraint(p, Layout(tuple(range(p.ndim))))
+                    for p in (kp, vp)
+                )
+                lp, layer = inp
+                h = rms_norm(x, lp.ln1, cfg.rms_eps)
+                a, kp, vp, ks, vs = attn_fn(
+                    lp.attn, h, kp, vp, layer, ks, vs, ar
+                )
+                x = x + a
+                h = rms_norm(x, lp.ln2, cfg.rms_eps)
+                y, aux = ffn(lp.mlp, h, ar, aux)
+                return (x + y, kp, vp, ks, vs, aux), None
 
-        carry, _ = jax.lax.scan(
-            layer_fn,
-            (x, cache.k_pages, cache.v_pages, cache.k_scale, cache.v_scale),
-            (params.layers, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
-        )
+            carry, _ = jax.lax.scan(
+                layer_fn, carry,
+                (layers, jnp.arange(start, start + n, dtype=jnp.int32)),
+            )
+            start += n
         return carry
 
     def _decode_shard_paged(self, params, tokens, cache, *, mode: Mode):
@@ -336,7 +360,7 @@ class Qwen3:
                 k_scale=ks, v_scale=vs, walk=walk,
             )
 
-        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
+        x, k_new, v_new, ks_new, vs_new, _ = self._scan_layers_paged(
             params, self._embed(params, tokens), cache, attn, mode
         )
         x = rms_norm(x, params.norm, self.cfg.rms_eps)
@@ -467,7 +491,7 @@ class Qwen3:
                 rope_pos=rope_pos, attn_bias=attn_bias,
             )
 
-        x, k_new, v_new, ks_new, vs_new = self._scan_layers_paged(
+        x, k_new, v_new, ks_new, vs_new, _ = self._scan_layers_paged(
             params, x, cache, attn, mode
         )
         x = rms_norm(x, params.norm, cfg.rms_eps)

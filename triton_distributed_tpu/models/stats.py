@@ -152,6 +152,16 @@ STAT_METRICS = {
     "moe_routed_tokens": ("tdt_moe_routed_tokens_total",
                           "Expert assignments routed (token positions "
                           "through the MoE FFN × top_k)."),
+    # One rank's share of an expert layer (docs/serving.md "Latent
+    # attention and one rank's share"): two int32 sums every decode step
+    # returns beside its tokens, over all expert layers and live rows.
+    "moe_decode_local_rows": ("tdt_moe_decode_local_rows_total",
+                              "Decode-step rows routed to an expert held "
+                              "on this rank (all expert layers)."),
+    "moe_decode_experts_touched": ("tdt_moe_decode_experts_touched_total",
+                                   "Held experts that got at least one "
+                                   "row, summed over expert layers and "
+                                   "decode steps."),
     "a2a_dropped": ("tdt_moe_a2a_dropped_total",
                     "EP all-to-all assignments dropped (capacity-mode "
                     "overflow; 0 on the lossless serving paths)."),

@@ -124,7 +124,7 @@ def _attn_kernel(
 def flash_attention(
     q: jax.Array,  # [B, Hq, Sq, D]
     k: jax.Array,  # [B, Hkv, Sk, D]
-    v: jax.Array,  # [B, Hkv, Sk, D]
+    v: jax.Array,  # [B, Hkv, Sk, W] (W may differ from D: latent attention)
     *,
     causal: bool = True,
     sm_scale: float | None = None,
@@ -160,12 +160,12 @@ def flash_attention(
     storage-order causality (ancestors precede descendants in storage),
     so the causal block skip stays valid.
 
-    Returns ``o [B, Hq, Sq, D]`` (and ``lse [B, Hq, Sq]`` f32 when
+    Returns ``o [B, Hq, Sq, W]`` (and ``lse [B, Hq, Sq]`` f32 when
     ``return_lse`` — base-e log-sum-exp of scaled scores, the quantity the
     distributed combine merges).
     """
     b, hq, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
+    (_, hkv, sk, _), w = k.shape, v.shape[-1]  # w: the values' width
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     group = hq // hkv
@@ -210,13 +210,13 @@ def flash_attention(
 
     qf = q.reshape(b * hq, sq, d)
     kf = k.reshape(b * hkv, sk, d)
-    vf = v.reshape(b * hkv, sk, d)
+    vf = v.reshape(b * hkv, sk, w)
     grid = (b * hq, sq // block_q, sk // block_k)
     dynamic_off = not isinstance(kv_offset, int)
 
-    out_shape = [jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b * hq, sq, w), q.dtype)]
     out_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, ki, *_: (bh, qi, 0)),
+        pl.BlockSpec((1, block_q, w), lambda bh, qi, ki, *_: (bh, qi, 0)),
     ]
     if return_lse:
         out_shape.append(jax.ShapeDtypeStruct((b * hq, 1, sq), jnp.float32))
@@ -244,7 +244,7 @@ def flash_attention(
             (1, block_k, d), lambda bh, qi, ki, *_: (bh // group, ki, 0)
         ),
         pl.BlockSpec(
-            (1, block_k, d), lambda bh, qi, ki, *_: (bh // group, ki, 0)
+            (1, block_k, w), lambda bh, qi, ki, *_: (bh // group, ki, 0)
         ),
     ]
     operands = [qf, kf, vf]
@@ -272,7 +272,7 @@ def flash_attention(
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, w), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
@@ -284,7 +284,7 @@ def flash_attention(
         interpret=interpret,
     )(*scalars, *operands)
 
-    o = res[0].reshape(b, hq, sq, d)
+    o = res[0].reshape(b, hq, sq, w)
     if return_lse:
         return o, res[1].reshape(b, hq, sq)
     return o

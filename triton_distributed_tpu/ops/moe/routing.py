@@ -53,6 +53,61 @@ def router_topk(
     return RouterOut(ids.astype(jnp.int32), weights)
 
 
+def router_group_limited(
+    x: jax.Array,         # [T, d]
+    w_router: jax.Array,  # [d, E]
+    bias: jax.Array,      # [E] f32: moves the choice, never the weight
+    k: int,
+    *,
+    n_group: int,
+    topk_group: int,
+    route_scale: float = 1.0,
+) -> RouterOut:
+    """DeepSeek-V3 gate (``noaux_tc``): sigmoid scores over all experts
+    in float32; the choice is made on ``score + bias``: a group's score
+    is the sum of its two best, the best ``topk_group`` of ``n_group``
+    groups are kept and the ``k`` best inside them chosen. Weights are
+    the chosen experts' scores WITHOUT the bias, divided by their sum,
+    times ``route_scale``."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        preferred_element_type=jnp.float32,
+    )
+    scores = jax.nn.sigmoid(logits)
+    t, e = scores.shape
+    choice = (scores + bias.astype(jnp.float32)).reshape(t, n_group, -1)
+    group_score = jnp.sum(jax.lax.top_k(choice, 2)[0], axis=-1)
+    kept = jax.lax.top_k(group_score, topk_group)[1]        # [T, topk_group]
+    keep = jnp.any(
+        kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1
+    )
+    choice = jnp.where(keep[:, :, None], choice, -jnp.inf).reshape(t, e)
+    ids = jax.lax.top_k(choice, k)[1]
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * route_scale
+    return RouterOut(ids.astype(jnp.int32), weights)
+
+
+def held_sort(route: RouterOut, offset: int, held: int) -> SortedTokens:
+    """:func:`moe_sort` for a rank that holds experts ``[offset, offset
+    + held)`` only: assignments to an expert held elsewhere are dropped
+    before the sort (they sort past every held expert and no group
+    counts them). ``expert_ids`` and ``group_sizes`` are local, ``[0,
+    held)``; ``group_sizes`` sums to the kept rows, which come first."""
+    local = route.expert_ids.reshape(-1) - offset
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    k = route.expert_ids.shape[1]
+    order = jnp.argsort(local, stable=True)
+    return SortedTokens(
+        order=order,
+        token_ids=(order // k).astype(jnp.int32),
+        expert_ids=local[order],
+        weights=route.weights.reshape(-1)[order],
+        group_sizes=jnp.bincount(local, length=held + 1)[:held].astype(
+            jnp.int32),
+    )
+
+
 def moe_sort(route: RouterOut, num_experts: int) -> SortedTokens:
     """Sort (token, expert) assignments into expert-contiguous order
     (parity: the CUDA align kernel's output contract)."""

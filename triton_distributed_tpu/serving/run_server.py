@@ -38,20 +38,34 @@ import jax
 
 def resolve_model_args(
     model: str, num_experts: int = 0, top_k: int = 0,
-    moe_intermediate: int = 0,
+    moe_intermediate: int = 0, *, num_layers: int = 0,
+    first_k_dense: int = 0, experts_held: int = 0, expert_offset: int = 0,
+    vocab_rows: int = 0,
 ) -> tuple[str, dict]:
     """``--model moe`` alias resolution (ONE definition for main and
     tests): the tiny-moe Qwen3MoE preset, with the expert knobs as
     config overrides. Non-moe names pass through with the same
-    overrides applied (an MoE checkpoint dir can be resized too)."""
+    overrides applied (an MoE checkpoint dir can be resized too).
+
+    The keyword knobs cut a published preset to one rank's share of a
+    deployment (docs/serving.md "Latent attention and one rank's
+    share"): the layers of this pipeline stage, how many of them are
+    leading dense ones, the routed experts held here and where they
+    start, the vocabulary rows held here. Widths are never cut."""
     name = "tiny-moe" if model == "moe" else model
     overrides: dict = {}
-    if num_experts:
-        overrides["num_experts"] = num_experts
-    if top_k:
-        overrides["num_experts_per_tok"] = top_k
-    if moe_intermediate:
-        overrides["moe_intermediate_size"] = moe_intermediate
+    for key, value in (
+        ("num_experts", num_experts),
+        ("num_experts_per_tok", top_k),
+        ("moe_intermediate_size", moe_intermediate),
+        ("num_layers", num_layers),
+        ("first_k_dense", first_k_dense),
+        ("experts_held", experts_held),
+        ("expert_offset", expert_offset),
+        ("vocab_size", vocab_rows),
+    ):
+        if value:
+            overrides[key] = value
     return name, overrides
 
 
@@ -82,6 +96,22 @@ def main(argv=None) -> int:
                    help="override the MoE preset's experts-per-token")
     p.add_argument("--moe-intermediate", type=int, default=0,
                    help="override the MoE preset's per-expert FFN width")
+    p.add_argument("--num-layers", type=int, default=0,
+                   help="serve this many of the preset's layers (one "
+                   "pipeline stage's; docs/serving.md 'Latent attention "
+                   "and one rank's share')")
+    p.add_argument("--first-k-dense", type=int, default=0,
+                   help="how many of --num-layers are leading dense "
+                   "layers (presets with expert layers after dense ones)")
+    p.add_argument("--experts-held", type=int, default=0,
+                   help="routed experts whose weights this rank holds: "
+                   "the router keeps every output, rows for experts "
+                   "held elsewhere are dropped")
+    p.add_argument("--expert-offset", type=int, default=0,
+                   help="first routed expert held here")
+    p.add_argument("--vocab-rows", type=int, default=0,
+                   help="rows of the embedding and columns of the head "
+                   "held here (a vocabulary-parallel slice)")
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--host", default="127.0.0.1",
                    help="bind address; 0.0.0.0 listens on every "
@@ -300,7 +330,10 @@ def main(argv=None) -> int:
     # --model moe: the Qwen3MoE serving alias (tiny-moe preset so a
     # laptop/CI run needs no checkpoint), sized by the knob overrides.
     model_name, overrides = resolve_model_args(
-        args.model, args.num_experts, args.top_k, args.moe_intermediate
+        args.model, args.num_experts, args.top_k, args.moe_intermediate,
+        num_layers=args.num_layers, first_k_dense=args.first_k_dense,
+        experts_held=args.experts_held, expert_offset=args.expert_offset,
+        vocab_rows=args.vocab_rows,
     )
     if (args.tier_bytes or args.tier_dir) and args.fleet == 0 and (
             args.model == "stub"
@@ -494,6 +527,11 @@ def main(argv=None) -> int:
                 child += ["--top-k", str(args.top_k)]
             if args.moe_intermediate:
                 child += ["--moe-intermediate", str(args.moe_intermediate)]
+            for flag in ("num_layers", "first_k_dense", "experts_held",
+                         "expert_offset", "vocab_rows"):
+                if getattr(args, flag):
+                    child += ["--" + flag.replace("_", "-"),
+                              str(getattr(args, flag))]
             if args.tier_bytes:
                 child += ["--tier-bytes", str(args.tier_bytes)]
 
@@ -679,6 +717,9 @@ def main(argv=None) -> int:
     # (the xla/pallas paths have no device ring); host profiling wraps
     # the run regardless of mode.
     kernel_trace = bool(args.trace) and args.mode == "mega"
+    # The front door and each replica take as many payloads in flight as
+    # there are decode slots, and never fewer than 8.
+    max_pending = max(8, args.max_batch)
     if args.replicas > 0:
         from triton_distributed_tpu.models.continuous import ContinuousEngine
         from triton_distributed_tpu.serving.router import Router
@@ -733,6 +774,7 @@ def main(argv=None) -> int:
         engine = Router(
             engines, policy=policy, drain_grace_s=args.drain_grace,
             request_timeout_s=args.request_timeout or None,
+            replica_max_pending=max_pending,
         )
         what = f"{args.model} x{args.replicas} ({policy} router)"
     elif args.continuous:
@@ -773,7 +815,7 @@ def main(argv=None) -> int:
         )
         what = f"{args.model} (tp={args.tp})"
     server = ModelServer(
-        engine, host=args.host, port=args.port,
+        engine, host=args.host, port=args.port, max_pending=max_pending,
         advertise_host=args.advertise_host,
         drain_grace_s=args.drain_grace, trace_dir=args.trace, slo=slo,
     )
