@@ -45,6 +45,14 @@ def pool_shaped_moves(hlo_text: str, pool_shape, dtype: str = "bf16") -> list:
     if len(pool_shape) == 5:  # the kernel's [L * P, H, page, hd] view
         shapes.add(",".join(map(str, (pool_shape[0] * pool_shape[1],
                                       *pool_shape[2:]))))
+    return moves_of_shapes(hlo_text, shapes, dtype)
+
+
+def moves_of_shapes(hlo_text: str, shapes, dtype: str = "bf16") -> list:
+    """The ``copy`` / ``dynamic-slice`` / fusion instructions whose
+    result has one of ``shapes`` (each ``"16,7168,4096"``): a layer's
+    stacked experts sliced out of a group's weights, as well as a pool
+    (:func:`pool_shaped_moves`, which says what is not counted)."""
     # "%x = bf16[..]{layout} copy(" and the tuple form "%x = (bf16[..]{..},
     # bf16[..]{..}) fusion(": the kind is the word before the first "("
     # after the shapes, with no "=" between.

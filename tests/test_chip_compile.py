@@ -138,9 +138,8 @@ def test_paged_flash_decode(chip, kv, pool_form, slots, hkv):
     assert call.count(f"{'bf16' if kv == 'bf16' else 's8'}[{flat}]") == 2
 
 
-def _pool_shaped_moves(hlo_text, pool_shape, kv="bf16"):
-    """prof/described.py's check (the builder's tool prints the same
-    list): instructions that copy or slice pool-sized data."""
+def _described():
+    """prof/described.py, the builder's tool that prints the same lists."""
     import importlib.util
     import pathlib
 
@@ -148,7 +147,12 @@ def _pool_shaped_moves(hlo_text, pool_shape, kv="bf16"):
     spec = importlib.util.spec_from_file_location("prof_described", path)
     described = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(described)
-    return described.pool_shaped_moves(
+    return described
+
+
+def _pool_shaped_moves(hlo_text, pool_shape, kv="bf16"):
+    """Instructions that copy or slice pool-sized data."""
+    return _described().pool_shaped_moves(
         hlo_text, pool_shape, "bf16" if kv == "bf16" else "s8")
 
 
@@ -254,6 +258,29 @@ def test_mla_paged_decode_kernel(chip):
     assert "tdt_mla_decode_paged" in text and "tpu_custom_call" in text
 
 
+def test_moe_decode_experts_kernel(chip):
+    """``tdt_moe_decode_experts`` at the published widths: 32 rows of
+    7168 against the four expert layers' 16 held experts of 2048,
+    stacked, with a traced layer, list and count."""
+    from triton_distributed_tpu.ops.moe.decode_experts import (
+        moe_decode_experts,
+    )
+
+    text = compile_for_chip(
+        lambda x, g, e, n, w1, w2, l: moe_decode_experts(
+            x, g, e, n, w1, w2, layer=l),
+        sds(chip, (DOTS_SLOTS, 7168), BF16),
+        sds(chip, (DOTS_SLOTS, 16), jnp.float32),
+        sds(chip, (16,), jnp.int32), sds(chip, (), jnp.int32),
+        sds(chip, (4, 16, 7168, 4096), BF16),
+        sds(chip, (4, 16, 2048, 7168), BF16), sds(chip, (), jnp.int32))
+    assert "tdt_moe_decode_experts" in text and "tpu_custom_call" in text
+    # The stacked weights reach the kernel as they are: no copy of them.
+    assert not _described().moves_of_shapes(
+        text, {"4,16,7168,4096", "64,7168,4096", "4,16,2048,7168",
+               "64,2048,7168"})
+
+
 @pytest.fixture
 def dots_share(chip):
     """The cut preset on the described chip, parameter shapes only."""
@@ -337,6 +364,14 @@ def test_latent_share_fits_the_chip(chip, dots_share, program):
     assert "tpu_custom_call" in text
     assert not _pool_shaped_moves(text, lat.shape, "bf16")
     assert not _pool_shaped_moves(text, rot.shape, "bf16")
+    if program == "decode":
+        # The step's experts are read in place, the ones its rows chose:
+        # no layer's 16 are sliced out of the group's stacked weights
+        # (as ``lax.scan`` ``xs`` they were, 1.4 GB a layer a step).
+        assert "tdt_moe_decode_experts" in text
+        assert not _described().moves_of_shapes(
+            text, {"16,7168,4096", "1,16,7168,4096", "16,2048,7168",
+                   "1,16,2048,7168"})
 
 
 @pytest.mark.parametrize(

@@ -262,6 +262,23 @@ def test_engine_counts_the_share_and_sets_the_gauges(served):
     assert eng.audit() == []
 
 
+def test_a_decode_step_slices_no_layer_of_experts_out_of_the_stack(served):
+    """The expert group's ``w1 [2, 4, 64, 64]`` / ``w2 [2, 4, 32, 64]``
+    reach ``tdt_moe_decode_experts`` whole, closed over by the layer
+    scan: as its ``xs`` each layer's four experts were sliced out (on
+    the chip a copy of 1.4 GB a layer a step, for a kernel). No
+    instruction of the lowered step yields one layer's experts."""
+    import re
+
+    model, _ = served
+    cache, _ = init_paged_cache(model.cfg, 2, model.ctx, page_size=PAGE)
+    text = jax.jit(model.decode_fn_paged("xla")).lower(
+        model.params, jnp.zeros((2,), jnp.int32), cache).as_text()
+    one_layer = re.compile(r"-> tensor<(1x)?4x(64x64|32x64)xf32>")
+    assert "tensor<2x4x64x64xf32>" in text  # the stack itself is there
+    assert [ln for ln in text.splitlines() if one_layer.search(ln)] == []
+
+
 def test_the_cut_reaches_the_program_through_resolve_model_args():
     from triton_distributed_tpu.models.config import get_config
     from triton_distributed_tpu.serving.run_server import resolve_model_args

@@ -230,15 +230,27 @@ class LatentMoE(Qwen3):
 
     # -- per-shard forward bodies ------------------------------------------
     def _layer_groups(self, params, live=None) -> list:
-        def dense(mp, h, ar, aux):
+        """The dense group, and the expert group with its routed
+        experts' stacked ``w1`` / ``w2`` taken OUT of the scanned
+        parameters: the ffn closes over them whole and names its layer
+        of the group, so that a decode step reads the touched experts in
+        place (``moe_share_fwd``'s ``layer``). Scanned as ``xs``, a
+        layer's 16 experts are copied out for the kernel in every step."""
+        def dense(mp, h, ar, aux, layer):
             return tp_mlp_fwd(mp, h, axis=self.axis, mode=ar,
                               ctx=self.ctx), aux
 
-        def sparse(mp, h, ar, aux):
-            y, counts = moe_share_fwd(mp, h, self.share, live)
+        moe = params.sparse.mlp
+
+        def sparse(mp, h, ar, aux, layer):
+            y, counts = moe_share_fwd(
+                dataclasses.replace(mp, w1=moe.w1, w2=moe.w2), h, self.share,
+                live, layer - self.cfg.first_k_dense)
             return y, aux + counts
 
-        return [(params.dense, dense), (params.sparse, sparse)]
+        scanned = dataclasses.replace(
+            params.sparse, mlp=dataclasses.replace(moe, w1=None, w2=None))
+        return [(params.dense, dense), (scanned, sparse)]
 
     def _scan(self, params, x, cache, attn, mode, live=None):
         """The two groups through the one pool; returns what

@@ -263,13 +263,14 @@ class Qwen3:
     def _layer_groups(self, params) -> list:
         """The model's layers as groups of like layers, in order: each
         ``(stacked layer params, ffn)`` with ``ffn(mlp params, h, mode,
-        aux) -> (y, aux)``. One group here; a model whose leading layers
+        aux, layer) -> (y, aux)``, ``layer`` the running index over all
+        groups. One group here; a model whose leading layers
         differ from the rest (dense before expert layers) returns one
         group a kind, and :meth:`_scan_layers_paged` runs them through
         the one carried pool under one running layer index."""
         return [(
             params.layers,
-            lambda mp, h, ar, aux: (self._mlp_fwd(mp, h, ar), aux),
+            lambda mp, h, ar, aux, layer: (self._mlp_fwd(mp, h, ar), aux),
         )]
 
     def _scan_layers_paged(self, params, x, cache, attn_fn, mode: Mode,
@@ -323,7 +324,7 @@ class Qwen3:
                 )
                 x = x + a
                 h = rms_norm(x, lp.ln2, cfg.rms_eps)
-                y, aux = ffn(lp.mlp, h, ar, aux)
+                y, aux = ffn(lp.mlp, h, ar, aux, layer)
                 return (x + y, kp, vp, ks, vs, aux), None
 
             carry, _ = jax.lax.scan(
