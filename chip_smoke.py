@@ -60,6 +60,11 @@ LATENT = {
     "cut": ["--num-layers", "5", "--first-k-dense", "1",
             "--experts-held", "16", "--vocab-rows", "16160"],
 }
+# granite-4.0-h-micro whole (36 Mamba-2 layers over a per-slot recurrent
+# state beside 4 attention layers over the pool), as the benchmark's
+# cell serves it: the `hybrid` phase (``--modes hybrid``; not in a
+# default run).
+HYBRID = {"model": "ibm-granite/granite-4.0-h-micro", "slots": 32}
 ONE_CHIP_MODEL = "Qwen/Qwen3-4B"   # largest dense preset one 16 GB chip holds
 FOUR_CHIP_MODEL = "Qwen/Qwen3-8B"  # 16.4 GB bf16: the preset that needs four
 
@@ -463,6 +468,75 @@ def latent_phase() -> None:
          peak_bytes_in_use=peak_bytes())
 
 
+def hybrid_phase() -> None:
+    """The Mamba-2 / attention hybrid: ``tdt_ssm_decode`` against the
+    plain einsums at the preset's widths (some rows in flight, the
+    others' state bit for bit as it was), then one server lifetime at
+    ``HYBRID["slots"]`` decode slots: as many requests as slots, a
+    repeat (no radix hit: a page holds no recurrent state), stats and
+    audit."""
+    from triton_distributed_tpu.models.config import get_config
+    from triton_distributed_tpu.ops.ssm.decode import (
+        live_rows,
+        ssm_decode,
+        ssm_decode_reference,
+    )
+    from triton_distributed_tpu.runtime import mesh
+
+    cfg = get_config(HYBRID["model"])
+    b, h, p, n = (HYBRID["slots"], cfg.mamba_n_heads, cfg.mamba_d_head,
+                  cfg.mamba_d_state)
+    ks = jax.random.split(jax.random.key(SEED), 5)
+    state = jax.random.normal(ks[0], (2, b, h, p, n), jnp.float32)
+    da = jax.random.uniform(ks[1], (b, h), jnp.float32, 0.5, 1.0)
+    dx = jax.random.normal(ks[2], (b, h, p), jnp.float32)
+    bv, cv = (jax.random.normal(k, (b, n), jnp.float32) for k in ks[3:])
+    live = jnp.arange(b) % 3 != 1
+    with jax.default_matmul_precision("highest"):  # the golden's einsum
+        want_y, want_s = ssm_decode_reference(state[1], da, dx, bv, cv, live)
+    got_y, got_s = ssm_decode(state, da, dx, bv, cv, *live_rows(live),
+                              layer=1)
+    errs = {"y": max_abs(got_y, want_y), "state": max_abs(got_s[1], want_s)}
+    untouched = bool(jnp.array_equal(got_s[0], state[0]))
+    emit(phase="hybrid_kernel", state=[h, p, n], slots=b, tolerance=1e-3,
+         max_abs_err=errs, other_layer_untouched=untouched)
+    require(max(errs.values()) <= 1e-3 and untouched,
+            f"ssm_decode: max abs err {errs}, untouched {untouched}")
+
+    rng = np.random.default_rng(SEED)
+    unit = cfg.max_length // 16
+    prompts = [rng.integers(0, cfg.vocab_size, size=unit + 7 * i).tolist()
+               for i in range(b)]
+    payload = {"requests": prompts, "gen_lens": [4 + i % 5 for i in range(b)]}
+    with running_server(["--model", HYBRID["model"], "--continuous",
+                         "--max-batch", str(b)]) as (ask, start_s):
+        check_on_chip(mesh.current_context())
+        cold, first_s = ask(payload)
+        n_tokens = check_response(cold, payload, cfg.vocab_size)
+        warm, warm_s = ask(payload)
+        n_tokens += check_response(warm, payload, cfg.vocab_size)
+        stats = ask({"cmd": "stats"})[0]["stats"]
+        require(stats["prefix_hit_tokens"] == 0,
+                "a radix hit for a model with a recurrent state")
+        decoded = stats["generated_tokens"] - stats["admitted"]
+        require(stats["ssm_decode_rows"]
+                == decoded + stats["lookahead_discarded"],
+                f"rows advanced are not the decoded tokens: {stats}")
+        problems = ask({"cmd": "audit"})[0]["problems"]
+        require(problems == [], f"audit: {problems}")
+    emit(phase="hybrid_serve", model=HYBRID["model"], slots=b,
+         listening_after_s=round(start_s, 2),
+         first_response_s_with_compile=round(first_s, 2),
+         warm_repeat_s=round(warm_s, 2), tokens_generated=n_tokens,
+         decode_steps=stats["decode_steps"],
+         kv_bytes_per_token=stats["kv_bytes_per_token"],
+         state_bytes_per_slot=stats["state_bytes_per_slot"],
+         rows_advanced=stats["ssm_decode_rows"],
+         warm_repeat_reproduced_cold_tokens=(
+             warm["outputs"] == cold["outputs"]),
+         peak_bytes_in_use=peak_bytes())
+
+
 def paged_logits(model, prompt, forced, mode: str, kv_dtype=None):
     """Logits ``[1 + len(forced), V]``: the prompt's last position
     through paged chunked prefill, then each teacher-forced token
@@ -757,6 +831,10 @@ def replica_check(model_name: str, n: int) -> None:
 
 # -- entry ------------------------------------------------------------------
 
+# Phases that serve a preset of their own, named by their mode.
+OTHER_MODELS = {"latent": latent_phase, "hybrid": hybrid_phase}
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--model", default=None,
@@ -765,14 +843,16 @@ def main(argv=None) -> int:
     p.add_argument("--modes", default=",".join(MODES),
                    help="comma-separated serving phases to run, of "
                    f"{list(MODES)} (one chip only), or 'latent': the cut "
-                   "latent-attention preset at 32 slots and its kernel")
+                   "latent-attention preset at 32 slots and its kernel, "
+                   "or 'hybrid': the Mamba-2 / attention hybrid whole at "
+                   "32 slots and its state kernel")
     p.add_argument("--chips", type=int, default=1, choices=[1, 4])
     args = p.parse_args(argv)
     modes = [m for m in args.modes.split(",") if m]
     for m in modes:
-        if m not in MODES and m != "latent":
+        if m not in MODES and m not in OTHER_MODELS:
             p.error(f"unknown mode {m!r}; choose from "
-                    f"{[*MODES, 'latent']}")
+                    f"{[*MODES, *OTHER_MODELS]}")
 
     # Fail, never hang: past the limit every thread's stack is dumped
     # and the process exits non-zero.
@@ -817,14 +897,14 @@ def run(chips: int, model: str | None, modes: list[str]) -> dict:
         replica_check(model or ONE_CHIP_MODEL, 4)
     else:
         for mode in modes:
-            if mode == "latent":
-                latent_phase()
+            if mode in OTHER_MODELS:
+                OTHER_MODELS[mode]()
             else:
                 serve_phase(model or ONE_CHIP_MODEL, mode)
             # The phase's model must be gone before the next is built:
             # the chip does not hold two.
             gc.collect()
-        if modes != ["latent"]:
+        if set(modes) - set(OTHER_MODELS):
             logit_checks(model or ONE_CHIP_MODEL)
     return facts
 

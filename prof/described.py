@@ -1,12 +1,17 @@
 """Described-chip compile of the served step programs: memory, and the
 ops whose result is pool-shaped (no chip; a compile is not a chip run).
 
-    python prof/described.py <model> <tp> <mode> <slots,slots,...> [bf16|int8]
+    python prof/described.py <model> <tp> <mode> <slots,slots,...> [bf16|int8] [key=int ...]
     python prof/described.py Qwen/Qwen3-4B 1 xla 4,8
+    python prof/described.py rednote-hilab/dots.vlm1.inst 1 xla 32 bf16 \
+        num_layers=5 first_k_dense=1 experts_held=16 vocab_size=16160
+    python prof/described.py ibm-granite/granite-4.0-h-micro 1 xla 32
 
 For each slot count it compiles the served decode step and one chunk
-prefill (256 tokens, 2 gathered pages) with a donated cache for a
-described v5e, and prints what ``memory_analysis`` says beside the
+prefill (the dense decoder: 256 tokens, 2 gathered pages; the latent
+expert model: 2,048 tokens over the whole table row; the hybrid: 1,024
+tokens, 8 pages) with a donated cache for a described v5e; ``key=int``
+pairs cut a preset as ``run_server``'s flags do. It prints what ``memory_analysis`` says beside the
 ``copy`` / ``dynamic-slice`` / fusion instructions whose result has the
 shape of the whole KV pool or of one layer of it, and a hash of each
 program's lowered text (``tdt_finite_greedy``'s too): equal hashes on
@@ -46,6 +51,15 @@ def pool_shaped_moves(hlo_text: str, pool_shape, dtype: str = "bf16") -> list:
         shapes.add(",".join(map(str, (pool_shape[0] * pool_shape[1],
                                       *pool_shape[2:]))))
     return moves_of_shapes(hlo_text, shapes, dtype)
+
+
+def state_shaped_moves(hlo_text: str, state_shape) -> list:
+    """The same for a float32 recurrent state ``[Lm, B, H, P, N]``: the
+    whole of it, its ``[Lm * B, H, P, N]`` view, or one layer of it."""
+    lm, b, *rest = state_shape
+    shapes = {",".join(map(str, s))
+              for s in (state_shape, (lm * b, *rest), (b, *rest))}
+    return moves_of_shapes(hlo_text, shapes, "f32")
 
 
 def moves_of_shapes(hlo_text: str, shapes, dtype: str = "bf16") -> list:
@@ -89,7 +103,6 @@ def main(argv):
         PagedKVCache,
         paged_cache_specs,
     )
-    from triton_distributed_tpu.models.qwen import Qwen3
     from triton_distributed_tpu.runtime import mesh as mesh_mod
 
     name, tp, mode = argv[1], int(argv[2]), argv[3]
@@ -97,44 +110,76 @@ def main(argv):
     quant = kv == "int8"
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     ctx = mesh_mod.initialize_distributed(tp=tp, devices=list(topo.devices)[:tp])
-    cfg = get_config(name)
-    model = Qwen3(cfg, ctx=ctx)
+    cfg = get_config(name, **{k: int(v) for k, v in
+                              (a.split("=") for a in argv[6:])})
+    # The model's class, its chunk and the pool's shapes from the
+    # configuration's own fields (what a parent checkout lacks reads as
+    # absent: this file is copied over it to compare hashes).
+    recurrent = bool(getattr(cfg, "mamba_layers", 0))
+    pool_layers = getattr(cfg, "attention_layers", cfg.num_layers)
+    if recurrent:
+        from triton_distributed_tpu.models.hybrid_ssm import HybridSSM as Model
+        chunk_tokens, chunk_pages = 1024, 8
+    elif cfg.kv_lora_rank:
+        from triton_distributed_tpu.models.latent_moe import LatentMoE as Model
+        chunk_tokens, chunk_pages = 2048, None
+    else:
+        from triton_distributed_tpu.models.qwen import Qwen3 as Model
+        chunk_tokens, chunk_pages = 256, 2
+    model = Model(cfg, ctx=ctx)
     shapes = jax.eval_shape(model.init_params, jax.random.key(0))
     params = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         shapes, model.param_shardings,
     )
     pps = cfg.max_length // PAGE
-    specs = paged_cache_specs("tp", quant)
+    specs = (paged_cache_specs("tp", quant, True) if recurrent
+             else paged_cache_specs("tp", quant))
 
     def sds(shape, dt, spec=()):
         return jax.ShapeDtypeStruct(shape, dt, sharding=ctx.sharding(*spec))
 
     for b in (int(x) for x in argv[4].split(",")):
-        pool_shape = (cfg.num_layers, b * pps + 1, cfg.num_kv_heads // tp,
-                      PAGE, cfg.head_dim)
-        glob = (cfg.num_layers, b * pps + 1, cfg.num_kv_heads, PAGE,
-                cfg.head_dim)
-        pages = sds(glob, jnp.int8 if quant else jnp.bfloat16, specs.k_pages)
+        row = getattr(cfg, "pool_row_dim", cfg.head_dim)
+        pool_shape = (pool_layers, b * pps + 1, cfg.num_kv_heads // tp,
+                      PAGE, row)
+        glob = (pool_layers, b * pps + 1, cfg.num_kv_heads, PAGE, row)
+        v_glob = glob
+        if cfg.kv_lora_rank:  # one latent row a token, the rotary part
+            pool_shape = glob = (*glob[:2], 1, PAGE, cfg.kv_lora_rank)
+            v_glob = (*glob[:3], cfg.qk_rope_head_dim, PAGE)  # transposed
+        dt = jnp.int8 if quant else jnp.bfloat16
         scale = sds(glob[:3], jnp.float32, specs.k_scale) if quant else None
+        state = {}
+        if recurrent:
+            from triton_distributed_tpu.models.paged_kv_cache import (
+                recurrent_state_shapes,
+            )
+
+            ssm, conv = recurrent_state_shapes(cfg, b)
+            state = dict(ssm_state=sds(ssm, jnp.float32),
+                         conv_state=sds(conv, jnp.bfloat16),
+                         live=sds((b,), jnp.bool_))
         cache = PagedKVCache(
-            k_pages=pages, v_pages=pages,
+            k_pages=sds(glob, dt, specs.k_pages),
+            v_pages=sds(v_glob, dt, specs.v_pages),
             page_table=sds((b, pps), jnp.int32), kv_len=sds((b,), jnp.int32),
-            k_scale=scale, v_scale=scale,
+            k_scale=scale, v_scale=scale, **state,
         )
         i32 = sds((), jnp.int32)
         step = model.decode_fn_paged(mode, quantized=quant)
         chunk = ctx.shard_map(
             lambda p, t, c, s, o, n, li: model._prefill_chunk_shard(
-                p, t, c, s, o, n, li, mode=mode, kv_pages=2),
+                p, t, c, s, o, n, li, mode=mode, kv_pages=chunk_pages),
             in_specs=(model.param_specs, jax.P(), specs, jax.P(), jax.P(),
                       jax.P(), jax.P()),
             out_specs=(jax.P(), specs),
         )
         programs = (
             ("decode", step, (params, sds((b,), jnp.int32), cache), (2,)),
-            ("chunk256", chunk, (params, sds((256,), jnp.int32), cache,
-                                 i32, i32, i32, i32), (2,)),
+            (f"chunk{chunk_tokens}", chunk,
+             (params, sds((chunk_tokens,), jnp.int32), cache,
+              i32, i32, i32, i32), (2,)),
             ("finite_greedy", tdt_finite_greedy,
              (sds((b, cfg.vocab_size), jnp.float32),), ()),
         )
@@ -161,7 +206,8 @@ def main(argv):
                 "| custom calls", txt.count("tpu_custom_call"),
                 "all-reduce", txt.count("all-reduce("),
                 "| pool-shaped moves:",
-                pool_shaped_moves(txt, pool_shape, "s8" if quant else "bf16")
+                (pool_shaped_moves(txt, pool_shape, "s8" if quant else "bf16")
+                 + (state_shaped_moves(txt, ssm) if recurrent else []))
                 or 0,
                 "| lowered sha256",
                 hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16],

@@ -166,7 +166,7 @@ def pytest_collection_modifyitems(config, items):
         if "slow" in item.keywords:
             item.add_marker(skip)
 
-# -- the benchmark's tests and a second architecture (PR 35) -------------------
+# -- the benchmark's tests and further architectures (PRs 35, 37) -------------
 #
 # Two of PR 34's tests cannot hold once the manifest has a second
 # architecture, and a `model_config` PR may not edit the files they and
@@ -186,7 +186,8 @@ def pytest_collection_modifyitems(config, items):
 # ``data/chip_readings.json`` as it is imported, so a configuration that
 # file does not know ends the whole file's collection. The new
 # configuration's readings are a file of their own,
-# ``data/chip_readings_latent_moe.json``, handed to that one lookup while
+# ``data/chip_readings_latent_moe.json`` (PR 37: a third,
+# ``chip_readings_hybrid_ssm.json``), handed to that one lookup while
 # the module is collected (and only then), so the cell's limits are held
 # to its chip readings by the same tests. One of them asks every cell for
 # three readings of the program's own ``--kv-dtype int8`` path: the latent
@@ -203,14 +204,25 @@ def pytest_collection_modifyitems(config, items):
 
 import json  # noqa: E402
 
-CELL = "dots-vlm1-ep16.docs-closed"
-EXPECTED = {
-    f"test_a_cell_carries_its_configurations_modules[{CELL}]":
-        "the cell's configuration names its own reference and work "
-        "modules, not the dense decoder's",
-    f"test_every_cell_has_readings_behind_its_limits[{CELL}]":
-        "the latent pool has no --kv-dtype int8 path to take readings of",
+# A cell whose configuration names its own modules, and the readings
+# file of that configuration (``data/chip_readings_<suffix>.json``)
+# with why it has no readings of a ``--kv-dtype int8`` path.
+OWN_MODULES = {
+    "dots-vlm1-ep16.docs-closed": (
+        "latent_moe",
+        "the latent pool has no --kv-dtype int8 path to take readings of"),
+    "granite-4.0-h-micro.chat-closed32": (
+        "hybrid_ssm",
+        "a model with a recurrent state has no --kv-dtype int8 path to "
+        "take readings of"),
 }
+EXPECTED = {}
+for _cell, (_, _no_int8) in OWN_MODULES.items():
+    EXPECTED[f"test_a_cell_carries_its_configurations_modules[{_cell}]"] = (
+        "the cell's configuration names its own reference and work "
+        "modules, not the dense decoder's")
+    EXPECTED[
+        f"test_every_cell_has_readings_behind_its_limits[{_cell}]"] = _no_int8
 LIMITS_TEST = "test_benchmark_limits.py"
 READINGS = os.path.join(os.path.dirname(__file__), "benchmark", "data",
                         "chip_readings")
@@ -220,8 +232,9 @@ _json_load = json.load
 def _load_with_the_new_configuration(fp, *args, **kw):
     out = _json_load(fp, *args, **kw)
     if getattr(fp, "name", "") == READINGS + ".json":
-        with open(READINGS + "_latent_moe.json") as f:
-            out["configs"].update(_json_load(f)["configs"])
+        for suffix, _ in OWN_MODULES.values():
+            with open(f"{READINGS}_{suffix}.json") as f:
+                out["configs"].update(_json_load(f)["configs"])
     return out
 
 

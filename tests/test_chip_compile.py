@@ -374,6 +374,111 @@ def test_latent_share_fits_the_chip(chip, dots_share, program):
                    "1,16,2048,7168"})
 
 
+GRANITE = "ibm-granite/granite-4.0-h-micro"
+GRANITE_SLOTS = 32
+STATE = (36, GRANITE_SLOTS, 64, 64, 128)
+
+
+def test_ssm_decode_kernel(chip):
+    """``tdt_ssm_decode`` at the published widths: 32 slots' states of
+    36 layers, aliased: the program holds no second state, whole or a
+    layer's."""
+    from triton_distributed_tpu.ops.ssm.decode import ssm_decode
+
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda s, da, dx, b, c, rows, n, layer: ssm_decode(
+            s, da, dx, b, c, rows, n, layer=layer),
+        donate_argnums=(0,),
+    ).lower(
+        sds(chip, STATE, f32), sds(chip, (GRANITE_SLOTS, 64), f32),
+        sds(chip, (GRANITE_SLOTS, 64, 64), f32),
+        sds(chip, (GRANITE_SLOTS, 128), f32),
+        sds(chip, (GRANITE_SLOTS, 128), f32),
+        sds(chip, (GRANITE_SLOTS,), jnp.int32), sds(chip, (), jnp.int32),
+        sds(chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tdt_ssm_decode" in text and "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4e6
+    assert mem.alias_size_in_bytes >= math.prod(STATE) * 4
+    assert not _described().state_shaped_moves(text, STATE)
+
+
+@pytest.fixture
+def granite(chip):
+    """The published preset on the described chip, parameter shapes only."""
+    from triton_distributed_tpu.models.config import get_config
+    from triton_distributed_tpu.models.hybrid_ssm import HybridSSM
+
+    model = HybridSSM(get_config(GRANITE), ctx=chip)
+    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
+    model.params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, model.param_shardings,
+    )
+    return model
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk1024"])
+def test_hybrid_fits_the_chip_and_moves_neither_state_nor_pool(
+        chip, granite, program):
+    """granite-4.0-h-micro whole: the 32-slot decode step and the widest
+    chunk the cell sends (1,024 tokens), ``head_dim`` 64 through both
+    attention kernels over pool rows padded to 128 lanes. Arguments as
+    reckoned: 6.79 GB of weights (the head drawn apart), 32 x 76.4 MB of
+    state, a 2.15 GB pool for 4 layers = 11.4 GB (9.9 + the padding and
+    the second table); the temporaries hold no copy of the state array
+    or of the pool, whole or a layer's."""
+    from triton_distributed_tpu.models.paged_kv_cache import (
+        PagedKVCache,
+        paged_cache_specs,
+        recurrent_state_shapes,
+    )
+
+    model, cfg = granite, granite.cfg
+    pages = GRANITE_SLOTS * PPS + 1
+    pool = (4, pages, 8, PAGE, 128)
+    ssm, conv = recurrent_state_shapes(cfg, GRANITE_SLOTS)
+    assert ssm == STATE and conv == (36, 3, GRANITE_SLOTS, 4352)
+    cache = PagedKVCache(
+        k_pages=sds(chip, pool, BF16), v_pages=sds(chip, pool, BF16),
+        page_table=sds(chip, (GRANITE_SLOTS, PPS), jnp.int32),
+        kv_len=sds(chip, (GRANITE_SLOTS,), jnp.int32),
+        ssm_state=sds(chip, ssm, jnp.float32),
+        conv_state=sds(chip, conv, BF16),
+        live=sds(chip, (GRANITE_SLOTS,), jnp.bool_),
+    )
+    i32 = sds(chip, (), jnp.int32)
+    if program == "decode":
+        fn = model.decode_fn_paged("xla")
+        args = (model.params, sds(chip, (GRANITE_SLOTS,), jnp.int32), cache)
+    else:
+        specs = paged_cache_specs("tp", recurrent=True)
+        fn = chip.shard_map(
+            functools.partial(model._prefill_chunk_shard, mode="xla",
+                              kv_pages=8),
+            in_specs=(model.param_specs, P(), specs, P(), P(), P(), P()),
+            out_specs=(P(), specs),
+        )
+        args = (model.params, sds(chip, (1024,), jnp.int32), cache,
+                i32, i32, i32, i32)
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    print(program, "args %.3f temp %.3f alias %.3f GB" % tuple(
+        x / 1e9 for x in (mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+                          mem.alias_size_in_bytes)))
+    assert 11.3e9 < mem.argument_size_in_bytes < 11.5e9
+    assert mem.temp_size_in_bytes < (0.02e9 if program == "decode"
+                                     else 0.2e9)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    text = compiled.as_text()
+    assert "tdt_flash_attention" in text if program != "decode" else (
+        "tdt_flash_decode_paged" in text and "tdt_ssm_decode" in text)
+    assert not _pool_shaped_moves(text, pool, "bf16")
+    assert not _described().state_shaped_moves(text, ssm)
+
+
 @pytest.mark.parametrize(
     "variant", ["prefill", "traced_offset_chunk", "int8_chunk", "tree_bias"]
 )

@@ -124,6 +124,30 @@ def test_latent_phase_at_tiny_size_when_steered(steered, monkeypatch, capsys):
     assert not any(ln.get("phase") == "logits" for ln in lines)
 
 
+def test_hybrid_phase_at_tiny_size_when_steered(steered, monkeypatch, capsys):
+    """``--modes hybrid`` at the `tiny-hybrid` preset: the state kernel
+    against the plain einsums, then the preset served on every slot;
+    the rows advanced are the decoded tokens (and the rows of steps in
+    flight under a slot that had ended)."""
+    monkeypatch.setattr(steered, "HYBRID", {"model": "tiny-hybrid",
+                                            "slots": 6})
+    assert steered.main(["--modes", "hybrid"]) == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    assert lines[-1]["ok"] is True
+    kernel = next(ln for ln in lines if ln.get("phase") == "hybrid_kernel")
+    assert max(kernel["max_abs_err"].values()) <= kernel["tolerance"]
+    assert kernel["other_layer_untouched"]
+    served = next(ln for ln in lines if ln.get("phase") == "hybrid_serve")
+    assert served["slots"] == 6 and served["tokens_generated"] > 0
+    assert served["kv_bytes_per_token"] == 2 * 2 * 4 * 16 * 4
+    assert served["state_bytes_per_slot"] == 5 * (8 * 16 * 16 * 4
+                                                  + 3 * 160 * 4)
+    assert served["rows_advanced"] > 0
+    assert served["warm_repeat_reproduced_cold_tokens"]
+    assert not any(ln.get("phase") == "logits" for ln in lines)
+
+
 def test_a_phase_that_raises_fails_the_run(steered, monkeypatch, capsys):
     def broken(model, mode):
         raise RuntimeError(f"phase {mode} broke")

@@ -29,6 +29,7 @@ from triton_distributed_tpu.ops.overlap.gemm_ar import gemm_ar
 from triton_distributed_tpu.ops.overlap.ag_gemm import ag_gemm
 from triton_distributed_tpu.ops.overlap.gemm_rs import gemm_rs
 from triton_distributed_tpu.runtime.mesh import DistContext, current_context
+from triton_distributed_tpu.runtime.pytree import register_param_dataclass
 
 Mode = Literal["xla", "pallas", "pallas_ar", "xla_ar"]
 
@@ -44,8 +45,6 @@ class TPAttnParams:
     q_norm: jax.Array | None
     k_norm: jax.Array | None
 
-
-from triton_distributed_tpu.runtime.pytree import register_param_dataclass
 
 register_param_dataclass(TPAttnParams, ["wqkv", "wo", "q_norm", "k_norm"])
 
@@ -65,7 +64,8 @@ class TPAttnDims:
     hq_loc: int
     hkv_loc: int
     head_dim: int
-    rope_theta: float = 1e6
+    rope_theta: float | None = 1e6  # None: no positions at all
+    sm_scale: float | None = None  # None: head_dim ** -0.5
 
     @property
     def qkv_loc(self) -> int:
@@ -221,6 +221,32 @@ def _write_chunk(pages, scales, rows, layer, table_row, start, n_real=None):
     return pages, scales
 
 
+def _rotate(dims: TPAttnDims, x: jax.Array, pos: jax.Array) -> jax.Array:
+    """Rotate-half RoPE at ``pos``, where the configuration has
+    positions at all (``rope_theta``)."""
+    if dims.rope_theta is None:
+        return x
+    return apply_rope(x, pos, dims.rope_theta)
+
+
+def _to_pool(dims: TPAttnDims, pages: jax.Array, *heads: jax.Array):
+    """``heads`` (each ``[..., head_dim]``) widened with zero columns to
+    the pool's row width, and the softmax scale to hand the kernels
+    with them. A pool row is ``head_dim`` wide except where the cache
+    manager pads it to the TPU's 128 lanes
+    (``ModelConfig.pool_row_dim``: XLA stores a 64-wide row transposed
+    and re-lays the pool out around every program); zero columns add
+    nothing to a score and come back as zero columns of the output, so
+    the arithmetic is the same under the head's OWN scale."""
+    pad = pages.shape[-1] - dims.head_dim
+    if not pad:
+        return (*heads, dims.sm_scale)
+    scale = (dims.head_dim ** -0.5 if dims.sm_scale is None
+             else dims.sm_scale)
+    return (*(jnp.pad(h, [(0, 0)] * (h.ndim - 1) + [(0, pad)])
+              for h in heads), scale)
+
+
 def tp_attn_prefill_paged_chunk(
     params: TPAttnParams,
     x: jax.Array,           # [C, d] replicated — one chunk of ONE sequence
@@ -289,9 +315,9 @@ def tp_attn_prefill_paged_chunk(
     k = _rms_head(k, params.k_norm)
     pos = q_offset + jnp.arange(c, dtype=jnp.int32)  # [C] absolute storage
     rpos = pos if rope_pos is None else rope_pos
-    q = apply_rope(q.swapaxes(0, 1), rpos, dims.rope_theta)  # [h, C, hd]
-    k = apply_rope(k.swapaxes(0, 1), rpos, dims.rope_theta)
-    v = v.swapaxes(0, 1)
+    q = _rotate(dims, q.swapaxes(0, 1), rpos)  # [h, C, hd]
+    k = _rotate(dims, k.swapaxes(0, 1), rpos)
+    q, k, v, sm_scale = _to_pool(dims, k_pages, q, k, v.swapaxes(0, 1))
 
     # Write the chunk's KV through the table, in place.
     n_real = None if q_end is None else q_end - q_offset
@@ -326,15 +352,19 @@ def tp_attn_prefill_paged_chunk(
         vs_dense = v_scale[layer, gather_row].T[None]
         o = flash_attention(
             q[None], k_dense, v_dense, causal=True, kv_offset=q_offset,
+            sm_scale=sm_scale,
             block_k=page, k_scale=ks_dense, v_scale=vs_dense,
             bias=None if attn_bias is None else attn_bias[:, :s_max],
         )[0]  # [h, C, hd]
     else:
         o = flash_attention(
             q[None], k_dense, v_dense, causal=True, kv_offset=q_offset,
+            sm_scale=sm_scale,
             block_k=128 if s_max % 128 == 0 else page,
             bias=None if attn_bias is None else attn_bias[:, :s_max],
         )[0]  # [h, C, hd]
+    if o.shape[-1] != dims.head_dim:  # a padded pool row's zero columns
+        o = o[..., : dims.head_dim]
     o_flat = o.swapaxes(0, 1).reshape(c, dims.hq_loc * dims.head_dim)
     o_flat = o_flat.astype(x.dtype)
     if mode in ("xla", "xla_ar"):
@@ -446,8 +476,9 @@ def tp_attn_decode_paged(
     q, k, v = dims.split_qkv(qkv)  # [B, h, hd]
     q = _rms_head(q, params.q_norm)
     k = _rms_head(k, params.k_norm)
-    q = apply_rope(q, kv_len[:, None], dims.rope_theta)
-    k = apply_rope(k, kv_len[:, None], dims.rope_theta)
+    q = _rotate(dims, q, kv_len[:, None])
+    k = _rotate(dims, k, kv_len[:, None])
+    q, k, v, sm_scale = _to_pool(dims, k_pages, q, k, v)
 
     # Active rows never share a page; inactive rows fan into the trash
     # page, where the scale protocol's duplicate-pid contract holds.
@@ -461,8 +492,10 @@ def tp_attn_decode_paged(
 
     o = paged_flash_decode(
         q, k_pages, v_pages, page_table, kv_len + 1, layer=layer,
-        walk=walk, k_scale=k_scale, v_scale=v_scale,
+        walk=walk, sm_scale=sm_scale, k_scale=k_scale, v_scale=v_scale,
     )
+    if o.shape[-1] != dims.head_dim:  # a padded pool row's zero columns
+        o = o[..., : dims.head_dim]
     o_flat = o.reshape(b, dims.hq_loc * dims.head_dim).astype(x.dtype)
     if mode in ("xla", "xla_ar"):
         part = jnp.dot(o_flat, params.wo, preferred_element_type=jnp.float32)

@@ -1,8 +1,10 @@
 """Models + serving (parity: reference ``python/triton_dist/models/``).
 
 ``AutoLLM`` mirrors ``models/__init__.py:32-48`` — dispatch by model
-name/config to Qwen3 dense or MoE, or to the latent-attention expert
-model (``LatentMoE``, presets with a ``kv_lora_rank``), loading HF weights when a checkpoint
+name/config to Qwen3 dense or MoE, to the latent-attention expert
+model (``LatentMoE``, presets with a ``kv_lora_rank``) or to the hybrid
+of Mamba-2 and attention layers (``HybridSSM``, presets whose
+``layer_types`` declares recurrent layers), loading HF weights when a checkpoint
 directory is given and random-initializing otherwise (the reference's
 perf scripts also run on random weights).
 """
@@ -73,7 +75,11 @@ class AutoLLM:
             model.set_params(load_hf_state_dict(cfg, state, n))
             return model
         cfg = get_config(name_or_path, **overrides)
-        if cfg.kv_lora_rank:
+        if cfg.mamba_layers:
+            from triton_distributed_tpu.models.hybrid_ssm import HybridSSM
+
+            model = HybridSSM(cfg, axis=axis, ctx=ctx)
+        elif cfg.kv_lora_rank:
             from triton_distributed_tpu.models.latent_moe import LatentMoE
 
             model = LatentMoE(cfg, axis=axis, ctx=ctx)

@@ -68,6 +68,31 @@ class ModelConfig:
     # all of them). The router keeps all its outputs.
     experts_held: int = 0
     expert_offset: int = 0
+    # The layer list, DECLARED: one kind a layer, ``"attention"`` or
+    # ``"mamba"`` (a Mamba-2 mixer over a per-slot recurrent state);
+    # empty = every layer is an attention layer. The scan groups of the
+    # serving programs follow from it (``models/hybrid_ssm.py``).
+    layer_types: tuple = ()
+    # The Mamba-2 mixer: ``mamba_n_heads`` heads of ``mamba_d_head``
+    # (together ``mamba_expand x hidden_size``), a state of
+    # ``mamba_d_state`` a head and channel, ``mamba_n_groups`` groups of
+    # B / C shared by their heads, a causal depthwise convolution of
+    # ``mamba_d_conv`` taps, prefill in blocks of ``mamba_chunk_size``.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    # What an attention layer does beside its dot products, and the
+    # stream's scalar multipliers (Qwen3: rotary, ``head_dim ** -0.5``,
+    # all ones; its per-head q/k norm is on where the params carry the
+    # scales). ``attention_multiplier`` 0 = the default scale.
+    rope: bool = True
+    attention_multiplier: float = 0.0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # runtime
     max_length: int = 4096
     dtype: jnp.dtype = jnp.bfloat16
@@ -76,6 +101,38 @@ class ModelConfig:
     # symmetric per-page-per-head scales, dequantized inside the
     # attention kernels (docs/serving.md "Quantized KV cache").
     kv_dtype: str | None = None
+
+    @property
+    def mamba_layers(self) -> int:
+        return sum(t == "mamba" for t in self.layer_types)
+
+    @property
+    def attention_layers(self) -> int:
+        """Layers that cache rows in the paged pool."""
+        return self.num_layers - self.mamba_layers
+
+    @property
+    def pool_row_dim(self) -> int:
+        """Width of a K/V row in the paged pool: ``head_dim``, but a
+        64-wide head is held padded to the TPU's 128 lanes with zero
+        columns. Left at 64, XLA stores the pool's rows transposed at
+        every program's entry and exit and copies the whole pool to and
+        from the layout the kernels read (a described compile: four
+        pool-sized copies a program); the padding costs the pool's
+        bytes twice and changes no score (``tp_attn._to_pool``)."""
+        return 128 if self.head_dim == 64 else self.head_dim
+
+    @property
+    def slot_keeps(self) -> tuple:
+        """What a decode slot holds between steps, the ONE statement
+        the engine's refusals and the cache manager read:
+        ``"kv_pages"`` (per-head K/V rows in the paged pool),
+        ``"latent_rows"`` (one latent row a token in it) and
+        ``"recurrent_state"`` (a fixed-size state a slot that is no
+        pages: it cannot be appended to, truncated, shared by page,
+        rolled back or, yet, exported)."""
+        rows = "latent_rows" if self.kv_lora_rank else "kv_pages"
+        return (rows, "recurrent_state") if self.mamba_layers else (rows,)
 
 
 # Architecture presets (numbers from the public HF configs the reference
@@ -124,6 +181,25 @@ _PRESETS: dict[str, dict] = {
         scoring_func="sigmoid", n_group=8, topk_group=4,
         routed_scaling_factor=2.5,
     ),
+    # ibm-granite/granite-4.0-h-micro as published (model_type
+    # granitemoehybrid, no routed experts): 36 Mamba-2 layers and GQA
+    # attention at layers 5, 15, 25, 35, every layer with the same
+    # SwiGLU; no positions (the recurrence orders the tokens), no q/k
+    # norm, a stated softmax scale and four scalar multipliers. The
+    # head is drawn apart from the embedding (random tied weights would
+    # make every token predict itself).
+    "ibm-granite/granite-4.0-h-micro": dict(
+        vocab_size=100352, hidden_size=2048, intermediate_size=8192,
+        num_layers=40, num_q_heads=32, num_kv_heads=8, head_dim=64,
+        rope_theta=1e4, rms_eps=1e-5,
+        layer_types=tuple(
+            "attention" if i % 10 == 5 else "mamba" for i in range(40)),
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+        mamba_d_conv=4, mamba_n_groups=1, mamba_chunk_size=256,
+        rope=False, attention_multiplier=0.015625,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0,
+    ),
     # Tiny configs for tests / CPU-simulator runs.
     "tiny": dict(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -146,6 +222,17 @@ _PRESETS: dict[str, dict] = {
         moe_intermediate_size=32, n_shared_experts=1,
         scoring_func="sigmoid", n_group=4, topk_group=2,
         routed_scaling_factor=2.5, dtype=jnp.float32,
+    ),
+    "tiny-hybrid": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=7,
+        num_q_heads=8, num_kv_heads=4, head_dim=16, rms_eps=1e-5,
+        max_length=256,
+        layer_types=("mamba", "mamba", "attention", "mamba", "mamba",
+                     "attention", "mamba"),
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+        mamba_chunk_size=8, rope=False,
+        attention_multiplier=0.0625, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0, dtype=jnp.float32,
     ),
 }
 

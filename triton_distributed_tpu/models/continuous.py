@@ -71,6 +71,7 @@ from triton_distributed_tpu.models.paged_kv_cache import (
     copy_page,
     gather_pages,
     init_paged_cache,
+    state_bytes_per_slot,
     truncate_pages,
     write_page,
     write_prefill,
@@ -348,13 +349,14 @@ class _StepLaunch:
     logits: object      # [max_batch, V] device
     finite: object      # [max_batch] device all-finite mask
     toks: object        # [max_batch] device greedy tokens
-    counts: object = None  # [2] device: an expert share's step sums
+    counts: object = None  # device int32 sums: ``model.step_counts``
     host: tuple | None = None
 
     def fetch(self) -> tuple:
         """THE host sync of a step: ``(finite, tokens)`` as numpy,
-        fetched once (an expert share's two sums with them, kept in
-        ``counts``). After it the device has left the step."""
+        fetched once (the step's own sums with them, kept in
+        ``counts``: ``model.step_counts`` names them). After it the
+        device has left the step."""
         if self.host is None:
             self.host = (np.asarray(self.finite), np.array(self.toks))
             if self.counts is not None:
@@ -418,24 +420,40 @@ class ContinuousEngine(MegaDispatch):
         self.mode = mode
         self.mega_cfg = mega_cfg
         # A model whose step returns sums beside its logits (an expert
-        # share's two counts, ``decode_step_counted``) has that ONE
-        # decode program; they ride each step's ``fetch()``.
+        # share's two counts, a hybrid's advanced rows:
+        # ``decode_step_counted``, named by ``model.step_counts``) has
+        # that ONE decode program; they ride each step's ``fetch()``.
         self._counted_step = getattr(model, "decode_step_counted", None)
-        if getattr(model.cfg, "kv_lora_rank", 0):
-            # A latent-attention model has the single-step paged
-            # programs only: refuse the rest by the flag that asks.
+        # What a slot of this model keeps (``cfg.slot_keeps``, the one
+        # statement) decides which paths have a program. Latent rows
+        # and a recurrent state have the single-step paged programs
+        # over a full-width pool only; a recurrent state, which is not
+        # pages, can besides not be rolled back, exported or spilled.
+        # Refused by the flag that asks.
+        keeps = model.cfg.slot_keeps
+        self._recurrent = "recurrent_state" in keeps
+        if keeps != ("kv_pages",):
             name = model.cfg.model_name
+            what = ("latent attention" if "latent_rows" in keeps
+                    else "a recurrent state beside K/V pages")
             for flag, asked in (
                 ("--mode mega", mode == "mega"),
                 ("--kv-dtype int8",
                  (kv_dtype or model.cfg.kv_dtype) == "int8"),
                 ("--speculative", bool(speculative)),
+                ("--snapshot-every",
+                 self._recurrent and bool(snapshot_every)),
+                ("--tier-bytes / --tier-dir", self._recurrent and bool(
+                    tier_bytes or tier_dir or tier is not None)),
             ):
                 if asked:
                     raise ValueError(
-                        f"{flag}: {name} has no such path (latent "
-                        "attention serves the xla/pallas single-step "
-                        "programs over a full-width latent pool)")
+                        f"{flag}: {name} has no such path ({what} "
+                        "serves the xla/pallas single-step programs "
+                        "over a full-width pool"
+                        + (", and a slot's state is not pages: it "
+                           "cannot be rolled back, exported or spilled)"
+                           if self._recurrent else ")"))
         # Resident decode (docs/megakernel.md "Resident decode"): the
         # NS launch width becomes a knob (perf/mega_serve_bench.py
         # sweeps it), batch buckets give a 2-slot round a 2-wide launch
@@ -561,8 +579,10 @@ class ContinuousEngine(MegaDispatch):
         self._kv_len = np.zeros((max_batch,), np.int32)
         self._tok = np.zeros((max_batch,), np.int32)
         self._slots: list[Request | None] = [None] * max_batch
-        self.prefix = PrefixCache(self.pool, page_size) if prefix_cache else None
-        if prefix_cache:
+        self.prefix = PrefixCache(
+            self.pool, page_size, pages_hold_all=not self._recurrent
+        ) if prefix_cache else None
+        if prefix_cache and not self._recurrent:
             # Compile the copy-on-write program now (the trash page onto
             # itself), not at the first partial-page hit in the middle
             # of serving: one small program a pool shape.
@@ -705,6 +725,16 @@ class ContinuousEngine(MegaDispatch):
                 "tdt_kv_row_bytes",
                 "Logical bytes of one token's cache row in one layer.",
             ).set(kv_bytes_per_token(self.cache) / model.cfg.num_layers)
+        if self._recurrent:
+            obs_metrics.gauge(
+                "tdt_ssm_state_bytes_per_slot",
+                "Bytes of one decode slot's recurrent state over all "
+                "its layers, whatever the slot's context.",
+            ).set(state_bytes_per_slot(self.cache))
+            obs_metrics.gauge(
+                "tdt_ssm_state_slots",
+                "Decode slots that hold a recurrent state (max_batch).",
+            ).set(max_batch)
         self.snapshot_every = int(snapshot_every)
         self._handoff_at: int | None = None
         self._round = 0
@@ -809,6 +839,10 @@ class ContinuousEngine(MegaDispatch):
             "moe_routed_tokens": 0,
             "moe_decode_local_rows": 0,
             "moe_decode_experts_touched": 0,
+            # Rows whose recurrent state a decode step advanced (a
+            # model with recurrent layers only; docs/serving.md
+            # "Recurrent state beside pages").
+            "ssm_decode_rows": 0,
             "a2a_dropped": 0,
             # Durable KV tier ledger (docs/serving.md "Tiered KV"):
             # evictions demoted to the tier, and admissions extended by
@@ -836,6 +870,9 @@ class ContinuousEngine(MegaDispatch):
         )
 
         stats["kv_bytes_per_token"] = kv_bytes_per_token(self.cache)
+        if self._recurrent:
+            stats["state_bytes_per_slot"] = state_bytes_per_slot(self.cache)
+            stats["state_slots"] = self.max_batch
         stats["kv_dtype"] = (
             self.kv_dtype or str(jnp.dtype(self.cache.k_pages.dtype))
         )
@@ -905,10 +942,19 @@ class ContinuousEngine(MegaDispatch):
         # the round after a slot ended under a step in flight, would
         # compile the step once more in the middle of serving).
         put = self.model.ctx.replicate
+        state = {}
+        if self._recurrent:
+            # The rows IN FLIGHT, which alone a decode step may advance:
+            # ``_slots`` holds a request from the end of its admission
+            # (a slot between two of its chunks is mapped above and not
+            # in flight) to its end.
+            state["live"] = put(
+                np.asarray([r is not None for r in self._slots]))
         self.cache = dataclasses.replace(
             self.cache,
             page_table=put(self._table.copy()),
             kv_len=put(self._kv_len.copy()),
+            **state,
         )
 
     def _admit(
@@ -921,6 +967,8 @@ class ContinuousEngine(MegaDispatch):
         fault_point("engine.admit", slot=slot)
         if req.timeline is not None:
             req.timeline.stamp_admit()
+        if req.snapshot is not None or req.prefill_only:
+            self._refuse_slot_export("a migrated or prefill-only request")
         if req.snapshot is not None:
             return self._admit_import(req, slot)
         if self.prefix is not None:
@@ -1178,7 +1226,12 @@ class ContinuousEngine(MegaDispatch):
         ends with ``step``'s token, by its length as by what the host
         cannot foresee (a stop token, a non-finite row, a cancel, a
         deadline), costs its row of the in-flight step, never an
-        emitted token (:meth:`_emit_step`): with 32 slots a request
+        emitted token (:meth:`_emit_step`). That row also advances the
+        slot's recurrent state, where the model keeps one, and that is
+        sound ONLY because the slot has ENDED: nobody reads its state
+        again (the next admission starts from zeros), while a slot that
+        goes on was in ``step`` with the same request and is advanced
+        exactly once a token. With 32 slots a request
         ends every tenth round, and a round that waits for it leaves
         the device idle for the host's 6 ms (PERF.md "PR 35")."""
         if (self.speculative or self.mode == "mega"
@@ -1240,8 +1293,8 @@ class ContinuousEngine(MegaDispatch):
             finite, toks = step.fetch()
         self._release_ended()  # the device has left ``step``
         if step.counts is not None:
-            self._bump("moe_decode_local_rows", int(step.counts[0]))
-            self._bump("moe_decode_experts_touched", int(step.counts[1]))
+            for key, count in zip(self.model.step_counts, step.counts):
+                self._bump(key, int(count))
         ended = sum(r is not None and self._slots[s] is not r
                     for s, r in enumerate(step.reqs))
         if ended:
@@ -2910,7 +2963,17 @@ class ContinuousEngine(MegaDispatch):
         this from ``begin_drain(handoff=True)`` while a batch is in
         flight. Tests arm it before ``run()`` for a deterministic
         mid-generation export point."""
+        self._refuse_slot_export("request_handoff")
         self._handoff_at = self._round + int(after_rounds)
+
+    def _refuse_slot_export(self, what: str) -> None:
+        """A slot's recurrent state is not pages, and ``SlotSnapshot``
+        has no room for it: a model that keeps one exports nothing."""
+        if self._recurrent:
+            raise ValueError(
+                f"{what}: {self.model.cfg.model_name} keeps a recurrent "
+                "state beside its K/V pages, and slot export / migration "
+                "carries pages only")
 
     def export_slot(self, slot: int, *, target_digest=None):
         """Snapshot one active slot (``models/slot_state.py``) — a pure
@@ -2922,6 +2985,7 @@ class ContinuousEngine(MegaDispatch):
         while one is in flight; the engine's own exports drain first)."""
         from triton_distributed_tpu.models import slot_state
 
+        self._refuse_slot_export("export_slot")
         return slot_state.export_slot(
             self, slot, target_digest=target_digest
         )
@@ -3163,6 +3227,16 @@ class ContinuousEngine(MegaDispatch):
                         f"slot {slot}: device kv_len {int(dev[slot])} != "
                         f"host {int(self._kv_len[slot])}"
                     )
+            if self._recurrent:
+                # The other kind of state: the rows the next step will
+                # advance are the slots that hold a request.
+                live = np.asarray(self.cache.live)
+                for slot, req in enumerate(self._slots):
+                    if bool(live[slot]) != (req is not None):
+                        problems.append(
+                            f"slot {slot}: in flight on the device "
+                            f"{bool(live[slot])}, on the host "
+                            f"{req is not None}")
         if problems and raise_on_violation:
             raise PoolAuditError("; ".join(problems))
         return problems

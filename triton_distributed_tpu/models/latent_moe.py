@@ -52,6 +52,7 @@ from triton_distributed_tpu.layers.moe_share import (
 from triton_distributed_tpu.layers.tp_mlp import TPMLPParams, tp_mlp_fwd
 from triton_distributed_tpu.models.config import ModelConfig
 from triton_distributed_tpu.models.qwen import (
+    CountedPagedStep,
     Mode,
     Qwen3,
     Qwen3LayerParams,
@@ -124,9 +125,13 @@ def tdt_draw_weights(keys, lead: tuple, mat: tuple, scale: float, dtype: str):
     return jax.lax.map(one, keys).reshape(*lead, *mat)
 
 
-class LatentMoE(Qwen3):
+class LatentMoE(CountedPagedStep, Qwen3):
     """Latent attention + (dense | expert-share) feed-forward layers on
     :class:`Qwen3`'s paged serving programs."""
+
+    # The step's two int32 sums, as the engine's ledger names them:
+    # rows routed to held experts, held experts that got a row.
+    step_counts = ("moe_decode_local_rows", "moe_decode_experts_touched")
 
     def __init__(self, cfg: ModelConfig, *, axis: str = "tp",
                  ctx: DistContext | None = None):
@@ -279,7 +284,7 @@ class LatentMoE(Qwen3):
                 self.mla, walk=walk)
             return out, kp, vp, ks, vs
 
-        x, k_new, v_new, _, _, counts = self._scan(
+        x, k_new, v_new, _, _, counts, _ = self._scan(
             params, self._embed(params, tokens), cache, attn, mode,
             live=cache.page_table[:, 0] != 0)
         x = rms_norm(x, params.norm, self.cfg.rms_eps)
@@ -311,7 +316,7 @@ class LatentMoE(Qwen3):
                 kv_pages=kv_pages)
             return out, kp, vp, ks, vs
 
-        x, k_new, v_new, _, _, _ = self._scan(
+        x, k_new, v_new, _, _, _, _ = self._scan(
             params, self._embed(params, tokens), cache, attn, mode)
         x = rms_norm(x, params.norm, self.cfg.rms_eps)
         logits = self._logits(params, jnp.take(x, last_idx, axis=0)[None])[0]
@@ -346,33 +351,6 @@ class LatentMoE(Qwen3):
             in_specs=(self.param_specs, P(), paged_cache_specs(self.axis)),
             out_specs=(P(), paged_cache_specs(self.axis), P()),
         )
-
-    def decode_step_counted(self, tokens, cache, mode: Mode = "xla"):
-        """:meth:`decode_step` with the step's two int32 sums beside the
-        logits: ``(logits, cache, counts [2])``: rows routed to held
-        experts and held experts that got at least one row, over all
-        expert layers. THE decode program of this model (one jit, named
-        ``tdt_decode_step`` like every model's)."""
-        from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
-
-        if not isinstance(cache, PagedKVCache):
-            raise ValueError(
-                f"{self.cfg.model_name} decodes over the paged latent pool "
-                "only (--continuous, or --replicas N)")
-        key = (mode, "paged")
-        if key not in self._decode_jit:
-            f = self.decode_fn_paged(mode)
-
-            def tdt_decode_step(p, t, c):
-                return f(p, t, c)
-
-            self._decode_jit[key] = jax.jit(
-                tdt_decode_step, donate_argnums=(2,))
-        return self._decode_jit[key](self.params, tokens, cache)
-
-    def decode_step(self, tokens, cache, mode: Mode = "xla"):
-        logits, cache, _ = self.decode_step_counted(tokens, cache, mode)
-        return logits, cache
 
     def _no_dense_cache(self, *_, **__):
         raise ValueError(

@@ -41,6 +41,23 @@ class PagedKVCache:
     # every code path over it) bit-identical to the unquantized build.
     k_scale: jax.Array | None = None
     v_scale: jax.Array | None = None
+    # The OTHER kind of state, for a model whose layer list declares
+    # recurrent layers (``cfg.slot_keeps`` holds "recurrent_state";
+    # ``None`` everywhere else keeps the tree leaf for leaf): a state
+    # that is not pages. ``ssm_state [Lm, B, H, P, N]`` float32 is one
+    # recurrent layer's state a slot, whatever the slot's context, and
+    # ``conv_state [Lm, taps - 1, B, C]`` the last inputs of its causal
+    # convolution (slots before channels: the layout XLA gives the array
+    # anyway, so pinning it copies nothing); both are indexed by
+    # (recurrent layer, slot), written
+    # absolutely by a chunk and advanced by a decode step ONLY for the
+    # rows ``live [B]`` marks: the slots the engine has IN FLIGHT,
+    # uploaded with the tables. The page table cannot say that: a slot
+    # whose admission is between two chunks is mapped, and a state
+    # advanced there is wrong for good.
+    ssm_state: jax.Array | None = None
+    conv_state: jax.Array | None = None
+    live: jax.Array | None = None
 
     @property
     def quantized(self) -> bool:
@@ -49,7 +66,8 @@ class PagedKVCache:
 
 register_param_dataclass(
     PagedKVCache,
-    ["k_pages", "v_pages", "page_table", "kv_len", "k_scale", "v_scale"],
+    ["k_pages", "v_pages", "page_table", "kv_len", "k_scale", "v_scale",
+     "ssm_state", "conv_state", "live"],
 )
 
 
@@ -248,8 +266,11 @@ def init_paged_cache(
         )
     else:
         table = np.zeros((batch_size, pages_per_seq), np.int32)
+    # The pool holds the layers that cache rows: all of them, but for a
+    # model whose layer list declares recurrent layers.
     shape = (
-        cfg.num_layers, num_pages, cfg.num_kv_heads, page_size, cfg.head_dim
+        cfg.attention_layers, num_pages, cfg.num_kv_heads, page_size,
+        cfg.pool_row_dim,
     )
     v_shape = shape
     if cfg.kv_lora_rank:
@@ -271,6 +292,19 @@ def init_paged_cache(
                             None, None, axis)
     else:
         k_scale = v_scale = None
+    state = {}
+    if "recurrent_state" in cfg.slot_keeps:
+        if resolved_kv == "int8":
+            raise ValueError(
+                f"--kv-dtype int8: {cfg.model_name} keeps a float32 "
+                "recurrent state beside a full-width pool of "
+                f"{cfg.attention_layers} layers, and has no int8 path")
+        ssm, conv = recurrent_state_shapes(cfg, batch_size)
+        state = dict(
+            ssm_state=ctx.replicate(jnp.zeros(ssm, jnp.float32)),
+            conv_state=ctx.replicate(jnp.zeros(conv, cfg.dtype)),
+            live=ctx.replicate(jnp.zeros((batch_size,), bool)),
+        )
     cache = PagedKVCache(
         k_pages=ctx.shard(jnp.zeros(shape, pool_dtype), *spec),
         v_pages=ctx.shard(jnp.zeros(v_shape, pool_dtype), *spec),
@@ -278,8 +312,35 @@ def init_paged_cache(
         kv_len=ctx.replicate(jnp.zeros((batch_size,), jnp.int32)),
         k_scale=k_scale,
         v_scale=v_scale,
+        **state,
     )
     return cache, pool
+
+
+def recurrent_state_shapes(cfg: ModelConfig, slots: int) -> tuple:
+    """``(ssm_state, conv_state)`` shapes for ``slots`` decode slots:
+    a state ``[H, P, N]`` and the convolution's last ``taps - 1`` inputs
+    (``x | B | C`` channels) a recurrent layer a slot."""
+    from triton_distributed_tpu.layers.mamba2 import Mamba2Dims
+
+    m = Mamba2Dims.of(cfg)
+    return (
+        (cfg.mamba_layers, slots, m.heads, m.head_dim, m.state),
+        (cfg.mamba_layers, m.taps - 1, slots, m.conv_dim),
+    )
+
+
+def state_bytes_per_slot(cache: PagedKVCache) -> int:
+    """HBM bytes of one slot's recurrent state over all its layers,
+    whatever the slot's context (0 for a model that keeps none): what a
+    decode step reads AND writes for every row in flight."""
+    if cache.ssm_state is None:
+        return 0
+    slots = cache.ssm_state.shape[1]
+    return sum(
+        a.dtype.itemsize * math.prod(a.shape) // slots
+        for a in (cache.ssm_state, cache.conv_state)
+    )
 
 
 def kv_bytes_per_token(cache: PagedKVCache) -> float:
@@ -528,13 +589,17 @@ def truncate_pages(
     return pages[:keep]
 
 
-def paged_cache_specs(axis: str = "tp", quantized: bool = False):
+def paged_cache_specs(axis: str = "tp", quantized: bool = False,
+                      recurrent: bool = False):
     """shard_map PartitionSpecs matching :func:`init_paged_cache`.
     ``quantized`` adds the per-page-per-head scale specs (head-sharded
-    like the pool); unset matches the scale-less pytree exactly."""
+    like the pool) and ``recurrent`` the replicated recurrent state's;
+    unset matches the pytree without them exactly."""
     from jax.sharding import PartitionSpec as P
 
+    state = dict(ssm_state=P(), conv_state=P(), live=P()) if recurrent else {}
     return PagedKVCache(
+        **state,
         k_pages=P(None, None, axis, None, None),
         v_pages=P(None, None, axis, None, None),
         page_table=P(),
@@ -644,11 +709,10 @@ def copy_page(cache: PagedKVCache, src: int, dst: int) -> PagedKVCache:
     COW."""
     s = jnp.asarray(src, jnp.int32)
     d = jnp.asarray(dst, jnp.int32)
-    return PagedKVCache(
+    return dataclasses.replace(
+        cache,
         k_pages=tdt_kv_copy_page(cache.k_pages, s, d),
         v_pages=tdt_kv_copy_page(cache.v_pages, s, d),
-        page_table=cache.page_table,
-        kv_len=cache.kv_len,
         # COW on a quantized pool clones the scale WITH the codes — the
         # pair is the page's content; cloning one without the other
         # would dequantize the copy under the wrong amax.

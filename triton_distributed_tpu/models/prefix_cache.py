@@ -119,9 +119,18 @@ class PrefixCache:
     # (tests/conftest.py) after every test.
     _live: "weakref.WeakSet[PrefixCache]" = weakref.WeakSet()
 
-    def __init__(self, pool, page_size: int):
+    def __init__(self, pool, page_size: int, *, pages_hold_all: bool = True):
         self.pool = pool
         self.page_size = page_size
+        # Whether a sequence's pages are ALL a later request needs to
+        # resume from them. Not for a model that keeps a recurrent state
+        # beside its pages (``cfg.slot_keeps``): a matched page would
+        # bring K/V for its attention layers and no state for the
+        # recurrent ones, so a match is none and a retired sequence's
+        # pages go straight back to the pool. (Reuse waits for state
+        # held at page boundaries: docs/serving.md "Recurrent state
+        # beside pages".)
+        self.pages_hold_all = pages_hold_all
         self.root = RadixNode((), -1, None)
         self._clock = 0
         # Durable KV tier hook (docs/serving.md "Tiered KV"): when set
@@ -161,7 +170,7 @@ class PrefixCache:
         :meth:`release_match` (admission abandoned) or the
         finish_cow → :meth:`release_node`-per-node protocol."""
         toks = [int(t) for t in tokens]
-        limit = len(toks) - 1
+        limit = len(toks) - 1 if self.pages_hold_all else 0
         node = self.root
         nodes: list[RadixNode] = []
         cow_node, cow_len = None, 0
@@ -309,6 +318,9 @@ class PrefixCache:
         below the deepest pinned node, then drop the pins. ``tokens`` is
         the full cached token chain — prompt plus every fed-back
         generated token, i.e. positions ``[0, s + gen - 1)``."""
+        if not self.pages_hold_all:
+            self.pool.release(pages)
+            return
         parent = shared_nodes[-1] if shared_nodes else self.root
         n_sh = len(shared_nodes)
         self.insert_chain(

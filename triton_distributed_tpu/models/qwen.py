@@ -102,7 +102,8 @@ class Qwen3:
             hq_loc=cfg.num_q_heads // n,
             hkv_loc=cfg.num_kv_heads // n,
             head_dim=cfg.head_dim,
-            rope_theta=cfg.rope_theta,
+            rope_theta=cfg.rope_theta if cfg.rope else None,
+            sm_scale=cfg.attention_multiplier or None,
         )
         self.params: Qwen3Params | None = None
         self._decode_jit: dict = {}
@@ -264,10 +265,13 @@ class Qwen3:
         """The model's layers as groups of like layers, in order: each
         ``(stacked layer params, ffn)`` with ``ffn(mlp params, h, mode,
         aux, layer) -> (y, aux)``, ``layer`` the running index over all
-        groups. One group here; a model whose leading layers
-        differ from the rest (dense before expert layers) returns one
-        group a kind, and :meth:`_scan_layers_paged` runs them through
-        the one carried pool under one running layer index."""
+        groups; a group whose MIXER is not the program's attention adds
+        it as a third item (:meth:`_scan_layers_paged`). One group here;
+        a model whose leading layers differ from the rest (dense before
+        expert layers), or whose layer list declares another mixer for
+        some (recurrent layers between attention layers), returns one
+        group a run of like layers, and :meth:`_scan_layers_paged` runs
+        them through the one carried cache under running indices."""
         return [(
             params.layers,
             lambda mp, h, ar, aux, layer: (self._mlp_fwd(mp, h, ar), aux),
@@ -292,46 +296,73 @@ class Qwen3:
         temporaries (docs/serving.md "Paged KV cache").
 
         One scan a group of :meth:`_layer_groups`, each carrying the
-        same pool on and indexing it from where the group before
-        stopped. ``aux`` is whatever the groups' ffns thread through
-        the layers (an expert layer's counts; ``None`` otherwise);
-        ``groups`` overrides :meth:`_layer_groups`.
+        same cache on. A group takes its mixer as it takes its ffn:
+        ``(layers, ffn)`` runs ``attn_fn`` over the pool, ``(layers,
+        ffn, mixer)`` runs ``mixer(mixer params, h, state, layer) ->
+        (y, state)`` over the cache's recurrent state (``(ssm_state,
+        conv_state)``, ``None`` where the model keeps none), which
+        rides the same carry under the same layout pin and is read and
+        written in place at (layer, slot). Each kind of state is
+        indexed by ITS layers: a mixer's ``layer`` counts the layers
+        before it that keep its kind (where every layer is an attention
+        layer that is the running index itself). ``aux`` is whatever
+        the groups' ffns thread through the layers (an expert layer's
+        counts; ``None`` otherwise); ``groups`` overrides
+        :meth:`_layer_groups`.
 
-        Returns ``(x, k_pages, v_pages, k_scale, v_scale, aux)``.
+        Returns ``(x, k_pages, v_pages, k_scale, v_scale, aux, state)``.
         """
         cfg = self.cfg
         ar = "pallas_ar" if mode == "pallas" else "xla_ar"
-        carry = (x, cache.k_pages, cache.v_pages, cache.k_scale,
-                 cache.v_scale, aux)
-        start = 0
-        for layers, ffn in groups or self._layer_groups(params):
-            n = jax.tree.leaves(layers)[0].shape[0]
+        res = cfg.residual_multiplier
 
-            def layer_fn(carry, inp, ffn=ffn):
-                x, kp, vp, ks, vs, aux = carry
+        def pin(p):
+            return with_layout_constraint(p, Layout(tuple(range(p.ndim))))
+
+        state = None if cache.ssm_state is None else (
+            cache.ssm_state, cache.conv_state)
+        carry = (x, cache.k_pages, cache.v_pages, cache.k_scale,
+                 cache.v_scale, aux, state)
+        start = pooled = stateful = 0
+        for layers, ffn, *mixer in groups or self._layer_groups(params):
+            mixer = mixer[0] if mixer else None
+            n = jax.tree.leaves(layers)[0].shape[0]
+            # Layers before this group that keep the OTHER kind of state.
+            skip = start - (stateful if mixer else pooled)
+
+            def layer_fn(carry, inp, ffn=ffn, mixer=mixer, skip=skip):
+                x, kp, vp, ks, vs, aux, state = carry
                 # Pin the carried pool row-major, the layout it is
                 # donated in: left free, XLA gives the loop's pool the
                 # layout of a chunk's transposed update and re-lays the
                 # whole pool out before and after the loop.
-                kp, vp = (
-                    with_layout_constraint(p, Layout(tuple(range(p.ndim))))
-                    for p in (kp, vp)
-                )
+                kp, vp = (pin(p) for p in (kp, vp))
+                if state is not None:
+                    state = tuple(pin(p) for p in state)
                 lp, layer = inp
                 h = rms_norm(x, lp.ln1, cfg.rms_eps)
-                a, kp, vp, ks, vs = attn_fn(
-                    lp.attn, h, kp, vp, layer, ks, vs, ar
-                )
-                x = x + a
+                if mixer is None:
+                    a, kp, vp, ks, vs = attn_fn(
+                        lp.attn, h, kp, vp, layer - skip if skip else layer,
+                        ks, vs, ar
+                    )
+                else:
+                    a, state = mixer(lp.attn, h, state, layer - skip)
+                x = x + (a if res == 1.0 else res * a)
                 h = rms_norm(x, lp.ln2, cfg.rms_eps)
                 y, aux = ffn(lp.mlp, h, ar, aux, layer)
-                return (x + y, kp, vp, ks, vs, aux), None
+                x = x + (y if res == 1.0 else res * y)
+                return (x, kp, vp, ks, vs, aux, state), None
 
             carry, _ = jax.lax.scan(
                 layer_fn, carry,
                 (layers, jnp.arange(start, start + n, dtype=jnp.int32)),
             )
             start += n
+            if mixer:
+                stateful += n
+            else:
+                pooled += n
         return carry
 
     def _decode_shard_paged(self, params, tokens, cache, *, mode: Mode):
@@ -361,7 +392,7 @@ class Qwen3:
                 k_scale=ks, v_scale=vs, walk=walk,
             )
 
-        x, k_new, v_new, ks_new, vs_new, _ = self._scan_layers_paged(
+        x, k_new, v_new, ks_new, vs_new, _, _ = self._scan_layers_paged(
             params, self._embed(params, tokens), cache, attn, mode
         )
         x = rms_norm(x, params.norm, self.cfg.rms_eps)
@@ -492,7 +523,7 @@ class Qwen3:
                 rope_pos=rope_pos, attn_bias=attn_bias,
             )
 
-        x, k_new, v_new, ks_new, vs_new, _ = self._scan_layers_paged(
+        x, k_new, v_new, ks_new, vs_new, _, _ = self._scan_layers_paged(
             params, x, cache, attn, mode
         )
         x = rms_norm(x, params.norm, cfg.rms_eps)
@@ -542,6 +573,7 @@ class Qwen3:
         )
 
         quant = cache.k_scale is not None
+        recurrent = cache.ssm_state is not None
         tree = tree_mask is not None
         if tree != (tree_depth is not None):
             raise ValueError("tree_mask and tree_depth go together")
@@ -554,10 +586,11 @@ class Qwen3:
                                   kv_pages=kv_pages, all_logits=all_logits),
                 in_specs=(
                     self.param_specs, P(),
-                    paged_cache_specs(self.axis, quant),
+                    paged_cache_specs(self.axis, quant, recurrent),
                     P(), P(), P(), P(), *tree_specs,
                 ),
-                out_specs=(P(), paged_cache_specs(self.axis, quant)),
+                out_specs=(P(), paged_cache_specs(self.axis, quant,
+                                                  recurrent)),
             )
             def tdt_prefill_chunk(p, t, c, s, o, n, li, *tr):
                 return f(p, t, c, s, o, n, li, *tr)
@@ -701,6 +734,41 @@ class Qwen3:
         return init_cache(
             self.cfg, batch_size, self.ctx, self.axis, max_length
         )
+
+
+class CountedPagedStep:
+    """Mixed into a model whose ONE decode program, over the paged
+    cache, returns int32 sums beside its logits (``_decode_shard_paged``
+    gives ``(logits, cache, counts)``; ``step_counts`` names each sum
+    as the engine's ledger has it). The engine fetches them with the
+    step's tokens (``_StepLaunch.counts``)."""
+
+    step_counts: tuple = ()
+
+    def decode_step_counted(self, tokens, cache, mode: Mode = "xla"):
+        """:meth:`Qwen3.decode_step` with the step's sums beside the
+        logits: ``(logits, cache, counts)``. THE decode program of the
+        model (one jit, named ``tdt_decode_step`` like every model's)."""
+        from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
+
+        if not isinstance(cache, PagedKVCache):
+            raise ValueError(
+                f"{self.cfg.model_name} decodes over the paged pool "
+                "only (--continuous, or --replicas N)")
+        key = (mode, "paged")
+        if key not in self._decode_jit:
+            f = self.decode_fn_paged(mode)
+
+            def tdt_decode_step(p, t, c):
+                return f(p, t, c)
+
+            self._decode_jit[key] = jax.jit(
+                tdt_decode_step, donate_argnums=(2,))
+        return self._decode_jit[key](self.params, tokens, cache)
+
+    def decode_step(self, tokens, cache, mode: Mode = "xla"):
+        logits, cache, _ = self.decode_step_counted(tokens, cache, mode)
+        return logits, cache
 
 
 def _fuse_by_shard(parts: list[jax.Array], n: int) -> jax.Array:

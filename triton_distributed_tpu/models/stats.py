@@ -162,6 +162,13 @@ STAT_METRICS = {
                                    "Held experts that got at least one "
                                    "row, summed over expert layers and "
                                    "decode steps."),
+    # A model with recurrent layers (docs/serving.md "Recurrent state
+    # beside pages"): the int32 sum every decode step returns beside
+    # its tokens, the rows IN FLIGHT whose state the step moved.
+    "ssm_decode_rows": ("tdt_ssm_decode_rows_total",
+                        "Rows whose recurrent state a decode step "
+                        "advanced (each once a step, whatever the number "
+                        "of recurrent layers)."),
     "a2a_dropped": ("tdt_moe_a2a_dropped_total",
                     "EP all-to-all assignments dropped (capacity-mode "
                     "overflow; 0 on the lossless serving paths)."),
