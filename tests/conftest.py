@@ -11,9 +11,22 @@ The platform is pinned through ``jax.config`` before any backend is
 instantiated, so the suite runs on the CPU whatever the environment
 says (``JAX_PLATFORMS=cpu``, which the driver's command sets, is
 honoured too).
+
+The rule for a new test: an engine test takes ``own_model``, the one
+tiny model a module whose compiled programs every engine of the module
+shares (``ctx4`` is for what needs the current context: collectives,
+``tp > 1`` layers; ``tp4_model`` for a case that is about a sharded
+pool); a case that runs past ``LIMIT`` is a failure, not a slow test
+(a wait inside XLA's C++ is not ended, only reported: see ``LIMIT``);
+and a ``slow`` mark needs a line in ``CHANGES.md`` with the case's
+seconds and why it cannot be made small.
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
+import tempfile
 
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
@@ -57,9 +70,88 @@ def ctx2x4():
     mesh_mod.finalize_distributed()
 
 
+@pytest.fixture(scope="module")
+def own_model():
+    """ONE tiny model a module, on a one-device mesh of its own (never
+    the current context, which the per-test ``ctx4`` fixtures set and
+    clear): its jitted programs are shared by every engine built on it.
+    The preset's geometry (eight query heads on four K/V heads), so a
+    page's payload has a head axis to get wrong. Its ``max_length`` is
+    the preset's 128; an engine that wants another takes it as its own
+    argument."""
+    from triton_distributed_tpu.models import AutoLLM
+
+    ctx = mesh_mod.initialize_distributed(
+        tp=1, devices=jax.devices()[:1], set_as_current=False
+    )
+    return AutoLLM.from_pretrained("tiny", ctx=ctx)
+
+
+@pytest.fixture(scope="module")
+def tp4_model():
+    """``own_model`` sharded over four devices, one K/V head a shard:
+    for the cases that hold what a SHARDED pool exports (a spill and
+    fault-back, a fabric pull) and for ``test_model.py``'s engines."""
+    from triton_distributed_tpu.models import AutoLLM
+
+    ctx = mesh_mod.initialize_distributed(
+        tp=4, devices=jax.devices()[:4], set_as_current=False
+    )
+    return AutoLLM.from_pretrained("tiny", ctx=ctx)
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+# Seconds one phase of one test (set-up, call or tear-down) may take. It
+# ends a wait (a thread never joined, a socket without a timeout) so
+# that one test cannot take the whole run's clock; it does not police
+# slowness. The longest case takes a third of it on a slow machine.
+# The alarm runs as Python, so a wait inside XLA's C++ (a compile, an
+# interpreted kernel's callback) still costs the run's clock: for that
+# a watchdog thread writes every stack to the run's own stderr ten
+# seconds later, so the log at least says where.
+LIMIT = 300
+_run_stderr = None  # pytest_configure: a copy of fd 2 that capture leaves alone
+
+
+@contextlib.contextmanager
+def time_limit(seconds, name):
+    """Fail ``name`` with every thread's stack if the body is still
+    running after ``seconds``. The alarm interrupts the main thread
+    where it waits in Python (a lock, a socket, a sleep); the timer and
+    handler found on entry are put back on exit."""
+    def on_alarm(signum, frame):
+        with tempfile.TemporaryFile(mode="w+") as f:
+            faulthandler.dump_traceback(f, all_threads=True)
+            f.seek(0)
+            stacks = f.read()
+        pytest.fail(f"{name} ran past its limit of {seconds} s; every "
+                    f"thread's stack:\n{stacks}", pytrace=False)
+
+    old_handler = signal.signal(signal.SIGALRM, on_alarm)
+    old_timer = signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *old_timer)
+        signal.signal(signal.SIGALRM, old_handler)
+
+
+def _limited(item):
+    faulthandler.dump_traceback_later(LIMIT + 10, file=_run_stderr)
+    try:
+        with time_limit(LIMIT, item.nodeid):
+            return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+pytest_runtest_setup = pytest.hookimpl(wrapper=True)(_limited)
+pytest_runtest_call = pytest.hookimpl(wrapper=True)(_limited)
+pytest_runtest_teardown = pytest.hookimpl(wrapper=True)(_limited)
 
 
 @pytest.fixture
@@ -110,6 +202,8 @@ def _audit_serving_pools():
 
 
 def pytest_configure(config):
+    global _run_stderr
+    _run_stderr = os.dup(2)  # capture is suspended while plugins configure
     config.addinivalue_line(
         "markers",
         "slow: heavyweight interpret-mode runs; excluded from the default "
@@ -119,40 +213,7 @@ def pytest_configure(config):
     )
 
 
-# Tier-1 runs under a hard wall-clock budget, and a run cut at its
-# limit counts only as far as it got — so spend the window
-# highest-yield-first: cheap/high-signal suites up front, the
-# multi-minute interpret-heavy suites at the back. Within-file order is
-# preserved (stable sort), every test still runs when the clock allows,
-# and the order is deterministic. Ordered by measured ascending
-# cost-per-verified-test. Files NOT in the list sort FIRST (rank -1): a
-# new test file must never be silently starved behind the multi-minute
-# tail — if it turns out expensive, add it here explicitly.
-_FILE_ORDER = [
-    "test_tools.py", "test_bench_tuning.py", "test_chip_compile.py",
-    "test_runtime.py", "test_sampling.py", "test_language.py",
-    "test_layers.py", "test_native.py", "test_obs.py", "test_router.py",
-    "test_fleet.py", "test_migration.py", "test_kv_tier.py",
-    "test_kv_fabric.py", "test_goodput.py", "test_pools.py",
-    "test_multihost.py", "test_chip_smoke.py",
-    "test_attention.py", "test_p2p.py", "test_kv_quant.py",
-    "test_speculative.py", "test_tree_spec.py", "test_kernel_trace.py",
-    "test_resident.py",
-    "test_moe_serving.py", "test_megakernel.py",
-    "test_tpu_lowering.py",
-    "test_prefix_cache.py", "test_faults.py", "test_serving.py",
-    "test_model.py", "test_collectives.py", "test_sp_attention.py",
-    "test_moe.py", "test_stress.py", "test_overlap.py",
-]
-_FILE_RANK = {name: i for i, name in enumerate(_FILE_ORDER)}
-
-
 def pytest_collection_modifyitems(config, items):
-    items.sort(
-        key=lambda item: _FILE_RANK.get(
-            os.path.basename(str(item.fspath)), -1
-        )
-    )
     for item in items:
         if item.name in EXPECTED:  # below: PR 34's tests and a second architecture
             item.add_marker(pytest.mark.xfail(strict=True,

@@ -82,21 +82,47 @@ def test_context_check_refuses_interpreted_kernels(smoke, monkeypatch):
         mesh.finalize_distributed()
 
 
-def test_phases_pass_at_tiny_size_when_steered(steered, capsys):
+def _result_lines(capsys):
+    return [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("{")]
+
+
+def test_phases_pass_at_tiny_size_when_steered(steered, monkeypatch, capsys):
     # Two served phases (full-width and int8 pools) through
-    # run_server.main and the socket, then the kernel and logit checks.
+    # run_server.main and the socket, then on to the kernel and logit
+    # checks, which the next test runs. The payloads' prompts are the
+    # chip's; its 24 tokens a request are there for the megakernel modes
+    # to chain launches, and every step is interpreted here.
+    payloads = steered.make_payloads
+
+    def short(cfg):
+        a, b = payloads(cfg)
+        a["gen_lens"], b["gen_lens"] = [6, 5, 3, 2], [2, 3]
+        return a, b
+
+    monkeypatch.setattr(steered, "make_payloads", short)
+    monkeypatch.setattr(steered, "logit_checks",
+                        lambda model: steered.emit(checked=model))
     assert steered.main(["--model", "tiny", "--modes", "xla,int8"]) == 0
-    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
-             if ln.startswith("{")]
-    last = lines[-1]
+    lines = _result_lines(capsys)
     d = jax.devices()
-    assert last == {"ok": True, "device": {
+    assert lines[-1] == {"ok": True, "device": {
         "platform": d[0].platform, "kind": d[0].device_kind,
         "count": len(d)}}
+    assert lines[-2] == {"checked": "tiny"}  # after both served phases
     served = [ln for ln in lines if ln.get("phase") == "serve"]
     assert [ln["mode"] for ln in served] == ["xla", "int8"]
     assert all(ln["prefix_hit_tokens_warm"] > 0 for ln in served)
-    logits = next(ln for ln in lines if ln.get("phase") == "logits")
+    assert all(ln["warm_repeat_reproduced_cold_tokens"] for ln in served)
+
+
+def test_kernel_and_logit_checks_pass_at_tiny_size_when_steered(
+        steered, capsys):
+    steered.logit_checks("tiny")
+    lines = _result_lines(capsys)
+    assert [ln["phase"] for ln in lines] == ["kernels", "logits"]
+    kernels, logits = lines
+    assert max(kernels["max_abs_err"].values()) <= kernels["tolerance"]
     assert logits["rel_err"]["full"] <= logits["tolerance"]
     # The int8 pool is the lower-precision path the full-width bound
     # has to be able to tell apart.
@@ -108,16 +134,15 @@ def test_latent_phase_at_tiny_size_when_steered(steered, monkeypatch, capsys):
     of its 16 experts: the absorbed decode kernel against the plain
     formula, then the cut preset served on every slot."""
     monkeypatch.setattr(steered, "LATENT", {
-        "model": "tiny-mla-moe", "slots": 6,
+        "model": "tiny-mla-moe", "slots": 4,
         "cut": ["--experts-held", "4", "--expert-offset", "8"]})
     assert steered.main(["--modes", "latent"]) == 0
-    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
-             if ln.startswith("{")]
+    lines = _result_lines(capsys)
     assert lines[-1]["ok"] is True
     kernel = next(ln for ln in lines if ln.get("phase") == "latent_kernel")
     assert kernel["max_abs_err"]["mla_decode_paged"] <= kernel["tolerance"]
     served = next(ln for ln in lines if ln.get("phase") == "latent_serve")
-    assert served["slots"] == 6 and served["tokens_generated"] > 0
+    assert served["slots"] == 4 and served["tokens_generated"] > 0
     assert served["kv_bytes_per_token"] == (32 + 16) * 4 * 3
     assert 0 < served["experts_touched"] <= served["local_rows"]
     assert served["warm_repeat_reproduced_cold_tokens"]
@@ -130,16 +155,15 @@ def test_hybrid_phase_at_tiny_size_when_steered(steered, monkeypatch, capsys):
     the rows advanced are the decoded tokens (and the rows of steps in
     flight under a slot that had ended)."""
     monkeypatch.setattr(steered, "HYBRID", {"model": "tiny-hybrid",
-                                            "slots": 6})
+                                            "slots": 4})
     assert steered.main(["--modes", "hybrid"]) == 0
-    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
-             if ln.startswith("{")]
+    lines = _result_lines(capsys)
     assert lines[-1]["ok"] is True
     kernel = next(ln for ln in lines if ln.get("phase") == "hybrid_kernel")
     assert max(kernel["max_abs_err"].values()) <= kernel["tolerance"]
     assert kernel["other_layer_untouched"]
     served = next(ln for ln in lines if ln.get("phase") == "hybrid_serve")
-    assert served["slots"] == 6 and served["tokens_generated"] > 0
+    assert served["slots"] == 4 and served["tokens_generated"] > 0
     assert served["kv_bytes_per_token"] == 2 * 2 * 4 * 16 * 4
     assert served["state_bytes_per_slot"] == 5 * (8 * 16 * 16 * 4
                                                   + 3 * 160 * 4)

@@ -16,7 +16,6 @@ import time
 import numpy as np
 import pytest
 
-from triton_distributed_tpu.models import AutoLLM
 from triton_distributed_tpu.models.continuous import (
     ContinuousEngine,
     Request,
@@ -34,18 +33,24 @@ P_A = [5, 9, 2, 4]
 P_B = [7, 1, 3, 8, 6, 2, 4, 9]
 
 
-def tiny_engine(ctx, **kw):
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx)
+def tiny_engine(model, **kw):
     kw.setdefault("max_batch", 2)
     kw.setdefault("page_size", 16)
     kw.setdefault("max_length", 64)
     return model, ContinuousEngine(model, **kw)
 
 
+_GOLDEN: dict = {}
+
+
 def golden(model, prompt, gen):
-    return Engine(model, temperature=0.0).serve(
-        np.asarray([prompt], np.int32), gen_len=gen
-    )[0, len(prompt):]
+    """What the module's one model decodes greedily, served once."""
+    key = (tuple(prompt), gen)
+    if key not in _GOLDEN:
+        _GOLDEN[key] = Engine(model, temperature=0.0).serve(
+            np.asarray([prompt], np.int32), gen_len=gen
+        )[0, len(prompt):]
+    return _GOLDEN[key]
 
 
 # -- FaultPlan semantics (pure host-side) --------------------------------
@@ -120,10 +125,10 @@ def test_faultplan_nested_activation_refused():
 # -- engine chaos: every seam leaves a clean, serviceable engine ---------
 
 
-def test_pool_exhaustion_isolated(ctx4):
+def test_pool_exhaustion_isolated(own_model):
     """An injected pool-exhaustion failure at admission fails ONLY that
     request; the others complete bit-exact and the audit is clean."""
-    model, eng = tiny_engine(ctx4, max_batch=1)
+    model, eng = tiny_engine(own_model, max_batch=1)
     gold_a = golden(model, P_A, 4)
     reqs = [(np.asarray(P_A, np.int32), 4)] * 3
     with FaultPlan().exhaust_pool(at=2):  # 2nd admission's allocate
@@ -143,11 +148,11 @@ def test_pool_exhaustion_isolated(ctx4):
     np.testing.assert_array_equal(eng.run([(P_A, 4)])[0], gold_a)
 
 
-def test_decode_exception_slot_attributed(ctx4):
+def test_decode_exception_slot_attributed(own_model):
     """A decode fault carrying slot attribution evicts exactly that
     request (partial output, structured error); its batchmate's greedy
     stream is untouched."""
-    model, eng = tiny_engine(ctx4)
+    model, eng = tiny_engine(own_model)
     gold_b = golden(model, P_B, 6)
     with FaultPlan().decode_exc(at=3, slot=0):
         results = eng.run(
@@ -164,11 +169,11 @@ def test_decode_exception_slot_attributed(ctx4):
     assert eng.audit() == []
 
 
-def test_decode_exception_unattributed_poisons_batch(ctx4):
+def test_decode_exception_unattributed_poisons_batch(own_model):
     """A decode fault with NO slot attribution fails every in-flight
     request — but queued requests still serve and the engine stays
     clean."""
-    model, eng = tiny_engine(ctx4, max_batch=1)
+    model, eng = tiny_engine(own_model, max_batch=1)
     gold_a = golden(model, P_A, 4)
     with FaultPlan().decode_exc(at=2):
         results = eng.run(
@@ -182,10 +187,10 @@ def test_decode_exception_unattributed_poisons_batch(ctx4):
     assert eng.audit() == []
 
 
-def test_nan_logits_guard(ctx4):
+def test_nan_logits_guard(own_model):
     """Injected NaN logits fail only the poisoned slot (structured
     `nan_logits`, counted in last_stats) — never silently sampled."""
-    model, eng = tiny_engine(ctx4)
+    model, eng = tiny_engine(own_model)
     gold_b = golden(model, P_B, 6)
     with FaultPlan().nan_logits(at=2, slot=0):
         results = eng.run(
@@ -201,21 +206,6 @@ def test_nan_logits_guard(ctx4):
     np.testing.assert_array_equal(results[1].tokens, gold_b)
     assert eng.last_stats["nonfinite_logits"] == 1
     assert eng.audit() == []
-
-
-@pytest.fixture(scope="module")
-def own_model():
-    """ONE tiny model on a mesh of its own (never the current context,
-    which the per-test ``ctx4`` fixtures set and clear): its jitted
-    programs are shared by every engine built on it."""
-    import jax
-
-    from triton_distributed_tpu.runtime import mesh as mesh_mod
-
-    ctx = mesh_mod.initialize_distributed(
-        tp=1, devices=jax.devices()[:1], set_as_current=False
-    )
-    return AutoLLM.from_pretrained("tiny", ctx=ctx)
 
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
@@ -259,11 +249,11 @@ def test_oversized_request_isolated(own_model, oversized, why,
     assert eng.audit() == []
 
 
-def test_deadline_and_load_shedding(ctx4):
+def test_deadline_and_load_shedding(own_model):
     """deadline_s=0 expires before admission (structured
     `deadline_exceeded`); max_queue sheds excess load as `overloaded`;
     the surviving request is unaffected."""
-    model, eng = tiny_engine(ctx4, max_batch=1, max_queue=2)
+    model, eng = tiny_engine(own_model, max_batch=1, max_queue=2)
     gold_a = golden(model, P_A, 4)
     results = eng.run(
         [
@@ -284,10 +274,10 @@ def test_deadline_and_load_shedding(ctx4):
     assert eng.audit() == []
 
 
-def test_legacy_run_raises_structured_failure(ctx4):
+def test_legacy_run_raises_structured_failure(own_model):
     """run(results=False) finishes the survivors, tears the failure
     down cleanly, and raises RequestFailedError carrying it."""
-    model, eng = tiny_engine(ctx4)
+    model, eng = tiny_engine(own_model)
     with FaultPlan().nan_logits(at=2, slot=0):
         with pytest.raises(RequestFailedError, match="nan_logits"):
             eng.run([(np.asarray(P_A, np.int32), 6),
@@ -295,12 +285,11 @@ def test_legacy_run_raises_structured_failure(ctx4):
     assert eng.audit() == []
 
 
-def test_prefix_cache_fault_isolation(ctx4):
+def test_prefix_cache_fault_isolation(own_model):
     """Faults on a prefix-cache engine release every pin: a failed
     admission drops its match refcounts and the tree/pool partition
     stays exact (the leak this PR exists to catch)."""
-    model, eng = tiny_engine(
-        ctx4, prefix_cache=True, num_pages=12
+    model, eng = tiny_engine(own_model, prefix_cache=True, num_pages=12
     )
     warm = np.asarray(P_B * 3, np.int32)  # 24 tokens: populates the tree
     eng.run([(warm, 4)])
@@ -318,11 +307,10 @@ def test_prefix_cache_fault_isolation(ctx4):
     assert out[0].ok and eng.last_stats["prefix_hit_tokens"] > 0
 
 
-def test_pool_exhaustion_mid_prefix_admission(ctx4):
+def test_pool_exhaustion_mid_prefix_admission(own_model):
     """Pool exhaustion raised INSIDE prefix admission (after the match
     pinned tree nodes) must release those pins on the failure path."""
-    model, eng = tiny_engine(
-        ctx4, prefix_cache=True, num_pages=12
+    model, eng = tiny_engine(own_model, prefix_cache=True, num_pages=12
     )
     warm = np.asarray(P_B * 3, np.int32)
     eng.run([(warm, 4)])
@@ -334,10 +322,10 @@ def test_pool_exhaustion_mid_prefix_admission(ctx4):
     assert all(n.refcount == 0 for n in eng.prefix.walk())
 
 
-def test_spec_verify_fault_isolated(ctx4):
+def test_spec_verify_fault_isolated(own_model):
     """A speculative verify that raises fails only its own request;
     the engine then serves the next request normally."""
-    model, eng = tiny_engine(ctx4, max_batch=1, speculative=3)
+    model, eng = tiny_engine(own_model, max_batch=1, speculative=3)
     rep = np.asarray(P_A * 2, np.int32)  # repetitive → drafts fire
     gold = golden(model, list(rep), 6)
     with FaultPlan().verify_exc(at=1):
@@ -348,13 +336,13 @@ def test_spec_verify_fault_isolated(ctx4):
     assert eng.audit() == []
 
 
-def test_spec_verify_nan_logits_guarded(ctx4):
+def test_spec_verify_nan_logits_guarded(own_model):
     """Non-finite logits inside a speculative verify chunk must fail
     that request with a structured `nan_logits` (counted), never be
     silently argmax'd into accepted tokens."""
     import numpy as _np
 
-    model, eng = tiny_engine(ctx4, max_batch=1, speculative=3)
+    model, eng = tiny_engine(own_model, max_batch=1, speculative=3)
     rep = np.asarray(P_A * 2, np.int32)
     gold = golden(model, list(rep), 6)
 
@@ -372,11 +360,11 @@ def test_spec_verify_nan_logits_guarded(ctx4):
     assert eng.audit() == []
 
 
-def test_engine_reusable_after_fault_storm(ctx4):
+def test_engine_reusable_after_fault_storm(own_model):
     """One engine, three different fault runs back to back, then a
     clean run: output bit-exact, zero leaked pages — the crash-safe
     teardown really is crash-safe."""
-    model, eng = tiny_engine(ctx4, max_batch=1)
+    model, eng = tiny_engine(own_model, max_batch=1)
     gold_a = golden(model, P_A, 4)
     for plan in (
         FaultPlan().exhaust_pool(at=1),
@@ -392,12 +380,12 @@ def test_engine_reusable_after_fault_storm(ctx4):
     np.testing.assert_array_equal(eng.run([(P_A, 4)])[0], gold_a)
 
 
-def test_all_deadlines_expire_with_queued_request(ctx4):
+def test_all_deadlines_expire_with_queued_request(own_model):
     """Regression: the active request expires mid-decode AND the queued
     request's deadline is already gone — run() must return two
     structured deadline_exceeded results, not crash popping an empty
     queue after _try_admit drained it."""
-    model, eng = tiny_engine(ctx4, max_batch=1)
+    model, eng = tiny_engine(own_model, max_batch=1)
     results = eng.run(
         [
             Request(np.asarray(P_A, np.int32), 48, deadline_s=0.2),
@@ -409,13 +397,13 @@ def test_all_deadlines_expire_with_queued_request(ctx4):
     assert eng.audit() == []
 
 
-def test_server_recv_fault_counted(ctx4):
+def test_server_recv_fault_counted(own_model):
     """Regression: a raise-style fault on the server.recv seam (a
     RuntimeError, not an OSError) must be absorbed by the connection
     thread AND counted as a conn error — never a silent thread death."""
     from triton_distributed_tpu.serving import ModelServer, request
 
-    model, eng = tiny_engine(ctx4)
+    model, eng = tiny_engine(own_model)
     server = ModelServer(eng).start()
     try:
         with FaultPlan().on("server.recv", at=1):
@@ -432,7 +420,7 @@ def test_server_recv_fault_counted(ctx4):
 # -- server chaos --------------------------------------------------------
 
 
-def test_server_serviceable_through_chaos(ctx4):
+def test_server_serviceable_through_chaos(own_model):
     """The acceptance scenario end to end: while a faulted generation
     runs, ping answers from another connection; a dropped connection
     (injected mid-response) is survived + counted, and the client-side
@@ -440,7 +428,7 @@ def test_server_serviceable_through_chaos(ctx4):
     results channel."""
     from triton_distributed_tpu.serving import ModelServer, request
 
-    model, eng = tiny_engine(ctx4)
+    model, eng = tiny_engine(own_model)
     server = ModelServer(eng).start()
     try:
         pings: list[bool] = []
@@ -488,11 +476,11 @@ def test_server_serviceable_through_chaos(ctx4):
         server.shutdown()
 
 
-def test_server_deadline_payload(ctx4):
+def test_server_deadline_payload(own_model):
     """deadline_s rides the requests payload down to the engine."""
     from triton_distributed_tpu.serving import ModelServer, request
 
-    model, eng = tiny_engine(ctx4)
+    model, eng = tiny_engine(own_model)
     server = ModelServer(eng).start()
     try:
         resp = request(
@@ -506,7 +494,7 @@ def test_server_deadline_payload(ctx4):
         server.shutdown()
 
 
-def test_chaos_counters_and_events_fire(ctx4, fresh_telemetry):
+def test_chaos_counters_and_events_fire(own_model, fresh_telemetry):
     """ISSUE 5 satellite: chaos scenarios leave matching telemetry —
     the shed/deadline/nan counters in the metrics registry AND the
     corresponding shed/deadline/nan_guard/fault events in the ring,
@@ -514,7 +502,7 @@ def test_chaos_counters_and_events_fire(ctx4, fresh_telemetry):
     from triton_distributed_tpu.obs import events as obs_events
     from triton_distributed_tpu.obs import metrics as obs_metrics
 
-    model, eng = tiny_engine(ctx4, max_batch=1, max_queue=2)
+    model, eng = tiny_engine(own_model, max_batch=1, max_queue=2)
     with FaultPlan().nan_logits(at=2, slot=0):
         results = eng.run(
             [

@@ -90,7 +90,7 @@ def _spawn_fleet(n, delay_s=0.4, spawn_timeout_s=120.0):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=150)  # past boot's own spawn_timeout_s
     assert len(out) == n, f"only {len(out)}/{n} replicas spawned"
     return [out[i] for i in range(n)]
 

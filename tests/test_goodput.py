@@ -202,68 +202,57 @@ def test_cancel_mid_stream_frees_pages():
         server.shutdown()
 
 
-def test_cancel_through_continuous_engine(fresh_telemetry):
+def test_cancel_through_continuous_engine(own_model, fresh_telemetry):
     """The non-streaming satellite: the cancel set aborts queued AND
     in-flight requests through a REAL ContinuousEngine — today
     ``aborted`` only fired on loop teardown. Deterministic: the
     in-flight cancel is issued from the victim's own on_token callback
     (applied at the next scheduling round), the queued cancel is
     pre-armed before run()."""
-    import jax
-
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import (
         ContinuousEngine,
         Request,
     )
     from triton_distributed_tpu.obs import events as obs_events
-    from triton_distributed_tpu.runtime import mesh as mesh_mod
 
-    ctx = mesh_mod.initialize_distributed(
-        tp=4, devices=jax.devices()[:4]
+    eng = ContinuousEngine(own_model, max_batch=2, page_size=16,
+                           max_length=64, prefix_cache=True)
+    prompts = [np.arange(1, 9, dtype=np.int32),
+               np.arange(20, 28, dtype=np.int32),
+               np.arange(30, 38, dtype=np.int32)]
+    # Golden for the surviving request, solo.
+    [gold] = eng.run([Request(prompts[2], 6)], results=True)
+    assert gold.status == "ok" and len(gold.tokens) == 6
+
+    victim = Request(prompts[0], 8, ticket_id="vic")
+    victim.on_token = (
+        lambda i, tok: eng.cancel(["vic", "queued"]) if i == 1
+        else None
     )
-    try:
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx)
-        eng = ContinuousEngine(model, max_batch=2, page_size=16,
-                               max_length=64, prefix_cache=True)
-        prompts = [np.arange(1, 9, dtype=np.int32),
-                   np.arange(20, 28, dtype=np.int32),
-                   np.arange(30, 38, dtype=np.int32)]
-        # Golden for the surviving request, solo.
-        [gold] = eng.run([Request(prompts[2], 6)], results=True)
-        assert gold.status == "ok" and len(gold.tokens) == 6
-
-        victim = Request(prompts[0], 8, ticket_id="vic")
-        victim.on_token = (
-            lambda i, tok: eng.cancel(["vic", "queued"]) if i == 1
-            else None
-        )
-        survivor = Request(prompts[2], 6, ticket_id="srv")
-        # max_batch=2: the third request queues; its id is cancelled
-        # mid-flight by the victim's callback above. The engine.cancel
-        # seam sequences the application deterministically (the
-        # cancel-vs-finish race's chaos handle) — assert it fired.
-        queued = Request(prompts[1], 6, ticket_id="queued")
-        plan = FaultPlan(seed=5).slow_cancel(0.01, at=1)
-        with plan:
-            results = eng.run([victim, survivor, queued], results=True)
-        assert ("engine.cancel" in [s for s, _, _ in plan.fired])
-        assert results[0].status == "cancelled"
-        assert 2 <= len(results[0].tokens) < 8  # partial tokens kept
-        assert results[1].status == "ok"
-        assert results[1].tokens.tolist() == gold.tokens.tolist()
-        assert results[2].status == "cancelled"
-        assert len(results[2].tokens) == 0  # never admitted
-        assert eng.stats["cancelled_requests"] == 2
-        assert eng.stats["failed_requests"] == 0
-        assert eng.audit() == []
-        # Telemetry: the status label + the cancel events.
-        reqs = obs_metrics.default_registry().get("tdt_requests_total")
-        assert reqs.value(status="cancelled") == 2
-        evts, _ = obs_events.default_ring().tail(kind="cancel")
-        assert len(evts) >= 2  # the verb-level + per-request events
-    finally:
-        mesh_mod.finalize_distributed()
+    survivor = Request(prompts[2], 6, ticket_id="srv")
+    # max_batch=2: the third request queues; its id is cancelled
+    # mid-flight by the victim's callback above. The engine.cancel
+    # seam sequences the application deterministically (the
+    # cancel-vs-finish race's chaos handle) — assert it fired.
+    queued = Request(prompts[1], 6, ticket_id="queued")
+    plan = FaultPlan(seed=5).slow_cancel(0.01, at=1)
+    with plan:
+        results = eng.run([victim, survivor, queued], results=True)
+    assert ("engine.cancel" in [s for s, _, _ in plan.fired])
+    assert results[0].status == "cancelled"
+    assert 2 <= len(results[0].tokens) < 8  # partial tokens kept
+    assert results[1].status == "ok"
+    assert results[1].tokens.tolist() == gold.tokens.tolist()
+    assert results[2].status == "cancelled"
+    assert len(results[2].tokens) == 0  # never admitted
+    assert eng.stats["cancelled_requests"] == 2
+    assert eng.stats["failed_requests"] == 0
+    assert eng.audit() == []
+    # Telemetry: the status label + the cancel events.
+    reqs = obs_metrics.default_registry().get("tdt_requests_total")
+    assert reqs.value(status="cancelled") == 2
+    evts, _ = obs_events.default_ring().tail(kind="cancel")
+    assert len(evts) >= 2  # the verb-level + per-request events
 
 
 def test_cancel_through_router_by_client_id():

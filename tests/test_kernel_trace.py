@@ -48,20 +48,19 @@ def _warm_cache(model, B=2, s_max=64, warm=((3, 5),)):
 
 
 class TestRingTp1:
-    def test_multi_trace_bit_identity_and_ring(self, ctx1):
+    def test_multi_trace_bit_identity_and_ring(self, own_model):
         """tp=1, NS=3: traced launch's tokens/logits/cache match the
         untraced build bit-exactly; the ring decodes gap-free, clock-
         monotonic, and dependency-consistent with the scheduled order;
         the untraced build keeps the PR 7 3-tuple contract."""
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
         B, NS = 2, 3
-        cache = _warm_cache(model, B)
-        mega = MegaQwen3(model)
+        cache = _warm_cache(own_model, B)
+        mega = MegaQwen3(own_model)
         s_max = int(cache.k.shape[3])
         tok0 = jnp.asarray([19, 23], jnp.int32)
 
         f0 = mega.decode_multi_fn(B, s_max, NS)
-        out0 = f0(model.params, tok0, jax.tree.map(jnp.copy, cache))
+        out0 = f0(own_model.params, tok0, jax.tree.map(jnp.copy, cache))
         assert len(out0) == 3  # PR 7 contract untouched with trace off
         # Untraced LAUNCH PARAMS bit-identical to the pre-tracer
         # layout: the task table's id column stays zero with trace
@@ -77,7 +76,7 @@ class TestRingTp1:
 
         f1 = mega.decode_multi_fn(B, s_max, NS, trace=True)
         t1, l1, c1, ring = f1(
-            model.params, tok0, jax.tree.map(jnp.copy, cache)
+            own_model.params, tok0, jax.tree.map(jnp.copy, cache)
         )
         t0_, l0, c0 = out0
         np.testing.assert_array_equal(np.asarray(t0_), np.asarray(t1))
@@ -103,17 +102,16 @@ class TestRingTp1:
               if r.opcode == int(TaskType.ALLREDUCE)]
         assert ar and all(r.begin <= r.mid <= r.end for r in ar)
 
-    def test_single_step_trace_build(self, ctx1):
+    def test_single_step_trace_build(self, own_model):
         """``build(trace=True)``: the single-step path returns
         (logits, cache, ring [tp, 1, T, 8]) and the ring decodes
         cleanly; trace=False keeps the 2-tuple step."""
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
-        cache = _warm_cache(model, B=1)
-        mega = MegaQwen3(model)
+        cache = _warm_cache(own_model, B=1)
+        mega = MegaQwen3(own_model)
         tok = jnp.asarray([7], jnp.int32)
         compiled, step, _ = mega.build(1, 64, trace=True)
         logits, c2, ring = step(
-            model.params, tok, jax.tree.map(jnp.copy, cache)
+            own_model.params, tok, jax.tree.map(jnp.copy, cache)
         )
         ring = np.asarray(ring)
         assert ring.shape == (1, 1, compiled.num_tasks, 8)
@@ -121,7 +119,7 @@ class TestRingTp1:
         assert kt.validate_ring(records, compiled.order) == []
         # Untraced contract unchanged.
         _, step0, _ = mega.build(1, 64)
-        out = step0(model.params, tok, cache)
+        out = step0(own_model.params, tok, cache)
         assert len(out) == 2
         np.testing.assert_array_equal(
             np.asarray(out[0]), np.asarray(logits)
@@ -315,7 +313,7 @@ class TestDecoderPure:
 
 
 class TestEngineAndServer:
-    def test_continuous_engine_trace_and_verbs(self, ctx1,
+    def test_continuous_engine_trace_and_verbs(self, own_model,
                                                fresh_telemetry):
         """ONE engine compile covers the serving acceptance: traced
         engine output == untraced engine output bit-exactly; launches
@@ -332,14 +330,13 @@ class TestEngineAndServer:
             request,
         )
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
-        reqs = [(list(range(1, 9)), 12), (list(range(3, 15)), 10)]
+        reqs = [(list(range(1, 9)), 6), (list(range(3, 15)), 5)]
         e0 = ContinuousEngine(
-            model, max_batch=2, max_length=64, page_size=16, mode="mega",
+            own_model, max_batch=2, max_length=64, page_size=16, mode="mega",
         )
         out0 = e0.run(reqs, results=True)
         e1 = ContinuousEngine(
-            model, max_batch=2, max_length=64, page_size=16, mode="mega",
+            own_model, max_batch=2, max_length=64, page_size=16, mode="mega",
             kernel_trace=True,
         )
         out1 = e1.run(reqs, results=True)
@@ -408,7 +405,7 @@ class TestEngineAndServer:
             request(server.host, server.port, {"cmd": "shutdown"})
             server.shutdown()
 
-    def test_fixed_batch_engine_trace(self, ctx1, fresh_telemetry):
+    def test_fixed_batch_engine_trace(self, own_model, fresh_telemetry):
         """``Engine(mode="mega", kernel_trace=True)``: the serve()
         multi-step launches record rings too — deterministic across
         serves, launches decoded into the summary/metrics. (Traced-vs-
@@ -418,9 +415,8 @@ class TestEngineAndServer:
         from triton_distributed_tpu.models.engine import Engine
         from triton_distributed_tpu.obs import metrics as obs_metrics
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
         ids = [list(range(1, 9))]
-        e1 = Engine(model, mode="mega", kernel_trace=True)
+        e1 = Engine(own_model, mode="mega", kernel_trace=True)
         out1 = e1.serve(ids, 9, max_length=64)
         out2 = e1.serve(ids, 9, max_length=64)
         np.testing.assert_array_equal(out1, out2)
@@ -434,7 +430,7 @@ class TestEngineAndServer:
         reg = obs_metrics.default_registry()
         assert reg.get("tdt_mega_task_seconds").count(opcode="ATTN") > 0
 
-    def test_sync_tables_never_aliases_host_arrays(self, ctx1):
+    def test_sync_tables_never_aliases_host_arrays(self, own_model):
         """Regression (found by the tracer's wider dispatch→fetch
         window): ``jnp.asarray`` on CPU may zero-copy an aligned numpy
         array, so the engine's device page_table/kv_len could ALIAS
@@ -446,9 +442,8 @@ class TestEngineAndServer:
             ContinuousEngine,
         )
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
         eng = ContinuousEngine(
-            model, max_batch=2, max_length=64, page_size=16, mode="mega",
+            own_model, max_batch=2, max_length=64, page_size=16, mode="mega",
         )
         eng._kv_len[:] = 0
         eng._table[:] = 0
@@ -462,17 +457,16 @@ class TestEngineAndServer:
         np.testing.assert_array_equal(
             np.asarray(eng.cache.page_table), before_tab)
 
-    def test_kernel_trace_requires_mega(self, ctx1):
+    def test_kernel_trace_requires_mega(self, own_model):
         from triton_distributed_tpu.models.continuous import (
             ContinuousEngine,
         )
         from triton_distributed_tpu.models.engine import Engine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
         with pytest.raises(ValueError, match="mode='mega'"):
-            ContinuousEngine(model, mode="xla", kernel_trace=True)
+            ContinuousEngine(own_model, mode="xla", kernel_trace=True)
         with pytest.raises(ValueError, match="mode='mega'"):
-            Engine(model, mode="xla", kernel_trace=True)
+            Engine(own_model, mode="xla", kernel_trace=True)
 
     def test_kernel_trace_verb_refused_without_tracer(self, ctx1):
         """A server over an engine with no tracer surface answers the

@@ -27,9 +27,7 @@ import time
 import numpy as np
 import pytest
 
-import jax
-
-from triton_distributed_tpu.models import AutoLLM, kv_tier
+from triton_distributed_tpu.models import kv_tier
 from triton_distributed_tpu.models.kv_tier import (
     PREFIX_KIND,
     SNAP_KIND,
@@ -40,19 +38,7 @@ from triton_distributed_tpu.models.kv_tier import (
     chain_digest,
     tier_digest_match_len,
 )
-from triton_distributed_tpu.runtime import mesh as mesh_mod
 from triton_distributed_tpu.runtime.faults import FaultPlan
-
-
-@pytest.fixture(scope="module")
-def fabric_model():
-    """ONE tiny model (and mesh) for the whole module — the
-    test_router.py convention: compiled programs cache per model
-    instance and every engine here shares the same shapes."""
-    ctx = mesh_mod.initialize_distributed(tp=4, devices=jax.devices()[:4])
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx)
-    yield model
-    mesh_mod.finalize_distributed()
 
 
 MK = dict(max_batch=1, page_size=16, max_length=64, prefix_cache=True)
@@ -283,7 +269,7 @@ def test_fleet_scope_tier_fabric_metrics_merge():
 # -- wire verbs ------------------------------------------------------------
 
 
-def test_wire_tier_verbs(fabric_model):
+def test_wire_tier_verbs(own_model):
     """``tier_probe`` answers digest membership without touching the
     store's stats/LRU; ``tier_get`` serves the store's wire bytes
     VERBATIM; malformed requests, foreign kinds, and tier-less engines
@@ -293,7 +279,7 @@ def test_wire_tier_verbs(fabric_model):
 
     rng = np.random.default_rng(11)
     [r1] = _mk_reqs(rng, n=1)
-    eng = _spill_engine(fabric_model, r1)
+    eng = _spill_engine(own_model, r1)
     keys = [k for k in eng.tier.keys(PREFIX_KIND)]
     assert keys
     hits_before = eng.tier.stats["hits"]
@@ -336,7 +322,7 @@ def test_wire_tier_verbs(fabric_model):
         srv.shutdown()
 
     # A tier-less engine refuses the whole verb family by name.
-    bare = ContinuousEngine(fabric_model, **MK)
+    bare = ContinuousEngine(own_model, **MK)
     srv2 = ModelServer(bare).start()
     try:
         with pytest.raises(RuntimeError, match="bad_request.*tier"):
@@ -353,25 +339,27 @@ def test_wire_tier_verbs(fabric_model):
 # -- engine: peer fault-back, containment ----------------------------------
 
 
-def test_fabric_local_miss_remote_hit_bitexact(fabric_model,
+def test_fabric_local_miss_remote_hit_bitexact(tp4_model,
                                                fresh_telemetry):
     """The tentpole in-process: engine B's LOCAL tier is cold, its
     peer's tier holds the chain — admission pulls it through the
     fabric, grafts it, and the output is bit-exact vs a tier-less
-    golden. The validated entry is ADOPTED into B's tier."""
+    golden. The validated entry is ADOPTED into B's tier. On the
+    four-device model: the pulled page is grafted into a SHARDED pool
+    (the module's other cases keep all four K/V heads on one device)."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
     from triton_distributed_tpu.obs import events as obs_events
     from triton_distributed_tpu.obs import metrics as obs_metrics
 
     rng = np.random.default_rng(21)
     [r1] = _mk_reqs(rng, n=1)
-    gold = ContinuousEngine(fabric_model, **MK).run([r1])[0]
-    a = _spill_engine(fabric_model, r1)
+    gold = ContinuousEngine(tp4_model, **MK).run([r1])[0]
+    a = _spill_engine(tp4_model, r1)
 
     fc = FabricClient()
     fc.set_peers([LocalFabricPeer("a", a.tier)])
     b = ContinuousEngine(
-        fabric_model, tier_bytes=32 << 20, fabric=fc, **MK
+        tp4_model, tier_bytes=32 << 20, fabric=fc, **MK
     )
     assert not b.tier.may_contain(PREFIX_KIND)  # cold local tier
     np.testing.assert_array_equal(b.run([r1])[0], gold)
@@ -392,7 +380,7 @@ def test_fabric_local_miss_remote_hit_bitexact(fabric_model,
     assert a.audit() == [] and b.audit() == []
 
 
-def test_fabric_wire_pull_bitexact(fabric_model):
+def test_fabric_wire_pull_bitexact(own_model):
     """The same pull over the WIRE: peer A behind a live ModelServer,
     B's client wired by tier_peers dicts — first batch on a cold B is
     bit-exact with remote pages faulted through tier_probe/tier_get."""
@@ -401,13 +389,13 @@ def test_fabric_wire_pull_bitexact(fabric_model):
 
     rng = np.random.default_rng(31)
     [r1] = _mk_reqs(rng, n=1)
-    gold = ContinuousEngine(fabric_model, **MK).run([r1])[0]
-    a = _spill_engine(fabric_model, r1)
+    gold = ContinuousEngine(own_model, **MK).run([r1])[0]
+    a = _spill_engine(own_model, r1)
     srv = ModelServer(a).start()
     try:
         fc = FabricClient(pull_timeout_s=5.0)
         b = ContinuousEngine(
-            fabric_model, tier_bytes=32 << 20, fabric=fc, **MK
+            own_model, tier_bytes=32 << 20, fabric=fc, **MK
         )
         # Wire the peer table THROUGH the verb (the supervisor
         # broadcast path) against B's own server.
@@ -436,7 +424,7 @@ def test_fabric_wire_pull_bitexact(fabric_model):
     assert a.audit() == [] and b.audit() == []
 
 
-def test_fabric_corrupt_remote_degrades_bitexact(fabric_model):
+def test_fabric_corrupt_remote_degrades_bitexact(own_model):
     """Chaos: a garbled remote entry dies at the client's CRC check —
     the SAME containment boundary a corrupt local entry crosses — and
     the admission re-prefills bit-exactly. No remote page lands."""
@@ -444,14 +432,14 @@ def test_fabric_corrupt_remote_degrades_bitexact(fabric_model):
 
     rng = np.random.default_rng(41)
     [r1] = _mk_reqs(rng, n=1)
-    gold = ContinuousEngine(fabric_model, **MK).run([r1])[0]
-    a = _spill_engine(fabric_model, r1)
+    gold = ContinuousEngine(own_model, **MK).run([r1])[0]
+    a = _spill_engine(own_model, r1)
     keys_before = set(a.tier.keys(PREFIX_KIND))
 
     fc = FabricClient()
     fc.set_peers([LocalFabricPeer("a", a.tier)])
     b = ContinuousEngine(
-        fabric_model, tier_bytes=32 << 20, fabric=fc, **MK
+        own_model, tier_bytes=32 << 20, fabric=fc, **MK
     )
     with FaultPlan(seed=1).corrupt_fabric(times=8) as plan:
         np.testing.assert_array_equal(b.run([r1])[0], gold)
@@ -466,7 +454,7 @@ def test_fabric_corrupt_remote_degrades_bitexact(fabric_model):
     assert a.audit() == [] and b.audit() == []
 
 
-def test_fabric_hung_and_dead_peer_not_blocking(fabric_model):
+def test_fabric_hung_and_dead_peer_not_blocking(own_model):
     """A hung peer trips the fetch deadline (late valid bytes are
     discarded) and a dead peer degrades to the local-miss path —
     admission completes bit-exactly either way, promptly."""
@@ -474,13 +462,13 @@ def test_fabric_hung_and_dead_peer_not_blocking(fabric_model):
 
     rng = np.random.default_rng(51)
     [r1] = _mk_reqs(rng, n=1)
-    gold = ContinuousEngine(fabric_model, **MK).run([r1])[0]
-    a = _spill_engine(fabric_model, r1)
+    gold = ContinuousEngine(own_model, **MK).run([r1])[0]
+    a = _spill_engine(own_model, r1)
 
     fc = FabricClient(pull_timeout_s=0.05, cooldown_s=60.0)
     fc.set_peers([LocalFabricPeer("a", a.tier)])
     b = ContinuousEngine(
-        fabric_model, tier_bytes=32 << 20, fabric=fc, **MK
+        own_model, tier_bytes=32 << 20, fabric=fc, **MK
     )
     with FaultPlan(seed=1).slow_fabric(0.3, times=8) as plan:
         t0 = time.monotonic()
@@ -499,7 +487,7 @@ def test_fabric_hung_and_dead_peer_not_blocking(fabric_model):
     fc2 = FabricClient(pull_timeout_s=0.5, cooldown_s=60.0)
     fc2.set_peers([WireFabricPeer("dead", "127.0.0.1", port)])
     c = ContinuousEngine(
-        fabric_model, tier_bytes=32 << 20, fabric=fc2, **MK
+        own_model, tier_bytes=32 << 20, fabric=fc2, **MK
     )
     np.testing.assert_array_equal(c.run([r1])[0], gold)
     assert c.last_stats["tier_remote_pages"] == 0
@@ -507,7 +495,7 @@ def test_fabric_hung_and_dead_peer_not_blocking(fabric_model):
     assert a.audit() == [] and b.audit() == [] and c.audit() == []
 
 
-def test_fabric_never_wrong_bits_matrix(fabric_model):
+def test_fabric_never_wrong_bits_matrix(own_model):
     """The acceptance contract: checksum-tampered, stale-geometry, and
     foreign-fingerprint peer entries ALL degrade to bit-exact
     re-prefill — the PR 12 validation path runs unchanged on remote
@@ -516,17 +504,17 @@ def test_fabric_never_wrong_bits_matrix(fabric_model):
 
     rng = np.random.default_rng(61)
     [r1] = _mk_reqs(rng, n=1)
-    gold = ContinuousEngine(fabric_model, **MK).run([r1])[0]
+    gold = ContinuousEngine(own_model, **MK).run([r1])[0]
 
     def cold_puller(peer_store):
         fc = FabricClient()
         fc.set_peers([LocalFabricPeer("a", peer_store)])
         return ContinuousEngine(
-            fabric_model, tier_bytes=32 << 20, fabric=fc, **MK
+            own_model, tier_bytes=32 << 20, fabric=fc, **MK
         )
 
     # 1) checksum-tamper: flip a byte in every peer RAM blob.
-    a1 = _spill_engine(fabric_model, r1)
+    a1 = _spill_engine(own_model, r1)
     with a1.tier._lock:
         for k, blob in list(a1.tier._ram.items()):
             bb = bytearray(blob)
@@ -542,7 +530,7 @@ def test_fabric_never_wrong_bits_matrix(fabric_model):
     #    not key-match this engine's 16-token page chains at all —
     #    and a re-stamped wrong-geometry payload under the RIGHT key
     #    fails the engine's page_size check after a clean pull.
-    a2 = _spill_engine(fabric_model, r1)
+    a2 = _spill_engine(own_model, r1)
     for k in a2.tier.keys(PREFIX_KIND):
         payload = a2.tier.get(PREFIX_KIND, k)
         payload["page_size"] = 8
@@ -556,7 +544,7 @@ def test_fabric_never_wrong_bits_matrix(fabric_model):
 
     # 3) foreign model fingerprint (a tier_dir outliving a checkpoint
     #    swap, served over the fabric): refused at the same check.
-    a3 = _spill_engine(fabric_model, r1)
+    a3 = _spill_engine(own_model, r1)
     for k in a3.tier.keys(PREFIX_KIND):
         payload = a3.tier.get(PREFIX_KIND, k)
         payload["model_fp"] = "other-weights"
@@ -573,7 +561,7 @@ def test_fabric_never_wrong_bits_matrix(fabric_model):
 # -- placement & warm boot -------------------------------------------------
 
 
-def test_router_tier_affinity_placement(fabric_model):
+def test_router_tier_affinity_placement(own_model):
     """The router scores TIER coverage alongside radix coverage: a
     prompt whose pages live only in a replica's tier routes back to
     that replica as ``tier_affinity`` (and faults back there) instead
@@ -583,14 +571,14 @@ def test_router_tier_affinity_placement(fabric_model):
 
     rng = np.random.default_rng(71)
     [(p, gen)] = _mk_reqs(rng, n=1)
-    gold = ContinuousEngine(fabric_model, **MK).run([(p, gen)])[0]
+    gold = ContinuousEngine(own_model, **MK).run([(p, gen)])[0]
 
     # e0 serves p, then a 4-page prompt evicts p's chain to its TIER.
-    e0 = _spill_engine(fabric_model, (p, gen))
+    e0 = _spill_engine(own_model, (p, gen))
     assert e0.tier.may_contain(PREFIX_KIND)
     toks = [int(t) for t in p]
     assert tier_digest_match_len(e0.tier_digest(), toks) >= 16
-    e1 = ContinuousEngine(fabric_model, tier_bytes=32 << 20, **MK)
+    e1 = ContinuousEngine(own_model, tier_bytes=32 << 20, **MK)
 
     router = Router([e0, e1])
     try:
@@ -611,7 +599,7 @@ def test_router_tier_affinity_placement(fabric_model):
         router.shutdown()
 
 
-def test_warm_boot_from_shared_dir(fabric_model, tmp_path):
+def test_warm_boot_from_shared_dir(own_model, tmp_path):
     """The scale-up arm in miniature: a FRESH engine over the pool's
     shared tier dir (the ``--tier-shared`` shape) serves its FIRST
     batch from the predecessors' spills — tier hits on batch one,
@@ -621,11 +609,11 @@ def test_warm_boot_from_shared_dir(fabric_model, tmp_path):
     d = str(tmp_path / "fabric")
     rng = np.random.default_rng(81)
     [r1] = _mk_reqs(rng, n=1)
-    gold = ContinuousEngine(fabric_model, **MK).run([r1])[0]
-    a = _spill_engine(fabric_model, r1, tier_dir=d)  # whole chain on disk
+    gold = ContinuousEngine(own_model, **MK).run([r1])[0]
+    a = _spill_engine(own_model, r1, tier_dir=d)  # whole chain on disk
 
     fresh = ContinuousEngine(
-        fabric_model, tier_bytes=32 << 20, tier_dir=d, **MK
+        own_model, tier_bytes=32 << 20, tier_dir=d, **MK
     )
     assert fresh.tier.may_contain(PREFIX_KIND)  # disk prescan: warm
     np.testing.assert_array_equal(fresh.run([r1])[0], gold)

@@ -285,14 +285,14 @@ def test_distributed_flash_decode_int8(ctx4, rng):
             k_scale=ks, v_scale=vs, ctx=ctx4,
         )
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         shard_fn,
         in_specs=(
             P(), P(None, None, "tp", None), P(None, None, "tp", None),
             P(), P(None, None, "tp"), P(None, None, "tp"),
         ),
         out_specs=P(),
-    )
+    ))
     out = f(
         q, k_q.reshape(b, hkv, s, d), v_q.reshape(b, hkv, s, d), lens,
         k_sc, v_sc,
@@ -452,13 +452,7 @@ def test_append_n_trash_routes_overshoot(rng):
     )
 
 
-def _tiny_model(ctx, max_length=128):
-    from triton_distributed_tpu.models import AutoLLM
-
-    return AutoLLM.from_pretrained("tiny", ctx=ctx, max_length=max_length)
-
-
-def test_engine_int8_teacher_forced_close(ctx4, rng):
+def test_engine_int8_teacher_forced_close(own_model, rng):
     """Documented accuracy tolerance on the tier-1 smoke model: with the
     SAME token stream fed to a full-width and an int8 engine cache, the
     per-step logits stay within atol 0.25 and the greedy argmax agrees
@@ -466,18 +460,17 @@ def test_engine_int8_teacher_forced_close(ctx4, rng):
     model's own top1-top2 gap is below the quantization noise)."""
     from triton_distributed_tpu.models.paged_kv_cache import write_prefill
 
-    model = _tiny_model(ctx4)
     prompt = rng.integers(1, 200, size=(2, 24)).astype(np.int32)
 
     def build(kv_dtype):
         cache, _pool = init_paged_cache(
-            model.cfg, 2, ctx4, "tp", max_length=128, page_size=16,
-            kv_dtype=kv_dtype,
+            own_model.cfg, 2, own_model.ctx, "tp", max_length=128,
+            page_size=16, kv_dtype=kv_dtype,
         )
-        dense1 = model.new_cache(1, 128)
+        dense1 = own_model.new_cache(1, 128)
         logits = []
         for i in range(2):
-            lg, dense1 = model.prefill_batched(
+            lg, dense1 = own_model.prefill_batched(
                 jnp.asarray(prompt[i : i + 1]), dense1, "xla",
                 jnp.asarray([24], np.int32),
             )
@@ -496,8 +489,8 @@ def test_engine_int8_teacher_forced_close(ctx4, rng):
     tok = jnp.argmax(lf, -1).astype(jnp.int32)
     steps, agree, max_diff = 6, 0, 0.0
     for _ in range(steps):
-        lgf, cf = model.decode_step(tok, cf, "xla")
-        lgq, cq = model.decode_step(tok, cq, "xla")
+        lgf, cf = own_model.decode_step(tok, cf, "xla")
+        lgq, cq = own_model.decode_step(tok, cq, "xla")
         max_diff = max(max_diff, float(jnp.max(jnp.abs(lgf - lgq))))
         agree += int((jnp.argmax(lgf, -1) == jnp.argmax(lgq, -1)).sum())
         tok = jnp.argmax(lgf, -1).astype(jnp.int32)
@@ -607,7 +600,7 @@ def test_copy_page_carries_scales(rng):
     np.testing.assert_array_equal(np.asarray(out.v_scale)[:, 3], v_sc_np[:, 1])
 
 
-def test_prefix_cow_audit_and_speculative_with_quant(ctx4, rng):
+def test_prefix_cow_audit_and_speculative_with_quant(own_model, rng):
     """One serving pass over an int8 pool covering three contracts:
 
     - a PAGE-ALIGNED shared prefix reuses the cold run's quantized
@@ -617,7 +610,6 @@ def test_prefix_cow_audit_and_speculative_with_quant(ctx4, rng):
       including under speculative decoding's verify/rollback churn."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = _tiny_model(ctx4)
     system = rng.integers(1, 200, size=32).astype(np.int32)  # 2 full pages
 
     # First suffix token differs per arrival → the radix walk stops
@@ -630,7 +622,7 @@ def test_prefix_cow_audit_and_speculative_with_quant(ctx4, rng):
         for i in range(2)
     ]
     warm = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=128,
+        own_model, max_batch=1, page_size=16, max_length=128,
         prefix_cache=True, kv_dtype="int8",
     )
     cold_outs = [warm.run([r])[0] for r in reqs]   # seeds the tree
@@ -641,8 +633,9 @@ def test_prefix_cow_audit_and_speculative_with_quant(ctx4, rng):
     assert warm.audit() == []
     st = warm.last_stats
     assert st["kv_dtype"] == "int8"
-    assert st["kv_bytes_per_token"] < 2 * model.cfg.num_layers * \
-        model.cfg.num_kv_heads * model.cfg.head_dim * 2  # < bf16 layout
+    cfg = own_model.cfg
+    assert st["kv_bytes_per_token"] < 2 * cfg.num_layers * \
+        cfg.num_kv_heads * cfg.head_dim * 2  # < bf16 layout
 
     # COW path: an arrival sharing a PARTIAL page (prompt diverges
     # mid-page) clones codes+scale and must serve cleanly.
@@ -659,7 +652,7 @@ def test_prefix_cow_audit_and_speculative_with_quant(ctx4, rng):
     # Speculative verify/rollback over the same quantized pool (the
     # repetitive prompt guarantees n-gram drafts, hence rollbacks).
     spec = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=128,
+        own_model, max_batch=2, page_size=16, max_length=128,
         prefix_cache=True, speculative=3, kv_dtype="int8",
     )
     prompt = np.tile(rng.integers(1, 200, size=8).astype(np.int32), 4)
@@ -668,7 +661,7 @@ def test_prefix_cow_audit_and_speculative_with_quant(ctx4, rng):
     assert spec.audit() == []
 
 
-def test_bf16_bit_identical_when_unset_and_validation(ctx4):
+def test_bf16_bit_identical_when_unset_and_validation(own_model):
     """kv_dtype unset: the cache pytree (dtypes, structure, specs) is
     EXACTLY the pre-quantization layout — no scale leaves, pool in
     cfg.dtype — so every compiled program and its donation/sharding
@@ -676,13 +669,12 @@ def test_bf16_bit_identical_when_unset_and_validation(ctx4):
     from triton_distributed_tpu.models.continuous import ContinuousEngine
     from triton_distributed_tpu.models.engine import Engine
 
-    model = _tiny_model(ctx4)
     cache, _pool = init_paged_cache(
-        model.cfg, 2, ctx4, "tp", max_length=128, page_size=16
+        own_model.cfg, 2, own_model.ctx, "tp", max_length=128, page_size=16
     )
     assert cache.k_scale is None and cache.v_scale is None
     assert not cache.quantized
-    assert cache.k_pages.dtype == model.cfg.dtype
+    assert cache.k_pages.dtype == own_model.cfg.dtype
     # EXACTLY four array leaves — scale fields are empty subtrees, so
     # every jitted program sees the pre-quantization pytree (same
     # donation indices, same shardings, same compiled cache keys).
@@ -693,20 +685,20 @@ def test_bf16_bit_identical_when_unset_and_validation(ctx4):
     assert rollback_kv(cache, 0, 0).k_scale is None
 
     with pytest.raises(ValueError, match="unsupported"):
-        init_paged_cache(model.cfg, 1, ctx4, "tp", kv_dtype="fp8")
+        init_paged_cache(own_model.cfg, 1, own_model.ctx, "tp", kv_dtype="fp8")
     with pytest.raises(ValueError, match="paged"):
-        Engine(model, kv_dtype="int8")
+        Engine(own_model, kv_dtype="int8")
     # PR 7: kv_dtype COMPOSES with mode="mega" (the fused decode
     # dequantizes the int8 pool in-kernel) — construction must succeed;
     # the one remaining mega exclusion is speculative.
-    Engine(model, paged=True, mode="mega", kv_dtype="int8")
-    ContinuousEngine(model, mode="mega", kv_dtype="int8")
+    Engine(own_model, paged=True, mode="mega", kv_dtype="int8")
+    ContinuousEngine(own_model, mode="mega", kv_dtype="int8")
     with pytest.raises(ValueError, match="speculative"):
-        ContinuousEngine(model, mode="mega", kv_dtype="int8",
+        ContinuousEngine(own_model, mode="mega", kv_dtype="int8",
                          speculative=4)
     # cfg-level default plumbs through without the explicit knob.
-    cfg = dataclasses.replace(model.cfg, kv_dtype="int8")
-    qcache, _ = init_paged_cache(cfg, 1, ctx4, "tp", max_length=128,
+    cfg = dataclasses.replace(own_model.cfg, kv_dtype="int8")
+    qcache, _ = init_paged_cache(cfg, 1, own_model.ctx, "tp", max_length=128,
                                  page_size=16)
     assert qcache.quantized and qcache.k_pages.dtype == jnp.int8
 

@@ -296,72 +296,69 @@ def _mk_reqs(rng, n_prefixes=2, prefix_tokens=32, tail=4, gen=3):
     return reqs
 
 
-def test_engine_spill_and_fault_back_bitexact(ctx4):
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_engine_spill_and_fault_back_bitexact(tp4_model, kv_dtype):
     """Eviction under pool pressure spills full radix pages to the
     tier; re-admitting the evicted prefix faults them back (suffix-only
     prefill, counted) with outputs bit-identical to a tier-less
-    engine. Runs the same proof on an int8 pool — codes + per-page
-    scales travel as a pair."""
-    from triton_distributed_tpu.models import AutoLLM
+    engine. The same proof on an int8 pool — codes + per-page scales
+    travel as a pair. On the four-device model: each shard's one K/V
+    head of a page leaves and comes back to its own shard (the
+    module's other cases keep all four heads on one device)."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(0)
     r1, r2 = _mk_reqs(rng)
-
-    for kv_dtype in (None, "int8"):
-        golds = [
-            ContinuousEngine(
-                model, max_batch=1, page_size=16, max_length=64,
-                prefix_cache=True, kv_dtype=kv_dtype,
-            ).run([r])[0]
-            for r in (r1, r2)
-        ]
-        # 4-page pool: serving r2 must evict r1's chain — through the
-        # tier instead of to nothing.
-        eng = ContinuousEngine(
-            model, max_batch=1, page_size=16, max_length=64,
-            prefix_cache=True, num_pages=4, kv_dtype=kv_dtype,
-            tier_bytes=32 << 20,
-        )
-        np.testing.assert_array_equal(eng.run([r1])[0], golds[0])
-        np.testing.assert_array_equal(eng.run([r2])[0], golds[1])
-        assert eng.last_stats["tier_spilled_pages"] >= 1
-        np.testing.assert_array_equal(eng.run([r1])[0], golds[0])
-        st = eng.last_stats
-        assert st["tier_hits"] >= 1 and st["tier_faults"] >= 1
-        assert st["tier_bytes"] > 0
-        # Fault-back beat re-prefill: only the un-faulted suffix ran
-        # through the prefill path.
-        assert st["prefill_tokens"] < len(r1[0])
-        assert st["prefix_hit_tokens"] >= 16
-        assert st["tier"]["hits"] >= 1
-        assert eng.audit() == []
+    golds = [
+        ContinuousEngine(
+            tp4_model, max_batch=1, page_size=16, max_length=64,
+            prefix_cache=True, kv_dtype=kv_dtype,
+        ).run([r])[0]
+        for r in (r1, r2)
+    ]
+    # 4-page pool: serving r2 must evict r1's chain — through the
+    # tier instead of to nothing.
+    eng = ContinuousEngine(
+        tp4_model, max_batch=1, page_size=16, max_length=64,
+        prefix_cache=True, num_pages=4, kv_dtype=kv_dtype,
+        tier_bytes=32 << 20,
+    )
+    np.testing.assert_array_equal(eng.run([r1])[0], golds[0])
+    np.testing.assert_array_equal(eng.run([r2])[0], golds[1])
+    assert eng.last_stats["tier_spilled_pages"] >= 1
+    np.testing.assert_array_equal(eng.run([r1])[0], golds[0])
+    st = eng.last_stats
+    assert st["tier_hits"] >= 1 and st["tier_faults"] >= 1
+    assert st["tier_bytes"] > 0
+    # Fault-back beat re-prefill: only the un-faulted suffix ran
+    # through the prefill path.
+    assert st["prefill_tokens"] < len(r1[0])
+    assert st["prefix_hit_tokens"] >= 16
+    assert st["tier"]["hits"] >= 1
+    assert eng.audit() == []
 
 
-def test_engine_tier_weight_identity(ctx4):
+def test_engine_tier_weight_identity(own_model):
     """Durable entries are valid under the weights that produced them,
     never across a checkpoint swap: a prefix entry whose model
     fingerprint differs is refused at fault-back (dropped; admission
     re-prefills bit-exactly), and a snapshot carrying a foreign
     fingerprint degrades to a bit-exact replay instead of importing
     old-weight KV."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import (
         ContinuousEngine,
         Request,
     )
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(3)
     r1, r2 = _mk_reqs(rng)
     gold = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True,
     ).run([r1])[0]
 
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, num_pages=4, tier_bytes=32 << 20,
     )
     np.testing.assert_array_equal(eng.run([r1])[0], gold)
@@ -381,12 +378,12 @@ def test_engine_tier_weight_identity(ctx4):
     # fingerprint resumes, foreign fingerprint replays; both bit-exact.
     prompt = np.arange(1, 20, dtype=np.int32)
     gold2 = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True,
     ).run([(prompt, 6)])[0]
     shared = PageStore(capacity_bytes=1 << 20)
     crasher = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, snapshot_every=1, tier=shared,
     )
     with FaultPlan(seed=5).on("engine.decode", at=3,
@@ -400,7 +397,7 @@ def test_engine_tier_weight_identity(ctx4):
     assert snap is not None and snap.get("model_fp")
 
     ok = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, tier_bytes=1 << 20,
     )
     out = ok.run([Request(prompt, 6, snapshot=dict(snap))], results=True)
@@ -410,7 +407,7 @@ def test_engine_tier_weight_identity(ctx4):
     bad = dict(snap)
     bad["model_fp"] = "other-weights"
     ok2 = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, tier_bytes=1 << 20,
     )
     out2 = ok2.run([Request(prompt, 6, snapshot=bad)], results=True)
@@ -420,37 +417,35 @@ def test_engine_tier_weight_identity(ctx4):
     assert ok2.last_stats["migrated_in"] == 0
 
 
-def test_engine_shared_tier_mismatch_skips_not_deletes(ctx4):
+def test_engine_shared_tier_mismatch_skips_not_deletes(own_model):
     """A mismatched probe against a SHARED store (``tier=``) degrades
     locally but never destroys the other engine's valid entry: an int8
     engine walking a bf16 engine's spilled chain re-prefills (zero
     faults), the entries survive, and the bf16 engine still faults
     them back afterwards. (Owned stores DO delete on mismatch —
     covered by the weight-identity test.)"""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(7)
     r1, r2 = _mk_reqs(rng)
     mk = dict(max_batch=1, page_size=16, max_length=64,
               prefix_cache=True)
     gold1, gold2 = (
-        ContinuousEngine(model, **mk).run([r])[0] for r in (r1, r2)
+        ContinuousEngine(own_model, **mk).run([r])[0] for r in (r1, r2)
     )
     gold1_i8 = ContinuousEngine(
-        model, kv_dtype="int8", **mk
+        own_model, kv_dtype="int8", **mk
     ).run([r1])[0]
 
     shared = PageStore(capacity_bytes=32 << 20)
-    a = ContinuousEngine(model, num_pages=4, tier=shared, **mk)
+    a = ContinuousEngine(own_model, num_pages=4, tier=shared, **mk)
     np.testing.assert_array_equal(a.run([r1])[0], gold1)
     np.testing.assert_array_equal(a.run([r2])[0], gold2)  # spills r1
     assert a.last_stats["tier_spilled_pages"] >= 1
     keys_before = set(shared.keys(PREFIX_KIND))
     assert keys_before
 
-    b = ContinuousEngine(model, kv_dtype="int8", tier=shared, **mk)
+    b = ContinuousEngine(own_model, kv_dtype="int8", tier=shared, **mk)
     np.testing.assert_array_equal(b.run([r1])[0], gold1_i8)
     assert b.last_stats["tier_hits"] == 0
     assert b.last_stats["tier_faults"] == 0
@@ -461,20 +456,18 @@ def test_engine_shared_tier_mismatch_skips_not_deletes(ctx4):
     assert a.audit() == [] and b.audit() == []
 
 
-def test_engine_tier_events_and_metrics(ctx4, fresh_telemetry):
+def test_engine_tier_events_and_metrics(own_model, fresh_telemetry):
     """The tier ledger is mirrored into the registry and the event
     ring: spills, fault-backs, and the tdt_tier_* series line up with
     ``last_stats``."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import ContinuousEngine
     from triton_distributed_tpu.obs import events as obs_events
     from triton_distributed_tpu.obs import metrics as obs_metrics
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(1)
     r1, r2 = _mk_reqs(rng)
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, num_pages=4, tier_bytes=32 << 20,
     )
     eng.run([r1])
@@ -500,22 +493,20 @@ def test_engine_tier_events_and_metrics(ctx4, fresh_telemetry):
         srv._sock.close()
 
 
-def test_engine_corrupt_tier_degrades_to_prefill(ctx4):
+def test_engine_corrupt_tier_degrades_to_prefill(own_model):
     """Failure containment: every tier entry corrupted in place still
     yields BIT-EXACT outputs — the checksum drops each entry and the
     admission re-prefills (tier_faults stays 0, drops count up)."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(2)
     r1, r2 = _mk_reqs(rng)
     gold = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True,
     ).run([r1])[0]
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, num_pages=4, tier_bytes=32 << 20,
     )
     eng.run([r1])
@@ -535,22 +526,20 @@ def test_engine_corrupt_tier_degrades_to_prefill(ctx4):
     assert eng.audit() == []
 
 
-def test_engine_tier_fault_seams_degrade(ctx4):
+def test_engine_tier_fault_seams_degrade(own_model):
     """Injected tier faults at the engine level: a refused spill
     behaves like the pre-tier drop, a refused fault-back read like a
     miss — outputs bit-exact either way."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(3)
     r1, r2 = _mk_reqs(rng)
     gold = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True,
     ).run([r1])[0]
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, num_pages=4, tier_bytes=32 << 20,
     )
     eng.run([r1])
@@ -569,30 +558,28 @@ def test_engine_tier_fault_seams_degrade(ctx4):
     assert eng.audit() == []
 
 
-def test_engine_randomized_spill_faultback_stress(ctx4):
+def test_engine_randomized_spill_faultback_stress(own_model):
     """Randomized shared-prefix traffic over a pool far smaller than
     the population, tier on: every output equals its tier-less golden,
     and the pool partition (free ∪ slots ∪ tree) plus the tier audits
     stay clean after every round (the autouse fixture re-audits at
     teardown)."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(5)
     bases = [
         rng.integers(1, 200, size=32).astype(np.int32) for _ in range(3)
     ]
     golden_engine = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=64,
+        own_model, max_batch=2, page_size=16, max_length=64,
         prefix_cache=True,
     )
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=64,
+        own_model, max_batch=2, page_size=16, max_length=64,
         prefix_cache=True, num_pages=6, tier_bytes=32 << 20,
     )
     golds: dict = {}
-    for _ in range(8):
+    for _ in range(6):
         base = bases[int(rng.integers(len(bases)))]
         cut = int(rng.integers(16, len(base) + 1))
         tail = rng.integers(1, 200, size=int(rng.integers(1, 4)))
@@ -611,19 +598,17 @@ def test_engine_randomized_spill_faultback_stress(ctx4):
     assert eng.last_stats["tier"]["puts"] >= 1  # the tier actually ran
 
 
-def test_audit_catches_tier_chain_drift(ctx4):
+def test_audit_catches_tier_chain_drift(own_model):
     """The tier-residency audit cross-check: an entry whose payload
     chain no longer matches its digest key (or a tree node's chain) is
     reported — the drift that would fault wrong KV back under a prompt
     if it went unseen."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     rng = np.random.default_rng(6)
     r1, _ = _mk_reqs(rng)
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, tier_bytes=32 << 20,
     )
     eng.run([r1])
@@ -650,28 +635,26 @@ def test_audit_catches_tier_chain_drift(ctx4):
 # -- crash durability: engine snapshots on disk ----------------------------
 
 
-def test_engine_snapshot_buffer_survives_crash(ctx4, tmp_path):
+def test_engine_snapshot_buffer_survives_crash(own_model, tmp_path):
     """``snapshot_every`` + a disk tier: a run killed mid-generation
     leaves checksummed snapshots on disk; a FRESH engine (new process
     stand-in) imports the leftover and finishes BIT-EXACTLY vs an
     uninterrupted golden — the engine-side half of supervisor-restart
     recovery."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import (
         ContinuousEngine,
         Request,
     )
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     prompt = np.arange(1, 20, dtype=np.int32)
     gold = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True,
     ).run([(prompt, 8)])[0]
 
     d = str(tmp_path / "tier")
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, snapshot_every=1, tier_dir=d,
     )
     # The crash must END the loop (a structured in-process failure
@@ -696,7 +679,7 @@ def test_engine_snapshot_buffer_survives_crash(ctx4, tmp_path):
     assert snap is not None and len(snap["out"]) >= 1
 
     fresh = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True,
     )
     out = fresh.run([Request(prompt, 8, snapshot=snap)], results=True)
@@ -710,7 +693,7 @@ def test_engine_snapshot_buffer_survives_crash(ctx4, tmp_path):
     # its first run() start — entries mean "crash", never "history";
     # without the owned-store clear they'd accumulate per crash cycle.
     respawn = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, snapshot_every=1, tier_dir=d,
     )
     respawn.run([Request(prompt, 2, ticket_id="tkt-2")], results=True)
@@ -724,7 +707,7 @@ def test_engine_snapshot_buffer_survives_crash(ctx4, tmp_path):
     shared = PageStore(capacity_bytes=1 << 20)
     shared.put(SNAP_KIND, "sibling-tkt", {"out": [1]})
     ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64,
+        own_model, max_batch=1, page_size=16, max_length=64,
         prefix_cache=True, tier=shared,
     ).run([(prompt, 1)])
     assert shared.get(SNAP_KIND, "sibling-tkt") is not None

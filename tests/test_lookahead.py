@@ -13,18 +13,15 @@ A CPU run gives counts and tokens, never a time: the gain is a chip
 matter (PERF.md).
 """
 
-import jax
 import numpy as np
 import pytest
 
-from triton_distributed_tpu.models import AutoLLM
 from triton_distributed_tpu.models.continuous import (
     ContinuousEngine,
     Request,
     _StepLaunch,
 )
 from triton_distributed_tpu.obs import metrics as obs_metrics
-from triton_distributed_tpu.runtime import mesh as mesh_mod
 from triton_distributed_tpu.runtime.faults import FaultPlan
 
 RNG = np.random.default_rng(30)
@@ -42,23 +39,14 @@ def prompt(head: int, tail_seed: int) -> np.ndarray:
 # a first token finishes a request, a round holds 1 and 2 live slots.
 # (A program call costs a third of a second here whatever its size, so
 # the batches are short.)
-MIXED = [(prompt(i % 2, i), g) for i, g in enumerate([7, 2, 9, 4, 1])]
+MIXED = [(prompt(i % 2, i), g) for i, g in enumerate([5, 2, 4, 1])]
 LONE = prompt(0, 99)
 
 
 @pytest.fixture(scope="module")
-def model():
-    """ONE tiny model for the module: the jitted programs live on it,
-    so every engine here shares one compile."""
-    ctx = mesh_mod.initialize_distributed(tp=1, devices=jax.devices()[:1])
-    yield AutoLLM.from_pretrained("tiny", ctx=ctx)
-    mesh_mod.finalize_distributed()
-
-
-@pytest.fixture(scope="module")
-def greedy(model):
+def greedy(own_model):
     """What ``LONE`` decodes to, alone and undisturbed."""
-    out = engine(model).run([(LONE, 8)])[0]
+    out = engine(own_model).run([(LONE, 8)])[0]
     assert out[3] not in out[:3]  # as a stop token it stops once, at 3
     return out
 
@@ -88,10 +76,10 @@ def run(eng, reqs):
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
 @pytest.mark.parametrize("prefix_cache", [False, True])
-def test_lookahead_matches_serial_round(model, prefix_cache, kv_dtype):
+def test_lookahead_matches_serial_round(own_model, prefix_cache, kv_dtype):
     kw = dict(prefix_cache=prefix_cache, kv_dtype=kv_dtype)
-    want, s_stats = run(serial(engine(model, **kw)), MIXED)
-    got, stats = run(engine(model, **kw), MIXED)
+    want, s_stats = run(serial(engine(own_model, **kw)), MIXED)
+    got, stats = run(engine(own_model, **kw), MIXED)
     assert got == want
     assert [len(t) for t, _ in got] == [g for _, g in MIXED]
     # The same steps, most of them dispatched a round early, none wasted.
@@ -126,11 +114,11 @@ def _fault_plan(model):
 
 
 @pytest.mark.parametrize("case", [_sampled, _speculative, _fault_plan])
-def test_lookahead_stays_out(model, case):
+def test_lookahead_stays_out(own_model, case):
     """A sampled slot, a speculative plan or an armed FaultPlan: the
     round keeps the parent's serial order and gives the parent's
     outputs."""
-    (parent, eng), reqs = case(model)
+    (parent, eng), reqs = case(own_model)
     want, _ = run(serial(parent), reqs())
     if case is _fault_plan:
         # Armed, with no rule: the seams fire into a plan that counts.
@@ -230,8 +218,8 @@ def _nan_row(eng, watch, greedy):
      (_cancel, "cancelled", 4, True),
      (_deadline, "deadline_exceeded", 4, True),
      (_nan_row, "nan_logits", 3, True)])
-def test_slot_ends_while_a_step_is_in_flight(model, greedy, event, status,
-                                             kept, prefix_cache):
+def test_slot_ends_while_a_step_is_in_flight(own_model, greedy, event,
+                                             status, kept, prefix_cache):
     """The slot's token of the in-flight step is never emitted and is
     counted; the parent's serial round gives the same tokens and status;
     no page goes back before the device has left the step; the engine
@@ -239,7 +227,7 @@ def test_slot_ends_while_a_step_is_in_flight(model, greedy, event, status,
     other = (prompt(1, 5), 6)
     outcomes = {}
     for name in ("serial", "ahead"):
-        eng = engine(model, prefix_cache=prefix_cache)
+        eng = engine(own_model, prefix_cache=prefix_cache)
         if name == "serial":
             serial(eng)
         watch = Watch(eng)
@@ -262,25 +250,25 @@ def test_slot_ends_while_a_step_is_in_flight(model, greedy, event, status,
     assert a_stats["lookahead_steps"] > 0
 
 
-def test_lookahead_goes_on_over_a_request_that_ends_by_its_length(model):
-    """Two requests of 4 and 9 tokens and nobody waiting: the round in
+def test_lookahead_goes_on_over_a_request_that_ends_by_its_length(own_model):
+    """Two requests of 3 and 6 tokens and nobody waiting: the round in
     which the shorter ends still dispatches the next step for the other
     (its own row of that step is dropped, and no page goes back before
     the device has left it); with a third request waiting for the slot
     the round waits, as the serial loop admits it into that very step.
     (32 slots end a request every tenth round: PERF.md "PR 35".)"""
-    reqs = lambda: [(prompt(0, 1), 4), (prompt(1, 2), 9)]  # noqa: E731
-    want, s_stats = run(serial(engine(model)), reqs())
-    eng = engine(model)
+    reqs = lambda: [(prompt(0, 1), 3), (prompt(1, 2), 6)]  # noqa: E731
+    want, s_stats = run(serial(engine(own_model)), reqs())
+    eng = engine(own_model)
     watch = Watch(eng)
     got, stats = run(eng, reqs())
     assert got == want and watch.unsettled == []
-    assert stats["decode_steps"] == s_stats["decode_steps"] == 8
-    assert stats["lookahead_steps"] == 7  # all but the first
+    assert stats["decode_steps"] == s_stats["decode_steps"] == 5
+    assert stats["lookahead_steps"] == 4  # all but the first
     assert stats["lookahead_discarded"] == 1
     queued = reqs() + [(prompt(0, 3), 3)]
-    want, s_stats = run(serial(engine(model)), queued)
-    got, stats = run(engine(model), queued)
+    want, s_stats = run(serial(engine(own_model)), queued)
+    got, stats = run(engine(own_model), queued)
     assert got == want
     assert stats["decode_steps"] == s_stats["decode_steps"]
     # The first end waited for the admission; the third request's own
@@ -288,10 +276,10 @@ def test_lookahead_goes_on_over_a_request_that_ends_by_its_length(model):
     assert stats["lookahead_discarded"] == 1
 
 
-def test_host_and_device_kv_len_agree_after_every_drain(model, greedy):
+def test_host_and_device_kv_len_agree_after_every_drain(own_model, greedy):
     """``audit()`` compares the two wherever nothing is in flight: here
     after each admission's drain, mid-run, with live slots."""
-    eng = engine(model, prefix_cache=True)
+    eng = engine(own_model, prefix_cache=True)
     admit, audits = eng._try_admit, []
 
     def audited(queue):
@@ -406,7 +394,7 @@ def _site_step_guard(eng, spy, greedy):
 
 
 @pytest.fixture(scope="module")
-def kinds(model):
+def kinds(own_model):
     """One engine a launch kind for all six sites (a mega engine
     compiles its launch programs anew): ``step`` parks a looked-ahead
     ``_StepLaunch``, ``mega`` a resident ``_MegaLaunch``."""
@@ -416,7 +404,7 @@ def kinds(model):
         if kind not in built:
             kw = dict(mode="mega", resident=True, ns=2) \
                 if kind == "mega" else {}
-            built[kind] = engine(model, prefix_cache=True, **kw)
+            built[kind] = engine(own_model, prefix_cache=True, **kw)
         return built[kind]
     return get
 
@@ -455,14 +443,14 @@ def test_state_mutation_syncs_pend_first(kinds, greedy, monkeypatch, kind,
 # -- (e) the engagement rate and where the counters show -------------------
 
 
-def test_engagement_rate_and_counters(model, fresh_telemetry):
+def test_engagement_rate_and_counters(own_model, fresh_telemetry):
     from triton_distributed_tpu.serving.server import ModelServer, request
 
-    eng = engine(model)
-    _, stats = run(eng, [(prompt(1, 40), 21)])
-    # 20 steps: the first from the host's tokens, the rest a round early.
-    assert stats["decode_steps"] == 20
-    assert stats["lookahead_steps"] == 19  # 0.95 of them
+    eng = engine(own_model)
+    _, stats = run(eng, [(prompt(1, 40), 11)])
+    # 10 steps: the first from the host's tokens, the rest a round early.
+    assert stats["decode_steps"] == 10
+    assert stats["lookahead_steps"] == 9  # 0.9 of them
     assert stats["lookahead_discarded"] == 0
     snap = obs_metrics.default_registry().snapshot()
     assert (snap["tdt_engine_lookahead_steps_total"]["series"][0]["value"]

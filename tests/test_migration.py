@@ -34,13 +34,10 @@ import sys
 import threading
 import time
 
-import jax
 import numpy as np
 import pytest
 
-from triton_distributed_tpu.models import AutoLLM
 from triton_distributed_tpu.models.stub import StubEngine, stub_generate
-from triton_distributed_tpu.runtime import mesh as mesh_mod
 from triton_distributed_tpu.runtime.faults import FaultPlan
 
 
@@ -60,23 +57,11 @@ needs_procs = pytest.mark.skipif(
 )
 
 
-@pytest.fixture(scope="module")
-def mig_model():
-    """ONE tiny model on a single device for the whole module (the
-    test_router.py rationale; tp=1 keeps the page gather/scatter free
-    of cross-device sharding concerns — multi-host pools are ROADMAP
-    item 1's open half)."""
-    ctx = mesh_mod.initialize_distributed(tp=1, devices=jax.devices()[:1])
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx)
-    yield model
-    mesh_mod.finalize_distributed()
-
-
 PROMPTS = [
     np.arange(1, 20, dtype=np.int32),
     np.arange(30, 42, dtype=np.int32),
 ]
-GENS = [12, 10]
+GENS = [8, 7]
 
 
 def make_engine(model, **kw):
@@ -201,18 +186,18 @@ def test_prefix_delta_math():
 
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
-def test_migration_bit_exact_greedy(mig_model, kv_dtype):
+def test_migration_bit_exact_greedy(own_model, kv_dtype):
     """Exported mid-generation → imported into a second engine →
     remaining greedy tokens bit-identical to the un-migrated run, on
     both pool dtypes; audits clean on both engines."""
     kw = {"kv_dtype": kv_dtype}
     gold = [
         r.tokens.tolist()
-        for r in make_engine(mig_model, **kw).run(
+        for r in make_engine(own_model, **kw).run(
             list(zip(PROMPTS, GENS)), results=True
         )
     ]
-    res2, res1, B = migrate_run(mig_model, kw)
+    res2, res1, B = migrate_run(own_model, kw)
     assert [r.tokens.tolist() for r in res2] == gold
     # Work actually carried over: stage 1 generated > 0 tokens and the
     # target restored them without re-generating.
@@ -223,7 +208,7 @@ def test_migration_bit_exact_greedy(mig_model, kv_dtype):
     assert st["migration_fallbacks"] == 0
 
 
-def test_migration_bit_exact_seeded_sampling(mig_model):
+def test_migration_bit_exact_seeded_sampling(own_model):
     """Seeded-sampled continuation is bit-identical too: the snapshot
     carries the per-request PRNG key + draw counter, so the target
     replays the exact draws the source would have made (int8 pool —
@@ -231,18 +216,18 @@ def test_migration_bit_exact_seeded_sampling(mig_model):
     kw = {"kv_dtype": "int8", "temperature": 0.8, "seed": 11}
     gold = [
         r.tokens.tolist()
-        for r in make_engine(mig_model, **kw).run(
+        for r in make_engine(own_model, **kw).run(
             list(zip(PROMPTS, GENS)), results=True
         )
     ]
-    res2, _res1, _B = migrate_run(mig_model, kw)
+    res2, _res1, _B = migrate_run(own_model, kw)
     assert [r.tokens.tolist() for r in res2] == gold
     # And a migrated sampled run is reproducible end to end.
-    res3, _, _ = migrate_run(mig_model, kw)
+    res3, _, _ = migrate_run(own_model, kw)
     assert [r.tokens.tolist() for r in res3] == gold
 
 
-def test_migration_prefix_delta_on_warm_target(mig_model):
+def test_migration_prefix_delta_on_warm_target(own_model):
     """When the target already caches the prefix (it served the same
     request before), only the non-shared page suffix ships — and the
     continuation stays bit-identical while the import pins the shared
@@ -250,11 +235,11 @@ def test_migration_prefix_delta_on_warm_target(mig_model):
     kw = {"kv_dtype": "int8"}
     gold = [
         r.tokens.tolist()
-        for r in make_engine(mig_model, **kw).run(
+        for r in make_engine(own_model, **kw).run(
             list(zip(PROMPTS, GENS)), results=True
         )
     ]
-    warm = make_engine(mig_model, **kw)
+    warm = make_engine(own_model, **kw)
     warm.run(list(zip(PROMPTS, GENS)), results=True)
     digest = warm.prefix_digest()
     assert digest  # the tree actually holds the chains
@@ -262,7 +247,7 @@ def test_migration_prefix_delta_on_warm_target(mig_model):
     from triton_distributed_tpu.models import slot_state
     from triton_distributed_tpu.models.continuous import Request
 
-    A = make_engine(mig_model, **kw)
+    A = make_engine(own_model, **kw)
     A.request_handoff(after_rounds=4)
     res1 = A.run(list(zip(PROMPTS, GENS)), results=True)
     assert all(r.status == "migrated" for r in res1)
@@ -279,7 +264,7 @@ def test_migration_prefix_delta_on_warm_target(mig_model):
     assert warm.audit() == [] and A.audit() == []
 
 
-def test_stale_prefix_delta_falls_back_to_replay(mig_model):
+def test_stale_prefix_delta_falls_back_to_replay(own_model):
     """A prefix-delta snapshot whose omitted pages the target no longer
     caches (fresh tree) cannot be reconstructed: the import falls back
     to a full replay from the prompt — same final tokens, counted
@@ -287,14 +272,14 @@ def test_stale_prefix_delta_falls_back_to_replay(mig_model):
     kw = {"kv_dtype": None}
     gold = [
         r.tokens.tolist()
-        for r in make_engine(mig_model, **kw).run(
+        for r in make_engine(own_model, **kw).run(
             list(zip(PROMPTS, GENS)), results=True
         )
     ]
-    warm = make_engine(mig_model, **kw)
+    warm = make_engine(own_model, **kw)
     warm.run(list(zip(PROMPTS, GENS)), results=True)
     res2, res1, B = migrate_run(
-        mig_model, kw, delta_digest=warm.prefix_digest()
+        own_model, kw, delta_digest=warm.prefix_digest()
     )
     # B's tree is EMPTY — every delta import must have fallen back.
     assert [r.tokens.tolist() for r in res2] == gold
@@ -302,7 +287,7 @@ def test_stale_prefix_delta_falls_back_to_replay(mig_model):
     assert B.last_stats["migrated_in"] == 0
 
 
-def test_migration_chaos_seams(mig_model):
+def test_migration_chaos_seams(own_model):
     """Kill-mid-migration on either end, deterministically: a failed
     EXPORT keeps the slot decoding locally (the handoff drain stays
     lossless — everything still completes with the right tokens); a
@@ -313,13 +298,13 @@ def test_migration_chaos_seams(mig_model):
     kw = {"kv_dtype": "int8"}
     gold = [
         r.tokens.tolist()
-        for r in make_engine(mig_model, **kw).run(
+        for r in make_engine(own_model, **kw).run(
             list(zip(PROMPTS, GENS)), results=True
         )
     ]
     # Export end dies: every export attempt fails → the handoff sweep
     # can migrate nothing, both requests FINISH on the draining engine.
-    A = make_engine(mig_model, **kw)
+    A = make_engine(own_model, **kw)
     A.request_handoff(after_rounds=4)
     with FaultPlan(seed=3).fail_export(at=0, times=999) as plan:
         res = A.run(list(zip(PROMPTS, GENS)), results=True)
@@ -329,11 +314,11 @@ def test_migration_chaos_seams(mig_model):
     assert A.audit() == []
 
     # Import end dies: the resume falls back to a full replay.
-    A2 = make_engine(mig_model, **kw)
+    A2 = make_engine(own_model, **kw)
     A2.request_handoff(after_rounds=4)
     res1 = A2.run(list(zip(PROMPTS, GENS)), results=True)
     assert all(r.status == "migrated" for r in res1)
-    B = make_engine(mig_model, **kw)
+    B = make_engine(own_model, **kw)
     with FaultPlan(seed=4).fail_import(at=0, times=999) as plan:
         res2 = B.run(
             [
@@ -348,7 +333,7 @@ def test_migration_chaos_seams(mig_model):
     assert B.audit() == [] and A2.audit() == []
 
 
-def test_prefill_only_exports_after_admission(mig_model):
+def test_prefill_only_exports_after_admission(own_model):
     """``prefill_only`` (the prefill→decode handoff's engine half):
     admission runs, ONE token emits, the slot exports — and a second
     engine finishes the decode bit-identically."""
@@ -357,11 +342,11 @@ def test_prefill_only_exports_after_admission(mig_model):
     kw = {"kv_dtype": None}
     gold = [
         r.tokens.tolist()
-        for r in make_engine(mig_model, **kw).run(
+        for r in make_engine(own_model, **kw).run(
             list(zip(PROMPTS, GENS)), results=True
         )
     ]
-    A = make_engine(mig_model, **kw)
+    A = make_engine(own_model, **kw)
     res1 = A.run(
         [Request(p, g, prefill_only=True)
          for p, g in zip(PROMPTS, GENS)],
@@ -369,7 +354,7 @@ def test_prefill_only_exports_after_admission(mig_model):
     )
     assert all(r.status == "migrated" for r in res1)
     assert all(len(r.tokens) == 1 for r in res1)  # the admission token
-    B = make_engine(mig_model, **kw)
+    B = make_engine(own_model, **kw)
     res2 = B.run(
         [Request(p, g, snapshot=r.snapshot)
          for (p, g), r in zip(list(zip(PROMPTS, GENS)), res1)],
@@ -609,7 +594,7 @@ def test_remote_handoff_drain_over_the_wire(fresh_telemetry):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=150)  # past boot's own spawn_timeout_s
     assert len(out) == 2
     reps = [out[0], out[1]]
     router = Router(reps, max_reroutes=3)
@@ -662,7 +647,7 @@ def test_remote_handoff_drain_over_the_wire(fresh_telemetry):
                 proc.wait(timeout=10)
 
 
-def test_import_fallback_preserves_seeded_draws(mig_model):
+def test_import_fallback_preserves_seeded_draws(own_model):
     """Code-review fix: the replay fallback restores the snapshot's
     per-request PRNG key (draw counter reset to 0), so even a FAILED
     import of a seeded-sampled request replays bit-identically to the
@@ -672,15 +657,15 @@ def test_import_fallback_preserves_seeded_draws(mig_model):
     kw = {"temperature": 0.8, "seed": 5}
     gold = [
         r.tokens.tolist()
-        for r in make_engine(mig_model, **kw).run(
+        for r in make_engine(own_model, **kw).run(
             list(zip(PROMPTS, GENS)), results=True
         )
     ]
-    A = make_engine(mig_model, **kw)
+    A = make_engine(own_model, **kw)
     A.request_handoff(after_rounds=4)
     res1 = A.run(list(zip(PROMPTS, GENS)), results=True)
     assert all(r.status == "migrated" for r in res1)
-    B = make_engine(mig_model, **kw)
+    B = make_engine(own_model, **kw)
     with FaultPlan(seed=6).fail_import(at=0, times=999) as plan:
         res2 = B.run(
             [Request(p, g, snapshot=r.snapshot)

@@ -152,9 +152,8 @@ def test_decode_matches_golden(tiny_setup):
         tokens.append(nxt)
 
 
-def test_engine_serve(ctx4):
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = Engine(model, temperature=0.0, mode="xla")
+def test_engine_serve(tp4_model):
+    eng = Engine(tp4_model, temperature=0.0, mode="xla")
     prompt = np.arange(8, dtype=np.int32)[None].repeat(2, 0)  # [2, 8]
     out = eng.serve(prompt, gen_len=4)
     assert out.shape == (2, 12)
@@ -162,11 +161,10 @@ def test_engine_serve(ctx4):
     np.testing.assert_array_equal(out[0], out[1])
 
 
-def test_engine_prompt_padding_inert(ctx4):
+def test_engine_prompt_padding_inert(tp4_model):
     """Left-padded prompts with prompt_start generate the same
     continuation as the unpadded prompt (pads must not be attended)."""
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = Engine(model, temperature=0.0, mode="xla")
+    eng = Engine(tp4_model, temperature=0.0, mode="xla")
     real = np.arange(3, 11, dtype=np.int32)  # length 8 (tp-divisible)
     gold = eng.serve(real[None], gen_len=4)[0, 8:]
     # Same prompt left-padded by 4 junk tokens to length 12 (pad to 12).
@@ -373,29 +371,26 @@ class TestPagedKVCache:
         np.testing.assert_array_equal(
             np.asarray(got)[[0, 2]], np.asarray(pool)[[0, 2]])
 
-    def test_engine_serve_paged(self, ctx4):
+    def test_engine_serve_paged(self, tp4_model):
         """Paged serving end-to-end matches dense serving token-for-token
         (parity: reference paged megakernel serving)."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.engine import Engine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         prompt = np.arange(8, dtype=np.int32)[None].repeat(2, 0)
         prompt[1] = prompt[1][::-1]  # distinct rows
-        dense = Engine(model, temperature=0.0, mode="xla").serve(
+        dense = Engine(tp4_model, temperature=0.0, mode="xla").serve(
             prompt, gen_len=6
         )
         paged = Engine(
-            model, temperature=0.0, mode="xla", paged=True, page_size=16
+            tp4_model, temperature=0.0, mode="xla", paged=True, page_size=16
         ).serve(prompt, gen_len=6)
         np.testing.assert_array_equal(dense, paged)
 
 
-def test_engine_autopads_indivisible_prompts(ctx4):
+def test_engine_autopads_indivisible_prompts(tp4_model):
     """Prompt lengths that don't divide tp are padded internally (the
     round-1 engine raised); output matches a client-padded run."""
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = Engine(model, temperature=0.0, mode="xla")
+    eng = Engine(tp4_model, temperature=0.0, mode="xla")
     prompt = (np.arange(7, dtype=np.int32) + 1)[None].repeat(2, 0)  # s=7, tp=4
     out = eng.serve(prompt, gen_len=4)
     assert out.shape == (2, 11)

@@ -85,13 +85,13 @@ def test_ep_moe(ctx4, rng, moe_weights, method):
     x = jnp.asarray(rng.standard_normal((n * t_loc, mw["d"])) * 0.1, jnp.float32)
     w1 = jnp.concatenate([mw["gate"], mw["up"]], axis=2)
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(
             ep_moe_ffn, k=mw["k"], axis="tp", method=method, ctx=ctx4,
         ),
         in_specs=(P("tp", None), P(), P("tp", None, None), P("tp", None, None)),
         out_specs=P("tp", None),
-    )
+    ))
     out = f(x, mw["w_router"], w1, mw["down"])
     gold = _golden_moe(x, mw["w_router"], mw["gate"], mw["up"], mw["down"], mw["k"])
     np.testing.assert_allclose(np.asarray(out), gold, atol=5e-4, rtol=5e-4)
@@ -113,13 +113,13 @@ def test_ep_moe_lossless_adversarial(ctx4, rng, moe_weights, method):
     w_router = mw["w_router"].at[:, 2:].add(-100.0).at[:, :2].add(100.0)
     w1 = jnp.concatenate([mw["gate"], mw["up"]], axis=2)
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(
             ep_moe_ffn, k=mw["k"], axis="tp", method=method, ctx=ctx4,
         ),
         in_specs=(P("tp", None), P(), P("tp", None, None), P("tp", None, None)),
         out_specs=P("tp", None),
-    )
+    ))
     out = f(x, w_router, w1, mw["down"])
     gold = _golden_moe(x, w_router, mw["gate"], mw["up"], mw["down"], mw["k"])
     np.testing.assert_allclose(np.asarray(out), gold, atol=5e-4, rtol=5e-4)
@@ -146,7 +146,8 @@ def test_ep_dispatch_overflow_detected(ctx4, rng, moe_weights):
         _, _, _, state = ep_dispatch(x_loc, route, mw["e"], capacity=8, axis="tp")
         return state.num_dropped[None]
 
-    f = ctx4.shard_map(body, in_specs=P("tp", None), out_specs=P("tp"))
+    f = jax.jit(ctx4.shard_map(body, in_specs=P("tp", None),
+                               out_specs=P("tp")))
     dropped = f(x)
     assert int(np.asarray(dropped).max()) > 0
 
@@ -162,14 +163,14 @@ def test_ep_moe_fp8_payload(ctx4, rng, moe_weights, method):
     x = jnp.asarray(rng.standard_normal((n * t_loc, mw["d"])) * 0.1, jnp.float32)
     w1 = jnp.concatenate([mw["gate"], mw["up"]], axis=2)
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(
             ep_moe_ffn, k=mw["k"], axis="tp", payload_dtype="fp8",
             method=method, ctx=ctx4,
         ),
         in_specs=(P("tp", None), P(), P("tp", None, None), P("tp", None, None)),
         out_specs=P("tp", None),
-    )
+    ))
     out = f(x, mw["w_router"], w1, mw["down"])
     gold = _golden_moe(x, mw["w_router"], mw["gate"], mw["up"], mw["down"], mw["k"])
     # fp8 payload: ~2^-3 relative mantissa error through one FFN
@@ -193,7 +194,7 @@ def test_ep_transport_parity(ctx4, rng, moe_weights, payload):
 
     outs = {}
     for method in ("xla", "pallas"):
-        f = ctx4.shard_map(
+        f = jax.jit(ctx4.shard_map(
             functools.partial(
                 ep_moe_ffn, k=mw["k"], axis="tp", method=method,
                 payload_dtype=payload, ctx=ctx4,
@@ -201,7 +202,7 @@ def test_ep_transport_parity(ctx4, rng, moe_weights, payload):
             in_specs=(P("tp", None), P(), P("tp", None, None),
                       P("tp", None, None)),
             out_specs=P("tp", None),
-        )
+        ))
         outs[method] = np.asarray(f(x, w_router, w1, mw["down"]))
     np.testing.assert_array_equal(outs["xla"], outs["pallas"])
 
@@ -215,14 +216,14 @@ def test_ep_moe_capacity_pallas(ctx4, rng, moe_weights):
     x = jnp.asarray(rng.standard_normal((n * t_loc, mw["d"])) * 0.1, jnp.float32)
     w1 = jnp.concatenate([mw["gate"], mw["up"]], axis=2)
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(
             ep_moe_ffn, k=mw["k"], axis="tp", method="pallas",
             capacity_factor=4.0, ctx=ctx4,
         ),
         in_specs=(P("tp", None), P(), P("tp", None, None), P("tp", None, None)),
         out_specs=P("tp", None),
-    )
+    ))
     out = f(x, mw["w_router"], w1, mw["down"])
     gold = _golden_moe(x, mw["w_router"], mw["gate"], mw["up"], mw["down"], mw["k"])
     np.testing.assert_allclose(np.asarray(out), gold, atol=5e-4, rtol=5e-4)

@@ -167,7 +167,7 @@ def test_moe_mega_greedy_parity_tp1(moe_model, fresh_telemetry):
     the serving default config) matches the unfused engine
     token-for-token, with the device tracer live: launches carry A2A
     windows and the measured overlap report is populated."""
-    reqs = list(zip(PROMPTS, GENS))
+    reqs = list(zip(PROMPTS[:2], GENS[:2]))  # the radix repeat is above
     gold_eng = make_engine(moe_model)
     gold = [r.tokens.tolist()
             for r in gold_eng.run(reqs, results=True)]

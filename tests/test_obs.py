@@ -116,7 +116,7 @@ def test_registry_thread_safety():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60)
     assert c.value() == N * T
     assert h.count() == N * T
 
@@ -412,24 +412,22 @@ def test_observe_request_skips_missing_stamps():
 # -- engine integration ------------------------------------------------------
 
 
-def _tiny_continuous(ctx, **kw):
-    from triton_distributed_tpu.models import AutoLLM
+def _tiny_continuous(model, **kw):
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx)
     kw.setdefault("max_batch", 2)
     kw.setdefault("page_size", 16)
     kw.setdefault("max_length", 64)
     return model, ContinuousEngine(model, **kw)
 
 
-def test_continuous_run_populates_latency_histograms(ctx4):
+def test_continuous_run_populates_latency_histograms(own_model):
     """Acceptance (ISSUE 5): TTFT/TPOT/queue-wait/e2e histograms with
     p50/p90/p99 appear for a multi-request run, labeled with PR 3
     finish statuses."""
     from triton_distributed_tpu.models.continuous import Request
 
-    _model, eng = _tiny_continuous(ctx4)
+    _model, eng = _tiny_continuous(own_model)
     reqs = [
         Request(np.asarray([5, 9, 2, 4], np.int32), 8),
         Request(np.asarray([7, 1, 3, 8, 6, 2], np.int32), 6),
@@ -502,7 +500,7 @@ def _series_defined_in_package() -> set:
 
 
 @pytest.mark.parametrize("side", ["registry", "docs"])
-def test_series_catalog_holds_only_what_exists(ctx4, side):
+def test_series_catalog_holds_only_what_exists(own_model, side):
     """The engine registers, and docs/observability.md's tables name,
     only series the program can move: nothing of the emulated work ring
     or the virtual-rank long-context path, and no row for a series the
@@ -514,7 +512,7 @@ def test_series_catalog_holds_only_what_exists(ctx4, side):
             STAT_METRICS,
         )
 
-        _tiny_continuous(ctx4)
+        _tiny_continuous(own_model)
         names = set(obs_metrics.default_registry().snapshot())
         catalog = {name for name, _ in STAT_METRICS.values()}
         catalog |= {name for aliases in STAT_METRIC_ALIASES.values()
@@ -528,18 +526,17 @@ def test_series_catalog_holds_only_what_exists(ctx4, side):
     assert not [n for n in names if n.startswith(gone)]
 
 
-def test_core_stats_keys_unified(ctx4):
+def test_core_stats_keys_unified(own_model):
     """Satellite (ISSUE 5): Engine.last_stats and
     ContinuousEngine.last_stats expose ONE shared core key set
     (models/stats.py) — the shapes must not drift again."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.engine import Engine
     from triton_distributed_tpu.models.stats import (
         CORE_STATS_KEYS,
         missing_core_stats,
     )
 
-    model, ceng = _tiny_continuous(ctx4)
+    model, ceng = _tiny_continuous(own_model)
     ceng.run([([5, 9, 2, 4], 4)])
     assert missing_core_stats(ceng.last_stats) == []
 
@@ -552,15 +549,15 @@ def test_core_stats_keys_unified(ctx4):
     assert len(CORE_STATS_KEYS) >= 5
 
 
-def test_outputs_bit_identical_with_telemetry_off(ctx4):
+def test_outputs_bit_identical_with_telemetry_off(own_model):
     """Acceptance (ISSUE 5): telemetry never touches the token path —
     the same workload decodes to identical tokens enabled or
     disabled."""
     prompts = [([5, 9, 2, 4], 8), ([7, 1, 3, 8, 6, 2], 6)]
-    _m1, e1 = _tiny_continuous(ctx4, prefix_cache=True, prefill_chunk=16)
+    _m1, e1 = _tiny_continuous(own_model, prefix_cache=True, prefill_chunk=16)
     on = [o.tolist() for o in e1.run(prompts)]
     obs.set_enabled(False)
-    _m2, e2 = _tiny_continuous(ctx4, prefix_cache=True, prefill_chunk=16)
+    _m2, e2 = _tiny_continuous(own_model, prefix_cache=True, prefill_chunk=16)
     off = [o.tolist() for o in e2.run(prompts)]
     obs.set_enabled(True)
     assert on == off
@@ -569,13 +566,13 @@ def test_outputs_bit_identical_with_telemetry_off(ctx4):
 # -- server integration ------------------------------------------------------
 
 
-def test_server_metrics_verb_and_grammar(ctx4):
+def test_server_metrics_verb_and_grammar(own_model):
     """Acceptance (ISSUE 5): {"cmd": "metrics"} returns Prometheus text
     that parses line-by-line, plus the JSON snapshot; {"cmd": "events"}
     tails the ring through the wire."""
     from triton_distributed_tpu.serving.server import ModelServer, request
 
-    _model, eng = _tiny_continuous(ctx4)
+    _model, eng = _tiny_continuous(own_model)
     server = ModelServer(eng).start()
     try:
         r = request(server.host, server.port,
@@ -617,12 +614,12 @@ def test_server_metrics_verb_and_grammar(ctx4):
         server.shutdown()
 
 
-def test_server_metrics_answers_mid_generation(ctx4):
+def test_server_metrics_answers_mid_generation(own_model):
     """Acceptance (ISSUE 5): the metrics verb never takes the engine
     lock — a scrape completes while a generation batch is in flight."""
     from triton_distributed_tpu.serving.server import ModelServer, request
 
-    _model, eng = _tiny_continuous(ctx4)
+    _model, eng = _tiny_continuous(own_model)
     server = ModelServer(eng).start()
     errors: list = []
 

@@ -653,20 +653,6 @@ def test_serving_cli_pool_flag_guardrails():
 # -- batched handoff export (tiny model) ------------------------------------
 
 
-@pytest.fixture(scope="module")
-def pool_model():
-    import jax
-
-    from triton_distributed_tpu.models import AutoLLM
-    from triton_distributed_tpu.runtime import mesh as mesh_mod
-
-    ctx = mesh_mod.initialize_distributed(
-        tp=1, devices=jax.devices()[:1])
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx)
-    yield model
-    mesh_mod.finalize_distributed()
-
-
 MODEL_PROMPTS = [
     np.arange(1, 20, dtype=np.int32),
     np.arange(30, 42, dtype=np.int32),
@@ -683,7 +669,7 @@ def _model_engine(model, **kw):
     return ContinuousEngine(model, **kw)
 
 
-def test_batched_handoff_export_matches_serial(pool_model, monkeypatch):
+def test_batched_handoff_export_matches_serial(own_model, monkeypatch):
     """The handoff-batching satellite: one export_slots_batch gather
     over a sweep's slots produces snapshots IDENTICAL (modulo the
     export wall stamp) to per-slot serial exports, and the batched
@@ -693,7 +679,7 @@ def test_batched_handoff_export_matches_serial(pool_model, monkeypatch):
 
     work = list(zip(MODEL_PROMPTS, MODEL_GENS))
     golds = [r.tokens.tolist() for r in
-             _model_engine(pool_model).run(work, results=True)]
+             _model_engine(own_model).run(work, results=True)]
     calls = []
     orig = slot_state.export_slots_batch
     monkeypatch.setattr(
@@ -702,7 +688,7 @@ def test_batched_handoff_export_matches_serial(pool_model, monkeypatch):
                                   orig(eng, slots, **kw))[1])
     snaps = {}
     for batched in (True, False):
-        eng = _model_engine(pool_model, handoff_batch=batched)
+        eng = _model_engine(own_model, handoff_batch=batched)
         eng.request_handoff(after_rounds=3)
         res = eng.run(work, results=True)
         assert all(r.status == "migrated" for r in res), [
@@ -719,7 +705,7 @@ def test_batched_handoff_export_matches_serial(pool_model, monkeypatch):
             db.pop(k), ds.pop(k)
         assert db == ds
     # And the batched snapshots resume bit-exact.
-    B = _model_engine(pool_model)
+    B = _model_engine(own_model)
     res2 = B.run([Request(p, g, snapshot=s)
                   for (p, g), s in zip(work, snaps[True])], results=True)
     for r, g in zip(res2, golds):
@@ -728,7 +714,7 @@ def test_batched_handoff_export_matches_serial(pool_model, monkeypatch):
 
 
 def test_handoff_sweep_degrades_to_serial_on_batch_failure(
-        pool_model, monkeypatch):
+        own_model, monkeypatch):
     """A failing batch gather must not fail the drain: the sweep
     degrades to per-slot serial exports and stays lossless."""
     from triton_distributed_tpu.models import slot_state
@@ -738,16 +724,16 @@ def test_handoff_sweep_degrades_to_serial_on_batch_failure(
         slot_state, "export_slots_batch",
         lambda *a, **kw: (_ for _ in ()).throw(RuntimeError("boom")))
     work = list(zip(MODEL_PROMPTS, MODEL_GENS))
-    eng = _model_engine(pool_model, handoff_batch=True)
+    eng = _model_engine(own_model, handoff_batch=True)
     eng.request_handoff(after_rounds=3)
     res = eng.run(work, results=True)
     assert all(r.status == "migrated" for r in res)
     assert eng.audit() == []
-    B = _model_engine(pool_model)
+    B = _model_engine(own_model)
     res2 = B.run([Request(p, g, snapshot=r.snapshot)
                   for (p, g), r in zip(work, res)], results=True)
     golds = [r.tokens.tolist() for r in
-             _model_engine(pool_model).run(work, results=True)]
+             _model_engine(own_model).run(work, results=True)]
     for r, g in zip(res2, golds):
         assert r.status == "ok" and r.tokens.tolist() == g
 

@@ -180,23 +180,21 @@ class TestEnginePrefixReuse:
             for p, g in reqs
         ]
 
-    def test_prefix_reuse_skips_recompute(self, ctx4):
+    def test_prefix_reuse_skips_recompute(self, own_model):
         """Second request sharing an N-page prefix performs suffix-only
         prefill (prefill_tokens counter) with outputs bit-identical to
         the cold-cache path."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         shared = np.asarray(
             [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3] * 2, np.int32
         )  # 32 tokens = 2 pages at page_size=16
         pA = np.concatenate([shared, np.asarray([10, 11, 12, 13], np.int32)])
         pB = np.concatenate([shared, np.asarray([20, 21, 22, 23], np.int32)])
-        goldA, goldB = self._goldens(model, [(pA, 4), (pB, 4)])
+        goldA, goldB = self._goldens(own_model, [(pA, 4), (pB, 4)])
 
         eng = ContinuousEngine(
-            model, max_batch=2, page_size=16, max_length=64,
+            own_model, max_batch=2, page_size=16, max_length=64,
             prefix_cache=True,
         )
         outA = eng.run([(pA, 4)])
@@ -212,7 +210,7 @@ class TestEnginePrefixReuse:
         # Bit-identical to the cold-cache path: a fresh engine serving B
         # from scratch produces the same tokens.
         cold = ContinuousEngine(
-            model, max_batch=2, page_size=16, max_length=64,
+            own_model, max_batch=2, page_size=16, max_length=64,
             prefix_cache=True,
         )
         np.testing.assert_array_equal(cold.run([(pB, 4)])[0], outB[0])
@@ -220,21 +218,19 @@ class TestEnginePrefixReuse:
         # Leak-free: every page is in the tree or the free list.
         assert len(eng.pool.free) + eng.prefix.node_count == eng._capacity
 
-    def test_cow_partial_tail_match(self, ctx4):
+    def test_cow_partial_tail_match(self, own_model):
         """A prefix ending inside a cached page is reused via COW: the
         page is cloned, matched positions count, outputs stay golden."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         rng = np.random.default_rng(3)
         head = rng.integers(1, 200, size=18).astype(np.int32)  # 1.125 pages
         pA = np.concatenate([head, np.asarray([10, 11], np.int32)])
         pB = np.concatenate([head, np.asarray([20, 21], np.int32)])
-        (goldB,) = self._goldens(model, [(pB, 4)])
+        (goldB,) = self._goldens(own_model, [(pB, 4)])
 
         eng = ContinuousEngine(
-            model, max_batch=2, page_size=16, max_length=64,
+            own_model, max_batch=2, page_size=16, max_length=64,
             prefix_cache=True,
         )
         eng.run([(pA, 4)])
@@ -244,20 +240,18 @@ class TestEnginePrefixReuse:
         assert st["pages_cow_copied"] == 1
         np.testing.assert_array_equal(outB[0], goldB)
 
-    def test_chunked_prefill_interleaves_decodes(self, ctx4):
+    def test_chunked_prefill_interleaves_decodes(self, own_model):
         """A long cold prompt admitted in chunks never blocks the
         running request's decode; outputs match dense goldens."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         rng = np.random.default_rng(7)
         long_p = rng.integers(1, 200, size=40).astype(np.int32)
         short_p = np.asarray([5, 9, 2, 4], np.int32)
-        goldS, goldL = self._goldens(model, [(short_p, 8), (long_p, 3)])
+        goldS, goldL = self._goldens(own_model, [(short_p, 8), (long_p, 3)])
 
         eng = ContinuousEngine(
-            model, max_batch=2, page_size=16, max_length=64,
+            own_model, max_batch=2, page_size=16, max_length=64,
             prefix_cache=True, prefill_chunk=16,
         )
         outs = eng.run([(short_p, 8), (long_p, 3)])
@@ -266,14 +260,12 @@ class TestEnginePrefixReuse:
         # 40-token prompt at chunk 16 → 3 chunks (+1 for the short one).
         assert eng.last_stats["prefill_chunks"] >= 4
 
-    def test_eviction_pressure_equivalence(self, ctx4):
+    def test_eviction_pressure_equivalence(self, own_model):
         """Pool sized to force LRU eviction: repeated shared-prefix
         serving never double-frees, leaks, or serves a stale page —
         outputs stay equal to the dense goldens every round."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         rng = np.random.default_rng(11)
         prefixes = [
             rng.integers(1, 200, size=16).astype(np.int32) for _ in range(3)
@@ -282,12 +274,12 @@ class TestEnginePrefixReuse:
         for i, pre in enumerate(prefixes):
             tail = rng.integers(1, 200, size=4 + i).astype(np.int32)
             reqs.append((np.concatenate([pre, tail]), 3))
-        golds = self._goldens(model, reqs)
+        golds = self._goldens(own_model, reqs)
 
         # 2 slots × 3 pages/req worst case, but only 7 pages: admission
         # must evict cached chains to serve new prefixes.
         eng = ContinuousEngine(
-            model, max_batch=2, page_size=16, max_length=64,
+            own_model, max_batch=2, page_size=16, max_length=64,
             prefix_cache=True, num_pages=7,
         )
         for round_ in range(3):
@@ -301,23 +293,21 @@ class TestEnginePrefixReuse:
             assert len(owned) == len(set(owned))
         assert eng.prefix.stats["evicted_pages"] > 0
 
-    def test_engine_paged_prefix_across_serves(self, ctx4):
+    def test_engine_paged_prefix_across_serves(self, own_model):
         """Engine(paged, prefix_cache): the tree persists across serve()
         calls — the second call prefills only the uncached suffix and
         returns the same tokens as a cold engine."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.engine import Engine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         shared = np.asarray(
             [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3], np.int32
         )
         pA = np.concatenate([shared, np.asarray([10, 11, 12, 13], np.int32)])
         pB = np.concatenate([shared, np.asarray([20, 21, 22, 23], np.int32)])
-        gold = Engine(model, temperature=0.0).serve(pB[None], gen_len=4)
+        gold = Engine(own_model, temperature=0.0).serve(pB[None], gen_len=4)
 
         eng = Engine(
-            model, temperature=0.0, paged=True, page_size=16,
+            own_model, temperature=0.0, paged=True, page_size=16,
             prefix_cache=True,
         )
         eng.serve(pA[None], gen_len=4, max_length=64)
@@ -327,36 +317,32 @@ class TestEnginePrefixReuse:
         assert eng.last_stats["prefix_hit_tokens"] == 16
         assert eng.last_stats["prefill_tokens"] == 4
 
-    def test_engine_paged_prefix_boundary_capacity(self, ctx4):
+    def test_engine_paged_prefix_boundary_capacity(self, own_model):
         """true_len + gen_len - 1 == max_length (the last sampled token
         is never appended) must serve: page reservation counts written
         positions, not prompt+gen."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.engine import Engine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         prompt = np.arange(1, 62, dtype=np.int32)[None]  # 61 tokens
-        gold = Engine(model, temperature=0.0).serve(
+        gold = Engine(own_model, temperature=0.0).serve(
             prompt, gen_len=4, max_length=64
         )
         eng = Engine(
-            model, temperature=0.0, paged=True, page_size=16,
+            own_model, temperature=0.0, paged=True, page_size=16,
             prefix_cache=True, prefill_chunk=61,  # unrounded width too
         )
         out = eng.serve(prompt, gen_len=4, max_length=64)  # 61+4-1 = 64
         np.testing.assert_array_equal(out, gold)
 
-    def test_engine_cow_pin_cannot_starve_pool(self, ctx4):
+    def test_engine_cow_pin_cannot_starve_pool(self, own_model):
         """A COW pin covers none of the row's page budget; when it alone
         starves allocation the engine degrades (drop COW, then cold)
         instead of crashing — outputs stay golden."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.engine import Engine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         p1 = np.arange(1, 25, dtype=np.int32)[None]  # 24 tokens, pps=2
         eng = Engine(
-            model, temperature=0.0, paged=True, page_size=16,
+            own_model, temperature=0.0, paged=True, page_size=16,
             prefix_cache=True,
         )
         eng.serve(p1, gen_len=4, max_length=32)
@@ -365,32 +351,28 @@ class TestEnginePrefixReuse:
         p2 = np.concatenate(
             [p1[0][:8], 90 + np.arange(16, dtype=np.int32)]
         )[None]
-        gold = Engine(model, temperature=0.0).serve(
+        gold = Engine(own_model, temperature=0.0).serve(
             p2, gen_len=4, max_length=32
         )
         np.testing.assert_array_equal(
             eng.serve(p2, gen_len=4, max_length=32), gold
         )
 
-    def test_engine_prefix_requires_paged(self, ctx4):
-        from triton_distributed_tpu.models import AutoLLM
+    def test_engine_prefix_requires_paged(self, own_model):
         from triton_distributed_tpu.models.engine import Engine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         with pytest.raises(ValueError, match="requires paged"):
-            Engine(model, prefix_cache=True)
+            Engine(own_model, prefix_cache=True)
 
-    def test_randomized_engine_page_accounting(self, ctx4):
+    def test_randomized_engine_page_accounting(self, own_model):
         """Random admit/finish interleavings across runs (mixed lengths,
         eos early-exit) keep the pool invariant: free + tree == capacity
         with no aliased pages."""
-        from triton_distributed_tpu.models import AutoLLM
         from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-        model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
         rng = np.random.default_rng(5)
         eng = ContinuousEngine(
-            model, max_batch=2, page_size=16, max_length=64,
+            own_model, max_batch=2, page_size=16, max_length=64,
             prefix_cache=True, num_pages=9,
         )
         base = rng.integers(1, 200, size=20).astype(np.int32)
@@ -410,16 +392,14 @@ class TestEnginePrefixReuse:
             assert all(n.refcount == 0 for n in eng.prefix.walk())
 
 
-def test_server_continuous_round_trip(ctx4):
+def test_server_continuous_round_trip(own_model):
     """The model server routes 'requests' payloads to the continuous
     engine and reports prefix-cache stats."""
-    from triton_distributed_tpu.models import AutoLLM
     from triton_distributed_tpu.models.continuous import ContinuousEngine
     from triton_distributed_tpu.serving import ModelServer, request
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=64, prefix_cache=True
+        own_model, max_batch=2, page_size=16, max_length=64, prefix_cache=True
     )
     prompts = [[5, 9, 2, 4], [5, 9, 2, 4, 7, 1, 3, 8]]
     gold = eng.run([(np.asarray(p, np.int32), 3) for p in prompts])

@@ -83,7 +83,7 @@ def test_cli_refusals_carry_splice_reason(capsys):
         assert "resident pipeline splices whole slots" in err
 
 
-def test_resident_knob_validation(capsys, ctx1):
+def test_resident_knob_validation(capsys, own_model):
     """--resident without --mode mega refuses by flag name at the CLI
     (exit 2, nothing loaded); the engine ctor enforces the same pair."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
@@ -96,16 +96,15 @@ def test_resident_knob_validation(capsys, ctx1):
     with pytest.raises(SystemExit):
         run_server.main(["--ns", "0"])
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
     with pytest.raises(ValueError, match="resident"):
-        ContinuousEngine(model, max_batch=1, max_length=64,
+        ContinuousEngine(own_model, max_batch=1, max_length=64,
                          mode="xla", resident=True)
     with pytest.raises(ValueError, match="ns"):
-        ContinuousEngine(model, max_batch=1, max_length=64,
+        ContinuousEngine(own_model, max_batch=1, max_length=64,
                          mode="mega", ns=0)
 
 
-def test_resident_metrics_pretouch(fresh_telemetry, ctx1):
+def test_resident_metrics_pretouch(fresh_telemetry, own_model):
     """Engine construction alone pre-touches the resident-decode
     catalog: every new series reads 0 from the first scrape (PR 15
     convention), including the fallback counter the acceptance gate
@@ -113,8 +112,7 @@ def test_resident_metrics_pretouch(fresh_telemetry, ctx1):
     from triton_distributed_tpu.models.continuous import ContinuousEngine
     from triton_distributed_tpu.obs import metrics as obs_metrics
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
-    ContinuousEngine(model, max_batch=1, page_size=16, max_length=64,
+    ContinuousEngine(own_model, max_batch=1, page_size=16, max_length=64,
                      mode="mega")
     text = obs_metrics.prometheus_text()
     for name in (
@@ -211,7 +209,7 @@ _PROMPTS = [np.asarray(p, np.int32) for p in (
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "sampled"])
 @pytest.mark.parametrize("bucket", [1, 2, 4])
-def test_resident_matches_non_resident(ctx1, bucket, sampled):
+def test_resident_matches_non_resident(own_model, bucket, sampled):
     """The resident pipeline (launch i+1 issued off launch i's device
     outputs, before launch i drains) serves the non-resident engine's
     tokens, token for token, at every batch bucket of a 4-slot engine,
@@ -219,13 +217,12 @@ def test_resident_matches_non_resident(ctx1, bucket, sampled):
     tokens to the unfused goldens)."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx1)
     prompts = _PROMPTS[:bucket]
     knobs = dict(temperature=0.8, seed=3) if sampled else {}
 
     def run(resident):
         eng = ContinuousEngine(
-            model, max_batch=4, page_size=16, max_length=64,
+            own_model, max_batch=4, page_size=16, max_length=64,
             mode="mega", ns=2, resident=resident, **knobs,
         )
         outs = eng.run([(p, 6) for p in prompts])

@@ -35,7 +35,10 @@ def tier_model():
     """ONE tiny model (and mesh) for the whole module: engines are
     cheap but compiled programs cache per model instance, and every
     test here uses the same shapes — per-test models would recompile
-    identical programs in a wall-clock-bound suite."""
+    identical programs in a wall-clock-bound suite. On four devices and
+    not ``own_model``'s one: two replicas' workers run kernels at once,
+    and the Pallas interpreter is not re-entrant on ONE device
+    ("Revisited block"); a four-device program takes them in turn."""
     ctx = mesh_mod.initialize_distributed(tp=4, devices=jax.devices()[:4])
     model = AutoLLM.from_pretrained("tiny", ctx=ctx)
     yield model
@@ -264,34 +267,51 @@ def test_router_kill_without_survivors_fails_clean(tier_model):
         router.shutdown()
 
 
-def test_router_timeout_marks_replica_and_reroutes(tier_model):
+def test_router_timeout_marks_replica_and_reroutes(tier_model, monkeypatch):
     """Router-observed timeout (the hang arm of the seam): a replica
     stalled past ``request_timeout_s`` is taken out of rotation and
     the ticket retries on a survivor; the late run's results latch
-    harmlessly."""
-    from triton_distributed_tpu.runtime.faults import FaultPlan
+    harmlessly. No margin here is the machine's: the hang lasts until
+    the test ends it, and the survivor is not on the clock."""
+    import threading
+    import types
 
-    model = tier_model
-    golds = goldens(model, [PROMPTS[0]], [2])
-    router = make_router(model, 2)
+    from triton_distributed_tpu.runtime import faults
+
+    golds = goldens(tier_model, [PROMPTS[0]], [2])
+    router = make_router(tier_model, 2)
+    # The seam's stall waits for the test, not for seconds to pass.
+    wake = threading.Event()
+    monkeypatch.setattr(faults, "time", types.SimpleNamespace(sleep=wake.wait))
+    reroute = router._reroute
+
+    def patient_reroute(ticket, reason, source=None):
+        router.request_timeout_s = 120.0  # however slow the survivor is
+        reroute(ticket, reason, source=source)
+
+    router._reroute = patient_reroute
     try:
         # Warm the decode/prefill programs (jit cache lives on the
         # model, shared by both replicas) BEFORE arming the timeout:
         # a cold compile must not read as a hung replica.
         router.run([(PROMPTS[0], 2)], results=True)
-        router.request_timeout_s = 1.0
-        plan = FaultPlan(seed=5).hang_replica(3.0, replica="r0")
+        router.request_timeout_s = 0.5
+        plan = faults.FaultPlan(seed=5).hang_replica(60.0, replica="r0")
         with plan:
             results = router.run([(PROMPTS[0], 2)], results=True)
+            assert [seam for seam, _, _ in plan.fired] == ["replica.run"]
             assert results[0].status == "ok"
             np.testing.assert_array_equal(results[0].tokens, golds[0])
             dead = [r for r in router.replicas if r.state == "dead"]
             assert len(dead) == 1 and "timeout" in dead[0].last_error
+            assert "0.5s" in dead[0].last_error
             assert router.last_stats["router"]["reroutes"] >= 1
             # Wait out the hung worker INSIDE the plan scope: it wakes,
             # runs its batch late (results latch-ignored), and exits.
+            wake.set()
             dead[0].join(timeout=30)
     finally:
+        wake.set()
         router.shutdown()
     assert router.audit() == []
 
@@ -467,7 +487,7 @@ def test_replace_add_replica_under_concurrent_submissions(
     barrier = _threading.Barrier(len(prompts) + 1)
 
     def submit(i):
-        barrier.wait()
+        barrier.wait(timeout=30)
         results[i] = router.run([(prompts[i], gens[i])], results=True)[0]
 
     threads = [
@@ -476,7 +496,7 @@ def test_replace_add_replica_under_concurrent_submissions(
     ]
     for t in threads:
         t.start()
-    barrier.wait()
+    barrier.wait(timeout=30)
     # Mid-flight: kill r0 (its orphans re-route), swap in its
     # generation-suffixed successor, and grow the rotation.
     dead = router.replica("r0")

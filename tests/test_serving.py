@@ -13,9 +13,8 @@ from triton_distributed_tpu.models.engine import Engine
 from triton_distributed_tpu.serving import ModelServer, request
 
 
-def test_server_round_trip(ctx4):
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    engine = Engine(model, temperature=0.0, mode="xla")
+def test_server_round_trip(own_model):
+    engine = Engine(own_model, temperature=0.0, mode="xla")
 
     prompts = np.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], np.int32)
     gold = engine.serve(prompts, gen_len=4)
@@ -35,17 +34,17 @@ def test_server_round_trip(ctx4):
         server.shutdown()
 
 
-def test_server_reports_errors(ctx4):
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    engine = Engine(model, mode="xla")
+def test_server_reports_errors(own_model):
+    engine = Engine(own_model, mode="xla")
     server = ModelServer(engine).start()
     try:
         import pytest
 
-        # Indivisible prompt lengths are auto-padded now — serve works.
+        # An odd prompt length serves (what pads it to the tp width is
+        # held under tp=4 in tests/test_model.py).
         resp = request(
             server.host, server.port,
-            {"input_ids": [[1, 2, 3]], "gen_len": 2},  # len 3 % tp4 != 0
+            {"input_ids": [[1, 2, 3]], "gen_len": 2},
         )
         assert np.asarray(resp["output_ids"]).shape == (1, 5)
 
@@ -60,13 +59,12 @@ def test_server_reports_errors(ctx4):
         server.shutdown()
 
 
-def test_continuous_batching(ctx4):
+def test_continuous_batching(own_model):
     """Admission/eviction over the paged pool: mixed-length requests,
     fewer slots than requests, outputs match per-request dense goldens
     and every pool page is released at the end."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     prompts = [
         np.asarray([5, 9, 2, 4], np.int32),
         np.asarray([7, 1, 3, 8, 6, 2, 4, 9], np.int32),
@@ -77,11 +75,11 @@ def test_continuous_batching(ctx4):
     # Goldens: the plain dense engine, one request at a time.
     golds = []
     for p, g in zip(prompts, gens):
-        out = Engine(model, temperature=0.0).serve(p[None], gen_len=g)
+        out = Engine(own_model, temperature=0.0).serve(p[None], gen_len=g)
         golds.append(out[0, len(p):])
 
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=64
+        own_model, max_batch=2, page_size=16, max_length=64
     )
     free0 = len(eng.pool.free)
     outs = eng.run(list(zip(prompts, gens)))
@@ -90,19 +88,18 @@ def test_continuous_batching(ctx4):
     assert len(eng.pool.free) == free0  # all pages released
 
 
-def test_continuous_batching_eos(ctx4):
+def test_continuous_batching_eos(own_model):
     """A request stopping at eos releases its slot early; the freed
     pages admit the waiting request."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     p = np.asarray([5, 9, 2, 4], np.int32)
     # Find what the model actually emits so we can use it as "eos".
-    probe = Engine(model, temperature=0.0).serve(p[None], gen_len=3)[0, 4:]
+    probe = Engine(own_model, temperature=0.0).serve(p[None], gen_len=3)[0, 4:]
     eos = int(probe[1])  # second generated token
 
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64, eos_id=eos
+        own_model, max_batch=1, page_size=16, max_length=64, eos_id=eos
     )
     outs = eng.run([(p, 6), (p, 2)])
     # Request 0 stops right after emitting eos (2 tokens, not 6).
@@ -110,19 +107,18 @@ def test_continuous_batching_eos(ctx4):
     assert len(outs[1]) == 2
 
 
-def test_continuous_batching_oversubscribed_pool(ctx4):
+def test_continuous_batching_oversubscribed_pool(own_model):
     """num_pages below max_batch*pages_per_seq (the point of paging):
     requests wait for pages, outputs stay correct, capacity errors are
     loud."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     p = np.asarray([5, 9, 2, 4], np.int32)
-    gold = Engine(model, temperature=0.0).serve(p[None], gen_len=4)[0, 4:]
+    gold = Engine(own_model, temperature=0.0).serve(p[None], gen_len=4)[0, 4:]
 
     # 2 slots but only one sequence's worth of pages: strictly serial.
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=64, num_pages=4
+        own_model, max_batch=2, page_size=16, max_length=64, num_pages=4
     )
     outs = eng.run([(p, 4), (p, 4)])
     for got in outs:
@@ -131,30 +127,29 @@ def test_continuous_batching_oversubscribed_pool(ctx4):
     import pytest
 
     small = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64, num_pages=3
+        own_model, max_batch=1, page_size=16, max_length=64, num_pages=3
     )
     with pytest.raises(ValueError, match="unservable"):
         # Needs 4 pages; capacity is 3.
         small.run([(np.zeros(48, np.int32), 16)])
 
 
-def test_max_length_page_size_validation(ctx4):
+def test_max_length_page_size_validation(own_model):
     """A misaligned (max_length, page_size) pair must refuse at
     construction NAMING BOTH VALUES — before it, ``pps`` silently
     truncated and the tail tokens had no page."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    lc_model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     with pytest.raises(ValueError, match=r"100.*not a multiple.*16"):
         ContinuousEngine(
-            lc_model, max_batch=1, page_size=16, max_length=100
+            own_model, max_batch=1, page_size=16, max_length=100
         )
     # Engine validates against the model's cfg.max_length (128 for
     # tiny) — 48 does not divide it.
     with pytest.raises(ValueError, match=r"max_length=128.*page_size=48"):
-        Engine(lc_model, paged=True, page_size=48)
+        Engine(own_model, paged=True, page_size=48)
     with pytest.raises(ValueError, match=r"max_length.*page_size"):
-        Engine(lc_model, paged=True, page_size=16).serve(
+        Engine(own_model, paged=True, page_size=16).serve(
             [np.arange(1, 9, dtype=np.int32)], gen_len=1, max_length=100
         )
 
@@ -184,7 +179,7 @@ def test_engine_has_no_long_context_options():
     assert len(options) - 2 <= 27  # self and the model aside
 
 
-def test_snapshot_longer_than_a_slot_is_unservable(ctx4):
+def test_snapshot_longer_than_a_slot_is_unservable(own_model):
     """A snapshot holding more KV than a slot's table row has pages
     for (what the sharded slot's stitched export could ship) is refused
     with a structured `unservable` error while the rest of the batch is
@@ -195,8 +190,7 @@ def test_snapshot_longer_than_a_slot_is_unservable(ctx4):
     )
     from triton_distributed_tpu.models.slot_state import SlotSnapshot
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = ContinuousEngine(model, max_batch=2, page_size=16, max_length=64)
+    eng = ContinuousEngine(own_model, max_batch=2, page_size=16, max_length=64)
     L, _, hkv, page, hd = eng.cache.k_pages.shape
     pages = np.zeros((L, 5, hkv, page, hd), eng.cache.k_pages.dtype)
     long_prompt = np.arange(1, 71, dtype=np.int32)  # 70 tokens: 5 pages
@@ -205,7 +199,7 @@ def test_snapshot_longer_than_a_slot_is_unservable(ctx4):
         kv_dtype=None, k_pages=pages, v_pages=pages,
     )
     p = np.asarray([5, 9, 2, 4], np.int32)
-    gold = Engine(model, temperature=0.0).serve(p[None], gen_len=4)[0, 4:]
+    gold = Engine(own_model, temperature=0.0).serve(p[None], gen_len=4)[0, 4:]
     free = len(eng.pool.free)
     results = eng.run(
         [Request(long_prompt, 8, snapshot=snap.to_wire()), (p, 4)],
@@ -429,44 +423,42 @@ def test_continuous_mega_telemetry(ctx4):
     assert kinds.count("mega:launch") == st["mega_launches"]
 
 
-def test_continuous_batching_first_token_finishes(ctx4):
+def test_continuous_batching_first_token_finishes(own_model):
     """gen_len=1 and first-token-eos requests complete at admission:
     exactly one token back, and the freed slot admits the next request
     immediately."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     p = np.asarray([5, 9, 2, 4], np.int32)
     first = int(
-        Engine(model, temperature=0.0).serve(p[None], gen_len=1)[0, 4]
+        Engine(own_model, temperature=0.0).serve(p[None], gen_len=1)[0, 4]
     )
 
-    eng = ContinuousEngine(model, max_batch=1, page_size=16, max_length=64)
+    eng = ContinuousEngine(own_model, max_batch=1, page_size=16, max_length=64)
     outs = eng.run([(p, 1), (p, 2)])
     assert len(outs[0]) == 1 and int(outs[0][0]) == first
     assert len(outs[1]) == 2
 
     # eos as the very first sampled token.
     eng2 = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64, eos_id=first
+        own_model, max_batch=1, page_size=16, max_length=64, eos_id=first
     )
     outs2 = eng2.run([(p, 6), (p, 2)])
     assert len(outs2[0]) == 1 and int(outs2[0][0]) == first
 
 
-def test_server_per_request_sampling(ctx4):
+def test_server_per_request_sampling(own_model):
     """The ``requests`` payload's sampling knobs: scalar broadcast and
     per-request lists reach each Request; a temperature-0 override
     inside a sampled-default engine reproduces the greedy golden."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     p = [5, 9, 2, 4]
-    gold = Engine(model, temperature=0.0).serve(
+    gold = Engine(own_model, temperature=0.0).serve(
         np.asarray([p], np.int32), gen_len=4
     )[0, 4:]
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=64, temperature=0.9
+        own_model, max_batch=2, page_size=16, max_length=64, temperature=0.9
     )
     server = ModelServer(eng).start()
     try:
@@ -491,18 +483,17 @@ def test_server_per_request_sampling(ctx4):
         server.shutdown()
 
 
-def test_server_speculative_stats(ctx4):
+def test_server_speculative_stats(own_model):
     """A server over a speculative ContinuousEngine serves the same
     tokens and reports the accept/rollback ledger in stats."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     p = [5, 9, 2, 4, 5, 9, 2, 4]
-    gold = Engine(model, temperature=0.0).serve(
+    gold = Engine(own_model, temperature=0.0).serve(
         np.asarray([p], np.int32), gen_len=6
     )[0, 8:]
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64, speculative=3
+        own_model, max_batch=1, page_size=16, max_length=64, speculative=3
     )
     server = ModelServer(eng).start()
     try:
@@ -519,7 +510,7 @@ def test_server_speculative_stats(ctx4):
         server.shutdown()
 
 
-def test_server_unknown_payload_and_malformed_json(ctx4):
+def test_server_unknown_payload_and_malformed_json(own_model):
     """Unknown payloads return a structured error naming the accepted
     shapes (was: a bare KeyError 'input_ids'); malformed JSON is
     reported AND the connection keeps serving; both bump the server
@@ -527,8 +518,7 @@ def test_server_unknown_payload_and_malformed_json(ctx4):
     import json
     import socket
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    server = ModelServer(Engine(model, mode="xla")).start()
+    server = ModelServer(Engine(own_model, mode="xla")).start()
     try:
         with pytest.raises(RuntimeError, match="accepted payloads"):
             request(server.host, server.port, {"whatever": 1})
@@ -550,15 +540,14 @@ def test_server_unknown_payload_and_malformed_json(ctx4):
         server.shutdown()
 
 
-def test_server_oversized_line_bounded(ctx4):
+def test_server_oversized_line_bounded(own_model):
     """A giant request line is refused at the byte bound (no OOM-sized
     buffering), the connection is dropped (framing is lost), and the
     server stays serviceable."""
     import json
     import socket
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    server = ModelServer(Engine(model, mode="xla")).start()
+    server = ModelServer(Engine(own_model, mode="xla")).start()
     server.MAX_LINE_BYTES = 1024  # instance override for the test
     try:
         with socket.create_connection(
@@ -585,7 +574,7 @@ def test_server_oversized_line_bounded(ctx4):
         server.shutdown()
 
 
-def test_server_client_disconnect_mid_request(ctx4):
+def test_server_client_disconnect_mid_request(own_model):
     """A client that sends a generation payload and hard-closes (RST)
     before reading must not kill the server: the failure is counted as
     a connection error and the engine/pool stay clean."""
@@ -596,8 +585,7 @@ def test_server_client_disconnect_mid_request(ctx4):
 
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = ContinuousEngine(model, max_batch=1, page_size=16, max_length=64)
+    eng = ContinuousEngine(own_model, max_batch=1, page_size=16, max_length=64)
     server = ModelServer(eng).start()
     try:
         s = socket.create_connection((server.host, server.port), timeout=10)
@@ -624,15 +612,14 @@ def test_server_client_disconnect_mid_request(ctx4):
         server.shutdown()
 
 
-def test_server_concurrent_requests_and_stats(ctx4):
+def test_server_concurrent_requests_and_stats(own_model):
     """stats/ping payloads bypass the engine lock: they answer while a
     generation payload is in flight on another connection."""
     import threading
 
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = ContinuousEngine(model, max_batch=1, page_size=16, max_length=64)
+    eng = ContinuousEngine(own_model, max_batch=1, page_size=16, max_length=64)
     server = ModelServer(eng).start()
     try:
         done = {}
@@ -663,7 +650,7 @@ def test_server_concurrent_requests_and_stats(ctx4):
         server.shutdown()
 
 
-def test_server_graceful_drain(ctx4):
+def test_server_graceful_drain(own_model):
     """Shutdown while a generation is in flight: the in-flight payload
     finishes and its response arrives intact; a payload on an already-
     open connection is refused with `shutting_down`; fresh connections
@@ -675,8 +662,7 @@ def test_server_graceful_drain(ctx4):
 
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = ContinuousEngine(model, max_batch=1, page_size=16, max_length=64)
+    eng = ContinuousEngine(own_model, max_batch=1, page_size=16, max_length=64)
     server = ModelServer(eng).start()
     done = {}
 
@@ -720,7 +706,7 @@ def test_server_graceful_drain(ctx4):
     assert eng.audit() == []
 
 
-def test_server_scrape_while_draining(ctx4):
+def test_server_scrape_while_draining(own_model):
     """metrics/events/ping verbs keep answering after shutdown has been
     requested but before the in-flight generation finishes (a drain is
     exactly when an operator wants to watch the tier). Post-shutdown a
@@ -733,8 +719,7 @@ def test_server_scrape_while_draining(ctx4):
 
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = ContinuousEngine(model, max_batch=1, page_size=16, max_length=64)
+    eng = ContinuousEngine(own_model, max_batch=1, page_size=16, max_length=64)
     server = ModelServer(eng).start()
     done = {}
 
@@ -776,7 +761,7 @@ def test_server_scrape_while_draining(ctx4):
     assert eng.audit() == []
 
 
-def test_client_honors_server_backoff_hint(ctx4):
+def test_client_honors_server_backoff_hint(own_model):
     """The overloaded shed reply carries ``retry_after_s``; the client
     retry loop sleeps THAT instead of its local exponential backoff —
     a local backoff_s large enough to fail the test proves the hint
@@ -792,6 +777,7 @@ def test_client_honors_server_backoff_hint(ctx4):
     lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
     lsock.bind(("127.0.0.1", 0))
     lsock.listen(4)
+    lsock.settimeout(10)  # the fake server fails where it waits
     host, port = lsock.getsockname()
 
     def fake_server():
@@ -829,8 +815,7 @@ def test_client_honors_server_backoff_hint(ctx4):
     # A real server's shed reply carries the hint on the wire.
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
-    eng = ContinuousEngine(model, max_batch=1, page_size=16, max_length=64)
+    eng = ContinuousEngine(own_model, max_batch=1, page_size=16, max_length=64)
     server = ModelServer(eng, max_pending=0).start()
     try:
         with pytest.raises(RuntimeError, match="server error") as ei:
@@ -841,13 +826,12 @@ def test_client_honors_server_backoff_hint(ctx4):
         server.shutdown()
 
 
-def test_engine_serve_profile_hook(ctx4, tmp_path):
+def test_engine_serve_profile_hook(own_model, tmp_path):
     """Engine.serve(profile=...) must capture a decode-loop trace
     (parity: the reference Engine's built-in profiled decode,
     ``models/engine.py:151-177``) — files on disk, output unchanged."""
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4)
     prompt = np.arange(8, dtype=np.int32)[None]
-    eng = Engine(model, temperature=0.0, mode="xla")
+    eng = Engine(own_model, temperature=0.0, mode="xla")
     gold = eng.serve(prompt, gen_len=4)
     prof_dir = str(tmp_path / "decode_trace")
     out = eng.serve(prompt, gen_len=4, profile=prof_dir)

@@ -29,11 +29,11 @@ def test_sp_ag_attention(ctx4, rng, hq, hkv):
     s, hd = 256, 64  # 64 rows per device
     q, k, v = _make(rng, hq, hkv, s, hd)
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(sp_ag_attention, axis="tp", block_q=32, ctx=ctx4),
         in_specs=(P(None, "tp", None),) * 3,
         out_specs=P(None, "tp", None),
-    )
+    ))
     out = f(q, k, v)
     ref = mha_reference(q[None], k[None], v[None], causal=True)[0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
@@ -45,12 +45,12 @@ def test_ring_attention(ctx4, rng, causal):
     s, hq, hkv, hd = 256, 4, 2, 64
     q, k, v = _make(rng, hq, hkv, s, hd)
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(ring_attention, axis="tp", causal=causal, block_q=64,
                           block_k=64),
         in_specs=(P(None, "tp", None),) * 3,
         out_specs=P(None, "tp", None),
-    )
+    ))
     out = f(q, k, v)
     ref = mha_reference(q[None], k[None], v[None], causal=causal)[0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
@@ -72,14 +72,14 @@ def test_sp_decode_attention(ctx4, rng, method):
     vn = jnp.asarray(rng.standard_normal((b, hkv, hd)), jnp.float32)
     lens = jnp.asarray([100, 37], jnp.int32)
 
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(
             sp_decode_attention, axis="tp", chunk_k=64, method=method, ctx=ctx4
         ),
         in_specs=(P(), P(), P(), P(None, None, "tp", None),
                   P(None, None, "tp", None), P()),
         out_specs=(P(), P(None, None, "tp", None), P(None, None, "tp", None)),
-    )
+    ))
     out, kc2, vc2 = f(q, kn, vn, kc, vc, lens)
 
     # Golden: cache with the new token written at kv_len[b].
@@ -104,14 +104,14 @@ def test_sp_ag_attention_2level(ctx2x4, rng, hq, hkv):
     s, hd = 128, 32  # 2 slices × 4 ranks → 16 rows per device
     q, k, v = _make(rng, hq, hkv, s, hd)
 
-    f = ctx2x4.shard_map(
+    f = jax.jit(ctx2x4.shard_map(
         functools.partial(
             sp_ag_attention_2level, inner_axis="tp", outer_axis="dp",
             block_q=16, ctx=ctx2x4,
         ),
         in_specs=(P(None, ("dp", "tp"), None),) * 3,
         out_specs=P(None, ("dp", "tp"), None),
-    )
+    ))
     out = f(q, k, v)
     ref = mha_reference(q[None], k[None], v[None], causal=True)[0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
@@ -133,7 +133,7 @@ def test_distributed_flash_decode_2level(ctx2x4, rng, method):
     vc = jnp.asarray(rng.standard_normal((b, hkv, s, hd)), jnp.float32)
     lens = jnp.asarray([200, 37], jnp.int32)
 
-    f = ctx2x4.shard_map(
+    f = jax.jit(ctx2x4.shard_map(
         functools.partial(
             distributed_flash_decode_2level, inner_axis="tp",
             outer_axis="dp", chunk_k=32, method=method, ctx=ctx2x4,
@@ -141,7 +141,7 @@ def test_distributed_flash_decode_2level(ctx2x4, rng, method):
         in_specs=(P(), P(None, None, ("dp", "tp"), None),
                   P(None, None, ("dp", "tp"), None), P()),
         out_specs=P(),
-    )
+    ))
     out = f(q, kc, vc, lens)
     ref = gqa_decode_reference(q, kc, vc, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
@@ -160,14 +160,14 @@ def test_ring_attention_bf16(ctx4, rng):
     q = jnp.asarray(rng.standard_normal((hq, s, hd)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((hkv, s, hd)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((hkv, s, hd)), jnp.bfloat16)
-    f = ctx4.shard_map(
+    f = jax.jit(ctx4.shard_map(
         functools.partial(
             ring_attention, axis="tp", causal=True, block_q=64,
             block_k=64,
         ),
         in_specs=(P(None, "tp", None),) * 3,
         out_specs=P(None, "tp", None),
-    )
+    ))
     out = f(q, k, v)
     assert out.dtype == jnp.bfloat16
     ref = mha_reference(
@@ -193,7 +193,7 @@ def test_distributed_flash_decode_2level_bf16(ctx2x4, rng):
     kc = jnp.asarray(rng.standard_normal((b, hkv, s, hd)), jnp.bfloat16)
     vc = jnp.asarray(rng.standard_normal((b, hkv, s, hd)), jnp.bfloat16)
     lens = jnp.asarray([200, 37], jnp.int32)
-    f = ctx2x4.shard_map(
+    f = jax.jit(ctx2x4.shard_map(
         functools.partial(
             distributed_flash_decode_2level, inner_axis="tp",
             outer_axis="dp", chunk_k=32, method="xla", ctx=ctx2x4,
@@ -201,7 +201,7 @@ def test_distributed_flash_decode_2level_bf16(ctx2x4, rng):
         in_specs=(P(), P(None, None, ("dp", "tp"), None),
                   P(None, None, ("dp", "tp"), None), P()),
         out_specs=P(),
-    )
+    ))
     out = f(q, kc, vc, lens)
     assert out.dtype == jnp.bfloat16
     ref = gqa_decode_reference(
@@ -237,14 +237,14 @@ def test_distributed_flash_decode_2level_int8(ctx2x4, rng):
             ctx=ctx2x4,
         )
 
-    f = ctx2x4.shard_map(
+    f = jax.jit(ctx2x4.shard_map(
         shard_fn,
         in_specs=(P(), P(None, None, ("dp", "tp"), None),
                   P(None, None, ("dp", "tp"), None), P(),
                   P(None, None, ("dp", "tp")),
                   P(None, None, ("dp", "tp"))),
         out_specs=P(),
-    )
+    ))
     out = f(
         q, k_q.reshape(b, hkv, s, hd), v_q.reshape(b, hkv, s, hd),
         lens, k_sc, v_sc,

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from triton_distributed_tpu.models import AutoLLM, sampling
+from triton_distributed_tpu.models import sampling
 from triton_distributed_tpu.models.engine import Engine
 from triton_distributed_tpu.models.paged_kv_cache import (
     PagePool,
@@ -203,15 +203,15 @@ def test_gather_bucket_powers_of_two():
     assert gather_bucket(999, 16, 8) == 8  # capped at pages_per_seq
 
 
-def test_rollback_kv_truncates_one_slot(ctx4):
+def test_rollback_kv_truncates_one_slot(own_model):
     from triton_distributed_tpu.models.paged_kv_cache import (
         init_paged_cache,
         rollback_kv,
     )
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=64)
     cache, _pool = init_paged_cache(
-        model.cfg, 2, model.ctx, model.axis, max_length=64, page_size=16
+        own_model.cfg, 2, own_model.ctx, own_model.axis, max_length=64,
+        page_size=16,
     )
     cache.kv_len.block_until_ready()
     import dataclasses
@@ -226,13 +226,12 @@ def test_rollback_kv_truncates_one_slot(ctx4):
 # -- engine integration ----------------------------------------------------
 
 
-def test_continuous_speculative_greedy_bit_identical(ctx4):
+def test_continuous_speculative_greedy_bit_identical(own_model):
     """The headline exactness proof: speculative greedy decode emits
     the same tokens as plain decode, for repetitive (high-accept) and
     chaotic (rollback-heavy) prompts, and releases every page."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=128)
     prompts = [
         np.asarray([5, 9, 2, 4] * 4, np.int32),     # repetitive
         np.asarray([7, 1, 3, 8, 6, 2, 4, 9], np.int32),
@@ -240,11 +239,12 @@ def test_continuous_speculative_greedy_bit_identical(ctx4):
     ]
     gens = [12, 6, 5]
     golds = [
-        Engine(model, temperature=0.0).serve(p[None], gen_len=g)[0, len(p):]
+        Engine(own_model, temperature=0.0).serve(
+            p[None], gen_len=g)[0, len(p):]
         for p, g in zip(prompts, gens)
     ]
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=128, speculative=4
+        own_model, max_batch=2, page_size=16, max_length=128, speculative=4
     )
     free0 = len(eng.pool.free)
     outs = eng.run(list(zip(prompts, gens)))
@@ -263,14 +263,13 @@ def test_continuous_speculative_greedy_bit_identical(ctx4):
     assert st["spec_accepted_tokens"] > 0  # the repetitive prompt drafted
 
 
-def test_engine_paged_speculative_greedy_bit_identical(ctx4):
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=128)
+def test_engine_paged_speculative_greedy_bit_identical(own_model):
     prompts = np.asarray(
         [[5, 9, 2, 4] * 2, [7, 1, 3, 8, 6, 2, 4, 9]], np.int32
     )
-    gold = Engine(model, temperature=0.0).serve(prompts, gen_len=10)
+    gold = Engine(own_model, temperature=0.0).serve(prompts, gen_len=10)
     eng = Engine(
-        model, temperature=0.0, paged=True, page_size=16, speculative=4
+        own_model, temperature=0.0, paged=True, page_size=16, speculative=4
     )
     out = eng.serve(prompts, gen_len=10, max_length=128)
     np.testing.assert_array_equal(out, gold)
@@ -290,17 +289,17 @@ def test_engine_paged_speculative_greedy_bit_identical(ctx4):
     assert st["spec_tokens_per_step"] >= 1.0
 
 
-def test_speculative_with_prefix_cache_warm_identical(ctx4):
+def test_speculative_with_prefix_cache_warm_identical(own_model):
     """speculative=K coexists with prefix_cache=True: warm arrivals map
     shared pages AND speculate, still bit-identical to the dense
     golden."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=128)
     p = np.asarray([5, 9, 2, 4] * 4, np.int32)
-    gold = Engine(model, temperature=0.0).serve(p[None], gen_len=12)[0, 16:]
+    gold = Engine(own_model, temperature=0.0).serve(
+        p[None], gen_len=12)[0, 16:]
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=128, speculative=4,
+        own_model, max_batch=2, page_size=16, max_length=128, speculative=4,
         prefix_cache=True, prefill_chunk=16,
     )
     for _ in range(2):  # second arrival is the warm (shared-prefix) one
@@ -310,16 +309,15 @@ def test_speculative_with_prefix_cache_warm_identical(ctx4):
     assert eng.last_stats["spec_accepted_tokens"] > 0
 
 
-def test_speculative_smoke_fast(ctx4):
+def test_speculative_smoke_fast(own_model):
     """Tier-1 CPU smoke (CI satellite): a short speculative run on both
     engines completes, bit-identical, with the counters present."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=64)
     p = np.asarray([5, 9, 2, 4, 5, 9, 2, 4], np.int32)
-    gold = Engine(model, temperature=0.0).serve(p[None], gen_len=6)
+    gold = Engine(own_model, temperature=0.0).serve(p[None], gen_len=6)
     eng = ContinuousEngine(
-        model, max_batch=1, page_size=16, max_length=64, speculative=3
+        own_model, max_batch=1, page_size=16, max_length=64, speculative=3
     )
     out = eng.run([(p, 6)])[0]
     np.testing.assert_array_equal(out, gold[0, 8:])
@@ -328,27 +326,28 @@ def test_speculative_smoke_fast(ctx4):
         assert key in eng.last_stats
 
 
-def test_speculative_requires_paged_and_non_mega(ctx4):
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=64)
+def test_speculative_requires_paged_and_non_mega(own_model):
     with pytest.raises(ValueError, match="paged"):
-        Engine(model, speculative=2)
+        Engine(own_model, speculative=2)
     with pytest.raises(ValueError, match="mega"):
-        Engine(model, speculative=2, paged=True, mode="mega")
+        # A page that does not tile max_length as well: the refusal of the
+        # flags comes before the one of the geometry.
+        Engine(own_model, speculative=2, paged=True, mode="mega",
+               page_size=48)
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
     with pytest.raises(ValueError, match="mega"):
-        ContinuousEngine(model, mode="mega", speculative=2)
+        ContinuousEngine(own_model, mode="mega", speculative=2)
 
 
-def test_continuous_speculative_sampled_lengths_and_ledger(ctx4):
+def test_continuous_speculative_sampled_lengths_and_ledger(own_model):
     """Sampled speculative serving: right lengths, ledger consistent
     (the distribution proof itself is the verify_sampled test)."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=64)
     p = np.asarray([5, 9, 2, 4] * 2, np.int32)
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=64, speculative=3,
+        own_model, max_batch=2, page_size=16, max_length=64, speculative=3,
         temperature=0.8, top_p=0.9, top_k=8,
     )
     outs = eng.run([(p, 8), (p, 5)])

@@ -467,192 +467,78 @@ def test_multihost_modules_compile():
 
 
 def test_tier1_marker_audit():
-    """ISSUE 8 satellite: the tier-1 window is spent by conftest's
-    ``_FILE_ORDER`` schedule — audit it against reality so new trace
-    tests actually run inside the wall clock: every listed file must
-    exist (a stale entry silently reorders nothing), and the device-
-    tracer suite must both be scheduled ahead of the multi-minute tail
-    AND carry runnable (non-slow) tests."""
+    """ISSUE 8 satellite: a ``slow`` mark takes a test out of tier-1,
+    so a suite marked slow wholesale, or nearly so, guards nothing
+    there. Every ``tests/test_*.py`` keeps a test that tier-1 runs, and
+    a file of ten tests or more keeps five."""
     import ast
+    import glob
     import os
+
+    def is_slow(node):
+        return any("slow" in ast.dump(d) for d in node.decorator_list)
+
+    thin = {}
+    here = os.path.dirname(__file__)
+    for path in sorted(glob.glob(os.path.join(here, "test_*.py"))):
+        marks = []  # one bool a test: is it marked slow?
+        for node in ast.parse(open(path).read()).body:
+            members = node.body if isinstance(node, ast.ClassDef) else [node]
+            marks += [
+                is_slow(m) or (m is not node and is_slow(node))
+                for m in members
+                if isinstance(m, ast.FunctionDef)
+                and m.name.startswith("test_")
+            ]
+        fast = marks.count(False)
+        if fast < (5 if len(marks) >= 10 else 1):
+            thin[os.path.basename(path)] = f"{fast} of {len(marks)}"
+    assert not thin, f"too few tier-1-runnable tests: {thin}"
+
+
+def test_a_wait_past_the_limit_fails_that_test_with_every_stack():
+    """``conftest.time_limit`` (armed with ``LIMIT`` round every phase of
+    every test): a wait past it fails the test BY NAME with every
+    thread's stack, the process lives on, and the timer and handler
+    found on entry (this test's own ``LIMIT``) are back afterwards."""
+    import signal
+    import threading
+    import time
 
     import conftest
 
-    tests_dir = os.path.dirname(__file__)
-    actual = {f for f in os.listdir(tests_dir)
-              if f.startswith("test_") and f.endswith(".py")}
-    stale = [f for f in conftest._FILE_ORDER if f not in actual]
-    assert not stale, f"conftest._FILE_ORDER lists missing files: {stale}"
+    handler = signal.getsignal(signal.SIGALRM)
+    left, _ = signal.getitimer(signal.ITIMER_REAL)
+    assert 0 < left <= conftest.LIMIT  # this test runs under its own limit
+    stop = threading.Event()
 
-    def fast_tests(fname):
-        """Non-slow test function names of one suite file — THE fast-
-        test detector every per-suite audit below shares (a fix to
-        the decorator check must not need N coordinated edits)."""
-        src = open(os.path.join(tests_dir, fname)).read()
-        return [
-            n.name for n in ast.walk(ast.parse(src))
-            if isinstance(n, ast.FunctionDef)
-            and n.name.startswith("test_")
-            and not any("slow" in ast.dump(d) for d in n.decorator_list)
-        ]
-    order = conftest._FILE_ORDER
-    # ISSUE-22: the chip-compile suite (main-path kernels compiled by
-    # the TPU's compiler for a described v5e) and the chip_smoke
-    # rehearsal are scheduled ahead of the interpret tail and carry
-    # tier-1-runnable tests: a kernel Mosaic would refuse, or a smoke
-    # that no longer fails without a TPU, has to FAIL tier-1, not wait
-    # for a chip run.
-    for suite, least in (("test_chip_compile.py", 5),
-                         ("test_chip_smoke.py", 5)):
-        assert suite in order
-        assert order.index(suite) < order.index("test_serving.py")
-        fast = fast_tests(suite)
-        assert len(fast) >= least, (
-            f"{suite} has too few tier-1-runnable tests: {fast}"
-        )
-    # The trace suite is explicitly scheduled (not just rank -1) and
-    # sits before the interpret-heavy tail.
-    assert "test_kernel_trace.py" in order
-    assert (order.index("test_kernel_trace.py")
-            < order.index("test_serving.py"))
-    # ISSUE-9: the process-fleet chaos suite spawns child interpreters
-    # (~seconds per fleet) — it must be explicitly scheduled (not
-    # rank -1 ahead of everything) AND sit before the multi-minute
-    # interpret tail so the wall clock actually reaches it.
-    assert "test_fleet.py" in order
-    assert (order.index("test_router.py")
-            < order.index("test_fleet.py")
-            < order.index("test_serving.py"))
-    # ISSUE-10: the slot-migration suite (tiny-model bit-exactness +
-    # stub fleets) rides right behind the fleet suite, still ahead of
-    # the interpret tail, and must carry tier-1-runnable tests.
-    assert "test_migration.py" in order
-    assert (order.index("test_fleet.py")
-            < order.index("test_migration.py")
-            < order.index("test_serving.py"))
-    mig_fast = fast_tests("test_migration.py")
-    assert len(mig_fast) >= 5, (
-        f"slot-migration suite has too few tier-1-runnable tests: "
-        f"{mig_fast}"
-    )
-    # ISSUE-12: the durable-KV-tier suite (pure store + tiny-model
-    # spill/fault-back + the supervisor-restart resume case) rides
-    # right behind the migration suite, ahead of the interpret tail,
-    # and must carry tier-1-runnable tests — containment regressions
-    # have to FAIL tier-1, not wait for a chip run.
-    assert "test_kv_tier.py" in order
-    assert (order.index("test_migration.py")
-            < order.index("test_kv_tier.py")
-            < order.index("test_serving.py"))
-    tier_fast = fast_tests("test_kv_tier.py")
-    assert len(tier_fast) >= 5, (
-        f"KV-tier suite has too few tier-1-runnable tests: {tier_fast}"
-    )
-    # ISSUE-17: the KV-fabric suite (wire tier verbs, peer fault-back
-    # bit-exactness, chaos degradation, tier-aware placement) rides
-    # right behind the KV-tier suite it extends, ahead of the
-    # interpret tail, and must carry tier-1-runnable tests — a
-    # wrong-bits-from-a-peer regression has to FAIL tier-1.
-    assert "test_kv_fabric.py" in order
-    assert (order.index("test_kv_tier.py")
-            < order.index("test_kv_fabric.py")
-            < order.index("test_serving.py"))
-    fabric_fast = fast_tests("test_kv_fabric.py")
-    assert len(fabric_fast) >= 5, (
-        f"KV-fabric suite has too few tier-1-runnable tests: "
-        f"{fabric_fast}"
-    )
-    # ISSUE-13: the SLO-goodput suite (streaming wire grammar, cancel
-    # teardown, loadgen determinism, fleet-scope scrape) rides with
-    # the fleet-family suites — streaming/cancel regressions must
-    # FAIL tier-1, not wait for a goodput_bench run.
-    assert "test_goodput.py" in order
-    assert (order.index("test_kv_tier.py")
-            < order.index("test_goodput.py")
-            < order.index("test_serving.py"))
-    gp_fast = fast_tests("test_goodput.py")
-    assert len(gp_fast) >= 5, (
-        f"SLO-goodput suite has too few tier-1-runnable tests: {gp_fast}"
-    )
-    # ISSUE-15: the elastic-pools suite (role scoring, scheduler
-    # waves/shedding, autoscaler control loop on a fake fleet, pools
-    # routing, batched handoff export) rides right behind the goodput
-    # suite, ahead of the interpret tail, and must carry tier-1-
-    # runnable tests — control-plane regressions have to FAIL tier-1,
-    # not wait for a pools_bench run.
-    assert "test_pools.py" in order
-    assert (order.index("test_goodput.py")
-            < order.index("test_pools.py")
-            < order.index("test_serving.py"))
-    pool_fast = fast_tests("test_pools.py")
-    assert len(pool_fast) >= 5, (
-        f"elastic-pools suite has too few tier-1-runnable tests: "
-        f"{pool_fast}"
-    )
-    # ISSUE-18: the multi-host suite (launcher contracts, host failure
-    # domains, epoch fencing, spawn failover) rides right behind the
-    # pools suite, ahead of the interpret tail, and must carry tier-1-
-    # runnable tests — a fencing or correlated-classification
-    # regression has to FAIL tier-1, not wait for a host_loss_bench
-    # run.
-    assert "test_multihost.py" in order
-    assert (order.index("test_pools.py")
-            < order.index("test_multihost.py")
-            < order.index("test_serving.py"))
-    mh_fast = fast_tests("test_multihost.py")
-    assert len(mh_fast) >= 5, (
-        f"multi-host suite has too few tier-1-runnable tests: "
-        f"{mh_fast}"
-    )
-    # ISSUE-16: the tree-speculation suite rides right behind the
-    # linear-speculation suite (shared tiny-model jit warmup), ahead of
-    # the interpret tail, and must carry tier-1-runnable tests — a
-    # tree-verify exactness regression has to FAIL tier-1, not wait
-    # for a spec_decode_bench run.
-    assert "test_tree_spec.py" in order
-    assert (order.index("test_speculative.py")
-            < order.index("test_tree_spec.py")
-            < order.index("test_serving.py"))
-    tree_fast = fast_tests("test_tree_spec.py")
-    assert len(tree_fast) >= 5, (
-        f"tree-speculation suite has too few tier-1-runnable tests: "
-        f"{tree_fast}"
-    )
-    # ISSUE-11: the MoE serving suite sits with the mega-family suites
-    # (after the tracer suite, before the interpret-heavy tail) and
-    # must carry tier-1-runnable tests — the MoE fast path has to FAIL
-    # tier-1 when broken, not wait for the post-tail test_moe.py.
-    assert "test_moe_serving.py" in order
-    assert (order.index("test_kernel_trace.py")
-            < order.index("test_moe_serving.py")
-            < order.index("test_serving.py"))
-    moe_fast = fast_tests("test_moe_serving.py")
-    assert len(moe_fast) >= 5, (
-        f"MoE serving suite has too few tier-1-runnable tests: "
-        f"{moe_fast}"
-    )
-    # And it contains non-slow tests, so tier-1 (which skips `slow`)
-    # actually exercises the tracer.
-    kt_fast = fast_tests("test_kernel_trace.py")
-    assert len(kt_fast) >= 5, (
-        f"device-tracer suite has too few tier-1-runnable tests: "
-        f"{kt_fast}"
-    )
-    # ISSUE-19: the resident-decode suite (resident == non-resident
-    # tokens, metric pre-touch, CLI refusal wording, knob guards) rides
-    # right behind the tracer suite whose validate_ring it uses, ahead
-    # of the interpret tail, and must carry tier-1-runnable tests — a
-    # pipeline or fallback regression has to FAIL tier-1, not wait for
-    # a mega_serve_bench run.
-    assert "test_resident.py" in order
-    assert (order.index("test_kernel_trace.py")
-            < order.index("test_resident.py")
-            < order.index("test_serving.py"))
-    res_fast = fast_tests("test_resident.py")
-    assert len(res_fast) >= 5, (
-        f"resident-decode suite has too few tier-1-runnable tests: "
-        f"{res_fast}"
-    )
+    def a_thread_that_waits():
+        stop.wait(30)
+
+    waiter = threading.Thread(target=a_thread_that_waits)
+    waiter.start()
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(pytest.fail.Exception) as e:
+            with conftest.time_limit(0.2, "tests/x.py::test_that_waits"):
+                stop.wait(30)
+    finally:
+        stop.set()
+        waiter.join(5)
+    assert time.monotonic() - t0 < 5
+    msg = str(e.value)
+    assert "tests/x.py::test_that_waits ran past its limit of 0.2 s" in msg
+    # The main thread's stack, down to the wait, and the other thread's.
+    assert "Current thread 0x" in msg and "test_a_wait_past_the_limit" in msg
+    assert "Thread 0x" in msg and "a_thread_that_waits" in msg
+    assert signal.getsignal(signal.SIGALRM) is handler
+    after, _ = signal.getitimer(signal.ITIMER_REAL)
+    assert 0 < after <= left
+    # And a body that ends in time leaves the same behind it.
+    with conftest.time_limit(0.2, "x"):
+        pass
+    time.sleep(0.3)
+    assert signal.getsignal(signal.SIGALRM) is handler
 
 
 def test_serving_tier_modules_compile():

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from triton_distributed_tpu.models import AutoLLM, sampling
+from triton_distributed_tpu.models import sampling
 from triton_distributed_tpu.models.engine import Engine
 from triton_distributed_tpu.models.paged_kv_cache import (
     PagePool,
@@ -186,14 +186,14 @@ def test_spec_state_record_tree_width_controller():
 # -- the commit primitive --------------------------------------------------
 
 
-def test_move_kv_rows_permutes_rows_and_refuses_quantized(ctx4):
+def test_move_kv_rows_permutes_rows_and_refuses_quantized(own_model):
     """``move_kv_rows`` relocates exactly the named token rows (both K
     and V, every layer, across page boundaries), leaves every other
     slot and row untouched, and refuses quantized pools (whose per-page
     scales would make a row hop a requantization event)."""
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=64)
     cache, _pool = init_paged_cache(
-        model.cfg, 2, model.ctx, model.axis, max_length=64, page_size=16
+        own_model.cfg, 2, own_model.ctx, own_model.axis, max_length=64,
+        page_size=16,
     )
     shape = cache.k_pages.shape
     rng = np.random.default_rng(3)
@@ -238,7 +238,7 @@ def test_move_kv_rows_permutes_rows_and_refuses_quantized(ctx4):
     with pytest.raises(ValueError, match="mismatch"):
         move_kv_rows(cache, 0, [1, 2], [1])
     qcache, _qp = init_paged_cache(
-        model.cfg, 2, model.ctx, model.axis,
+        own_model.cfg, 2, own_model.ctx, own_model.axis,
         max_length=64, page_size=16, kv_dtype="int8",
     )
     with pytest.raises(ValueError, match="quantized"):
@@ -312,20 +312,19 @@ def test_tier_resident_chains_memoized():
 # -- engine integration: greedy bit-identity -------------------------------
 
 
-def test_continuous_tree_greedy_bit_identical(ctx4):
+def test_continuous_tree_greedy_bit_identical(own_model):
     """The headline exactness proof for trees: a warmed radix makes the
     drafter propose real multi-branch trees, and the emitted stream
     stays bit-identical to plain greedy decode — with the rollback
     ledger balanced and every page released."""
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=256)
     p1 = np.asarray(MOTIF * 5 + [3, 5], np.int32)
     p2 = np.asarray(MOTIF * 5 + [9], np.int32)
-    g = 32
-    golds = [golden(model, list(p), g) for p in (p1, p2)]
+    g = 16
+    golds = [golden(own_model, list(p), g) for p in (p1, p2)]
     eng = ContinuousEngine(
-        model, max_batch=2, page_size=16, max_length=256,
+        own_model, max_batch=2, page_size=16, max_length=128,
         speculative=4, spec_width=4, prefix_cache=True,
     )
     assert eng._spec_tree
@@ -349,20 +348,19 @@ def test_continuous_tree_greedy_bit_identical(ctx4):
     assert all(n.refcount == 0 for n in eng.prefix.walk())
 
 
-def test_engine_paged_tree_greedy_bit_identical(ctx4):
+def test_engine_paged_tree_greedy_bit_identical(own_model):
     """The fixed-batch paged Engine grows the same tree arm: its
     persistent radix (prefix_cache=True) feeds the drafter on repeat
     serves, greedy output stays bit-identical, and the ledger closes."""
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=256)
     # An APERIODIC motif: the n-gram fallback and the radix walk then
     # disagree about the continuation, so the draft really branches
     # (a 4-periodic prompt collapses every proposal into one chain).
     motif = np.random.default_rng(0).integers(1, 50, size=7).tolist()
     p = motif * 4 + [3, 5]
-    g = 48
-    gold = golden(model, p, g)
+    g = 24
+    gold = golden(own_model, p, g)
     eng = Engine(
-        model, temperature=0.0, paged=True, page_size=16,
+        own_model, temperature=0.0, paged=True, page_size=16,
         speculative=4, spec_width=4, prefix_cache=True,
     )
     assert eng._spec_tree
@@ -376,18 +374,17 @@ def test_engine_paged_tree_greedy_bit_identical(ctx4):
     )
 
 
-def test_tree_branch_accept_row_moves_bit_identical(ctx4, monkeypatch):
+def test_tree_branch_accept_row_moves_bit_identical(own_model, monkeypatch):
     """Force the target down a NON-first branch every round: the decoy
     branch occupies the early storage rows, so every accept must
     relocate KV rows (``spec_tree_branch_accepts`` counts the moves) —
     and the output must STILL be bit-identical to plain greedy decode,
     proving moved rows equal linearly-written rows."""
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx4, max_length=256)
     p = MOTIF * 5 + [3, 5]
     g = 24
-    gold = [int(t) for t in golden(model, p, g)]
+    gold = [int(t) for t in golden(own_model, p, g)]
     full = list(p) + gold
-    vocab = model.cfg.vocab_size
+    vocab = own_model.cfg.vocab_size
 
     def decoy_first(self, tokens, *, width, depth, tier_chains=None):
         pos = len(tokens)
@@ -401,7 +398,7 @@ def test_tree_branch_accept_row_moves_bit_identical(ctx4, monkeypatch):
         PrefixCache, "propose_continuations", decoy_first
     )
     eng = Engine(
-        model, temperature=0.0, paged=True, page_size=16,
+        own_model, temperature=0.0, paged=True, page_size=16,
         speculative=4, spec_width=4, prefix_cache=True,
     )
     out = eng.serve(np.asarray([p], np.int32), gen_len=g)[0, len(p):]
@@ -415,17 +412,7 @@ def test_tree_branch_accept_row_moves_bit_identical(ctx4, monkeypatch):
 # -- sampled replay + migration -------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def one_dev_model():
-    from triton_distributed_tpu.runtime import mesh as mesh_mod
-
-    ctx = mesh_mod.initialize_distributed(tp=1, devices=jax.devices()[:1])
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx)
-    yield model
-    mesh_mod.finalize_distributed()
-
-
-def test_tree_sampled_replay_and_migration_bit_exact(one_dev_model):
+def test_tree_sampled_replay_and_migration_bit_exact(own_model):
     """Seeded-sampled decode with trees ON is reproducible and survives
     a mid-flight slot migration bit-exactly: the sampled walk draws one
     key per EMITTED token (draft-shape independent), and the snapshot
@@ -445,7 +432,7 @@ def test_tree_sampled_replay_and_migration_bit_exact(one_dev_model):
     work = list(zip(prompts, gens))
 
     def fresh():
-        eng = ContinuousEngine(one_dev_model, **kw)
+        eng = ContinuousEngine(own_model, **kw)
         assert eng._spec_tree
         return eng
 
@@ -472,10 +459,9 @@ def test_tree_sampled_replay_and_migration_bit_exact(one_dev_model):
 # -- fault seams on the tree path -----------------------------------------
 
 
-def _tree_engine(ctx, **kw):
+def _tree_engine(model, **kw):
     from triton_distributed_tpu.models.continuous import ContinuousEngine
 
-    model = AutoLLM.from_pretrained("tiny", ctx=ctx, max_length=128)
     kw.setdefault("max_batch", 1)
     kw.setdefault("page_size", 16)
     kw.setdefault("max_length", 128)
@@ -485,13 +471,13 @@ def _tree_engine(ctx, **kw):
     return model, ContinuousEngine(model, **kw)
 
 
-def test_tree_verify_fault_isolated(ctx4):
+def test_tree_verify_fault_isolated(own_model):
     """A tree verify that raises fails only its own request; the engine
     serves the next request normally and every audit stays clean (the
     failed slot's un-committed tree rows are reclaimed wholesale)."""
     from triton_distributed_tpu.runtime.faults import FaultPlan
 
-    model, eng = _tree_engine(ctx4)
+    model, eng = _tree_engine(own_model)
     rep = np.asarray(MOTIF * 4, np.int32)
     gold = golden(model, list(rep), 8)
     eng.run([(rep, 8)])  # warm the radix so verifies run on trees
@@ -504,13 +490,13 @@ def test_tree_verify_fault_isolated(ctx4):
     assert all(n.refcount == 0 for n in eng.prefix.walk())
 
 
-def test_tree_verify_nan_logits_guarded(ctx4):
+def test_tree_verify_nan_logits_guarded(own_model):
     """Non-finite logits in a tree-verify chunk fail that request with
     a structured ``nan_logits`` — never argmax'd into accepted tokens,
     and never a poisoned pool."""
     from triton_distributed_tpu.runtime.faults import FaultPlan
 
-    model, eng = _tree_engine(ctx4)
+    model, eng = _tree_engine(own_model)
     rep = np.asarray(MOTIF * 4, np.int32)
     gold = golden(model, list(rep), 8)
     eng.run([(rep, 8)])
@@ -532,13 +518,13 @@ def test_tree_verify_nan_logits_guarded(ctx4):
 # -- observability ---------------------------------------------------------
 
 
-def test_tree_metrics_exposed_on_the_wire(ctx4):
+def test_tree_metrics_exposed_on_the_wire(own_model):
     """Acceptance (ISSUE 16): the tree counters, the ``tdt_spec_*``
     counter aliases for the draft/rollback ledger, and the accept-rate
     gauge all surface through ``{"cmd": "metrics"}``."""
     from triton_distributed_tpu.serving.server import ModelServer, request
 
-    _model, eng = _tree_engine(ctx4, max_batch=2)
+    _model, eng = _tree_engine(own_model, max_batch=2)
     server = ModelServer(eng).start()
     try:
         prompt = (MOTIF * 4)
