@@ -384,11 +384,11 @@ def _site_step_guard(eng, spy, greedy):
     spy.mutator(eng, "_teardown_slot")
     process, calls = eng._process, []
 
-    def raising(slot_tokens):
+    def raising(slot_tokens, *after):
         calls.append(1)
         if len(calls) == 2:
             raise RuntimeError("injected emit fault")
-        return process(slot_tokens)
+        return process(slot_tokens, *after)
     spy.patch.setattr(eng, "_process", raising)
     return [(LONE, 10), (prompt(1, 5), 10)], ["failed"] * 2
 
@@ -465,3 +465,91 @@ def test_engagement_rate_and_counters(own_model, fresh_telemetry):
     finally:
         request(server.host, server.port, {"cmd": "shutdown"})
         server.shutdown()
+
+
+# -- (f) the schedule: the rows a step carried, what stood in a token's gap --
+
+
+def schedule(snap) -> tuple:
+    """``(rows histogram, {after: count of gaps})`` of a registry
+    snapshot (docs/observability.md "Metric catalog")."""
+    (rows,) = snap["tdt_engine_step_rows"]["series"]
+    gaps = {s["labels"]["after"]: s["count"]
+            for s in snap["tdt_engine_token_gap_seconds"]["series"]}
+    return rows, gaps
+
+
+def _one_greedy(model):
+    # 10 steps for one row: the first from the host's tokens, nine
+    # looked ahead to. The row's own admission stands in none of its
+    # gaps: the first starts at its first token.
+    return engine(model), [(prompt(1, 40), 11)], {"serial": 1, "ahead": 9}
+
+
+def _queue_longer_than_the_slots(model):
+    # Four requests (5, 2, 4, 1 tokens) on two slots. Round 1: the
+    # first row waited behind the second's admission, whose own gap
+    # holds only the serial step. The second ends, nobody looked ahead
+    # over an end that the third waits for, and round 2 is round 1
+    # again with the third. Rounds 3 and 4 were looked ahead to and end
+    # both rows; the fourth request ends at its first token.
+    return engine(model), MIXED, {"admit": 2, "serial": 2, "ahead": 4}
+
+
+def _sampled_row(model):
+    # A sampled row keeps every round serial while it lives.
+    return (engine(model, seed=7),
+            [Request(LONE, 6, temperature=0.8, top_k=20)], {"serial": 5})
+
+
+def _chunked_admission(model):
+    # The second prompt's 24 tokens go in as chunks of 16: the running
+    # row's round between the two chunks and its first round after the
+    # admission both had a chunk in their gap; the admitted row's own
+    # first gap has none, only the serial step of the round after it.
+    # Every later round was looked ahead to (the running row's other
+    # five tokens, the admitted row's last).
+    eng = engine(model, prefix_cache=True, prefill_chunk=16)
+    return (eng, [(LONE, 8), (prompt(1, 5), 3)],
+            {"admit": 2, "serial": 1, "ahead": 6})
+
+
+@pytest.mark.parametrize("case", [_one_greedy, _queue_longer_than_the_slots,
+                                  _sampled_row, _chunked_admission])
+def test_schedule_histograms_hold_their_identities(own_model, fresh_telemetry,
+                                                   case):
+    """One observation a launched step, of its rows: as many as decode
+    steps, summing to the decoded tokens plus the discarded ones. One a
+    decoded token, by what stood in its gap: as many as decoded tokens."""
+    eng, reqs, want = case(own_model)
+    got, stats = run(eng, reqs)
+    decoded = sum(len(t) - 1 for t, _ in got)
+    rows, gaps = schedule(obs_metrics.default_registry().snapshot())
+    assert rows["count"] == stats["decode_steps"]
+    assert rows["sum"] == decoded + stats["lookahead_discarded"]
+    # One edge a row: a bucket holds the steps of exactly that many.
+    edges, counts = rows["buckets"]["edges"], rows["buckets"]["counts"]
+    assert edges == list(range(1, 129))
+    assert sum(e * c for e, c in zip(edges, counts)) == rows["sum"]
+    assert sum(gaps.values()) == decoded
+    assert gaps == want
+
+
+def test_a_rows_gap_is_its_own(own_model, fresh_telemetry):
+    """A row admitted a moment ago is not charged the running row's
+    whole period: its first gap starts at its own first token."""
+    eng = engine(own_model)
+    stamps = []
+    admit = eng._admit
+
+    def slow_admit(req, slot, m=None):
+        first = admit(req, slot, m)
+        stamps.append(list(eng._tok_t))
+        return first
+    eng._admit = slow_admit
+    run(eng, [(LONE, 4), (prompt(1, 5), 4)])
+    # At the second admission the first row's stamp is its first
+    # token's, earlier than the clock the second row's is set from.
+    assert stamps[0] == [None, None]
+    assert stamps[1][0] is not None and stamps[1][1] is None
+    assert eng._tok_t[1] > stamps[1][0]

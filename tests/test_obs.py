@@ -556,11 +556,21 @@ def test_outputs_bit_identical_with_telemetry_off(own_model):
     prompts = [([5, 9, 2, 4], 8), ([7, 1, 3, 8, 6, 2], 6)]
     _m1, e1 = _tiny_continuous(own_model, prefix_cache=True, prefill_chunk=16)
     on = [o.tolist() for o in e1.run(prompts)]
+    schedule = ("tdt_engine_step_rows", "tdt_engine_token_gap_seconds")
+    snap = obs_metrics.default_registry().snapshot()
+    before = {name: snap[name] for name in schedule}
+    assert sum(s["count"] for s in before[schedule[1]]["series"]) == 7 + 5
     obs.set_enabled(False)
     _m2, e2 = _tiny_continuous(own_model, prefix_cache=True, prefill_chunk=16)
     off = [o.tolist() for o in e2.run(prompts)]
     obs.set_enabled(True)
     assert on == off
+    # Neither histogram of the schedule moved, and no clock was read
+    # for them: every stamp the second engine took is None.
+    snap = obs_metrics.default_registry().snapshot()
+    assert {name: snap[name] for name in schedule} == before
+    assert e1._admit_t is not None and None not in e1._tok_t
+    assert e2._admit_t is None and e2._tok_t == [None, None]
 
 
 # -- server integration ------------------------------------------------------

@@ -35,6 +35,7 @@ SPANS = {
     "engine:admit": "scheduler:batch",
     "engine:decode_round": "scheduler:batch",
     "engine:dispatch": "engine:decode_round",
+    "engine:serial_launch": "engine:dispatch",
     "engine:fetch": "engine:decode_round",
     "engine:sample_emit": "engine:decode_round",
     "engine:audit": "scheduler:batch",
@@ -232,6 +233,9 @@ def test_every_program_of_the_served_payload_has_a_name_of_its_own(session):
 # that only deletes code no served configuration reaches leaves them.
 ROUND = ["engine:decode_round", "engine:dispatch", "engine:fetch",
          "engine:sample_emit"]
+# A round that found no step parked launches its own (since PR 39 under
+# a span of its own; the order of everything else is the parent's).
+SERIAL = ROUND[:2] + ["engine:serial_launch"] + ROUND[2:]
 ADMIT = ["engine:admit", "prefix_cache:admit", "prefix_cache:chunk"]
 PARENT = {
     # name -> programs compiled under it (one a signature).
@@ -246,10 +250,12 @@ PARENT = {
                  "tdt_kv_copy_page": 1},
     # The one traced batch, in start order: both admissions (the second
     # prompt's three chunks step the first request between them), then
-    # the rounds the longer generation needs, then the audit.
-    "spans": (["scheduler:batch"] + ADMIT + ADMIT + ROUND
-              + ["prefix_cache:chunk"] + ROUND + ["prefix_cache:chunk"]
-              + ROUND * 9 + ["engine:audit"]),
+    # the rounds the longer generation needs, then the audit. Serial:
+    # the two rounds between chunks (an admission is mid-prefill) and
+    # the first after it; the other eight were looked ahead to.
+    "spans": (["scheduler:batch"] + ADMIT + ADMIT + SERIAL
+              + ["prefix_cache:chunk"] + SERIAL + ["prefix_cache:chunk"]
+              + SERIAL + ROUND * 8 + ["engine:audit"]),
 }
 
 

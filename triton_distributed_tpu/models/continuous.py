@@ -351,6 +351,7 @@ class _StepLaunch:
     toks: object        # [max_batch] device greedy tokens
     counts: object = None  # device int32 sums: ``model.step_counts``
     host: tuple | None = None
+    ahead: bool = False  # dispatched before the step before's fetch
 
     def fetch(self) -> tuple:
         """THE host sync of a step: ``(finite, tokens)`` as numpy,
@@ -771,6 +772,27 @@ class ContinuousEngine(MegaDispatch):
             "tdt_migration_fallbacks_total",
             "Snapshot imports that fell back to replay-from-prompt.",
         )
+        # The schedule (docs/observability.md "Metric catalog"): the
+        # rows each decode step carried, one edge a row so that a reader
+        # can weight a bucket by its rows exactly, and every decoded
+        # token's gap by what stood in it.
+        self._m_step_rows = obs_metrics.histogram(
+            "tdt_engine_step_rows",
+            "Rows in flight of each decode step launched.",
+            buckets=tuple(range(1, 129)),
+        )
+        self._m_token_gap = obs_metrics.histogram(
+            "tdt_engine_token_gap_seconds",
+            "Gap before each decoded token of a row, by what stood in "
+            "it: an admission, a serially launched step, or nothing "
+            "but a looked-ahead step.",
+            labels=("after",),
+        )
+        # When each slot's row got its last token, and when an
+        # admission (or a chunk of one) last ended: a row whose stamp
+        # is the older had it in its gap. None: telemetry was off then.
+        self._tok_t: list = [None] * max_batch
+        self._admit_t: float | None = None
         ContinuousEngine._live.add(self)
 
     @staticmethod
@@ -910,6 +932,14 @@ class ContinuousEngine(MegaDispatch):
         self.stats[key] += n
         for handle in self._metric_handles[key]:
             handle.inc(n)
+
+    @staticmethod
+    def _gap_clock() -> float | None:
+        """Now, for a row's token stamp; None with telemetry off (no
+        clock is read for a histogram that would not move)."""
+        if obs_metrics.default_registry().enabled:
+            return time.monotonic()
+        return None
 
     def _finish_obs(self, req: Request) -> None:
         """Latch a request's terminal timeline stamp and fold it into
@@ -1144,6 +1174,7 @@ class ContinuousEngine(MegaDispatch):
             # bumped the in-flight slot's device counter.
             self.cache = cache
             self._kv_len[slot] = new_len
+            self._admit_t = self._gap_clock()  # a chunk stood in the gap
             if self._step_guard(self._decode_once):
                 # An interleaved decode finished (or failed) a request:
                 # its pages retired/released, and the device table must
@@ -1197,16 +1228,20 @@ class ContinuousEngine(MegaDispatch):
         step, self._pend = self._pend, None
         with trace_span("engine:dispatch", _ring=False):
             if step is None:
-                step = self._launch_step(
-                    self.model.ctx.replicate(self._tok.copy()), active,
-                    n_active
-                )
+                # A serial round: the device waits for this upload and
+                # launch, and by this span a trace's reader finds it.
+                with trace_span("engine:serial_launch", _ring=False):
+                    step = self._launch_step(
+                        self.model.ctx.replicate(self._tok.copy()), active,
+                        n_active
+                    )
             if self._may_look_ahead(step):
                 # Parked BEFORE the emit below: whatever that raises
                 # reaches the step guard with the in-flight step still
                 # owned, so ``_abort_pend`` blocks on it before the
                 # teardown frees pages it appends to.
                 self._pend = self._launch_step(step.toks, active, n_active)
+                self._pend.ahead = True
                 self._bump("lookahead_steps")
         return self._emit_step(step)
 
@@ -1276,6 +1311,7 @@ class ContinuousEngine(MegaDispatch):
         # and its first fetch raced the device's kv_len read).
         self._kv_len = self._kv_len + active
         self._bump("decode_steps")
+        self._m_step_rows.observe(n_active)
         if self._moe_k:
             self._bump("moe_routed_tokens", n_active * self._moe_k)
         # One device program computes the finite mask AND the greedy
@@ -1315,7 +1351,8 @@ class ContinuousEngine(MegaDispatch):
                         _ring=False):
             failed = self._guard_logits(finite)
             nxt = self._sample_slots(step.logits, toks)
-            changed = self._process(lambda slot: [nxt[slot]])
+            changed = self._process(lambda slot: [nxt[slot]],
+                                    "ahead" if step.ahead else "serial")
         return changed or bool(failed)
 
     def _guard_logits(self, finite: np.ndarray) -> list[int]:
@@ -1337,14 +1374,21 @@ class ContinuousEngine(MegaDispatch):
             failed.append(slot)
         return failed
 
-    def _process(self, slot_tokens) -> bool:
+    def _process(self, slot_tokens, after: str = "serial") -> bool:
         """Append per-slot tokens; evict on gen_len/eos. Returns whether
-        slot state changed."""
+        slot state changed. ``after`` says what stood in the gap before
+        these tokens (``tdt_engine_token_gap_seconds``): the round's
+        step was launched ``serial``ly, or looked ``ahead`` to; an
+        admission since a row's last token outranks both for that row."""
         finished = []
         emitted = 0
+        now = self._gap_clock()
+        since: dict = {}  # a row's last token's stamp -> rows with it
+        burst = 0  # tokens beyond a row's first of this call
         for slot, req in enumerate(self._slots):
             if req is None:
                 continue
+            before = emitted
             for t in slot_tokens(slot):
                 req.out.append(int(t))
                 self._emit_token(req)
@@ -1355,8 +1399,24 @@ class ContinuousEngine(MegaDispatch):
                 if self._ends(req, int(t)):
                     finished.append(req)
                     break
+            if emitted > before:
+                last, self._tok_t[slot] = self._tok_t[slot], now
+                if now is not None and last is not None:
+                    since[last] = since.get(last, 0) + 1
+                    burst += emitted - before - 1
         if emitted:
             self._bump("generated_tokens", emitted)
+            # The gap is the ROW's own: rows of one stamp (all that
+            # were in the round before) go in together; what a burst
+            # holds beyond a row's first token follows it at once.
+            admit_t = self._admit_t
+            for last, rows in since.items():
+                self._m_token_gap.observe_n(
+                    now - last, rows,
+                    after="admit" if admit_t is not None and last < admit_t
+                    else after)
+            if burst:
+                self._m_token_gap.observe_n(0.0, burst, after=after)
         # Every slot's frames are out. A step looked ahead to is still
         # appending to the finished slots' pages: it stays parked, the
         # slots' tokens in it are dropped at its own drain, and the
@@ -1626,8 +1686,7 @@ class ContinuousEngine(MegaDispatch):
         toks = np.concatenate(
             [req.prompt, np.asarray(req.out[:gen_cached], np.int32)]
         )
-        with trace_span("prefix_cache:retire", tokens=len(toks)):
-            self.prefix.retire_sequence(toks, req.pages, req.shared_nodes)
+        self.prefix.retire_sequence(toks, req.pages, req.shared_nodes)
         req.shared_nodes = []
 
     # -- durable KV tier (docs/serving.md "Tiered KV") --------------------
@@ -1966,7 +2025,6 @@ class ContinuousEngine(MegaDispatch):
         )
 
         bursts: dict[int, list[int]] = {}
-        rolled_total = drafted_total = accepted_total = 0
         any_failed = False
         for slot, req in enumerate(self._slots):
             if req is None or slot not in drafts:
@@ -1978,12 +2036,6 @@ class ContinuousEngine(MegaDispatch):
                 if self._spec_tree_slot(req, slot, draft, kv, t, p, k,
                                         bursts):
                     any_failed = True
-                else:
-                    drafted_total += draft.num_drafted
-                    accepted_total += len(bursts[slot]) - 1
-                    rolled_total += (
-                        draft.num_drafted - (len(bursts[slot]) - 1)
-                    )
                 continue
             # One per-request subkey per verify (the internal
             # accept/resample splits derive from it) — the draw
@@ -2032,23 +2084,14 @@ class ContinuousEngine(MegaDispatch):
             self._bump("spec_draft_tokens", len(draft))
             self._bump("spec_accepted_tokens", a)
             self._bump("spec_rollback_tokens", len(draft) - a)
-            drafted_total += len(draft)
-            accepted_total += a
-            rolled_total += len(draft) - a
             self._kv_len[slot] = kv + a + 1
             bursts[slot] = emitted
         changed = self._process(lambda slot: bursts.get(slot, []))
         # Every verify left the device kv_len at the chunk's end
         # (accepted + rejected rows); resyncing the host table rolls the
         # rejected tail back and drops any evicted/failed slot's pages
-        # in one write. The round's accept rate rides the span as a
-        # NATIVE float (trace_span keeps numbers numeric in the event
-        # ring; only the profiler's metadata may stringify).
-        with trace_span(
-            "spec:rollback", tokens=rolled_total,
-            accept_rate=accepted_total / max(drafted_total, 1),
-        ):
-            self._sync_tables()
+        # in one write.
+        self._sync_tables()
         self._spec_accept_gauge.set(
             self.stats["spec_accepted_tokens"]
             / max(self.stats["spec_draft_tokens"], 1)
@@ -2216,6 +2259,10 @@ class ContinuousEngine(MegaDispatch):
                     self._admit_failure(req, m, e)
                     progress = True
                     break
+                # The row's first token (a resumed one's last): its
+                # next gap starts here, behind its own admission, which
+                # stood in the gap of every row admitted before it.
+                self._admit_t = self._tok_t[slot] = self._gap_clock()
                 if first is None:
                     # Snapshot path: either resumed mid-generation (its
                     # pending token is already out[-1]) or failed inside
@@ -2544,6 +2591,7 @@ class ContinuousEngine(MegaDispatch):
         if plan.filtered:
             self._bump("mega_filtered_rounds")
         self._bump("decode_steps", NS)
+        self._m_step_rows.observe_n(n_active, NS)
         if self._moe_k:
             self._bump("moe_routed_tokens", NS * n_active * self._moe_k)
         self._bump("mega_launches")

@@ -769,7 +769,6 @@ class Engine(MegaDispatch):
             spec_verify_slot,
             spec_verify_tree,
         )
-        from triton_distributed_tpu.runtime.profiling import trace_span
 
         b = len(first_toks)
         kv = true_lens.astype(np.int64).copy()
@@ -829,9 +828,7 @@ class Engine(MegaDispatch):
             new_kv = int(kv[i]) + a + 1
             if a < len(draft):
                 counters["spec_rollback_tokens"] += len(draft) - a
-                with trace_span("spec:rollback", slot=i,
-                                tokens=len(draft) - a):
-                    cache = rollback_kv(cache, i, new_kv)
+                cache = rollback_kv(cache, i, new_kv)
             kv[i] = new_kv
             states[i].observe(emitted)
             outs[i].extend(emitted)
@@ -873,9 +870,7 @@ class Engine(MegaDispatch):
             new_kv = int(kv[i]) + a + 1
             if a < tr.num_drafted:
                 counters["spec_rollback_tokens"] += tr.num_drafted - a
-                with trace_span("spec:rollback", slot=i,
-                                tokens=tr.num_drafted - a):
-                    cache = rollback_kv(cache, i, new_kv)
+                cache = rollback_kv(cache, i, new_kv)
             kv[i] = new_kv
             states[i].observe(emitted)
             outs[i].extend(emitted)

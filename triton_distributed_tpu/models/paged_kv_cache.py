@@ -631,16 +631,13 @@ def write_prefill(
     row = cache.page_table[b_idx]
     kv_len = cache.kv_len.at[b_idx].set(jnp.asarray(true_len, jnp.int32))
     if cache.quantized:
-        from triton_distributed_tpu.runtime.profiling import trace_span
-
         tl = jnp.asarray(true_len, jnp.int32)
-        with trace_span("kv:quant", op="write_prefill", pages=2 * npages):
-            k_pages, k_scale = tdt_kv_scatter_q(
-                cache.k_pages, cache.k_scale, k_dense, row, tl, npages, page
-            )
-            v_pages, v_scale = tdt_kv_scatter_q(
-                cache.v_pages, cache.v_scale, v_dense, row, tl, npages, page
-            )
+        k_pages, k_scale = tdt_kv_scatter_q(
+            cache.k_pages, cache.k_scale, k_dense, row, tl, npages, page
+        )
+        v_pages, v_scale = tdt_kv_scatter_q(
+            cache.v_pages, cache.v_scale, v_dense, row, tl, npages, page
+        )
         return PagedKVCache(
             k_pages=k_pages, v_pages=v_pages, page_table=cache.page_table,
             kv_len=kv_len, k_scale=k_scale, v_scale=v_scale,

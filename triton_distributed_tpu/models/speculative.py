@@ -68,7 +68,6 @@ from triton_distributed_tpu.models.paged_kv_cache import gather_bucket
 from triton_distributed_tpu.models.prefix_cache import round_chunk
 from triton_distributed_tpu.obs import events as obs_events
 from triton_distributed_tpu.runtime.faults import fault_point, mutate_point
-from triton_distributed_tpu.runtime.profiling import trace_span
 
 
 class NGramDraft:
@@ -333,12 +332,10 @@ def spec_verify_slot(
     buf = np.zeros(c, np.int32)
     buf[:n] = toks
     kv_pages = gather_bucket(int(kv_len) + c, page, pps)
-    with trace_span("spec:verify", slot=slot, drafted=len(draft),
-                    offset=int(kv_len), _ring=False):
-        logits, cache = model.prefill_paged_chunk(
-            buf, slot, int(kv_len), int(kv_len) + n, n - 1, cache, mode,
-            kv_pages=kv_pages, all_logits=True,
-        )
+    logits, cache = model.prefill_paged_chunk(
+        buf, slot, int(kv_len), int(kv_len) + n, n - 1, cache, mode,
+        kv_pages=kv_pages, all_logits=True,
+    )
     arr = np.asarray(logits[:n], np.float32)
     arr = mutate_point("spec.logits", arr, slot=slot)
     if not np.isfinite(arr).all():
@@ -358,7 +355,7 @@ def spec_verify_slot(
             arr, draft, key, temperature, top_p, top_k
         )
     # One emit site covers both engines (each verify chunk routes
-    # through here); rollbacks surface via the spec:rollback span.
+    # through here); rollbacks count in ``spec_rollback_tokens``.
     obs_events.emit(
         "spec_verify", slot=slot, drafted=len(draft), accepted=accepted
     )
@@ -566,13 +563,11 @@ def spec_verify_tree(
     buf = np.zeros(c, np.int32)
     buf[:n] = tree.tokens
     kv_pages = gather_bucket(int(kv_len) + c, page, pps)
-    with trace_span("spec:tree", slot=slot, nodes=tree.num_drafted,
-                    depth=tree.max_depth, offset=int(kv_len), _ring=False):
-        logits, cache = model.prefill_paged_chunk(
-            buf, slot, int(kv_len), int(kv_len) + n, n - 1, cache, mode,
-            kv_pages=kv_pages, all_logits=True,
-            tree_mask=tree.mask(c), tree_depth=tree.depths(c),
-        )
+    logits, cache = model.prefill_paged_chunk(
+        buf, slot, int(kv_len), int(kv_len) + n, n - 1, cache, mode,
+        kv_pages=kv_pages, all_logits=True,
+        tree_mask=tree.mask(c), tree_depth=tree.depths(c),
+    )
     arr = np.asarray(logits[:n], np.float32)
     arr = mutate_point("spec.logits", arr, slot=slot)
     if not np.isfinite(arr).all():
