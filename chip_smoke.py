@@ -470,7 +470,7 @@ def latent_phase() -> None:
 
 def hybrid_phase() -> None:
     """The Mamba-2 / attention hybrid: ``tdt_ssm_decode`` against the
-    plain einsums at the preset's widths (some rows in flight, the
+    plain sums at the preset's widths (some rows in flight, the
     others' state bit for bit as it was), then one server lifetime at
     ``HYBRID["slots"]`` decode slots: as many requests as slots, a
     repeat (no radix hit: a page holds no recurrent state), stats and
@@ -487,18 +487,18 @@ def hybrid_phase() -> None:
     b, h, p, n = (HYBRID["slots"], cfg.mamba_n_heads, cfg.mamba_d_head,
                   cfg.mamba_d_state)
     ks = jax.random.split(jax.random.key(SEED), 5)
-    state = jax.random.normal(ks[0], (2, b, h, p, n), jnp.float32)
-    da = jax.random.uniform(ks[1], (b, h), jnp.float32, 0.5, 1.0)
-    dx = jax.random.normal(ks[2], (b, h, p), jnp.float32)
-    bv, cv = (jax.random.normal(k, (b, n), jnp.float32) for k in ks[3:])
+    state = jax.random.normal(ks[0], (2, b, n, h * p), jnp.float32)
+    a = jax.random.uniform(ks[1], (b, h), jnp.float32, 0.5, 1.0)
+    dt = jax.random.uniform(ks[2], (b, h), jnp.float32, 0.0, 1.0)
+    d = jax.random.normal(ks[3], (h,), jnp.float32)
+    xbc = jax.random.normal(ks[4], (b, h * p + 2 * n), jnp.float32)
     live = jnp.arange(b) % 3 != 1
-    with jax.default_matmul_precision("highest"):  # the golden's einsum
-        want_y, want_s = ssm_decode_reference(state[1], da, dx, bv, cv, live)
-    got_y, got_s = ssm_decode(state, da, dx, bv, cv, *live_rows(live),
+    want_y, want_s = ssm_decode_reference(state[1], a, dt, d, xbc, live)
+    got_y, got_s = ssm_decode(state, a, dt, d, xbc, *live_rows(live),
                               layer=1)
     errs = {"y": max_abs(got_y, want_y), "state": max_abs(got_s[1], want_s)}
     untouched = bool(jnp.array_equal(got_s[0], state[0]))
-    emit(phase="hybrid_kernel", state=[h, p, n], slots=b, tolerance=1e-3,
+    emit(phase="hybrid_kernel", state=[n, h * p], slots=b, tolerance=1e-3,
          max_abs_err=errs, other_layer_untouched=untouched)
     require(max(errs.values()) <= 1e-3 and untouched,
             f"ssm_decode: max abs err {errs}, untouched {untouched}")

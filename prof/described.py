@@ -56,8 +56,9 @@ def pool_shaped_moves(hlo_text: str, pool_shape, dtype: str = "bf16") -> list:
 
 
 def state_shaped_moves(hlo_text: str, state_shape) -> list:
-    """The same for a float32 recurrent state ``[Lm, B, H, P, N]``: the
-    whole of it, its ``[Lm * B, H, P, N]`` view, or one layer of it."""
+    """The same for a float32 recurrent state ``[Lm, B, ...]`` (granite's
+    ``[Lm, B, N, di]``): the whole of it, its ``[Lm * B, ...]`` view, or
+    one layer of it."""
     lm, b, *rest = state_shape
     shapes = {",".join(map(str, s))
               for s in (state_shape, (lm * b, *rest), (b, *rest))}

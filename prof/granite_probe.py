@@ -1,7 +1,9 @@
 """Chip probe of granite-4.0-h-micro whole on one chip: init time and
-memory, ``tdt_ssm_decode`` alone (against the plain einsum, and by the
+memory, ``tdt_ssm_decode`` alone (against the plain sums, and by the
 heads a grid step takes), a decode step's device time by operation at 32
-slots with 32, 13 and one row in flight, and the chunk widths' times.
+slots with 32, 13 and one row in flight (and, apart, every operation of
+the recurrent layers' scans beside the kernel), and the chunk widths'
+times.
 Run from the tree to probe.
 
     python prof/granite_probe.py <tag>
@@ -10,6 +12,7 @@ Run from the tree to probe.
 import dataclasses
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -52,21 +55,22 @@ def traced(fn):
 
 def kernel_alone(result, cfg):
     """The kernel over every recurrent layer in one scan, by rows in
-    flight and by heads a grid step, against the einsum."""
+    flight and by channels a grid step (16, 32 and 64 heads of 64),
+    against the plain sums."""
     lm, h, p, n = (cfg.mamba_layers, cfg.mamba_n_heads, cfg.mamba_d_head,
                    cfg.mamba_d_state)
     ks = jax.random.split(jax.random.key(0), 5)
-    state = jax.random.normal(ks[0], (lm, SLOTS, h, p, n), jnp.float32)
-    da = jax.random.uniform(ks[1], (SLOTS, h), jnp.float32, 0.5, 1.0)
-    dx = jax.random.normal(ks[2], (SLOTS, h, p), jnp.float32)
-    b = jax.random.normal(ks[3], (SLOTS, n), jnp.float32)
-    c = jax.random.normal(ks[4], (SLOTS, n), jnp.float32)
+    state = jax.random.normal(ks[0], (lm, SLOTS, n, h * p), jnp.float32)
+    a = jax.random.uniform(ks[1], (SLOTS, h), jnp.float32, 0.5, 1.0)
+    dt = jax.random.uniform(ks[2], (SLOTS, h), jnp.float32, 0.0, 1.0)
+    d = jax.random.normal(ks[3], (h,), jnp.float32)
+    xbc = jax.random.normal(ks[4], (SLOTS, h * p + 2 * n), jnp.float32)
 
     def scan_all(state, live):
         rows, cnt = ssm.live_rows(live)
 
         def one(st, layer):
-            y, st = ssm.ssm_decode(st, da, dx, b, c, rows, cnt, layer=layer)
+            y, st = ssm.ssm_decode(st, a, dt, d, xbc, rows, cnt, layer=layer)
             return st, jnp.sum(y)
 
         return jax.lax.scan(one, state, jnp.arange(lm, dtype=jnp.int32))
@@ -74,17 +78,17 @@ def kernel_alone(result, cfg):
     out = {}
     live_all = jnp.ones((SLOTS,), bool)
     state_bytes = 2 * h * p * n * 4
-    with jax.default_matmul_precision("highest"):  # the golden's einsum
-        want_y, want_s = ssm.ssm_decode_reference(
-            state[0], da, dx, b, c, live_all)
+    want_y, want_s = ssm.ssm_decode_reference(state[0], a, dt, d, xbc,
+                                              live_all)
     got_y, got_s = jax.jit(lambda s: ssm.ssm_decode(
-        s, da, dx, b, c, *ssm.live_rows(live_all), layer=0))(state)
+        s, a, dt, d, xbc, *ssm.live_rows(live_all), layer=0))(state)
     out["max_abs_err_y"] = float(jnp.max(jnp.abs(got_y - want_y)))
     out["max_abs_err_state"] = float(jnp.max(jnp.abs(got_s[0] - want_s)))
     out["untouched_layer_equal"] = bool(jnp.array_equal(got_s[1], state[1]))
     del got_s
-    for heads in ((16, 64) if not REH else (h,)):
-        ssm.HEADS = heads
+    block = ssm.BLOCK
+    for heads in ((16, 32, 64) if not REH else (h,)):
+        ssm.BLOCK = heads * p
         for live_n in (SLOTS, min(13, SLOTS), 1, 0):
             live = jnp.arange(SLOTS) < live_n
             f = jax.jit(scan_all, donate_argnums=(0,))
@@ -105,9 +109,22 @@ def kernel_alone(result, cfg):
             out[f"heads{heads}_rows{live_n}"] = {
                 "ms_a_layer": sec / lm * 1e3,
                 "gb_s": live_n * state_bytes * lm / sec / 1e9 if sec else 0}
-    ssm.HEADS = 16
+    ssm.BLOCK = block
     result["kernel"] = out
     print("kernel", json.dumps(out), flush=True)
+
+
+def recurrent_bodies(hlo_text: str) -> set:
+    """Names of the instructions of the decode program's recurrent layer
+    scans (the computations that call ``tdt_ssm_decode``), the kernel's
+    own included: what a trace's operations are matched against."""
+    names = set()
+    for comp in re.split(r"\n(?=\S)", hlo_text):
+        if "tdt_ssm_decode" not in comp:
+            continue
+        names.update(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = ", comp,
+                                re.MULTILINE))
+    return names
 
 
 def main():
@@ -146,6 +163,9 @@ def main():
     logits, cache, counts = model.decode_step_counted(tokens, cache, "xla")
     np.asarray(logits)
     result["decode_compile_s"] = round(time.perf_counter() - t0, 2)
+    (step_jit,) = model._decode_jit.values()
+    body = recurrent_bodies(step_jit.lower(
+        model.params, tokens, cache).compile().as_text())
     result["after_decode"] = mem()
     print("decode compiled", result["decode_compile_s"], mem(), flush=True)
     for name, live_n in (("rows32", SLOTS), ("rows13", min(13, SLOTS)),
@@ -184,8 +204,12 @@ def main():
         attn = sum(v for k, v in own.items() if "tdt_flash_decode_paged" in k)
         moved = [k for k in own if "dynamic_update_slice" not in k
                  and "tdt_" not in k
-                 and ("f32[36,32,64,64,128]" in k
-                      or "f32[1152,64,64,128]" in k or "bf16[4,1025" in k)]
+                 and ("f32[36,32,128,4096]" in k
+                      or "f32[1152,128,4096]" in k or "bf16[4,1025" in k)]
+        # The recurrent layers' scans by operation, the kernel apart:
+        # what stands beside it in every step at any load.
+        mine = {k: v for k, v in own.items() if k.split(" ")[0] in body}
+        beside = {k: v for k, v in mine.items() if "tdt_ssm_decode" not in k}
         result[name] = {
             "mean_len": float(lens.sum() / max(live_n, 1)),
             "step_ms_median": durs[len(durs) // 2] / 1e6,
@@ -197,6 +221,11 @@ def main():
             "attn_kernel_ms_a_step": attn / STEPS * 1e3,
             "rows_last_step": np.asarray(holder["n"]).tolist(),
             "state_or_pool_shaped_ops": moved,
+            "recurrent_beside_kernel_ms_a_step": sum(beside.values())
+            / STEPS * 1e3,
+            "recurrent_ops_ms_a_step": {
+                k: round(v / STEPS * 1e3, 4) for k, v in
+                sorted(beside.items(), key=lambda kv: -kv[1])},
             "ops_ms_a_step": {k: round(v / STEPS * 1e3, 4) for k, v in
                               sorted(own.items(), key=lambda kv: -kv[1])[:28]},
         }

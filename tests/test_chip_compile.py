@@ -21,6 +21,7 @@ refuses on bf16 operands and which no chip run sets.
 import functools
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -381,25 +382,26 @@ def test_latent_share_fits_the_chip(chip, dots_share, program):
 
 GRANITE = "ibm-granite/granite-4.0-h-micro"
 GRANITE_SLOTS = 32
-STATE = (36, GRANITE_SLOTS, 64, 64, 128)
+STATE = (36, GRANITE_SLOTS, 128, 4096)
 
 
 def test_ssm_decode_kernel(chip):
     """``tdt_ssm_decode`` at the published widths: 32 slots' states of
-    36 layers, aliased: the program holds no second state, whole or a
-    layer's."""
+    36 layers, each ``[N, di]`` = ``[128, 4096]``, aliased: the program
+    holds no second state, whole or a layer's. Its operands are the
+    mixer's own: the per-head numbers ``[32, 64]`` and the convolution's
+    output ``x | B | C`` ``[32, 4352]``."""
     from triton_distributed_tpu.ops.ssm.decode import ssm_decode
 
     f32 = jnp.float32
     compiled = jax.jit(
-        lambda s, da, dx, b, c, rows, n, layer: ssm_decode(
-            s, da, dx, b, c, rows, n, layer=layer),
+        lambda s, a, dt, d, xbc, rows, n, layer: ssm_decode(
+            s, a, dt, d, xbc, rows, n, layer=layer),
         donate_argnums=(0,),
     ).lower(
         sds(chip, STATE, f32), sds(chip, (GRANITE_SLOTS, 64), f32),
-        sds(chip, (GRANITE_SLOTS, 64, 64), f32),
-        sds(chip, (GRANITE_SLOTS, 128), f32),
-        sds(chip, (GRANITE_SLOTS, 128), f32),
+        sds(chip, (GRANITE_SLOTS, 64), f32), sds(chip, (64,), f32),
+        sds(chip, (GRANITE_SLOTS, 4096 + 2 * 128), f32),
         sds(chip, (GRANITE_SLOTS,), jnp.int32), sds(chip, (), jnp.int32),
         sds(chip, (), jnp.int32)).compile()
     text = compiled.as_text()
@@ -483,6 +485,11 @@ def test_hybrid_fits_the_chip_and_moves_neither_state_nor_pool(
         "tdt_flash_decode_paged" in text and "tdt_ssm_decode" in text)
     assert not _pool_shaped_moves(text, pool, "bf16")
     assert not _described().state_shaped_moves(text, ssm)
+    if program == "decode":
+        # The state is [N, di] a slot and every operand of the kernel a
+        # lane-dense row: no per-head column padded from 16 lanes to 128
+        # (``[32, 4, 64, 16]``) is made on the way into or out of it.
+        assert not re.findall(r"f32\[(?:\d+,)*16\]", text)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk48", "chunk1024"])

@@ -151,7 +151,7 @@ def test_latent_phase_at_tiny_size_when_steered(steered, monkeypatch, capsys):
 
 def test_hybrid_phase_at_tiny_size_when_steered(steered, monkeypatch, capsys):
     """``--modes hybrid`` at the `tiny-hybrid` preset: the state kernel
-    against the plain einsums, then the preset served on every slot;
+    against the plain sums, then the preset served on every slot;
     the rows advanced are the decoded tokens (and the rows of steps in
     flight under a slot that had ended)."""
     monkeypatch.setattr(steered, "HYBRID", {"model": "tiny-hybrid",

@@ -15,10 +15,17 @@ channels in ``H`` heads of ``P``, a state of ``N`` a channel, ONE group:
     out = (rms(y * silu(z)) * w) W_out       the norm over all of di
 
 A slot keeps ``S`` (float32) and the convolution's last ``taps - 1``
-inputs between programs (``PagedKVCache.ssm_state [Lm, B, H, P, N]`` /
+inputs between programs (``PagedKVCache.ssm_state [Lm, B, N, di]`` /
 ``conv_state [Lm, taps - 1, B, C]``, indexed by (recurrent layer, slot) and addressed in place off the layer
-scan's carry). A decode step moves them by one position for the rows IN
-FLIGHT (:func:`mamba2_decode`, the state through ``tdt_ssm_decode``); a
+scan's carry). ``S`` is kept TRANSPOSED, ``S^T [N, di]`` with the inner
+channels in ``h * P + p`` order on the lanes: every per-channel vector
+of the mixer (``x``, ``y``, ``z``) is a lane-dense row of ``di``, ``B``
+and ``C`` are columns of ``N``, and the numbers a head (``delta``,
+``exp(delta A)``, ``D``) are spread over the head's ``P`` lanes inside
+the kernel; nothing is relaid out on the way into or out of it
+(ops/ssm/decode.py). A decode step moves the state and the
+convolution's inputs by one position for the rows IN FLIGHT
+(:func:`mamba2_decode`, the state through ``tdt_ssm_decode``); a
 prefill chunk computes the same recurrence in its chunked
 (state-space-duality) form as batched matrix products
 (:func:`mamba2_chunk`): inside a block of ``chunk`` positions ``Y = ((C
@@ -107,9 +114,9 @@ def _conv(p: Mamba2Params, window, m: Mamba2Dims, dtype):
 
 
 def _split_xbc(xbc, m: Mamba2Dims):
-    x, b, c = jnp.split(xbc.astype(jnp.float32),
-                        [m.inner, m.inner + m.state], axis=-1)
-    return x.reshape(*x.shape[:-1], m.heads, m.head_dim), b, c
+    """``x [.., di]``, ``B``, ``C`` ``[.., N]``, float32."""
+    return jnp.split(xbc.astype(jnp.float32), [m.inner, m.inner + m.state],
+                     axis=-1)
 
 
 def _delta(p: Mamba2Params, dt):
@@ -117,9 +124,9 @@ def _delta(p: Mamba2Params, dt):
 
 
 def _gated_out(p: Mamba2Params, y, z, m: Mamba2Dims, dtype):
-    """``y [T, H, P]`` f32 (the skip included) through the gated norm
-    and the output projection: ``[T, d]``."""
-    g = y.reshape(y.shape[0], m.inner) * jax.nn.silu(z.astype(jnp.float32))
+    """``y [T, di]`` f32 (the skip included) through the gated norm and
+    the output projection: ``[T, d]``."""
+    g = y * jax.nn.silu(z.astype(jnp.float32))
     g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + m.eps)
     g = (g * p.norm.astype(jnp.float32)).astype(dtype)
     return jnp.dot(g, p.w_out, preferred_element_type=jnp.float32).astype(
@@ -141,27 +148,31 @@ def mamba2_decode(p: Mamba2Params, u, state, layer, m: Mamba2Dims, *,
     conv = jax.lax.dynamic_update_slice(
         conv, jnp.where(live[None, :, None], window[1:], tail)[None],
         (layer, 0, 0, 0))                               # [taps - 1, B, C]
-    x, b, c = _split_xbc(_conv(p, window, m, u.dtype), m)
     delta = _delta(p, dt)                               # [B, H]
     y, ssm = ssm_decode(
-        ssm, jnp.exp(-delta * jnp.exp(p.a_log)), delta[:, :, None] * x, b, c,
-        rows, n, layer=layer)
-    y = y + p.d_skip[None, :, None] * x
+        ssm, jnp.exp(-delta * jnp.exp(p.a_log)), delta, p.d_skip,
+        _conv(p, window, m, u.dtype).astype(jnp.float32), rows, n,
+        layer=layer)
     return _gated_out(p, y, z, m, u.dtype), (ssm, conv)
 
 
 def ssd_chunked(x, delta, a, b, c, s0, block: int):
     """The recurrence over ``T`` positions of one sequence in its
-    chunked form, all float32: ``x [T, H, P]``, ``delta [T, H]`` (nought
-    at a position that must not move the state), ``a [H]`` (negative),
-    ``b``, ``c`` ``[T, N]``, ``s0 [H, P, N]`` the state before. ``T`` is
-    a multiple of ``block``. Returns ``(y [T, H, P]`` without the skip,
-    the state after)``."""
-    t, h, p = x.shape
+    chunked form, all float32: ``x [T, di]`` (``H`` heads of ``P``),
+    ``delta [T, H]`` (nought at a position that must not move the
+    state), ``a [H]`` (negative), ``b``, ``c`` ``[T, N]``, ``s0 [N, di]``
+    the state before, transposed as the cache keeps it. ``T`` is a
+    multiple of ``block``. Returns ``(y [T, di]`` without the skip, the
+    state after, ``[N, di]``)``. The blocks work head by head, on the
+    state as ``[H, P, N]``: one slot's state is stood up on the way in
+    and laid back down on the way out."""
+    t, di = x.shape
+    h = delta.shape[1]
+    p, n = di // h, s0.shape[0]
     nb, q = t // block, block
     cum = jnp.cumsum((delta * a).reshape(nb, q, h), axis=1)   # [nb, q, H]
     cum_h = cum.swapaxes(1, 2)                                # [nb, H, q]
-    xdt = (x * delta[:, :, None]).reshape(nb, q, h, p)
+    xdt = (x.reshape(t, h, p) * delta[:, :, None]).reshape(nb, q, h, p)
     bc, cc = b.reshape(nb, q, -1), c.reshape(nb, q, -1)
     # Inside a block: ((C B^T) * L) (delta x).
     seen = jnp.tril(jnp.ones((q, q), bool))
@@ -178,10 +189,11 @@ def ssd_chunked(x, delta, a, b, c, s0, block: int):
         add, dec = inp
         return dec[:, None, None] * s + add, s
 
-    s_end, s_before = jax.lax.scan(carry, s0, (added, block_decay))
+    s_end, s_before = jax.lax.scan(
+        carry, s0.reshape(n, h, p).transpose(1, 2, 0), (added, block_decay))
     y = y + (jnp.einsum("ctn,chpn->cthp", cc, s_before)
              * jnp.exp(cum)[..., None])
-    return y.reshape(t, h, p), s_end
+    return y.reshape(t, di), s_end.transpose(2, 0, 1).reshape(n, di)
 
 
 def mamba2_chunk(p: Mamba2Params, u, state, layer, m: Mamba2Dims, *,
@@ -218,11 +230,11 @@ def mamba2_chunk(p: Mamba2Params, u, state, layer, m: Mamba2Dims, *,
     real = jnp.arange(n_tok, dtype=jnp.int32) < n_real
     delta = jnp.where(real[:, None], _delta(p, dt), 0.0)
     s0 = jax.lax.dynamic_slice(
-        ssm, (layer, slot, 0, 0, 0), (1, 1, *ssm.shape[2:]))[0, 0]
+        ssm, (layer, slot, 0, 0), (1, 1, *ssm.shape[2:]))[0, 0]  # [N, di]
     y, s_end = ssd_chunked(
         x, delta, -jnp.exp(p.a_log), b, c,
         jnp.where(fresh, jnp.zeros_like(s0), s0), block)
     ssm = jax.lax.dynamic_update_slice(
-        ssm, s_end[None, None], (layer, slot, 0, 0, 0))
-    y = y + p.d_skip[None, :, None] * x
+        ssm, s_end[None, None], (layer, slot, 0, 0))
+    y = y + jnp.repeat(p.d_skip, m.head_dim) * x
     return _gated_out(p, y, z, m, u.dtype), (ssm, conv)

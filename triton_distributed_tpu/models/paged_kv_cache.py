@@ -44,8 +44,10 @@ class PagedKVCache:
     # The OTHER kind of state, for a model whose layer list declares
     # recurrent layers (``cfg.slot_keeps`` holds "recurrent_state";
     # ``None`` everywhere else keeps the tree leaf for leaf): a state
-    # that is not pages. ``ssm_state [Lm, B, H, P, N]`` float32 is one
-    # Mamba-2 layer's state a slot, whatever the slot's context (``None``
+    # that is not pages. ``ssm_state [Lm, B, N, di]`` float32 is one
+    # Mamba-2 layer's state a slot, transposed (``N`` on sublanes, the
+    # ``di = H x P`` inner channels on lanes: layers/mamba2.py), whatever
+    # the slot's context (``None``
     # for a model of short convolutions, which keeps none), and
     # ``conv_state [Lr, taps - 1, B, C]`` the last inputs of a recurrent
     # layer's causal convolution (slots before channels: the layout XLA
@@ -321,7 +323,8 @@ def init_paged_cache(
 
 def recurrent_state_shapes(cfg: ModelConfig, slots: int) -> tuple:
     """``(ssm_state, conv_state)`` shapes for ``slots`` decode slots: of
-    a Mamba-2 layer a state ``[H, P, N]`` and its convolution's last
+    a Mamba-2 layer a state ``[N, di]`` (transposed: layers/mamba2.py)
+    and its convolution's last
     ``taps - 1`` inputs (``x | B | C`` channels) a slot; of a short
     convolution (LFM2) no state (``None``) and its last ``taps - 1``
     gated inputs (``hidden_size`` channels)."""
@@ -332,7 +335,7 @@ def recurrent_state_shapes(cfg: ModelConfig, slots: int) -> tuple:
 
     m = Mamba2Dims.of(cfg)
     return (
-        (cfg.mamba_layers, slots, m.heads, m.head_dim, m.state),
+        (cfg.mamba_layers, slots, m.state, m.inner),
         (cfg.mamba_layers, m.taps - 1, slots, m.conv_dim),
     )
 
