@@ -217,8 +217,10 @@ def test_paged_flash_decode_walk(rng, case, dtype, pool_form, return_lse):
 def test_paged_flash_decode_hoisted_walk(rng):
     """``walk=`` (the served step derives it once, outside its layer
     scan) is the walk the call would derive itself, bit for bit; one
-    built for another batch or table is refused by its shape."""
+    built for another batch or table, or the walk of one page a grid
+    step, is refused by its shape."""
     from triton_distributed_tpu.ops.attention import (
+        dense_decode_walk,
         paged_decode_walk,
         paged_flash_decode,
     )
@@ -233,12 +235,159 @@ def test_paged_flash_decode_hoisted_walk(rng):
     want = paged_flash_decode(q, k_pool, v_pool, table, lens)
     got = paged_flash_decode(
         q, k_pool, v_pool, table, lens,
-        walk=paged_decode_walk(lens, page, pps))
+        walk=dense_decode_walk(lens, page, pps))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    with pytest.raises(ValueError, match="paged_decode_walk"):
+    with pytest.raises(ValueError, match="dense_decode_walk"):
         paged_flash_decode(
             q, k_pool, v_pool, table, lens,
-            walk=paged_decode_walk(lens[:2], page, pps))
+            walk=dense_decode_walk(lens[:2], page, pps))
+    with pytest.raises(ValueError, match="a group of 4 pages a grid step"):
+        paged_flash_decode(
+            q, k_pool, v_pool, table, lens,
+            walk=paged_decode_walk(lens, page, pps))
+
+
+@pytest.fixture
+def group_of_4(monkeypatch):
+    """The cases below are named for a group of 4 pages a grid step,
+    whatever the module's constant."""
+    import sys
+
+    monkeypatch.setattr(
+        sys.modules["triton_distributed_tpu.ops.attention.flash_decode"],
+        "GROUP_PAGES", 4)
+
+
+# Lengths over tables of 16-token pages, at a group of 4 pages a grid
+# step: what the grouped walk has to get right at a group's edges.
+_GROUP_CASES = {
+    # (pages a table, lengths)
+    "pps_not_a_multiple": (6, [6 * 16, 70, 5]),  # 2 groups, the last of 2
+    "pps_below_the_group": (3, [3 * 16, 17, 1]),  # one group of 3 entries
+    "ends_mid_group": (8, [40, 100, 7]),
+    "ends_on_a_group_boundary": (8, [64, 8 * 16, 64]),
+    "ends_on_the_last_entry": (8, [8 * 16, 8 * 16 - 1, 33]),
+    "empty_row_among_live": (8, [50, 0, 90]),
+}
+
+
+@pytest.mark.parametrize("pool_form", ["one_layer", "layer_of_pool"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_GROUP_CASES))
+def test_paged_flash_decode_groups(rng, group_of_4, case, dtype, pool_form):
+    """A grid step takes up to four consecutive table entries of a slot
+    and every KV head at once: against the plain formula (and its LSE)
+    wherever a length ends inside a group, on its edge or on the table's
+    last entry, over a table shorter than a group or not a multiple of
+    one; the pages past a length (NaN here) are never fetched, and an
+    empty row reads zeros."""
+    from triton_distributed_tpu.ops.attention import paged_flash_decode
+    from triton_distributed_tpu.ops.attention.flash_decode import (
+        pages_to_dense,
+    )
+
+    n_layers, layer = 2, 1
+    b, hq, hkv, d, page = 3, 8, 2, 64, 16
+    pps, lens = _GROUP_CASES[case]
+    p = b * pps + 1
+    table = 1 + rng.permutation(p - 1).reshape(b, pps)
+    k_pool = rng.standard_normal((n_layers, p, hkv, page, d))
+    v_pool = rng.standard_normal((n_layers, p, hkv, page, d))
+    live = {int(table[i, c]) for i, n in enumerate(lens)
+            for c in range(-(-n // page))}
+    dead = [i for i in range(p) if i not in live]
+    k_pool[:, dead] = v_pool[:, dead] = np.nan
+    k_pool, v_pool = (jnp.asarray(a, dtype) for a in (k_pool, v_pool))
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+    if pool_form == "layer_of_pool":
+        out, lse = jax.jit(lambda lyr: paged_flash_decode(
+            q, k_pool, v_pool, table, lens, layer=lyr, return_lse=True,
+        ))(jnp.asarray(layer, jnp.int32))
+    else:
+        out, lse = jax.jit(lambda: paged_flash_decode(
+            q, k_pool[layer], v_pool[layer], table, lens, return_lse=True))()
+    k_d, v_d = (jnp.nan_to_num(pages_to_dense(a[layer], table))
+                for a in (k_pool, v_pool))
+    gold, gold_lse = gqa_decode_reference(q, k_d, v_d, lens, return_lse=True)
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    rows = np.asarray(lens) > 0
+    tol = 2e-5 if dtype == "float32" else 2e-2  # bf16: P rounds for P.V
+    np.testing.assert_allclose(
+        out[rows], np.asarray(gold, np.float32)[rows], atol=tol, rtol=tol)
+    np.testing.assert_allclose(
+        np.asarray(lse)[rows], np.asarray(gold_lse)[rows],
+        atol=2e-5, rtol=2e-5)
+    assert not out[~rows].any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_flash_decode_head_dim_64_on_padded_rows(rng, dtype):
+    """A model of 64-wide heads keeps its pool rows padded to 128 lanes
+    (``ModelConfig.pool_row_dim``), its queries padded alike and its own
+    scale: the grouped kernel over the padded rows is the plain formula
+    at 64, and the padding's columns read zero."""
+    from triton_distributed_tpu.ops.attention import paged_flash_decode
+    from triton_distributed_tpu.ops.attention.flash_decode import (
+        pages_to_dense,
+    )
+
+    b, hq, hkv, hd, page, pps = 3, 8, 2, 64, 16, 6
+    p = b * pps + 1
+    table = jnp.asarray(1 + rng.permutation(p - 1).reshape(b, pps), jnp.int32)
+    lens = jnp.asarray([90, 1, 33], jnp.int32)
+    k, v = (rng.standard_normal((p, hkv, page, hd)) for _ in range(2))
+    q = rng.standard_normal((b, hq, hd))
+    pad = [(0, 0)] * 3 + [(0, 128 - hd)]
+    k_pad, v_pad = (jnp.asarray(np.pad(a, pad), dtype) for a in (k, v))
+    q_pad = jnp.asarray(np.pad(q, pad[1:]), dtype)
+    out = np.asarray(jax.jit(lambda: paged_flash_decode(
+        q_pad, k_pad, v_pad, table, lens, sm_scale=1 / 64))(), np.float32)
+    gold = gqa_decode_reference(
+        jnp.asarray(q, dtype),
+        pages_to_dense(jnp.asarray(k, dtype), table),
+        pages_to_dense(jnp.asarray(v, dtype), table), lens, sm_scale=1 / 64)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(out[..., :hd], np.asarray(gold, np.float32),
+                               atol=tol, rtol=tol)
+    assert not out[..., hd:].any()
+
+
+@pytest.mark.parametrize("pps", [32, 30, 3])
+def test_the_dense_walk_is_the_walk_over_groups(group_of_4, pps):
+    """The kernel's grid is :func:`paged_decode_walk` with a group of
+    pages as its page: one step a group of a slot's live pages, one for
+    an empty slot; the host's count of it is the same."""
+    from triton_distributed_tpu.ops.attention import (
+        dense_decode_walk,
+        paged_decode_walk,
+    )
+    from triton_distributed_tpu.ops.attention.flash_decode import (
+        decode_group,
+        dense_decode_counts,
+    )
+
+    page, g = 128, decode_group(pps)
+    assert g == min(4, pps)
+    kv_len = np.minimum([0, 1, 128, 129, 512, 513, 2466, pps * page],
+                        pps * page)
+    pages = np.clip(-(-kv_len // page), 1, pps)
+    groups = np.maximum(-(-(-(-kv_len // page)) // g), 1)
+    slot, group_of, steps = dense_decode_walk(
+        jnp.asarray(kv_len, jnp.int32), page, pps)
+    assert slot.shape == (len(kv_len) * -(-pps // g),)
+    assert int(steps) == groups.sum() == int(paged_decode_walk(
+        jnp.asarray(kv_len, jnp.int32), g * page, -(-pps // g))[2])
+    n = int(steps)
+    assert np.asarray(slot)[:n].tolist() == np.repeat(
+        np.arange(len(kv_len)), groups).tolist()
+    assert np.asarray(group_of)[:n].tolist() == [
+        j for k in groups for j in range(k)]
+    assert dense_decode_counts(kv_len, page, pps) == (
+        pages.sum(), groups.sum())
+    assert pages.sum() == int(paged_decode_walk(
+        jnp.asarray(kv_len, jnp.int32), page, pps)[2])
 
 
 @pytest.mark.parametrize("lens,page,pps,steps", [

@@ -97,18 +97,21 @@ def compile_for_chip(fn, *args) -> str:
     ("int8", "layer_of_pool", 8, HKV),
     ("bf16", "layer_of_pool", 8, HKV // 4),  # one shard of --tp 4
     ("int8", "layer_of_pool", 8, HKV // 4),
+    ("bf16", "layer_of_hybrid_pool", 32, HKV),  # granite's 4 layers
 ])
 def test_paged_flash_decode(chip, kv, pool_form, slots, hkv):
     """``layer_of_pool``: the served form — the whole 36-layer pool and
     a traced layer index, which rides into the kernel inside the page
     table (``table + layer * P`` over the ``[L * P, ...]`` view). One
-    kernel at every shape: its blocks follow what it sees in its
-    operands (``(1, Hkv, page, d)``: all of a page's heads)."""
+    kernel at every shape: its copies follow what it sees in its
+    operands (``(Hkv, page, d)``: all of a page's heads, a group of
+    pages a grid step in a two-deep VMEM buffer)."""
     from triton_distributed_tpu.ops.attention.flash_decode import (
         paged_flash_decode,
     )
 
-    lead = (36,) if pool_form == "layer_of_pool" else ()
+    lead = {"one_layer": (), "layer_of_pool": (36,),
+            "layer_of_hybrid_pool": (4,)}[pool_form]
     kv_dtype = BF16 if kv == "bf16" else jnp.int8
     pool_shape = (*lead, slots * PPS + 1, hkv, PAGE, D)
     pages = sds(chip, pool_shape, kv_dtype)

@@ -316,6 +316,56 @@ def test_rows_total_is_the_decoded_tokens_and_ends_cost_only_ended_rows(
     assert st["kv_bytes_per_token"] == 2 * 2 * 4 * 16 * 4
 
 
+def test_the_dense_walk_is_counted_over_the_attention_layers(
+        served, fresh_telemetry, monkeypatch):
+    """``tdt_paged_decode_{pages,grid_steps}_total`` count what
+    ``tdt_flash_decode_paged`` walked in each launched step over the
+    lengths the step was given, in the two attention layers only, a
+    prompt admitted in chunks between steps and rows that end early
+    included; several pages a grid step over a table of 16 pages."""
+    from triton_distributed_tpu.obs import metrics as obs_metrics
+    from triton_distributed_tpu.ops.attention import (
+        dense_decode_walk,
+        paged_decode_walk,
+    )
+
+    model, _ = served
+    rng = np.random.default_rng(10)
+    reqs = [(rng.integers(0, 256, n).tolist(), g)
+            for n, g in ((9, 6), (13, 3), (44, 4))]
+    alone = [_alone(model, r) for r in reqs]
+
+    def totals():
+        snap = obs_metrics.default_registry().snapshot()
+        return tuple(snap[f"tdt_paged_decode_{n}_total"]["series"][0]["value"]
+                     for n in ("pages", "grid_steps"))
+
+    before = totals()
+    lens = []
+    step = model.decode_step_counted
+
+    def seen(tokens, cache, mode="xla"):
+        lens.append(np.asarray(cache.kv_len) + 1)
+        return step(tokens, cache, mode)
+
+    monkeypatch.setattr(model, "decode_step_counted", seen)
+    outs, eng = _serve(model, reqs, prefill_chunk=16)
+    assert outs == alone
+    st = eng.last_stats
+    assert len(lens) == st["decode_steps"] > 0
+    page, pps, layers = PAGE, eng.pps, eng.cache.k_pages.shape[0]
+    assert layers == 2  # the pool holds the attention layers only
+    pages = layers * sum(int(paged_decode_walk(n, page, pps)[2])
+                         for n in lens)
+    grid = layers * sum(int(dense_decode_walk(n, page, pps)[2])
+                        for n in lens)
+    assert (st["paged_decode_pages"], st["paged_decode_grid_steps"]) == (
+        pages, grid)
+    assert grid < pages
+    after = totals()
+    assert (after[0] - before[0], after[1] - before[1]) == (pages, grid)
+
+
 def test_a_slot_readmitted_the_moment_it_ends_serves_as_alone(served):
     """Six requests through three slots: a request ends (under a step in
     flight or not), its slot is readmitted at once, and every request
@@ -446,7 +496,7 @@ def test_head_dim_64_through_both_attention_kernels(kernel):
         tp_attn_decode_paged,
         tp_attn_prefill_paged_chunk,
     )
-    from triton_distributed_tpu.ops.attention import paged_decode_walk
+    from triton_distributed_tpu.ops.attention import dense_decode_walk
 
     hq, hkv, hd, d, page = 4, 2, 64, 32, 16
     dims = TPAttnDims(hq_loc=hq, hkv_loc=hkv, head_dim=hd, rope_theta=None,
@@ -471,7 +521,7 @@ def test_head_dim_64_through_both_attention_kernels(kernel):
             kv_len = jnp.asarray([n], jnp.int32)
             return tp_attn_decode_paged(
                 p, x, kp, vp, jnp.int32(0), table, kv_len, dims,
-                walk=paged_decode_walk(kv_len + 1, page, 4), mode="xla_ar",
+                walk=dense_decode_walk(kv_len + 1, page, 4), mode="xla_ar",
                 ctx=ctx)
 
         pad = jnp.zeros((32 - n, d), jnp.float32)

@@ -182,6 +182,79 @@ def test_paged_flash_decode_int8_layer_addressed(rng, layer):
         )
 
 
+# (pages a table, lengths) at 16-token pages and a group of 4 pages a
+# grid step: every page of a group reads ITS scales over its columns.
+_INT8_GROUP_CASES = {
+    "pps_not_a_multiple": (6, [6 * 16, 70]),
+    "pps_below_the_group": (3, [3 * 16, 17]),
+    "ends_mid_group": (8, [40, 100]),
+    "ends_on_a_group_boundary": (8, [64, 8 * 16]),
+    "empty_row_among_live": (8, [0, 90]),
+}
+
+
+@pytest.mark.parametrize("q_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("pool_form", ["one_layer", "layer_of_pool"])
+@pytest.mark.parametrize("case", list(_INT8_GROUP_CASES))
+def test_paged_flash_decode_int8_groups(rng, monkeypatch, case, pool_form,
+                                        q_dtype):
+    """The grouped kernel over int8 codes: each page's per-head scales
+    multiply its own columns of the scores and of P, the LSE included,
+    against the plain formula over the dequantized view; the table
+    entries past a group's live pages are never fetched (their scales
+    are not even read: the pages are NaN-free codes, the scales NaN),
+    and an empty row reads zeros."""
+    import sys
+
+    from triton_distributed_tpu.ops.attention.flash_decode import (
+        pages_to_dense,
+    )
+
+    # The cases are named for a group of 4 pages a grid step.
+    monkeypatch.setattr(
+        sys.modules["triton_distributed_tpu.ops.attention.flash_decode"],
+        "GROUP_PAGES", 4)
+    n_layers, layer = 2, 1
+    b, hq, hkv, page, d = 2, 8, 2, 16, 32
+    pps, lens = _INT8_GROUP_CASES[case]
+    p = b * pps + 1
+    table = 1 + rng.permutation(p - 1).reshape(b, pps)
+    pools = [_random_pool(rng, p, hkv, page, d) for _ in range(n_layers)]
+    k_q, k_sc = quantize_pages(jnp.stack([k for k, _ in pools]))
+    v_q, v_sc = quantize_pages(jnp.stack([v for _, v in pools]))
+    live = {int(table[i, c]) for i, n in enumerate(lens)
+            for c in range(-(-n // page))}
+    dead = np.asarray([i not in live for i in range(p)])
+    k_sc, v_sc = (jnp.where(dead[None, :, None], jnp.nan, sc)
+                  for sc in (k_sc, v_sc))
+    q = jnp.asarray(rng.standard_normal((b, hq, d)), q_dtype)
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+    if pool_form == "layer_of_pool":
+        out, lse = jax.jit(lambda lyr: paged_flash_decode(
+            q, k_q, v_q, table, lens, layer=lyr, k_scale=k_sc,
+            v_scale=v_sc, return_lse=True))(jnp.asarray(layer, jnp.int32))
+    else:
+        out, lse = jax.jit(lambda: paged_flash_decode(
+            q, k_q[layer], v_q[layer], table, lens, k_scale=k_sc[layer],
+            v_scale=v_sc[layer], return_lse=True))()
+    k_deq, v_deq = (
+        jnp.nan_to_num(pages_to_dense(dequantize_page(c[layer], sc[layer]),
+                                      table))
+        for c, sc in ((k_q, k_sc), (v_q, v_sc)))
+    gold, gold_lse = gqa_decode_reference(
+        q.astype(jnp.float32), k_deq, v_deq, lens, return_lse=True)
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    rows = np.asarray(lens) > 0
+    tol = 2e-4 if q_dtype == "float32" else 2 ** -7  # the output's rounding
+    np.testing.assert_allclose(
+        out[rows], np.asarray(gold)[rows], atol=tol, rtol=tol)
+    np.testing.assert_allclose(
+        np.asarray(lse)[rows], np.asarray(gold_lse)[rows],
+        atol=2e-4, rtol=2e-4)
+    assert not out[~rows].any()
+
+
 @pytest.mark.parametrize("writer", ["append", "chunk"])
 def test_in_place_writers_int8_match_one_layer_scatter(rng, writer):
     """The serving programs' in-place writers on the WHOLE int8 pool

@@ -535,6 +535,76 @@ def test_schedule_histograms_hold_their_identities(own_model, fresh_telemetry,
     assert gaps == want
 
 
+def _walked(lens: list, page: int, pps: int, layers: int) -> tuple:
+    """``(pages, grid steps)`` the dense paged kernel walked over the
+    lengths each decode step was given, from the device walks."""
+    from triton_distributed_tpu.ops.attention import (
+        dense_decode_walk,
+        paged_decode_walk,
+    )
+
+    return (layers * sum(int(paged_decode_walk(n, page, pps)[2])
+                         for n in lens),
+            layers * sum(int(dense_decode_walk(n, page, pps)[2])
+                         for n in lens))
+
+
+@pytest.mark.parametrize("case", [_one_greedy, _queue_longer_than_the_slots,
+                                  _sampled_row, _chunked_admission])
+def test_the_dense_walk_is_counted_from_the_lengths_each_step_was_given(
+        own_model, fresh_telemetry, monkeypatch, case):
+    """``tdt_paged_decode_{pages,grid_steps}_total`` are the host's count
+    of what ``tdt_flash_decode_paged`` walked in every launched step, an
+    idle row's pages included, over the model's layers: the walks of the
+    lengths the device's cache held, whether the step was looked ahead
+    to, launched between two chunks of an admission or after a slot
+    ended."""
+    lens = []
+    step = own_model.decode_step
+
+    def seen(tokens, cache, mode="xla"):
+        lens.append(np.asarray(cache.kv_len) + 1)
+        return step(tokens, cache, mode)
+
+    monkeypatch.setattr(own_model, "decode_step", seen)
+    eng, reqs, _ = case(own_model)
+    _, stats = run(eng, reqs)
+    assert len(lens) == stats["decode_steps"] > 0
+    want = _walked(lens, eng.page_size, eng.pps, own_model.cfg.num_layers)
+    assert (stats["paged_decode_pages"],
+            stats["paged_decode_grid_steps"]) == want
+    snap = obs_metrics.default_registry().snapshot()
+    assert (snap["tdt_paged_decode_pages_total"]["series"][0]["value"],
+            snap["tdt_paged_decode_grid_steps_total"]["series"][0]["value"]
+            ) == want
+
+
+def test_the_dense_walk_counters_ride_the_stats_and_metrics_verbs(
+        own_model, fresh_telemetry):
+    """Over a table of 8 pages (a group of 4) a row of 40 tokens walks 3
+    pages in ONE grid step a layer: pages over grid steps reads above 1
+    in the ``stats`` and ``metrics`` verbs; a megakernel step, which
+    runs no such kernel, counts none."""
+    from triton_distributed_tpu.serving.server import ModelServer, request
+
+    eng = engine(own_model, max_length=128)
+    _, stats = run(eng, [(prompt(1, 41), 20)])
+    assert stats["paged_decode_pages"] > stats["paged_decode_grid_steps"]
+    server = ModelServer(eng).start()
+    try:
+        m = request(server.host, server.port, {"cmd": "metrics"})
+        for name in ("tdt_paged_decode_pages_total",
+                     "tdt_paged_decode_grid_steps_total"):
+            assert name in m["prometheus"]
+        s = request(server.host, server.port, {"cmd": "stats"})
+        for key in ("paged_decode_pages", "paged_decode_grid_steps"):
+            assert s["stats"][key] == stats[key]
+    finally:
+        request(server.host, server.port, {"cmd": "shutdown"})
+        server.shutdown()
+    assert engine(own_model, mode="mega")._walk_layers == 0
+
+
 def test_a_rows_gap_is_its_own(own_model, fresh_telemetry):
     """A row admitted a moment ago is not charged the running row's
     whole period: its first gap starts at its own first token."""

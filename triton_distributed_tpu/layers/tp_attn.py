@@ -436,7 +436,7 @@ def tp_attn_decode_paged(
     kv_len: jax.Array,      # [B] int32
     dims: TPAttnDims,
     *,
-    walk,  # paged_decode_walk(kv_len + 1, page, pages_per_seq)
+    walk,  # dense_decode_walk(kv_len + 1, page, pages_per_seq)
     axis: str = "tp",
     mode: Mode = "pallas_ar",
     ctx: DistContext | None = None,

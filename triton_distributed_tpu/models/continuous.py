@@ -83,6 +83,9 @@ from triton_distributed_tpu.models.prefix_cache import (
     round_chunk,
 )
 from triton_distributed_tpu.models.qwen import Mode, Qwen3
+from triton_distributed_tpu.ops.attention.flash_decode import (
+    dense_decode_counts,
+)
 from triton_distributed_tpu.runtime.faults import (
     FaultError,
     active_plan,
@@ -578,6 +581,16 @@ class ContinuousEngine(MegaDispatch):
         self._capacity = len(self.pool.free)
         self._table = np.zeros((max_batch, self.pps), np.int32)
         self._kv_len = np.zeros((max_batch,), np.int32)
+        # The lengths the device's cache holds, as far as a launched
+        # step reads them: the host's at every upload, every row one
+        # further a step (an idle row too, which ``_kv_len`` leaves).
+        self._dev_len = self._kv_len.copy()
+        # The attention layers that run ``tdt_flash_decode_paged`` in a
+        # launched step (none for a latent pool or a megakernel step):
+        # its walk is counted from ``_dev_len``.
+        self._walk_layers = (
+            self.cache.k_pages.shape[0]
+            if "kv_pages" in model.cfg.slot_keeps and mode != "mega" else 0)
         self._tok = np.zeros((max_batch,), np.int32)
         self._slots: list[Request | None] = [None] * max_batch
         self.prefix = PrefixCache(
@@ -866,6 +879,11 @@ class ContinuousEngine(MegaDispatch):
             # took (``tdt_mla_decode_paged``: several pages a step).
             "mla_decode_grid_steps": 0,
             "mla_decode_pages": 0,
+            # The dense paged kernel's grid steps and the live pages
+            # they took (``tdt_flash_decode_paged``: several pages a
+            # step), over the attention layers; counted on the host.
+            "paged_decode_grid_steps": 0,
+            "paged_decode_pages": 0,
             # Rows whose recurrent state a decode step advanced (a
             # model with recurrent layers only; docs/serving.md
             # "Recurrent state beside pages").
@@ -994,6 +1012,7 @@ class ContinuousEngine(MegaDispatch):
             kv_len=put(self._kv_len.copy()),
             **state,
         )
+        self._dev_len = self._kv_len.copy()
 
     def _admit(
         self, req: Request, slot: int, m: PrefixMatch | None = None
@@ -1181,7 +1200,7 @@ class ContinuousEngine(MegaDispatch):
             # host and device agree even after interleaved decode steps
             # bumped the in-flight slot's device counter.
             self.cache = cache
-            self._kv_len[slot] = new_len
+            self._kv_len[slot] = self._dev_len[slot] = new_len
             self._admit_t = self._gap_clock()  # a chunk stood in the gap
             if self._step_guard(self._decode_once):
                 # An interleaved decode finished (or failed) a request:
@@ -1195,7 +1214,7 @@ class ContinuousEngine(MegaDispatch):
             self.model, self.cache, slot, prompt, start,
             self.prefill_chunk, self._prefill_mode, between_chunks,
         )
-        self._kv_len[slot] = len(prompt)
+        self._kv_len[slot] = self._dev_len[slot] = len(prompt)
         self._bump("prefill_tokens", len(prompt) - start)
         self._bump("prefill_chunks", chunks)
         if self._moe_k:
@@ -1318,8 +1337,14 @@ class ContinuousEngine(MegaDispatch):
         # ``_sync_tables`` (an in-place add between an async dispatch
         # and its first fetch raced the device's kv_len read).
         self._kv_len = self._kv_len + active
+        self._dev_len = self._dev_len + 1
         self._bump("decode_steps")
         self._m_step_rows.observe(n_active)
+        if self._walk_layers:
+            pages, grid = dense_decode_counts(
+                self._dev_len, self.page_size, self.pps)
+            self._bump("paged_decode_pages", self._walk_layers * pages)
+            self._bump("paged_decode_grid_steps", self._walk_layers * grid)
         if self._moe_k:
             self._bump("moe_routed_tokens", n_active * self._moe_k)
         # One device program computes the finite mask AND the greedy

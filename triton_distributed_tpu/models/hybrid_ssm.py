@@ -170,9 +170,9 @@ class RecurrentPagedStep(CountedPagedStep):
         """One decode step, per shard: ``(logits, cache, counts)``.
         Every row is computed; only the rows ``cache.live`` marks (the
         slots the engine has in flight) move their recurrent state."""
-        from triton_distributed_tpu.ops.attention import paged_decode_walk
+        from triton_distributed_tpu.ops.attention import dense_decode_walk
 
-        walk = paged_decode_walk(
+        walk = dense_decode_walk(
             cache.kv_len + 1, cache.k_pages.shape[3],
             cache.page_table.shape[1],
         )

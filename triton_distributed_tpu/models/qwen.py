@@ -377,12 +377,12 @@ class Qwen3:
         paged decode (``mega_triton_kernel/models/paged_kv_cache.py``).
         """
         from triton_distributed_tpu.models.paged_kv_cache import PagedKVCache
-        from triton_distributed_tpu.ops.attention import paged_decode_walk
+        from triton_distributed_tpu.ops.attention import dense_decode_walk
 
-        # The attention kernel's grid (its live (slot, page) pairs, each
+        # The attention kernel's grid (its live (slot, group) pairs, each
         # row's appended token included) follows kv_len alone: derived
         # here, once a step, not in each of the scan's layers.
-        walk = paged_decode_walk(
+        walk = dense_decode_walk(
             cache.kv_len + 1, cache.k_pages.shape[3],
             cache.page_table.shape[1],
         )

@@ -171,6 +171,18 @@ STAT_METRICS = {
     "mla_decode_pages": ("tdt_mla_decode_pages_total",
                          "Live pages of the decode steps' slots (at "
                          "least one a slot): what the grid steps took."),
+    # The dense paged decode kernel's walk (tdt_flash_decode_paged), as
+    # the host counts it from the lengths each launched step was given,
+    # summed over the layers that run it: pages over grid steps is how
+    # many pages a grid step takes on average.
+    "paged_decode_grid_steps": ("tdt_paged_decode_grid_steps_total",
+                                "Grid steps of tdt_flash_decode_paged, one "
+                                "a group of a slot's pages, over the "
+                                "attention layers of the decode steps."),
+    "paged_decode_pages": ("tdt_paged_decode_pages_total",
+                           "Live pages (at least one a slot) the grid "
+                           "steps of tdt_flash_decode_paged took, over "
+                           "the attention layers of the decode steps."),
     # A model with recurrent layers (docs/serving.md "Recurrent state
     # beside pages"): the int32 sum every decode step returns beside
     # its tokens, the rows IN FLIGHT whose state the step moved.

@@ -15,6 +15,7 @@ from triton_distributed_tpu.ops.attention.flash_decode import (  # noqa: F401
     gqa_decode_reference,
     distributed_flash_decode,
     distributed_flash_decode_2level,
+    dense_decode_walk,
     paged_decode_walk,
     paged_flash_decode,
 )

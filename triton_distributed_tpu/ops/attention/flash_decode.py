@@ -16,9 +16,9 @@ intra-chip over the chunk axis, and for the distributed form across the
 collective or our Pallas ring — the device-initiated putmem analog).
 
 The PAGED form (:func:`paged_flash_decode`, the served decode step's
-kernel) is a kernel of its own: one grid step per live (sequence, page)
-pair with every KV head of the page in one block, and the merge over a
-sequence's pages done online inside the kernel.
+kernel) is a kernel of its own: one grid step per live (sequence, group
+of pages) pair with every KV head of the group at once, and the merge
+over a sequence's groups done online inside the kernel.
 """
 
 from __future__ import annotations
@@ -267,81 +267,180 @@ def paged_decode_walk(kv_len: jax.Array, page: int, pps: int):
     return slot, page_of, ends[-1]
 
 
+# Table entries one grid step of :func:`paged_flash_decode` takes, chosen
+# by a sweep of 2, 4 and 8 on the chip (PERF.md, section 6). A shorter
+# table is taken whole.
+GROUP_PAGES = 4
+
+
+def decode_group(pps: int) -> int:
+    """Pages of a slot one grid step of :func:`paged_flash_decode` takes."""
+    return min(GROUP_PAGES, pps)
+
+
+def dense_decode_walk(kv_len: jax.Array, page: int, pps: int):
+    """The grid of :func:`paged_flash_decode`: :func:`paged_decode_walk`
+    with a group of :func:`decode_group` table entries as its "page".
+    ``(slot, group, steps)``; a caller that runs the kernel once a layer
+    derives it once a step and hands it in as ``walk=``."""
+    g = decode_group(pps)
+    return paged_decode_walk(kv_len, g * page, pl.cdiv(pps, g))
+
+
+def dense_decode_counts(kv_len, page: int, pps: int) -> tuple[int, int]:
+    """``(pages, grid steps)`` of one call's walk over the host's lengths
+    ``kv_len``: each slot's live pages, at least one (what a kernel of
+    one page a grid step would walk), and its groups
+    (:func:`dense_decode_walk`'s ``steps``). Their ratio is the mean
+    pages a grid step takes."""
+    g = decode_group(pps)
+    lens = [int(n) for n in kv_len]
+    return (sum(min(max(-(-n // page), 1), pps) for n in lens),
+            sum(min(max(-(-n // (g * page)), 1), -(-pps // g)) for n in lens))
+
+
 def _paged_decode_kernel(
     kv_len_ref,  # [B] int32 SMEM (scalar prefetch)
-    table_ref,   # [B, pps] int32 SMEM — consumed by the index maps
-    slot_ref,    # [B * pps] int32 SMEM — the walk: sequence of step i
-    page_ref,    # [B * pps] int32 SMEM — ... and its table entry
+    table_ref,   # [B, pps] int32 SMEM: the pages the copies fetch
+    slot_ref,    # [B * groups] int32 SMEM: the walk: sequence of step i
+    group_ref,   # [B * groups] int32 SMEM: ... and its group of entries
     *refs,
     sm_scale: float,
+    group: int,
     quant: bool,
     return_lse: bool,
 ):
-    """One live (slot, page) pair: every KV head of the page against
-    that sequence's q heads, folded into the sequence's running softmax
-    (the pairs of a sequence are consecutive grid steps, so its
-    accumulators and output block stay resident in VMEM)."""
+    """One live (slot, group) pair: every KV head of the group's pages
+    against that sequence's q heads, folded into the sequence's running
+    softmax once (the pairs of a sequence are consecutive grid steps, so
+    its accumulators and output block stay resident in VMEM)."""
     if quant:
-        # [B * pps * Hkv] f32 SMEM — the table's pages' scales.
+        # [B * pps * Hkv] f32 SMEM: the table's pages' scales.
         ks_ref, vs_ref, *refs = refs
-    q_ref, k_ref, v_ref, o_ref, *refs = refs  # q/o [1, Hkv, group, d]
-    if return_lse:                            # k/v [1, Hkv, page, d]
-        lse_ref, *refs = refs                 # [1, Hkv, group, 1]
-    m_ref, l_ref, acc_ref = refs  # VMEM f32 [Hkv, group, 1 | 1 | d]
+    q_ref, k_hbm, v_hbm, o_ref, *refs = refs  # q/o [1, Hkv, gq, d]
+    if return_lse:                            # k/v [N, Hkv, page, d] HBM
+        lse_ref, *refs = refs                 # [1, Hkv, gq, 1]
+    # k/v_buf VMEM [2, Hkv, G * page, d]: a step's pages, two steps; sem
+    # DMA [2], one a buffer; m/l/acc VMEM f32 [Hkv, gq, 1 | 1 | d].
+    k_buf, v_buf, sem, m_ref, l_ref, acc_ref = refs
     i = pl.program_id(0)
-    b, ci = slot_ref[i], page_ref[i]
     pps = table_ref.shape[1]
-    hkv, page = k_ref.shape[1:3]
-    valid = kv_len_ref[b] - ci * page  # keys of this page that count
+    hkv, span = k_buf.shape[1:3]
+    page = span // group
 
-    @pl.when(ci == 0)
-    def _first_page():
+    def live(step):
+        """Sequence, group and the group's live pages of grid step
+        ``step``, and the group's keys that count."""
+        b, g = slot_ref[step], group_ref[step]
+        valid = kv_len_ref[b] - g * span
+        n = jnp.minimum((jnp.maximum(valid, 0) + page - 1) // page,
+                        jnp.minimum(group, pps - g * group))
+        return b, g, n, valid
+
+    def copy(step, buf, start: bool):
+        """Start (or wait for) the copies of step ``step``'s live pages,
+        all heads of each, into buffer ``buf``: a page past the length
+        is not fetched."""
+        b, g, n, _ = live(step)
+        for j in range(group):
+            @pl.when(j < n)
+            def _page():
+                pid = table_ref[b, g * group + j]
+                for src, dst in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    cp = pltpu.make_async_copy(
+                        src.at[pid], dst.at[buf, :, pl.ds(j * page, page)],
+                        sem.at[buf])
+                    if start:
+                        cp.start()
+                    else:
+                        cp.wait()
+
+    # The next step's pages are fetched while this one computes.
+    @pl.when(i == 0)
+    def _first_fetch():
+        copy(0, 0, True)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next_fetch():
+        copy(i + 1, (i + 1) % 2, True)
+
+    buf = i % 2
+    copy(i, buf, False)
+    b, g, n, valid = live(i)
+
+    @pl.when(g == 0)
+    def _first_group():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def col_scales(sc_ref, mult):
+        """``[Hkv, 1, G * page]``: each live page's per-head scale over
+        its columns, 0 over a page not fetched (whatever its scale
+        holds: its columns are masked and its values zeroed)."""
+        def scale(j, h):
+            si = (b * pps + jnp.minimum(g * group + j, pps - 1)) * hkv + h
+            return jnp.where(j < n, sc_ref[si] * mult, 0.0)
+
+        return jnp.concatenate([
+            jnp.concatenate([jnp.full((1, 1, page), scale(j, h), jnp.float32)
+                             for j in range(group)], axis=2)
+            for h in range(hkv)], axis=0)
+
     @pl.when(valid > 0)
     def _accumulate():
+        # The group's scores and values are ONE batched matmul each over
+        # its pages in VMEM, every head at once: a page not fetched (past
+        # the length) holds what an earlier step left, so its values are
+        # zeroed (p = 0 times a stale NaN would be NaN) and its columns
+        # masked.
+        for j in range(1, group):
+            @pl.when(j >= n)
+            def _zero():
+                v_buf[buf, :, pl.ds(j * page, page)] = jnp.zeros(
+                    (hkv, page, v_buf.shape[3]), v_buf.dtype)
+
         # The MXU takes the pool's own dtype (bf16 x bf16 products are
         # exact in the f32 accumulator; an f32 upcast buys nothing and
-        # costs a multi-pass matmul). int8 codes widen exactly for
-        # QK^T; their P.V keeps f32 operands (below).
-        dt = jnp.promote_types(q_ref.dtype, k_ref.dtype)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (q_ref.shape[2], page), 1)
-        for h in range(hkv):
-            # In-register dequant: the symmetric per-page-per-head scale
-            # is a scalar, so it folds into the softmax multiplier AFTER
-            # QK^T and into the accumulator AFTER P·V — full-width KV
-            # never exists anywhere (not even in VMEM).
-            si = (b * pps + ci) * hkv + h
-            s = jax.lax.dot_general(
-                q_ref[0, h].astype(dt), k_ref[0, h].astype(dt),
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            ) * (sm_scale * ks_ref[si] if quant else sm_scale)
-            s = jnp.where(cols < valid, s, _NEG_INF)  # [group, page]
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            if quant:
-                # P stays f32 against the widened codes, as in the dense
-                # body: with bf16 q, ``dt`` would round P to 8 bits.
-                pv = jnp.dot(
-                    p, v_ref[0, h].astype(jnp.float32),
-                    preferred_element_type=jnp.float32,
-                ) * vs_ref[si]
-            else:
-                pv = jnp.dot(
-                    p.astype(dt), v_ref[0, h].astype(dt),
-                    preferred_element_type=jnp.float32,
-                )
-            m_ref[h] = m_new
-            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[h] = alpha * acc_ref[h] + pv
+        # costs a multi-pass matmul). int8 codes widen exactly for QK^T;
+        # their P.V keeps f32 operands (below).
+        dt = jnp.promote_types(q_ref.dtype, k_buf.dtype)
+        s = jax.lax.dot_general(
+            q_ref[0].astype(dt), k_buf[buf].astype(dt),
+            (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32,
+        )  # [Hkv, gq, G * page]
+        # In-register dequant: the symmetric per-page-per-head scale
+        # multiplies its page's columns of the scores AFTER QK^T and of P
+        # BEFORE P.V: full-width KV never exists anywhere (not even in
+        # VMEM).
+        s = s * (col_scales(ks_ref, sm_scale) if quant else sm_scale)
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(cols < valid, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        if quant:
+            # P stays f32 against the widened codes, as in the dense
+            # body: with bf16 q, ``dt`` would round P to 8 bits.
+            pv = jax.lax.dot_general(
+                p * col_scales(vs_ref, 1.0), v_buf[buf].astype(jnp.float32),
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            pv = jax.lax.dot_general(
+                p.astype(dt), v_buf[buf].astype(dt),
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )  # [Hkv, gq, d]
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + pv
 
-    # The sequence's last live page (or its table's last entry).
-    @pl.when((valid <= page) | (ci == pps - 1))
-    def _last_page():
+    # The sequence's last live group (or its table's last).
+    @pl.when((valid <= span) | (g == pl.cdiv(pps, group) - 1))
+    def _last_group():
         l = jnp.maximum(l_ref[...], 1e-30)  # an empty row reads 0
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         if return_lse:
@@ -356,7 +455,7 @@ def paged_flash_decode(
     kv_len: jax.Array,      # [B] int32 — valid context length
     *,
     layer: jax.Array | int | None = None,  # which layer of a 5-D pool
-    walk=None,  # paged_decode_walk(kv_len, page, pages_per_seq), hoisted
+    walk=None,  # dense_decode_walk(kv_len, page, pages_per_seq), hoisted
     sm_scale: float | None = None,
     return_lse: bool = False,
     k_scale: jax.Array | None = None,  # [(L,) P, Hkv] f32 — per-page-per-head
@@ -368,29 +467,34 @@ def paged_flash_decode(
     Parity: the reference megakernel's paged decode
     (``mega_triton_kernel/models/paged_kv_cache.py:58`` + its attention
     task reading through the page table). TPU design: the grid is ONE
-    axis with a step per live (sequence, page) pair —
-    ``sum_b ceil(kv_len[b] / page)`` steps (:func:`paged_decode_walk`),
-    a dynamic bound — and each step carries every KV head of its page.
-    The walk and the page table ride as scalar-prefetch operands and the
-    BlockSpec index maps dereference them: step ``i`` fetches pool page
-    ``table[slot[i], page_of[i]]`` as one ``(1, Hkv, page, D)`` block (in
-    the pool's layout all heads of a page are one contiguous run: 256 KB
-    at Qwen3-4B), so no gather materializes, a short sequence costs its
-    own pages and not the longest one's, and nothing is fetched for a
-    table entry past a sequence's length. The body is a static loop over
-    the KV heads (the GQA group rides the sublanes, q block
-    ``[Hkv, group, D]``) feeding the MXU the pool's own dtype with f32
-    accumulation, the softmax in f32 and ONLINE across a sequence's
-    pages: its pairs are consecutive steps, so the running max, sum and
-    accumulator stay in VMEM and ``o [B, Hq, D]`` (and the LSE) leave
-    the kernel finished — no partials, no merge in XLA.
+    axis with a step per live (sequence, group) pair, a group being up
+    to :func:`decode_group` consecutive table entries of a sequence —
+    ``sum_b ceil(kv_len[b] / (G * page))`` steps
+    (:func:`dense_decode_walk`), a dynamic bound. The walk and the page
+    table ride as scalar-prefetch operands; the pool stays in HBM and a
+    step copies its group's live pages, every head of each (in the
+    pool's layout one contiguous run: 256 KB at Qwen3-4B), through the
+    table by hand into a two-deep VMEM buffer while the step before it
+    computes. So no gather materializes, a short sequence costs its own
+    pages and not the longest one's, and nothing is fetched for a table
+    entry past a sequence's length (its place in VMEM is zeroed and its
+    columns masked). The body runs in phases over the whole group: the
+    scores of every head against the group's ``G * page`` keys in ONE
+    batched matmul (the GQA group rides the sublanes, q block ``[Hkv,
+    group, D]``), one masked online-softmax update, the values in ONE
+    batched matmul, feeding the MXU the pool's own dtype with f32
+    accumulation. The softmax is f32 and ONLINE across a sequence's
+    groups: its pairs are consecutive steps, so the running max, sum and
+    accumulator stay in VMEM, read and written once a group, and ``o [B,
+    Hq, D]`` (and the LSE) leave the kernel finished — no partials, no
+    merge in XLA.
 
     With ``k_scale``/``v_scale`` (the pool's per-page-per-head int8
-    scales), the K/V blocks are int8 codes and each step reads its
-    page's scales (gathered through the SAME table indirection, SMEM
-    scalars), dequantizing in-register after QK^T / P·V — the decode
-    step streams HALF the bf16 pool's HBM bytes and full-width KV never
-    exists.
+    scales), the K/V pages are int8 codes and each step reads its
+    pages' scales (gathered through the SAME table indirection, SMEM
+    scalars), each applied to its page's columns of the scores after
+    QK^T and of P before P·V — the decode step streams HALF the bf16
+    pool's HBM bytes and full-width KV never exists.
 
     The pool is addressed in place by (layer, page): given the WHOLE
     ``[L, P, Hkv, page, D]`` pool (scales ``[L, P, Hkv]``) and ``layer``
@@ -426,7 +530,7 @@ def paged_flash_decode(
     p, hkv, page, _ = k_pages.shape
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
-    group = hq // hkv
+    gq = hq // hkv
     if sm_scale is None:
         sm_scale = d**-0.5
     pps = page_table.shape[1]
@@ -449,15 +553,18 @@ def paged_flash_decode(
             q, k_d, v_d, kv_len, sm_scale=sm_scale, return_lse=return_lse
         )
 
-    slot, page_of, steps = (
-        paged_decode_walk(kv_len, page, pps) if walk is None else walk
+    group = decode_group(pps)
+    slot, group_of, steps = (
+        dense_decode_walk(kv_len, page, pps) if walk is None else walk
     )
-    if slot.shape != (b * pps,) or page_of.shape != (b * pps,):
+    entries = b * pl.cdiv(pps, group)
+    if slot.shape != (entries,) or group_of.shape != (entries,):
         raise ValueError(
-            f"walk of {slot.shape} / {page_of.shape} entries is not "
-            f"paged_decode_walk's for {b} sequences of {pps} pages"
+            f"walk of {slot.shape} / {group_of.shape} entries is not "
+            f"dense_decode_walk's for {b} sequences of {pps} pages "
+            f"({entries} entries, a group of {group} pages a grid step)"
         )
-    scalars = [kv_len, page_table, slot, page_of]
+    scalars = [kv_len, page_table, slot, group_of]
     if quant:
         # Scales follow their pages through the table HERE, in XLA (a
         # [B, pps, Hkv] gather of f32), and reach the kernel as
@@ -468,38 +575,36 @@ def paged_flash_decode(
             for sc in (k_scale, v_scale)
         ]
 
-    def of_slot(i, _, tab, slot, page_of, *__):
+    def of_slot(i, _, tab, slot, group_of, *__):
         return slot[i], 0, 0, 0
 
-    def of_page(i, _, tab, slot, page_of, *__):
-        # The paged part: the pair's block is pool page
-        # table[slot, page_of], all of its heads.
-        return tab[slot[i], page_of[i]], 0, 0, 0
-
-    out_specs = [pl.BlockSpec((1, hkv, group, d), of_slot)]
-    out_shape = [jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype)]
+    out_specs = [pl.BlockSpec((1, hkv, gq, d), of_slot)]
+    out_shape = [jax.ShapeDtypeStruct((b, hkv, gq, d), q.dtype)]
     if return_lse:
-        out_specs.append(pl.BlockSpec((1, hkv, group, 1), of_slot))
-        out_shape.append(jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, hkv, gq, 1), of_slot))
+        out_shape.append(jax.ShapeDtypeStruct((b, hkv, gq, 1), jnp.float32))
     out = pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel,
-            sm_scale=sm_scale, quant=quant, return_lse=return_lse,
+            _paged_decode_kernel, sm_scale=sm_scale, group=group,
+            quant=quant, return_lse=return_lse,
         ),
         name="tdt_flash_decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(steps,),
             in_specs=[
-                pl.BlockSpec((1, hkv, group, d), of_slot),
-                pl.BlockSpec((1, hkv, page, d), of_page),
-                pl.BlockSpec((1, hkv, page, d), of_page),
+                pl.BlockSpec((1, hkv, gq, d), of_slot),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((hkv, group, 1), jnp.float32),
-                pltpu.VMEM((hkv, group, 1), jnp.float32),
-                pltpu.VMEM((hkv, group, d), jnp.float32),
+                pltpu.VMEM((2, hkv, group * page, d), k_pages.dtype),
+                pltpu.VMEM((2, hkv, group * page, d), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((hkv, gq, 1), jnp.float32),
+                pltpu.VMEM((hkv, gq, 1), jnp.float32),
+                pltpu.VMEM((hkv, gq, d), jnp.float32),
             ],
         ),
         out_shape=out_shape,
@@ -507,7 +612,7 @@ def paged_flash_decode(
             dimension_semantics=("arbitrary",),
         ),
         interpret=resolved,
-    )(*scalars, q.reshape(b, hkv, group, d), k_pages, v_pages)
+    )(*scalars, q.reshape(b, hkv, gq, d), k_pages, v_pages)
     o = out[0].reshape(b, hq, d)
     if return_lse:
         return o, out[1].reshape(b, hq)

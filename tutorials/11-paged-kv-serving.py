@@ -5,13 +5,13 @@ page-pool cache with free-list allocation, consumed by its megakernel's
 attention task through a page table.
 
 TPU design: the pool is one array ``[L, P, Hkv, page, hd]``; the page
-table rides as a scalar-prefetch operand and ``paged_flash_decode``'s
-K/V BlockSpec index maps dereference it — the grid step of sequence
-``b``'s entry ``ci`` fetches pool page ``table[b, ci]``, all of its KV
-heads in one block, of the layer addressed (the model's
-layer scan carries the whole pool and hands the kernel ``layer=``), so
-attention reads the pool in place and NO dense gather or per-layer
-slice ever materializes. Three consumers share
+table rides as a scalar-prefetch operand and ``paged_flash_decode``
+dereferences it — the grid step of sequence ``b``'s group ``g`` copies
+pool pages ``table[b, 4g .. 4g + 3]`` up to its length, all of the KV
+heads of each, of the layer addressed (the model's layer scan carries
+the whole pool and hands the kernel ``layer=``), so attention reads
+the pool in place and NO dense gather or per-layer slice ever
+materializes. Three consumers share
 the design: the model decode step (``decode_step`` dispatches on cache
 type), ``Engine(paged=True)`` serving, and the megakernel (per-row page
 DMAs in its attention block loop).
